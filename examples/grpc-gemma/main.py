@@ -9,9 +9,12 @@ the checkpoint dir) for text in/out. Without GEMMA_CKPT the model is
 randomly initialized (this environment has no weight downloads) and the API
 still works on raw token ids — the serving path is identical.
 
-GEMMA_PRESET=tiny (default, CI/dev) | 2b | 7b | llama3-8b | tiny-llama
-chooses the architecture; llama presets load via the Llama checkpoint
-mapping (untied lm_head, silu, plain RMSNorm absorbed at load).
+GEMMA_PRESET=tiny (default, CI/dev) | 2b | 7b | llama3-8b | tiny-llama |
+mistral-7b | tiny-mistral | qwen2-7b chooses the architecture; llama,
+mistral and qwen2 presets load via the Llama checkpoint mapping (untied
+lm_head, silu, plain RMSNorm absorbed at load). With GEMMA_INT8=1 and no
+checkpoint the random tree is built int8 directly on the device: a 7B
+bf16 tree plus its int8 copy does not fit one 16 GB chip.
 
 Drive it:
   unary:  json_unary(target, "Gemma", "Generate", {"prompt": "...", "max_new_tokens": 8})
@@ -68,9 +71,9 @@ def _topology_kw(cfg) -> dict:
     (docs/advanced-guide/sharded-serving.md):
 
     - ``LLM_TP=K`` carves the device slice into K-chip tensor-parallel
-      submeshes — one replica per submesh (dp x tp serving). Unset with
-      >1 devices keeps the legacy default: ONE engine tensor-parallel
-      over the whole slice.
+      submeshes — one replica per submesh (dp x tp serving; K=1 is one
+      single-chip replica per device). Unset with >1 devices keeps the
+      legacy default: ONE engine tensor-parallel over the whole slice.
     - ``LLM_DISAGG=1`` splits the replicas into prefill/decode role
       pools with device-to-device KV handoff
       (``LLM_DISAGG_PREFILL_REPLICAS`` sizes the prefill pool; the
@@ -103,6 +106,8 @@ def _topology_kw(cfg) -> dict:
             kw["mesh"], kw["param_specs"] = meshes[0]
         else:
             kw["meshes"] = meshes
+    elif tp == 1 and n_dev > 1:
+        kw["replicas"] = n_dev
     elif n_dev > 1 and tp_env == "":
         from gofr_tpu.parallel import make_mesh, param_specs
 
@@ -129,8 +134,10 @@ def build_engine(app):
         # slot memory O(window) instead of O(LLM_MAX_SEQ)
         "mistral-7b": TransformerConfig.mistral_7b,
         "tiny-mistral": TransformerConfig.tiny_mistral,
+        "qwen2-7b": TransformerConfig.qwen2_7b,
     }[preset]()
-    is_llama = "llama" in preset or "mistral" in preset
+    is_llama = any(f in preset for f in ("llama", "mistral", "qwen2"))
+    int8 = os.environ.get("GEMMA_INT8", "").lower() in ("1", "true")
 
     ckpt = os.environ.get("GEMMA_CKPT", "")
     if ckpt:
@@ -144,7 +151,14 @@ def build_engine(app):
         params = loader(ckpt, cfg)
     else:
         app.logger.warn("GEMMA_CKPT not set: serving randomly initialized weights")
-        params = init_params(jax.random.PRNGKey(0), cfg)
+        if int8:
+            from gofr_tpu.models.quant import init_params_quantized
+
+            params = jax.jit(
+                lambda k: init_params_quantized(k, cfg, cfg.dtype, untied=is_llama)
+            )(jax.random.PRNGKey(0))
+        else:
+            params = init_params(jax.random.PRNGKey(0), cfg)
 
     tok_path = os.environ.get("GEMMA_TOKENIZER", "") or (ckpt if os.path.isdir(ckpt) else "")
     if tok_path:
@@ -170,7 +184,7 @@ def build_engine(app):
         # GEMMA_INT8=1: serve int8 weights (W8A8 prefill, weight-only
         # decode) — halves the HBM stream decode is bound by, and the only
         # way 7B fits one v5e chip
-        quantize=os.environ.get("GEMMA_INT8", "").lower() in ("1", "true"),
+        quantize=int8,
         # LLM_SPEC=1: speculative decoding — the host-side n-gram
         # drafter with fused on-device verification. Greedy outputs are
         # token-identical to spec-off and temperature outputs keep their

@@ -57,12 +57,13 @@ class Container:
         # TPU_PLATFORM=cpu|tpu pins the jax backend. Applied here — before
         # any user code can touch jax — because backend choice is global and
         # first-touch-wins (the runtime re-checks, but by then user model
-        # init may already have initialized the wrong platform).
+        # init may already have initialized the wrong platform). A platform
+        # that did not take raises.
         platform = config.get("TPU_PLATFORM")
         if platform:
             from ..utils import pin_jax_platform
 
-            pin_jax_platform(platform, c.logger)
+            pin_jax_platform(platform)
 
         c.logger = RemoteLevelLogger(
             gl.level_from_string(config.get("LOG_LEVEL")),

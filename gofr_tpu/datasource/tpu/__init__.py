@@ -240,12 +240,12 @@ class TPURuntime:
         self.config = config
         get = (lambda k, d: config.get_or_default(k, d)) if config is not None else (lambda k, d: d)
         # TPU_PLATFORM=cpu|tpu pins the jax backend before first device touch
-        # (needed where a platform plugin overrides JAX_PLATFORMS; also the
-        # dev/CI story: run the same app on the CPU backend). Normally done
-        # by Container.create; repeated for standalone runtimes.
+        # (the dev/CI story: run the same app on the CPU backend). Normally
+        # done by Container.create; repeated for standalone runtimes. A
+        # platform that did not take raises.
         from ...utils import pin_jax_platform
 
-        pin_jax_platform(get("TPU_PLATFORM", ""), logger)
+        pin_jax_platform(get("TPU_PLATFORM", ""))
         self.default_max_batch = int(get("TPU_BATCH_MAX_SIZE", "64"))
         self.default_max_delay_ms = float(get("TPU_BATCH_MAX_DELAY_MS", "2"))
         self.default_max_inflight = int(get("TPU_BATCH_MAX_INFLIGHT", "8"))

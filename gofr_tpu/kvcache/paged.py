@@ -35,7 +35,7 @@ CacheManager lock — see the threading note on CacheManager):
 
 Device-side helpers (pure jnp, traced into the engine's jitted
 programs): ``gather_slots`` materializes the dense per-slot view from
-the pool through the tables (the CPU/old-jax fallback for the Pallas
+the pool through the tables (the off-TPU path beside the Pallas
 paged-attention kernel in gofr_tpu.ops.attention), ``scatter_rows``
 writes freshly-computed K/V rows through the tables (indices computed
 FROM DEVICE STATE, so speculative rollback and pipelined verifies can

@@ -485,8 +485,8 @@ class DisaggregatedLLMEngine:
         """Move an export payload onto the decode engine's placement:
         direct device-to-device ``jax.device_put`` against the
         committed device/submesh when enabled and available, else
-        byte-identical host staging (numpy) — the CPU/old-jax fallback
-        and the equality tests' oracle."""
+        byte-identical host staging (numpy) — the fallback and the
+        equality tests' oracle."""
         import jax
         import numpy as np
 

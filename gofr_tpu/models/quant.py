@@ -123,7 +123,7 @@ def quantize_params(params: dict, dtype=jnp.bfloat16) -> dict:
     return out
 
 
-def init_params_quantized(rng, cfg, dtype=jnp.bfloat16) -> dict:
+def init_params_quantized(rng, cfg, dtype=jnp.bfloat16, *, untied: bool = False) -> dict:
     """Random-weight int8 param tree built DIRECTLY on device.
 
     Benchmark/test initializer for models whose bf16 tree does not fit
@@ -131,7 +131,9 @@ def init_params_quantized(rng, cfg, dtype=jnp.bfloat16) -> dict:
     8.2 GB int8, so init-then-quantize would OOM before quantize ran.
     Draws int8 weights uniform in [-127, 127] with per-channel scales
     matching init_params' 1/sqrt(fan_in) magnitude; norms stay zeros
-    (the real-weights path is models.checkpoint + quantize_params)."""
+    (the real-weights path is models.checkpoint + quantize_params).
+    ``untied`` adds the separate [vocab, d] output head the Llama, Mistral
+    and Qwen2 families publish (untied-ness lives in the pytree)."""
     import jax
 
     d, hd, hq, hkv, ff, L = (
@@ -154,7 +156,7 @@ def init_params_quantized(rng, cfg, dtype=jnp.bfloat16) -> dict:
         if getattr(cfg, "qkv_bias", False)
         else {}
     )
-    return {
+    tree = {
         "embed": qw((cfg.vocab_size, d), d),
         "final_norm": jnp.zeros((d,), dtype),
         "layers": {
@@ -169,6 +171,9 @@ def init_params_quantized(rng, cfg, dtype=jnp.bfloat16) -> dict:
             "w_down": qw((L, ff, d), ff),
         },
     }
+    if untied:  # the split's eighth key: the tied tree's draws are unchanged
+        tree["unembed"] = qw((cfg.vocab_size, d), d)
+    return tree
 
 
 def quantize_param_specs(specs: dict) -> dict:

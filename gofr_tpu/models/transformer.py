@@ -744,6 +744,7 @@ def decode_chunk_paged(
     interpret: bool = False,
     overlap=None,  # TP collective-compute overlap (see _layer_scan)
     sample_state=None,  # stateful sampler (see decode_chunk)
+    mesh=None,  # TP mesh: the paged kernel runs per head shard (ops.attention)
 ) -> tuple[jnp.ndarray, jnp.ndarray, KVCache, jnp.ndarray | None, jax.Array]:
     """decode_chunk against a BLOCK-PAGED pool (gofr_tpu.kvcache.paged).
 
@@ -815,7 +816,7 @@ def decode_chunk_paged(
                 q, kp_l, vp_l, tables, kb_l, vb_l, pool.length, k_i,
                 logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
                 k_scales=ks_l, v_scales=vs_l,
-                use_kernel=use_kernel, interpret=interpret,
+                use_kernel=use_kernel, interpret=interpret, mesh=mesh,
             )
             x = x + _lora_mm(
                 qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
@@ -872,6 +873,7 @@ def _append_forward(
     *,
     ring: int = 0,
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
+    mesh=None,  # TP mesh: the flash kernel runs per head shard (ops.attention)
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """Shared write-then-attend chunk append (prefill_append and
     verify_chunk): write the chunk's K/V rows at the per-sequence cursor,
@@ -916,7 +918,7 @@ def _append_forward(
         attn = chunk_prefill_attention(
             q, kc, vc, cursors,
             logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-            ring=ring,
+            ring=ring, mesh=mesh,
         )
         x = x + _lora_mm(
             mm, attn.reshape(b, c, hq * hd), lp, "wo", aids
@@ -939,6 +941,7 @@ def prefill_append(
     *,
     ring: int = 0,  # >0: cache is a rolling ring of this capacity
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
+    mesh=None,  # TP mesh (see _append_forward)
 ) -> tuple[jnp.ndarray, KVCache]:
     """Append one prefill chunk into an existing per-slot KV cache.
 
@@ -965,7 +968,8 @@ def prefill_append(
     """
     b, c = tokens.shape
     x, (ks, vs) = _append_forward(
-        params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids
+        params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids,
+        mesh=mesh,
     )
     last = jnp.clip(n_new - 1, 0, c - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None].astype(jnp.int32), axis=1)
@@ -984,6 +988,7 @@ def verify_chunk(
     *,
     ring: int = 0,  # >0: cache is a rolling ring of this capacity
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
+    mesh=None,  # TP mesh (see _append_forward)
 ) -> tuple[jnp.ndarray, KVCache]:
     """Score every position of a speculative-decoding draft in ONE
     forward pass (gofr_tpu.spec; docs/advanced-guide/speculative-decoding.md).
@@ -1008,7 +1013,8 @@ def verify_chunk(
     count). Positions >= n_new carry garbage logits the engine ignores.
     """
     x, (ks, vs) = _append_forward(
-        params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids
+        params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids,
+        mesh=mesh,
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x)  # [b, c, vocab] f32

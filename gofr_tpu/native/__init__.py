@@ -8,7 +8,8 @@ HTTP server (httpcore.cc; used by gofr_tpu/http/nativeserver.py).
 Build strategy: pybind11 and pip are unavailable in the image, so the
 extension is compiled straight from source with the system g++ against the
 running interpreter's headers (`sysconfig`), cached under
-``native/_build/`` keyed by source mtime+interpreter. A build failure (no
+``native/_build/`` keyed by source content+interpreter (an mtime says
+when a checkout was unpacked, not what it holds). A build failure (no
 compiler, exotic platform) degrades gracefully: `load_http_codec()` returns
 None and the HTTP plane falls back to the pure-Python parser — behavior is
 identical, only slower (see tests/test_native_http.py which asserts
@@ -19,6 +20,7 @@ Set GOFR_NATIVE=0 to disable native components entirely.
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -44,7 +46,8 @@ def _build(src: str, modname: str) -> str | None:
     src_path = os.path.join(_HERE, src)
     out_path = os.path.join(_BUILD_DIR, modname + _ext_suffix())
     stamp_path = out_path + ".stamp"
-    stamp = f"{os.path.getmtime(src_path)}:{sys.version_info[:2]}"
+    with open(src_path, "rb") as f:
+        stamp = f"{hashlib.sha256(f.read()).hexdigest()}:{sys.version_info[:2]}"
     if os.path.exists(out_path) and os.path.exists(stamp_path):
         try:
             with open(stamp_path) as f:

@@ -11,13 +11,14 @@ this package exists for the TPU north star (BASELINE.json).
 from .attention import (
     chunk_decode_attention,
     chunk_prefill_attention,
+    chunk_prefill_why_not_flash,
     decode_attention,
     flash_attention,
     mha_reference,
     multi_head_attention,
     paged_chunk_decode_attention,
     paged_gather,
-    paged_kernel_ok,
+    paged_kernel_why_not,
     ring_positions,
 )
 from .norms import rms_norm
@@ -32,7 +33,8 @@ __all__ = [
     "chunk_prefill_attention",
     "paged_chunk_decode_attention",
     "paged_gather",
-    "paged_kernel_ok",
+    "paged_kernel_why_not",
+    "chunk_prefill_why_not_flash",
     "ring_positions",
     "rms_norm",
     "apply_rope",

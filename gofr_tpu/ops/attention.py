@@ -25,14 +25,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent in some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -2.3819763e38  # close to bf16 min; avoids nan from (-inf) - (-inf)
 
@@ -94,12 +88,36 @@ def mha_reference(
 
 
 # ---------------------------------------------------------------------------
+# Pallas kernels under a tensor-parallel mesh
+# ---------------------------------------------------------------------------
+
+
+def _head_axes(mesh, hq: int, hkv: int, axis: str = "model"):
+    """(q, kv) mesh axes a per-head kernel shards its heads over, None =
+    replicated — the whole-head rule of parallel.sharding: q when the TP
+    degree divides n_heads, kv when it also divides n_kv_heads (or there
+    is one kv head for every shard to read). Mosaic kernels have no GSPMD
+    partitioning rule (jax refuses to lower one under a multi-device jit),
+    so under a mesh each kernel runs inside a shard_map over these axes:
+    every chip attends its own heads against its own shard of the KV —
+    nothing is gathered, and the per-head math is the single-chip math."""
+    tp = mesh.shape.get(axis, 1)
+    if tp == 1 or hq % tp:
+        return None, None
+    if hkv % tp == 0:
+        return axis, axis
+    return (axis, None) if hkv == 1 else (None, None)
+
+
+# ---------------------------------------------------------------------------
 # Pallas flash attention (TPU prefill path)
 # ---------------------------------------------------------------------------
 
 
 def _flash_kernel(
-    *refs,  # [off_ref?, q_ref, k_ref, v_ref, o_ref, m, l, acc]
+    off_ref,  # scalar prefetch: [b] int32 per-batch query offsets
+    q_ref, k_ref, v_ref, o_ref, m_scratch, l_scratch, acc_scratch,
+    *,
     causal: bool,
     scale: float,
     logit_cap: float,
@@ -109,15 +127,10 @@ def _flash_kernel(
     num_k_blocks: int,
     offset: bool = False,
 ):
-    # Ref layout: inputs (optionally led by the per-batch query-offset
-    # scalar in SMEM — the chunk-append prefill path), then the output,
-    # then VMEM scratch: running max / denom (lane-replicated) + f32
+    # Ref layout: the per-batch query offsets (scalar prefetch, SMEM; read
+    # only on the chunk-append prefill path), inputs, the output, then
+    # VMEM scratch: running max / denom (lane-replicated) + f32
     # accumulator, persistent across the sequential k iterations.
-    if offset:
-        off_ref, q_ref, k_ref, v_ref, o_ref, m_scratch, l_scratch, acc_scratch = refs
-    else:
-        off_ref = None
-        q_ref, k_ref, v_ref, o_ref, m_scratch, l_scratch, acc_scratch = refs
     qi = pl.program_id(2)
     ki_raw = pl.program_id(3)
     grid_k = pl.num_programs(3)
@@ -150,7 +163,7 @@ def _flash_kernel(
     # is decided compute-side (pl.when takes dynamic predicates); the
     # banded-grid DMA skip stays disabled on this path (flash_attention
     # never requests both).
-    off = off_ref[0, 0] if offset else 0
+    off = off_ref[pl.program_id(0)] if offset else 0
 
     # Causal: block is live iff some query position >= some key position,
     # i.e. block_q_end >= block_k_start. Sliding window additionally kills
@@ -220,6 +233,7 @@ def flash_attention(
     block_k: int = 128,
     q_offsets: jnp.ndarray | None = None,  # [b] int32 per-batch query offset
     interpret: bool = False,
+    mesh=None,  # TP mesh: the kernel runs per head shard (_head_axes)
 ) -> jnp.ndarray:
     """Blockwise online-softmax attention on the Pallas TPU kernel.
 
@@ -230,17 +244,25 @@ def flash_attention(
     are traced values, so block liveness is decided in-kernel and the
     banded-grid DMA skip is disabled on this path (every k block is
     fetched; masked blocks are skipped compute-side)."""
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "flash_attention requires jax.experimental.pallas.tpu (scratch "
-            "memory spaces); use mha_reference / multi_head_attention instead"
-        )
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     if sq % block_q or sk % block_k:
         raise ValueError(f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
+    if mesh is not None and mesh.size > 1:
+        qa, ka = _head_axes(mesh, hq, hkv)
+        local = functools.partial(
+            flash_attention, causal=causal, scale=scale, logit_cap=logit_cap,
+            window=window, block_q=block_q, block_k=block_k, interpret=interpret,
+        )
+        q_spec, kv_spec = P(None, None, qa, None), P(None, None, ka, None)
+        offs = () if q_offsets is None else (q_offsets,)
+        return jax.shard_map(
+            lambda q, k, v, *off: local(q, k, v, q_offsets=off[0] if off else None),
+            mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec, *(P() for _ in offs)),
+            out_specs=q_spec, check_vma=False,
+        )(q, k, v, *offs)
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     num_k_blocks = sk // block_k
@@ -275,7 +297,10 @@ def flash_attention(
     else:
         grid_k = num_k_blocks
 
-    def kv_index(bi, hi, qi, ki):
+    def q_index(bi, hi, qi, ki, off):
+        return (bi, hi, qi, 0)
+
+    def kv_index(bi, hi, qi, ki, off):
         if grid_k == num_k_blocks:
             return (bi, hi // group, ki, 0)
         kb_hi = ((qi + 1) * block_q - 1) // block_k
@@ -293,34 +318,32 @@ def flash_attention(
         num_k_blocks=num_k_blocks,
         offset=offset,
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d), kv_index),
-        pl.BlockSpec((1, 1, block_k, d), kv_index),
-    ]
-    operands = [qt, kt, vt]
-    if offset:
-        # per-batch scalar in SMEM, one (1, 1) cell per grid batch index
-        in_specs.insert(0, pl.BlockSpec(
-            (1, 1), lambda bi, hi, qi, ki: (bi, 0),
-            memory_space=pltpu.SMEM,
-        ))
-        operands.insert(0, q_offsets.astype(jnp.int32).reshape(b, 1))
+    # The offsets ride as a scalar-prefetch operand (whole [b] vector in
+    # SMEM, indexed by the batch grid coordinate): a blocked (1, 1) SMEM
+    # spec of a [b, 1] array does not lower on TPU.
+    offsets = (
+        q_offsets.astype(jnp.int32) if offset else jnp.zeros((b,), jnp.int32)
+    )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, d), q_index),
+                pl.BlockSpec((1, 1, block_k, d), kv_index),
+                pl.BlockSpec((1, 1, block_k, d), kv_index),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, d), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
         interpret=interpret,
-    )(*operands)
+    )(offsets, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -512,6 +535,7 @@ def chunk_prefill_attention(
     logit_cap: float = 0.0,
     window: int = 0,  # sliding window over absolute positions
     ring: int = 0,  # >0: cache is a rolling ring of this capacity (kvcache)
+    mesh=None,  # TP mesh the engine serves over (flash path only)
 ) -> jnp.ndarray:
     """Chunked-prefill attention: a query block at absolute positions
     [cursors, cursors + c) attends every prior key resident in the slot
@@ -580,17 +604,13 @@ def chunk_prefill_attention(
         mask = (pos[:, None, :] >= 0) & (pos[:, None, :] <= qpos[:, :, None])
         mask = mask & (pos[:, None, :] > qpos[:, :, None] - window)
     else:
-        if (
-            _flash_ok(q, k_cache, min(128, c), 128)
-            and c % min(128, c) == 0
-            and c % 8 == 0  # sub-sublane widths (spec verify) stay on XLA
-        ):
+        if not chunk_prefill_why_not_flash(c, capacity, d):
             # dense path on TPU: the flash kernel accepts the query block
             # via per-batch offsets (block_q clamped to the chunk length)
             return flash_attention(
                 q, k_cache, v_cache, causal=True, scale=scale,
                 logit_cap=logit_cap, window=window,
-                block_q=min(128, c), q_offsets=cursors,
+                block_q=min(128, c), q_offsets=cursors, mesh=mesh,
             )
         kpos = jnp.arange(capacity, dtype=jnp.int32)[None, None, :]
         mask = kpos <= qpos[:, :, None]
@@ -631,7 +651,7 @@ def chunk_prefill_attention(
 #   merge the chunk ring buffer region with one rescale.
 # - Dense-gather reference (paged_gather): jnp.take the table rows into
 #   the contiguous layout and reuse the proven attention above — the
-#   CPU/old-jax fallback and the test oracle. Bit-exact with the
+#   off-TPU path and the test oracle. Bit-exact with the
 #   contiguous engine because gathering blocks in table order
 #   reconstructs the same slab.
 #
@@ -674,6 +694,7 @@ def _paged_decode_kernel(
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, m_s, l_s, acc_s = refs
         ks_ref = vs_ref = None
     bi = pl.program_id(0)
+    head = pl.program_id(1)
     ji = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -688,14 +709,24 @@ def _paged_decode_kernel(
     base = ji * block  # logical position of this table slot's first row
     live = jnp.logical_and(base < hi, base + block > lo)
 
+    def head_scale(s_ref):
+        # [block, hkv] scales of every kv head -> this head's [block, 1]
+        # column (masked lane reduce: the head is a grid coordinate, and
+        # a one-lane block of the scales array has no TPU lowering)
+        sc = s_ref[0]
+        lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        return jnp.sum(
+            jnp.where(lane == head, sc, 0.0), axis=1, keepdims=True
+        )
+
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # [group, d]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [block, d]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)  # [block, d]
+        v = v_ref[0].astype(jnp.float32)
         if ks_ref is not None:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+            k = k * head_scale(ks_ref)
+            v = v * head_scale(vs_ref)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # [group, block]
@@ -737,40 +768,70 @@ def _paged_decode_partials(
     k_scales=None,  # [NB, B, hkv] f32 (int8 pool)
     v_scales=None,
     interpret: bool = False,
+    mesh=None,  # TP mesh: the kernel runs per head shard (_head_axes)
 ):
     """Pallas paged-attention decode over the valid band [lo, hi):
     returns (o [b, hq, d] f32 normalized, m [b, hq] f32, l [b, hq] f32)
     online-softmax partials for region merging."""
-    if not _HAS_PLTPU:
-        raise RuntimeError("paged decode kernel requires pallas TPU support")
     b, hq, d = q.shape
     NB, B, hkv, _ = k_pool.shape
     MB = tables.shape[1]
-    group = hq // hkv
     quantized = k_scales is not None
+    if mesh is not None and mesh.size > 1:
+        qa, ka = _head_axes(mesh, hq, hkv)
+        pool_spec, sc_spec = P(None, None, ka, None), P(None, None, ka)
+
+        def local(q, k_pool, v_pool, tables, lo, hi, *scales):
+            ks, vs = scales or (None, None)
+            return _paged_decode_partials(
+                q, k_pool, v_pool, tables, lo, hi, scale=scale,
+                logit_cap=logit_cap, k_scales=ks, v_scales=vs,
+                interpret=interpret,
+            )
+
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(
+                P(None, qa, None), pool_spec, pool_spec, P(), P(), P(),
+                *((sc_spec, sc_spec) if quantized else ()),
+            ),
+            out_specs=(P(None, qa, None), P(None, qa), P(None, qa)),
+            check_vma=False,
+        )(q, k_pool, v_pool, tables, lo, hi,
+          *((k_scales, v_scales) if quantized else ()))
+    group = hq // hkv
 
     qt = q.reshape(b, hkv, group, d)
 
     def q_index(bi, hi_, ji, tbl, lo_, hi__):
         return (bi, hi_, 0, 0)
 
+    # TPU blocks must tile (8, 128) over an array's last two dims or span
+    # them whole, so a one-head (1, B, 1, d) block of the [NB, B, hkv, d]
+    # pool has no lowering for hkv > 1. Viewed as [NB, B, hkv * d] (a
+    # free reshape) the same rows are the (1, B, d) block at lane-block
+    # index `head`: B spans its dim, d is a multiple of 128.
     def kv_index(bi, hi_, ji, tbl, lo_, hi__):
-        return (tbl[bi, ji], 0, hi_, 0)
+        return (tbl[bi, ji], 0, hi_)
 
     in_specs = [
         pl.BlockSpec((1, 1, group, d), q_index),
-        pl.BlockSpec((1, B, 1, d), kv_index),
-        pl.BlockSpec((1, B, 1, d), kv_index),
+        pl.BlockSpec((1, B, d), kv_index),
+        pl.BlockSpec((1, B, d), kv_index),
     ]
-    operands = [qt, k_pool, v_pool]
+    operands = [
+        qt, k_pool.reshape(NB, B, hkv * d), v_pool.reshape(NB, B, hkv * d)
+    ]
     if quantized:
+        # scales [NB, B, hkv]: the block spans every head (hkv f32 lanes
+        # per row) and the kernel selects its own head's column
 
         def sc_index(bi, hi_, ji, tbl, lo_, hi__):
-            return (tbl[bi, ji], 0, hi_)
+            return (tbl[bi, ji], 0, 0)
 
         in_specs += [
-            pl.BlockSpec((1, B, 1), sc_index),
-            pl.BlockSpec((1, B, 1), sc_index),
+            pl.BlockSpec((1, B, hkv), sc_index),
+            pl.BlockSpec((1, B, hkv), sc_index),
         ]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -812,15 +873,19 @@ def _paged_decode_partials(
     )
 
 
-def paged_kernel_ok(head_dim: int, block: int, *, interpret: bool = False) -> bool:
-    """Whether the Pallas paged-decode kernel can serve this config:
-    TPU backend (or interpret mode for tests), lane-aligned head_dim,
-    sublane-aligned block size."""
-    if not _HAS_PLTPU:
-        return False
+def paged_kernel_why_not(head_dim: int, block: int, *, interpret: bool = False) -> str:
+    """Why the Pallas paged-decode kernel cannot serve this config, or ""
+    when it can: it needs the TPU backend (or interpret mode for tests),
+    a lane-aligned head_dim and a sublane-aligned block size. The one
+    decision point for the kernel/XLA-gather choice — the engine reports
+    the same answer in stats()["attention"]."""
     if not interpret and jax.default_backend() != "tpu":
-        return False
-    return head_dim % 128 == 0 and block % 8 == 0
+        return f"backend {jax.default_backend()} is not tpu"
+    if head_dim % 128:
+        return f"head_dim {head_dim} is not a multiple of 128"
+    if block % 8:
+        return f"kv block {block} is not a multiple of 8"
+    return ""
 
 
 def paged_chunk_decode_attention(
@@ -840,6 +905,7 @@ def paged_chunk_decode_attention(
     v_scales=None,
     use_kernel: bool | None = None,
     interpret: bool = False,
+    mesh=None,  # TP mesh the engine serves over (kernel path only)
 ) -> jnp.ndarray:
     """chunk_decode_attention reading the MAIN region through a block
     table: pool rows hold logical positions [0, lengths) via the table,
@@ -853,7 +919,7 @@ def paged_chunk_decode_attention(
     B = k_pool.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     if use_kernel is None:
-        use_kernel = paged_kernel_ok(d, B, interpret=interpret)
+        use_kernel = not paged_kernel_why_not(d, B, interpret=interpret)
     if not use_kernel:
         kc, vc = paged_gather(
             k_pool, v_pool, tables,
@@ -872,7 +938,7 @@ def paged_chunk_decode_attention(
     o_m, m_m, l_m = _paged_decode_partials(
         q[:, 0], k_pool, v_pool, tables, lo, hi,
         scale=scale, logit_cap=logit_cap,
-        k_scales=k_scales, v_scales=v_scales, interpret=interpret,
+        k_scales=k_scales, v_scales=v_scales, interpret=interpret, mesh=mesh,
     )
     # buffer region (dense, [b, chunk]) — same mask set as
     # chunk_decode_attention's buffer half
@@ -915,16 +981,28 @@ def paged_chunk_decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def _flash_ok(q: jnp.ndarray, k: jnp.ndarray, block_q: int, block_k: int) -> bool:
-    b, sq, hq, d = q.shape
-    sk = k.shape[1]
-    return (
-        _HAS_PLTPU
-        and jax.default_backend() == "tpu"
-        and sq % block_q == 0
-        and sk % block_k == 0
-        and d % 128 == 0
-    )
+def flash_why_not(sq: int, sk: int, head_dim: int, block_q: int, block_k: int) -> str:
+    """Why the Pallas flash kernel cannot serve these shapes, or "" when
+    it can (TPU backend, sequences that divide their blocks, lane-aligned
+    head_dim)."""
+    if jax.default_backend() != "tpu":
+        return f"backend {jax.default_backend()} is not tpu"
+    if head_dim % 128:
+        return f"head_dim {head_dim} is not a multiple of 128"
+    if sq % block_q or sk % block_k:
+        return f"seq lengths ({sq},{sk}) do not divide blocks ({block_q},{block_k})"
+    return ""
+
+
+def chunk_prefill_why_not_flash(chunk: int, capacity: int, head_dim: int) -> str:
+    """flash_why_not for a dense chunk append of `chunk` queries against a
+    `capacity`-row slot cache (block_q clamped to the chunk). Chunks
+    narrower than one 8-row sublane tile — the speculative verify widths
+    — have no MXU-aligned block_q. The engine reports the same answer in
+    stats()["attention"]."""
+    if chunk % 8:
+        return f"chunk {chunk} is not a multiple of 8"
+    return flash_why_not(chunk, capacity, head_dim, min(128, chunk), 128)
 
 
 def multi_head_attention(
@@ -948,7 +1026,7 @@ def multi_head_attention(
     assumes dense right-aligned prefill)."""
     if (
         kv_mask is None and q_positions is None
-        and _flash_ok(q, k, block_q, block_k)
+        and not flash_why_not(q.shape[1], k.shape[1], q.shape[3], block_q, block_k)
     ):
         return flash_attention(
             q, k, v, causal=causal, scale=scale, logit_cap=logit_cap,

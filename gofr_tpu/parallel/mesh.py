@@ -15,11 +15,7 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: sharding-in-types axis modes
-    from jax.sharding import AxisType, Mesh
-except ImportError:  # older jax: meshes are implicitly Auto everywhere
-    AxisType = None  # type: ignore[assignment]
-    from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def mesh_shape_for(n_devices: int, tp: int | None = None) -> dict[str, int]:
@@ -48,14 +44,9 @@ def make_mesh(
     # Auto axis types = classic GSPMD: the compiler propagates shardings and
     # inserts collectives from our annotations (explicit mode would demand a
     # jax.set_mesh context at every call site — wrong trade for a framework).
-    # On jax builds without AxisType the kwarg is omitted: every mesh is
-    # Auto there, so behavior is identical.
-    kw = {}
-    if AxisType is not None:
-        kw["axis_types"] = (AxisType.Auto,) * len(shape)
     return jax.make_mesh(
         tuple(shape.values()),
         tuple(shape.keys()),
         devices=devices,
-        **kw,
+        axis_types=(AxisType.Auto,) * len(shape),
     )

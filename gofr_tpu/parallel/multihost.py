@@ -48,17 +48,7 @@ def init_distributed(
         p = os.environ.get("GOFR_PROCESS_ID")
         process_id = int(p) if p else None
 
-    # jax < 0.6 has no jax.distributed.is_initialized — probe the global
-    # state object it wraps, defaulting to "not initialized" if that moves
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is None:
-        state = getattr(
-            getattr(jax._src, "distributed", None), "global_state", None
-        )
-        already = state is not None and state.client is not None
-    else:
-        already = is_init()
-    if not already:
+    if not jax.distributed.is_initialized():
         if coordinator is not None:
             jax.distributed.initialize(
                 coordinator_address=coordinator,

@@ -39,19 +39,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace, same signature
-    from jax.experimental.shard_map import shard_map
-
-try:  # jax >= 0.8: explicit varying-manual-axes cast (the VMA check)
-    _pcast = lax.pcast
-except AttributeError:  # older jax: shard_map values are varying already
-
-    def _pcast(x, *_a, **_k):
-        return x
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.transformer import TransformerConfig, _embed_tokens, _layer_body, _unembed
@@ -99,8 +87,8 @@ def pipeline_layers(
         # mark the carries device-varying up front (each stage's state and
         # output buffer genuinely differ) — jax 0.9's vma tracking rejects
         # a scan whose carry starts replicated and becomes varying
-        state = _pcast(jnp.zeros(x_mb.shape[1:], x_mb.dtype), (axis,), to="varying")
-        out = _pcast(jnp.zeros_like(x_mb), (axis,), to="varying")
+        state = lax.pcast(jnp.zeros(x_mb.shape[1:], x_mb.dtype), (axis,), to="varying")
+        out = lax.pcast(jnp.zeros_like(x_mb), (axis,), to="varying")
 
         def tick(carry, t):
             state, out = carry

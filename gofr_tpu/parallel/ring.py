@@ -19,17 +19,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental namespace, same signature
-    from jax.experimental.shard_map import shard_map
-
-try:  # jax >= 0.8: explicit varying-manual-axes cast (the VMA check)
-    _pcast = jax.lax.pcast
-except AttributeError:  # older jax: shard_map values are varying already
-
-    def _pcast(x, *_a, **_k):
-        return x
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import NEG_INF
@@ -80,9 +70,9 @@ def ring_attention(
 
         # pcast-to-varying: accumulators are per-shard values (device-varying
         # over the ring axis), matching branch outputs under the VMA check.
-        m_acc = _pcast(jnp.full((b, h, sq, 1), NEG_INF, jnp.float32), axis, to="varying")
-        l_acc = _pcast(jnp.zeros((b, h, sq, 1), jnp.float32), axis, to="varying")
-        o_acc = _pcast(jnp.zeros((b, h, sq, d), jnp.float32), axis, to="varying")
+        m_acc = jax.lax.pcast(jnp.full((b, h, sq, 1), NEG_INF, jnp.float32), axis, to="varying")
+        l_acc = jax.lax.pcast(jnp.zeros((b, h, sq, 1), jnp.float32), axis, to="varying")
+        o_acc = jax.lax.pcast(jnp.zeros((b, h, sq, d), jnp.float32), axis, to="varying")
 
         # Static unroll over the ring (n = mesh axis size, known at trace
         # time): lets the diagonal mask be chosen statically and skips the

@@ -9,11 +9,12 @@ Attention projections shard at WHOLE-HEAD granularity only: q/o when the
 TP degree divides n_heads, kv when it divides n_kv_heads — with MQA
 (Gemma-2B, n_kv_heads=1) KV is replicated, the standard layout, so decode
 all-gathers ride ICI only for Q/O. A shard boundary INSIDE a head is not
-just unconventional; on the pinned old-jax CPU stack GSPMD miscompiles
-the rope/attention reshapes it induces (tiny config at tp=8: logits off
-by ~1.0, cache rows off by ~3.5 — the "old-jax TP prefill drift" that
-failed tests/test_parallel.py since PR 2), so head-indivisible degrees
-replicate q/o and keep only the MLP/embed sharded. wkv's output columns
+just unconventional: the rope/attention reshapes it induces were seen to
+miscompile under GSPMD on an earlier jax (tiny config at tp=8: logits off
+by ~1.0, cache rows off by ~3.5; not re-checked on jax 0.9), and the
+Pallas kernels shard whole heads only (ops.attention._head_axes). So
+head-indivisible degrees replicate q/o and keep only the MLP/embed
+sharded. wkv's output columns
 pack heads outermost ([hkv, 2, hd] blocks, transformer._layer_body), so
 each TP shard of the flat dim holds whole (k, v) head pairs — never K on
 one half of the group and V on the other.

@@ -98,6 +98,7 @@ class CompileRegistry:
         self._entries: dict[tuple, dict] = {}
         self._events: dict[str, list] = {}  # jax.monitoring: name -> [n, total_s]
         self._warmups: dict[str, dict] = {}
+        self._degraded: dict[tuple, str] = {}  # (program, model) -> reason
 
     # -- writers ----------------------------------------------------------
     def record_compile(
@@ -152,6 +153,13 @@ class CompileRegistry:
             if e is not None:
                 e["hits"] += 1
 
+    def note_degraded(self, program: str, model: str, reason: str) -> None:
+        """An InstrumentedJit left AOT dispatch for plain jit (its compiled
+        executable rejected intact inputs). Listed in snapshot() so the
+        fallback is never silent."""
+        with self._lock:
+            self._degraded.setdefault((program, model), reason)
+
     def note_backend_event(self, event: str, duration_s: float) -> None:
         """Aggregate a jax.monitoring duration event (bounded cardinality:
         jax emits a handful of /jax/core/compile/* phase names)."""
@@ -178,6 +186,8 @@ class CompileRegistry:
             gone = [k for k in self._entries if k[1] == model]
             for k in gone:
                 del self._entries[k]
+            for k in [k for k in self._degraded if k[1] == model]:
+                del self._degraded[k]
             self._warmups.pop(model, None)
             return len(gone)
 
@@ -186,6 +196,7 @@ class CompileRegistry:
             self._entries.clear()
             self._events.clear()
             self._warmups.clear()
+            self._degraded.clear()
 
     # -- readers ----------------------------------------------------------
     def snapshot(self, model: str | None = None) -> dict:
@@ -204,6 +215,11 @@ class CompileRegistry:
                 m: dict(w) for m, w in self._warmups.items()
                 if model is None or m == model
             }
+            degraded = [
+                {"program": k[0], "model": k[1], "reason": why}
+                for k, why in sorted(self._degraded.items())
+                if model is None or k[1] == model
+            ]
         entries.sort(key=lambda e: (e["model"], e["program"], e["arg_shapes"]))
         for e in entries:
             e.pop("first_compiled_at", None)
@@ -217,6 +233,9 @@ class CompileRegistry:
             },
             "backend_events": events,
             "warmup": warmups,
+            # programs whose AOT executable rejected its inputs and that
+            # dispatch through plain jit since (InstrumentedJit)
+            "degraded": degraded,
         }
 
 
@@ -295,10 +314,12 @@ class InstrumentedJit:
     and installs the executable. Donation and input shardings flow
     through lowering unchanged, so engine semantics are identical.
 
-    If an AOT call ever rejects its inputs (a committed-device or layout
-    drift the signature missed), the wrapper logs the entry as degraded
-    and permanently falls back to plain jit dispatch, where compiles are
-    still counted per signature but timed as first-call envelopes.
+    If an AOT call ever rejects intact inputs (a committed-device or
+    layout drift the signature missed), the wrapper notes itself as
+    degraded in the registry (snapshot()["degraded"], with the error) and
+    permanently falls back to plain jit dispatch, where compiles are
+    still counted per signature but timed as first-call envelopes. A
+    compile error is never caught: plain jit would fail identically.
     """
 
     def __init__(
@@ -411,7 +432,7 @@ class InstrumentedJit:
                 )
             try:
                 return exe(*self._dyn_args(args))
-            except Exception:
+            except Exception as e:
                 # Committed-device/layout drift the signature missed: fall
                 # back to jit dispatch for good rather than failing serving.
                 # But ONLY when the inputs are intact — a failure after the
@@ -428,6 +449,9 @@ class InstrumentedJit:
                 with self._lock:
                     self._aot = False
                     self._compiled.clear()  # _seen still routes hits to jit
+                self.registry.note_degraded(
+                    self.program, self.model, f"{type(e).__name__}: {e}"[:300]
+                )
                 return self._jitted(*args)
         if sig in self._seen:  # degraded mode hit
             self.registry.note_hit(self.program, self.model, self._shapes[sig])
@@ -446,19 +470,14 @@ class InstrumentedJit:
         shapes = _describe_args(args)
         with self._lock:
             self._shapes.setdefault(sig, shapes)
-        compiled = None
         if self._aot:
-            # tracing errors propagate — plain jit would raise identically,
-            # and a bad input batch must not degrade the wrapper for good
+            # tracing and compile errors propagate — plain jit would raise
+            # identically, and a bad input batch must not degrade the
+            # wrapper for good
             t0 = time.perf_counter()
             lowered = self._jitted.lower(*args)
             t1 = time.perf_counter()
-            try:
-                compiled = lowered.compile()
-            except Exception:  # noqa: BLE001 — AOT unsupported here; degrade
-                with self._lock:
-                    self._aot = False
-        if compiled is not None:
+            compiled = lowered.compile()
             # install + record BEFORE the first execution: a runtime
             # failure there must neither hide the (expensive) compile from
             # the registry nor discard the executable — the retry then

@@ -60,37 +60,46 @@ _FALLBACK_PEAK_FLOPS = 1e12
 _FALLBACK_HBM_BW = 1e11
 
 
+def _tpu_peaks(platform: str, device_kind: str) -> tuple[float, float] | None:
+    """(peak FLOP/s, HBM B/s) of a TPU device kind, None off-TPU. A TPU
+    kind that is not in the table is an error, not a default: utilization
+    against an invented peak reads as a measurement."""
+    kind = (device_kind or "").lower()
+    if platform != "tpu" and "tpu" not in kind:
+        return None
+    for sub, peak, bw in _TPU_PEAKS:
+        if sub in kind:
+            return peak, bw
+    raise ValueError(
+        f"TPU device kind {device_kind!r} is not in the peaks table "
+        "(gofr_tpu.profiling.mfu._TPU_PEAKS); add it with its source"
+    )
+
+
+def _env_float(name: str) -> float | None:
+    try:
+        return float(os.environ.get(name) or "")
+    except ValueError:
+        return None
+
+
 def device_peak_flops(platform: str = "", device_kind: str = "") -> float:
     """Peak dense FLOP/s per chip (bf16 convention). TPU_PEAK_FLOPS
-    overrides; unknown device kinds fall back to the nominal placeholder."""
-    env = os.environ.get("TPU_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    kind = (device_kind or "").lower()
-    if platform == "tpu" or "tpu" in kind:
-        for sub, peak, _bw in _TPU_PEAKS:
-            if sub in kind:
-                return peak
-    return _FALLBACK_PEAK_FLOPS
+    overrides; off-TPU devices take the nominal placeholder."""
+    env = _env_float("TPU_PEAK_FLOPS")
+    if env is not None:
+        return env
+    peaks = _tpu_peaks(platform, device_kind)
+    return peaks[0] if peaks else _FALLBACK_PEAK_FLOPS
 
 
 def device_hbm_bandwidth(platform: str = "", device_kind: str = "") -> float:
     """Peak HBM bandwidth per chip in B/s (TPU_HBM_BW overrides)."""
-    env = os.environ.get("TPU_HBM_BW")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    kind = (device_kind or "").lower()
-    if platform == "tpu" or "tpu" in kind:
-        for sub, _peak, bw in _TPU_PEAKS:
-            if sub in kind:
-                return bw
-    return _FALLBACK_HBM_BW
+    env = _env_float("TPU_HBM_BW")
+    if env is not None:
+        return env
+    peaks = _tpu_peaks(platform, device_kind)
+    return peaks[1] if peaks else _FALLBACK_HBM_BW
 
 
 @dataclass(frozen=True)

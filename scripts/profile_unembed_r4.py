@@ -2,7 +2,7 @@
 int8 matmul) and the sampling epilogue (argmax + approx_max_k +
 categorical) cost inside the decode chunk at bench shapes?
 
-Variants (delta method, same harness as profile_attn_r4):
+Variants (delta method):
   full     — real chunk: unembed + greedy/topk sample
   nounembed— logits replaced by a [b, 64] slice of x (kills the vocab
              matmul AND full-vocab reductions)

@@ -8,7 +8,7 @@ inside the dot doesn't ride the MXU (same reason qmm_a8 wins prefill,
 quant.py:72-81), so an s8 x s8 -> s32 dot with per-row dynamic activation
 scales may pull the matvec cost toward the weight-stream bound.
 
-Variants (delta method, chained chunks, same harness as profile_attn_r4):
+Variants (delta method, chained chunks):
   w8a16  — the shipped decode_chunk path (qmm everywhere)
   w8a8   — qmm_a8 for all seven per-layer matvecs
   w8a8mlp— qmm_a8 for the three MLP matvecs only (75% of weight bytes)

@@ -18,7 +18,6 @@ import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax  # noqa: E402
-jax.config.update("jax_platforms", "cpu")
 
 from gofr_tpu.parallel.multihost import init_distributed, is_primary, topology  # noqa: E402
 

@@ -124,3 +124,109 @@ def test_flash_window_multiple_of_block_skips_blocks():
     ref = mha_reference(q, k, v, causal=True, window=128)
     out = flash_attention(q, k, v, causal=True, window=128, interpret=True)
     assert jnp.abs(ref - out).max() < 2e-5
+
+
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("hkv", [1, 4, 8])
+class TestPallasLowersForTpu:
+    """Every Pallas entry point, cross-lowered for TPU on this CPU
+    (``.trace().lower(lowering_platforms=("tpu",))``): the Pallas TPU
+    block-shape rules run at lowering, no chip needed. The kernel tests
+    above run ``interpret=True`` at head_dim 16, which applies none of
+    them — a one-head block of the [NB, B, hkv, d] pool and a (1, 1) SMEM
+    block of the [b, 1] offsets both passed there and lowered nowhere."""
+
+    @staticmethod
+    def _lower(fn, *structs):
+        jax.jit(fn).trace(*structs).lower(lowering_platforms=("tpu",))
+
+    @pytest.mark.parametrize("window", [0, 512])
+    @pytest.mark.parametrize("chunk", [16, 64, 128])
+    def test_flash(self, hkv, d, chunk, window):
+        hq = 28 if hkv == 4 else 8 * hkv  # Qwen2's group of 7, else 8
+        S = jax.ShapeDtypeStruct
+        q = S((2, chunk, hq, d), jnp.bfloat16)
+        k = S((2, chunk, hkv, d), jnp.bfloat16)
+        self._lower(
+            lambda q, k, v: flash_attention(
+                q, k, v, window=window, block_q=chunk, block_k=chunk
+            ),
+            q, k, k,
+        )
+        # the chunked-prefill call: per-batch query offsets into a slot cache
+        cache = S((2, 1024, hkv, d), jnp.bfloat16)
+        self._lower(
+            lambda q, k, v, o: flash_attention(
+                q, k, v, window=window, block_q=chunk, q_offsets=o
+            ),
+            q, cache, cache, S((2,), jnp.int32),
+        )
+
+    @pytest.mark.parametrize("window", [0, 512])
+    @pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize("block", [16, 64, 128])
+    def test_paged_decode(self, hkv, d, block, pool, window):
+        from gofr_tpu.ops.attention import paged_chunk_decode_attention
+
+        hq = 28 if hkv == 4 else 8 * hkv
+        S = jax.ShapeDtypeStruct
+        n_blocks, n_tbl, steps = 40, 1024 // block, 8
+        kp = S((n_blocks, block, hkv, d), pool)
+        sc = S((n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
+        buf = S((2, steps, hkv, d), jnp.bfloat16)
+        self._lower(
+            lambda q, kp, vp, t, kb, vb, n, s, ks, vs: paged_chunk_decode_attention(
+                q, kp, vp, t, kb, vb, n, s, window=window,
+                k_scales=ks, v_scales=vs, use_kernel=True,
+            ),
+            S((2, 1, hq, d), jnp.bfloat16), kp, kp, S((2, n_tbl), jnp.int32),
+            buf, buf, S((2,), jnp.int32), S((), jnp.int32), sc, sc,
+        )
+
+
+@pytest.mark.parametrize("hkv", [4, 1, 2])  # kv sharded / MQA / replicated
+def test_kernels_under_a_tp_mesh_match_single_device(hkv):
+    """Mosaic kernels cannot be partitioned by GSPMD, so under a TP mesh
+    each runs inside a shard_map over its heads (ops.attention._head_axes).
+    Interpret mode on the virtual mesh: same values as with no mesh."""
+    import numpy as np
+
+    from gofr_tpu.kvcache.paged import quantize_rows
+    from gofr_tpu.ops.attention import paged_chunk_decode_attention
+    from gofr_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"data": 1, "model": 4}, devices=jax.devices()[:4])
+    hq, d, b = 8, 128, 2
+    q, k, v = _qkv(b=b, sq=128, sk=256, hq=hq, hkv=hkv, d=d)
+    offs = jnp.asarray([0, 97], jnp.int32)
+    for kw in ({}, {"q_offsets": offs}):
+        kk = k if kw else k[:, :128]
+        vv = v if kw else v[:, :128]
+        want = flash_attention(q, kk, vv, interpret=True, **kw)
+        got = jax.jit(
+            lambda q, k, v: flash_attention(q, k, v, interpret=True, mesh=mesh, **kw)
+        )(q, kk, vv)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+    rng = np.random.RandomState(0)
+    block, n_tbl, n_blocks, steps = 16, 4, 12, 4
+    pk = jnp.asarray(rng.randn(n_blocks, block, hkv, d).astype(np.float32))
+    pv = jnp.asarray(rng.randn(n_blocks, block, hkv, d).astype(np.float32))
+    tables = jnp.asarray(rng.randint(0, n_blocks, size=(b, n_tbl)).astype(np.int32))
+    kb = jnp.asarray(rng.randn(b, steps, hkv, d).astype(np.float32))
+    vb = jnp.asarray(rng.randn(b, steps, hkv, d).astype(np.float32))
+    lengths, step = jnp.asarray([13, 50], jnp.int32), jnp.asarray(2, jnp.int32)
+    (qk, sk), (qv, sv) = quantize_rows(pk), quantize_rows(pv)
+    for kp, vp, ks, vs in ((pk, pv, None, None), (qk, qv, sk, sv)):
+
+        def attend(mesh):
+            return jax.jit(
+                lambda q, kp, vp, ks, vs: paged_chunk_decode_attention(
+                    q, kp, vp, tables, kb, vb, lengths, step, k_scales=ks,
+                    v_scales=vs, use_kernel=True, interpret=True, mesh=mesh,
+                )
+            )(q[:, :1], kp, vp, ks, vs)
+
+        np.testing.assert_allclose(
+            np.asarray(attend(mesh)), np.asarray(attend(None)), atol=1e-6
+        )

@@ -59,10 +59,10 @@ class TestTensorParallel:
     def test_tp_prefill_matches_single_device(self, tp):
         """The same params sharded over the model axis must produce the
         single-device logits — GSPMD collectives are numerically
-        transparent. The long-standing tp=8 failure ("old-jax TP prefill
-        drift", flagged since PR 2) was not reduction-order noise: tiny's
+        transparent. The long-standing tp=8 failure ("TP prefill drift",
+        flagged since PR 2) was not reduction-order noise: tiny's
         4 heads x 16 head_dim sharded 8 ways put a shard boundary INSIDE
-        each head, which this jax/XLA version miscompiles through the
+        each head, which an earlier jax/XLA miscompiled through the
         rope/attention reshapes (logits off by ~1.0, cache rows by ~3.5).
         param_specs now shards q/o at whole-head granularity only
         (replicated when tp does not divide n_heads, the kv rule), so

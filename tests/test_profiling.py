@@ -107,6 +107,26 @@ class TestInstrumentJit:
         assert float(f(jnp.ones((4,)), jnp.ones((4,)))[0]) == 1.0
         assert reg.snapshot()["programs"][0]["measured"] == "aot"
 
+    def test_leaving_aot_dispatch_is_listed_not_silent(self):
+        """An executable that rejects intact inputs drops the wrapper to
+        plain jit for good — and the registry says so, with the error."""
+        reg = CompileRegistry()
+        f = instrument_jit("drift", lambda a: a + 1, registry=reg, model="m")
+        x = jnp.ones((4,))
+        f(x)
+        assert reg.snapshot()["degraded"] == []
+
+        def reject(*_a):
+            raise ValueError("layout drift")
+
+        for sig in list(f._compiled):
+            f._compiled[sig] = reject
+        assert float(f(x)[0]) == 2.0  # served through plain jit
+        assert reg.snapshot()["degraded"] == [
+            {"program": "drift", "model": "m", "reason": "ValueError: layout drift"}
+        ]
+        assert reg.snapshot(model="other")["degraded"] == []
+
     def test_static_argnums_compile_per_value(self):
         """Static args are compile-time constants: distinct values must
         compile distinct executables (never collide on one signature),
@@ -408,7 +428,11 @@ class TestEndpoints:
         _, base = served
         with urllib.request.urlopen(f"{base}/.well-known/debug/compiles", timeout=10) as r:
             body = json.loads(r.read())["data"]
-        assert set(body) == {"programs", "totals", "backend_events", "warmup"}
+        assert set(body) == {
+            "programs", "totals", "backend_events", "warmup", "degraded",
+        }
+        # the registry is process-wide: only this engine's rows are ours
+        assert [d for d in body["degraded"] if d["model"] == "tinyprof"] == []
         mine = [e for e in body["programs"] if e["model"] == "tinyprof"]
         # chunked scheduler: prompts run through the unified step programs
         assert any(

@@ -110,6 +110,31 @@ class TestKVSpecs:
             tp_submeshes(CFG, 4, replicas=3)  # 12 chips > 8
 
 
+def test_tp_warmup_compiles_what_serving_dispatches(params):
+    """Under a TP mesh the programs hand their small state back replicated
+    over the mesh, and an AOT executable accepts only the shardings it
+    was compiled for: a warm-up that stood in single-device zeros sent
+    llm.admit_update and llm.hit_first to plain jit (a recompile in the
+    serving path) on the first admission and the first prefix hit."""
+    from gofr_tpu.profiling import default_registry
+
+    eng = _tp_engine(
+        params, 2, warmup=True, prefix_cache_mb=8.0, kv_label="tpwarm"
+    )
+    try:
+        warm = default_registry().snapshot(model="tpwarm")["totals"]["compiles"]
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3]
+        first = eng.generate(list(prompt), max_new_tokens=6)
+        again = eng.generate(list(prompt), max_new_tokens=6)  # exact hit
+        assert first == again == _reference(params, CFG, prompt, 6)
+        assert eng.stats()["kvcache"]["prefix"]["hits"] >= 1
+        snap = default_registry().snapshot(model="tpwarm")
+        assert snap["degraded"] == []
+        assert snap["totals"]["compiles"] == warm
+    finally:
+        eng.close()
+
+
 # ---------------------------------------------------------------------------
 # TP == single chip, across the slot families
 # ---------------------------------------------------------------------------
