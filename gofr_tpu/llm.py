@@ -122,6 +122,8 @@ from typing import Any, Iterator
 
 import numpy as np
 
+from .profiling import engine_span, name_os_thread
+
 __all__ = [
     "LLMEngine",
     "ReplicatedLLMEngine",
@@ -133,6 +135,17 @@ __all__ = [
 ]
 
 _EOS_DEFAULT = -1  # no EOS cut by default (random-weight models)
+
+# One record per dispatched program (docs/advanced-guide/profiling.md, "The
+# step timeline"): opened at dispatch in the in-flight entry's info, finished
+# by the collector, kept as a tuple in this order. Times are perf_counter().
+STEP_FIELDS = (
+    "seq", "kind", "program", "k", "depth", "lanes", "decode_ctx", "rows",
+    "t_dispatch", "t_dispatched", "t_fetch", "t_fetched", "t_emitted", "emitted",
+)
+# the ring holds two benchmark windows of programs (50 s each) down to 25 ms a program
+STEP_LOG_LEN = 4096
+_DECODE_KINDS = ("chunk", "step", "verify")
 
 # Serializes app_llm_* registration across engines (ReplicatedLLMEngine
 # builds N engines on parallel threads; same rationale as the kvcache
@@ -2434,9 +2447,14 @@ class LLMEngine:
         # in-flight device work, oldest first. Entries snapshot the REQUEST
         # objects they serve, so a slot can be reassigned while older
         # chunks still carry its previous request's tokens:
-        #   ("chunk", toks_dev [K,S], [req-or-None per slot])
-        #   ("prefill", first_dev [nb], [(slot, req), ...])
+        #   ("chunk", toks_dev [K,S], [req-or-None per slot], k, info)
+        #   ("prefill", first_dev [nb], [(slot, req), ...], info)
+        # every kind's last element is its info dict, which holds the
+        # program's step record (STEP_FIELDS) from dispatch to emit
         self._inflight: deque = deque()
+        self._step_seq = 0
+        self._stat_admitted = 0  # slots assigned: sched.admit's count per pass
+        self._step_log: deque = deque(maxlen=STEP_LOG_LEN)
         # Two engine threads: the SCHEDULER owns every device dispatch
         # (admission prefills, inserts, decode chunks); the COLLECTOR owns
         # the blocking device->host fetches and token emission. One
@@ -2867,6 +2885,10 @@ class LLMEngine:
                 # recent-window phase latencies (seconds): exact p50/p99
                 # over the last ~512 observations per phase
                 "phases": {k: w.summary() for k, w in self._phases.items()},
+                # the step timeline: one finished record per dispatched
+                # program, oldest first; a copy of the ring's references,
+                # no work per record
+                "step_log": {"fields": STEP_FIELDS, "records": tuple(self._step_log)},
                 # utilization: analytic-FLOPs MFU + tokens/s/chip windows
                 # and the roofline verdict (profiling.mfu)
                 "mfu": self._mfu_summary(),
@@ -2953,7 +2975,7 @@ class LLMEngine:
                         "requests": [r.id for _, r in e[2] if r is not None],
                         "wave": e[3]["nb"] or len(e[2]),
                         "bucket": e[3]["bucket"],
-                        "age_ms": round((now - e[3]["t0"]) * 1e3, 1),
+                        "age_ms": round((now - e[3]["t_dispatch"]) * 1e3, 1),
                     })
                 elif e[0] == "step":
                     inflight.append({
@@ -2963,7 +2985,7 @@ class LLMEngine:
                         "finishing": [r.id for _j, _s, r in e[2]],
                         "decode_steps": e[5],
                         "active": e[6]["active"],
-                        "age_ms": round((now - e[6]["t0"]) * 1e3, 1),
+                        "age_ms": round((now - e[6]["t_dispatch"]) * 1e3, 1),
                     })
                 elif e[0] == "verify":
                     inflight.append({
@@ -2971,18 +2993,19 @@ class LLMEngine:
                         "requests": [r.id for _s, r in e[3]],
                         "draft": e[4]["W"] - 1,
                         "proposed": e[4]["proposed"],
-                        "age_ms": round((now - e[4]["t0"]) * 1e3, 1),
+                        "age_ms": round((now - e[4]["t_dispatch"]) * 1e3, 1),
                     })
                 else:
                     inflight.append({
                         "kind": "chunk",
                         "steps": e[3],
                         "active": sum(r is not None for r in e[2]),
-                        "age_ms": round((now - e[4]) * 1e3, 1),
+                        "age_ms": round((now - e[4]["t_dispatch"]) * 1e3, 1),
                     })
             waiting_total = self._admit_q.qsize() + len(self._waiting)
             waiting = [req_row(r) for r in self._waiting[:32]]
             phases = {k: w.summary() for k, w in self._phases.items()}
+            steps = list(self._step_log)[-32:]
         return {
             "label": self.label,
             "version": self.version,
@@ -3025,6 +3048,9 @@ class LLMEngine:
             "moe_experts": int(getattr(self.cfg, "n_experts", 0) or 0),
             "slot_table": slot_table,
             "inflight": inflight,
+            # the step timeline's newest records (STEP_FIELDS; the whole
+            # ring is stats()["step_log"])
+            "steps": [dict(zip(STEP_FIELDS, rec)) for rec in steps],
             "waiting_total": waiting_total,
             "waiting": waiting,
             "admitting": self._admitting,
@@ -4577,7 +4603,7 @@ class LLMEngine:
                 pack[j, -2] = n
                 pack[j, -1] = np.float32(r.temperature).view(np.int32)
             t0 = time.perf_counter()
-            with self._hb_dispatch.beat("dispatch:prefill"):
+            with engine_span("dispatch.call", self._hb_dispatch, kind="prefill"):
                 first_dev, new_cache, logits_dev, self._rng = self._prefill_op(
                     self.params, jnp.asarray(pack), self._rng,
                 )
@@ -4712,7 +4738,9 @@ class LLMEngine:
                 self._start_fetch(first_dev)
                 self._inflight.append((
                     "prefill", first_dev, taken,
-                    {"t0": t0, "nb": 0, "bucket": None},
+                    {**self._step_open(None, "prefill", self._hit_first_op, t0,
+                                       time.perf_counter()),
+                     "nb": 0, "bucket": None},
                 ))
                 self._admitting -= len(reqs)
                 self._work_cv.notify()
@@ -4782,6 +4810,7 @@ class LLMEngine:
             old.out.put(None)
         self._slot_req[slot] = r
         r.slot = slot
+        self._stat_admitted += 1
         if self.lora_slots and self._aids_host[slot] != r._aid:
             # the slot's lane now computes under r's adapter; the device
             # mirror re-ships lazily at the next dispatch (_ship_aids)
@@ -5398,11 +5427,6 @@ class LLMEngine:
         now = time.perf_counter()
         for r in reqs:
             self._observe_admission(r, now)
-        info = {
-            "t0": wave_t0 if wave_t0 is not None else now,
-            "nb": wave_nb or 0,
-            "bucket": bucket,
-        }
         with self._work_cv:
             meta = np.zeros((3, self.admit_cap), np.int32)
             taken: list[tuple[int, GenRequest]] = []
@@ -5437,6 +5461,18 @@ class LLMEngine:
                 self._tail, self._active, self._temps, first_dev, md
             )
             self._start_fetch(first_dev)
+            # a miss wave ran the prefill program over whole prompts; a hit
+            # wave only sampled first tokens from stored logits
+            info = {
+                **self._step_open(
+                    None, "prefill",
+                    self._prefill_op if bucket is not None else self._hit_first_op,
+                    wave_t0 if wave_t0 is not None else now, time.perf_counter(),
+                    rows=tuple((0, len(r.prompt_tokens), bucket) for r in reqs)
+                    if bucket is not None else (),
+                ),
+                "nb": wave_nb or 0, "bucket": bucket,
+            }
             self._inflight.append(("prefill", first_dev, taken, info))
             self._admitting -= len(reqs)
             if wave_nb is not None:
@@ -5453,6 +5489,55 @@ class LLMEngine:
                 copy()
             except Exception:  # pragma: no cover — backend-dependent
                 pass
+
+    # -- the step timeline --------------------------------------------------
+    def _decode_depth(self) -> int:
+        """Decode-class programs in flight, the one the collector holds
+        included. Call with the lock held."""
+        depth = sum(1 for e in self._inflight if e[0] in _DECODE_KINDS)
+        if self._processing is not None and self._processing[0] in _DECODE_KINDS:
+            depth += 1
+        return depth
+
+    def _step_open(
+        self, span, kind: str, op, t_dispatch: float, t_dispatched: float,
+        *, k: int = 0, lanes: int = 0, rows: tuple = (),
+    ) -> dict:
+        """The record of the program just dispatched (STEP_FIELDS): it rides
+        the in-flight entry's info and the collector finishes it. `span` is
+        the open sched.dispatch span, which learns which program it was
+        (None for an admission wave: its programs go out inside sched.admit).
+        Call with the lock held, before the entry is appended."""
+        self._step_seq += 1
+        program = getattr(op, "program", "")
+        if span is not None:
+            span.set(seq=self._step_seq, kind=kind, program=program)
+        return {
+            "seq": self._step_seq, "kind": kind, "program": program, "k": k,
+            "depth": self._decode_depth(), "lanes": lanes, "decode_ctx": (),
+            "rows": rows, "t_dispatch": t_dispatch, "t_dispatched": t_dispatched,
+            "t_fetch": 0.0, "t_fetched": 0.0, "t_emitted": 0.0, "emitted": 0,
+        }
+
+    def _step_close(self, info: dict, decode_ctx=(), first: int = 0) -> None:
+        """The collector emitted the program's tokens: the record is whole
+        and joins the ring. Call with the lock held (stats() copies the ring
+        under it). `decode_ctx` pairs each decoding lane's context at the
+        program's start (window-capped) with the tokens it emitted; `first`
+        counts the first tokens that came out of prompt rows."""
+        info["decode_ctx"] = tuple(decode_ctx)
+        info["emitted"] = first + sum(n for _ctx, n in info["decode_ctx"])
+        info["t_emitted"] = time.perf_counter()
+        self._step_log.append(tuple(info[f] for f in STEP_FIELDS))
+
+    def _ctx_of(self, r: GenRequest) -> int:
+        """A decoding lane's context (prompt + emitted so far), capped at
+        the sliding window since the rolling ring never reads past it. Read
+        by the collector, where every earlier program's tokens are counted;
+        at dispatch `emitted` lags by the programs in flight."""
+        c = len(r.prompt_tokens) + r.emitted
+        w = self._costs.sliding_window
+        return min(c, w) if w else c
 
     # -- observability ----------------------------------------------------
     def _observe_mfu(
@@ -5508,21 +5593,6 @@ class LLMEngine:
                 "bound": self._mfu_mod.classify_bound(decode_ratio["p50"]),
             },
         }
-
-    def _ctx_tokens(self, snapshot: list) -> tuple[int, int]:
-        """(active requests, summed attended context positions) for one
-        chunk step — per-slot context capped at the sliding window, since
-        the rolling ring never reads past it."""
-        w = self._costs.sliding_window
-        active = 0
-        ctx = 0
-        for r in snapshot:
-            if r is None:
-                continue
-            active += 1
-            c = len(r.prompt_tokens) + r.emitted
-            ctx += min(c, w) if w else c
-        return active, ctx
 
     def _phase_span(
         self, r: GenRequest, name: str, t0: float, t1: float,
@@ -5730,14 +5800,15 @@ class LLMEngine:
         for ev in events:
             self.logger.info(ev)
 
-    def _emit_to(self, r: GenRequest, slot: int, toks: list[int], now: float | None = None) -> None:
-        """Append a request's next tokens, honoring max_new/eos/cancel.
+    def _emit_to(self, r: GenRequest, slot: int, toks: list[int], now: float | None = None) -> int:
+        """Append a request's next tokens, honoring max_new/eos/cancel, and
+        return how many the consumer was handed.
         Frees the slot only if `r` still owns it (virtual-free admission
         may already have handed the slot to a successor). `now` is the
         fetch-completion time (phase attribution measures device+fetch,
         not the emit loop's position within the batch)."""
         if r.finish_reason is not None:
-            return  # already finished; stale chunk overlap
+            return 0  # already finished; stale chunk overlap
         if self._died:
             # a dying engine must NEVER emit: its recoverable requests are
             # (or are about to be) rescued by the failover hook, and a
@@ -5746,7 +5817,7 @@ class LLMEngine:
             # under _lock — the same lock _die holds while rescuing — so
             # an emission is either fully before the rescue (counted in
             # history) or fully dropped.
-            return
+            return 0
         if now is None:
             now = time.perf_counter()
         finish = None
@@ -5811,8 +5882,9 @@ class LLMEngine:
             r.out.put(None)
             if self._slot_req[slot] is r:
                 self._slot_req[slot] = None
+        return len(toks)
 
-    def _dispatch(self, needed_steps: int) -> int:
+    def _dispatch(self, needed_steps: int, span) -> int:
         """Launch one decode chunk chained from the on-device tail and
         return the dispatched chunk length (the scheduler debits it from
         its step budget). All inputs are device-resident — zero h2d
@@ -5828,7 +5900,7 @@ class LLMEngine:
         a fresh arrival waits at most one chunk, and the collector's
         prefill-priority jump still fetches its first token ahead of
         queued chunk fetches. The saturated path is unchanged (full chunks
-        either way)."""
+        either way). `span` is the scheduler's open sched.dispatch span."""
         self._ship_aids()
         with self._work_cv:
             # partial-prefill occupants are resident but NOT decoding:
@@ -5855,6 +5927,7 @@ class LLMEngine:
             if use_g:
                 self._ensure_c_ops()
                 gids = self._jnp.asarray(self._gids_np())
+            op = (self._chunk_ops_c if use_g else self._chunk_ops)[k]
             if self.kv.paged:
                 # allocate blocks ahead of the chunk's cursor advance and
                 # build the host liveness mask. Two exclusions: stale
@@ -5877,44 +5950,45 @@ class LLMEngine:
                         self._kv_hi[i] + k, r._kv_limit or self.kv.capacity
                     )
                     self.kv.ensure(i, self._kv_hi[i])
-                td = self._tables_device()
-                with self._hb_dispatch.beat("dispatch:chunk"):
+                with engine_span("dispatch.inputs"):
+                    td = self._tables_device()
+                    live_dev = self._jnp.asarray(live)
+                with engine_span("dispatch.call", self._hb_dispatch, kind="chunk"):
                     if use_g:
                         (
                             toks, last, self.cache, self._kv_scales,
                             self._gstate, self._rng,
-                        ) = self._chunk_ops_c[k](
+                        ) = op(
                             self.params, self._tail, self.cache,
-                            self._kv_scales, td, self._jnp.asarray(live),
+                            self._kv_scales, td, live_dev,
                             self._active, self._temps, self._gstate,
                             gids, self._rng, self._gr_dev,
                         )
                     else:
-                        toks, last, self.cache, self._kv_scales, self._rng = (
-                            self._chunk_ops[k](
-                                self.params, self._tail, self.cache,
-                                self._kv_scales, td, self._jnp.asarray(live),
-                                self._active, self._temps, self._rng,
-                            )
+                        toks, last, self.cache, self._kv_scales, self._rng = op(
+                            self.params, self._tail, self.cache,
+                            self._kv_scales, td, live_dev,
+                            self._active, self._temps, self._rng,
                         )
             else:
-                with self._hb_dispatch.beat("dispatch:chunk"):
+                with engine_span("dispatch.call", self._hb_dispatch, kind="chunk"):
                     if use_g:
-                        toks, last, self.cache, self._gstate, self._rng = (
-                            self._chunk_ops_c[k](
-                                self.params, self._tail, self.cache,
-                                self._active, self._temps, self._gstate,
-                                gids, self._rng, self._gr_dev,
-                            )
+                        toks, last, self.cache, self._gstate, self._rng = op(
+                            self.params, self._tail, self.cache,
+                            self._active, self._temps, self._gstate,
+                            gids, self._rng, self._gr_dev,
                         )
                     else:
-                        toks, last, self.cache, self._rng = self._chunk_ops[k](
+                        toks, last, self.cache, self._rng = op(
                             self.params, self._tail, self.cache,
                             self._active, self._temps, self._rng,
                         )
+            info = self._step_open(
+                span, "chunk", op, t0, time.perf_counter(), k=k, lanes=active_n,
+            )
             self._tail = last
             self._start_fetch(toks)
-            self._inflight.append(("chunk", toks, snapshot, k, t0))
+            self._inflight.append(("chunk", toks, snapshot, k, info))
             self._stat_chunks += 1
             self._stat_chunk_steps += k
             self._stat_active_sum += active_n
@@ -5932,7 +6006,7 @@ class LLMEngine:
                 return s
         return self.chunk_shapes[-1]
 
-    def _dispatch_step(self) -> bool:
+    def _dispatch_step(self, span) -> bool:
         """Pack one unified device step: one decode chunk for the active
         slots fused with up to admit_cap pending prefill chunks. The
         decode tokens are charged against step_token_budget first and
@@ -5944,7 +6018,8 @@ class LLMEngine:
         immediately in the same program (an all-inactive decode part is
         masked work that only occurs during cold prefill ramp). Returns
         False when every queued prefill row turned out stale
-        (reassigned/cancelled)."""
+        (reassigned/cancelled). `span` is the scheduler's open
+        sched.dispatch span."""
         jnp = self._jnp
         self._ship_aids()
         self._fault("device_step")  # before any cursor mutation
@@ -6004,7 +6079,7 @@ class LLMEngine:
             meta[2, :] = -1  # pad/unconstrained lanes: no grammar
             finishes: list[tuple[int, int, GenRequest]] = []
             prefill_tokens = 0
-            spans: list[tuple[int, int]] = []  # (cursor, n) for MFU
+            spans: list[tuple[int, int, int]] = []  # (cursor, n, shape): the record's rows
             for j, (r, n) in enumerate(rows):
                 pos = r.prefill_pos
                 pack[j, :n] = r.prompt_tokens[pos : pos + n]
@@ -6041,7 +6116,7 @@ class LLMEngine:
                     self.kv.ensure(r.slot, self._kv_hi[r.slot])
                 self._load_credit(r, n)
                 prefill_tokens += n
-                spans.append((pos, n))
+                spans.append((pos, n, shape))
                 if done:
                     r.prefill_done = True
                     finishes.append((j, r.slot, r))
@@ -6077,43 +6152,46 @@ class LLMEngine:
                             r._kv_limit or self.kv.capacity,
                         )
                         self.kv.ensure(i, self._kv_hi[i])
-                td = self._tables_device()
-                with self._hb_dispatch.beat("dispatch:step"):
+            with engine_span("dispatch.inputs"):
+                pack_dev = jnp.asarray(pack)
+                meta_dev = jnp.asarray(meta if use_g else meta[:2])
+                if self.kv.paged:
+                    td = self._tables_device()
+                    live_dev = jnp.asarray(live)
+            with engine_span("dispatch.call", self._hb_dispatch, kind="step"):
+                if self.kv.paged:
                     if use_g:
                         (first_dev, logits_dev, toks_dev, last, cache,
                          self._kv_scales, active, temps, self._gstate,
                          rng) = op(
                             self.params, self.cache, self._kv_scales, td,
-                            jnp.asarray(live), self._tail, self._active,
-                            self._temps, self._gstate, jnp.asarray(pack),
-                            jnp.asarray(meta), gids, self._rng,
-                            self._gr_dev,
+                            live_dev, self._tail, self._active,
+                            self._temps, self._gstate, pack_dev,
+                            meta_dev, gids, self._rng, self._gr_dev,
                         )
                     else:
                         (first_dev, logits_dev, toks_dev, last, cache,
                          self._kv_scales, active, temps, rng) = op(
                             self.params, self.cache, self._kv_scales, td,
-                            jnp.asarray(live), self._tail, self._active,
-                            self._temps, jnp.asarray(pack),
-                            jnp.asarray(meta[:2]), self._rng,
+                            live_dev, self._tail, self._active,
+                            self._temps, pack_dev, meta_dev, self._rng,
                         )
-            else:
-                with self._hb_dispatch.beat("dispatch:step"):
-                    if use_g:
-                        (first_dev, logits_dev, toks_dev, last, cache,
-                         active, temps, self._gstate, rng) = op(
-                            self.params, self.cache, self._tail,
-                            self._active, self._temps, self._gstate,
-                            jnp.asarray(pack), jnp.asarray(meta), gids,
-                            self._rng, self._gr_dev,
-                        )
-                    else:
-                        (first_dev, logits_dev, toks_dev, last, cache,
-                         active, temps, rng) = op(
-                            self.params, self.cache, self._tail,
-                            self._active, self._temps, jnp.asarray(pack),
-                            jnp.asarray(meta[:2]), self._rng,
-                        )
+                elif use_g:
+                    (first_dev, logits_dev, toks_dev, last, cache,
+                     active, temps, self._gstate, rng) = op(
+                        self.params, self.cache, self._tail,
+                        self._active, self._temps, self._gstate,
+                        pack_dev, meta_dev, gids,
+                        self._rng, self._gr_dev,
+                    )
+                else:
+                    (first_dev, logits_dev, toks_dev, last, cache,
+                     active, temps, rng) = op(
+                        self.params, self.cache, self._tail,
+                        self._active, self._temps, pack_dev,
+                        meta_dev, self._rng,
+                    )
+            t_dispatched = time.perf_counter()
             self._tail = last
             self.cache, self._active, self._temps, self._rng = (
                 cache, active, temps, rng,
@@ -6155,12 +6233,15 @@ class LLMEngine:
             decode_n = active_n + len(finishes)
             step_tokens = prefill_tokens + K * decode_n
             info = {
-                "t0": t0, "shape": shape, "nb": nb,
-                "prefill_tokens": prefill_tokens, "spans": spans,
-                "active": active_n,
-                # row requests aligned with spans — the goodput ledger
-                # attributes each prefill span to its owner at the fetch
-                "rows": [r for r, _n in rows],
+                **self._step_open(
+                    span, "step", op, t0, t_dispatched,
+                    k=K, lanes=decode_n, rows=tuple(spans),
+                ),
+                "shape": shape, "nb": nb,
+                "prefill_tokens": prefill_tokens, "active": active_n,
+                # row requests aligned with the record's rows — the goodput
+                # ledger attributes each prefill span to its owner at the fetch
+                "row_reqs": [r for r, _n in rows],
             }
             self._inflight.append(
                 ("step", first_dev, finishes, toks_dev, snapshot, K, info)
@@ -6274,7 +6355,7 @@ class LLMEngine:
         bonus = d_full[k : k + 1] or d[-1:]
         return d, d + bonus
 
-    def _dispatch_verify(self) -> bool:
+    def _dispatch_verify(self, span) -> bool:
         """Dispatch one fused speculative verify step (gofr_tpu.spec):
         every decoding slot whose in-flight coverage is verify-only gets
         its draft packed into one full-batch llm.step_v program; lanes
@@ -6289,7 +6370,8 @@ class LLMEngine:
         bounds the step, it is not a stall gate). Returns False when no
         slot was eligible OR nothing was drafted anywhere — the caller
         then runs the plain chunk pipeline, which is the adaptive
-        backoff's no-regression guarantee at engine scope."""
+        backoff's no-regression guarantee at engine scope. `span` is the
+        scheduler's open sched.dispatch span."""
         jnp = self._jnp
         self._ship_aids()
         self._fault("device_step")
@@ -6388,46 +6470,46 @@ class LLMEngine:
                         r._kv_limit or self.kv.capacity,
                     )
                     self.kv.ensure(slot, self._kv_hi[slot])
-                td = self._tables_device()
-                with self._hb_dispatch.beat("dispatch:verify"):
+            op = self._verify_op_c if use_g else self._verify_op
+            with engine_span("dispatch.inputs"):
+                pack_dev = jnp.asarray(pack)
+                if self.kv.paged:
+                    td = self._tables_device()
+            with engine_span("dispatch.call", self._hb_dispatch, kind="verify"):
+                if self.kv.paged:
                     if use_g:
                         (ys, acc, cache, self._kv_scales, tail,
-                         self._gstate, self._rng) = self._verify_op_c(
+                         self._gstate, self._rng) = op(
                             self.params, self.cache, self._kv_scales, td,
                             self._tail, self._temps, self._gstate,
-                            jnp.asarray(pack), gids_dev, self._rng,
-                            self._gr_dev,
+                            pack_dev, gids_dev, self._rng, self._gr_dev,
                         )
                     else:
-                        ys, acc, cache, self._kv_scales, tail, self._rng = (
-                            self._verify_op(
-                                self.params, self.cache, self._kv_scales,
-                                td, self._tail, self._temps,
-                                jnp.asarray(pack), self._rng,
-                            )
+                        ys, acc, cache, self._kv_scales, tail, self._rng = op(
+                            self.params, self.cache, self._kv_scales,
+                            td, self._tail, self._temps, pack_dev, self._rng,
                         )
-            else:
-                with self._hb_dispatch.beat("dispatch:verify"):
-                    if use_g:
-                        ys, acc, cache, tail, self._gstate, self._rng = (
-                            self._verify_op_c(
-                                self.params, self.cache, self._tail,
-                                self._temps, self._gstate,
-                                jnp.asarray(pack), gids_dev, self._rng,
-                                self._gr_dev,
-                            )
-                        )
-                    else:
-                        ys, acc, cache, tail, self._rng = self._verify_op(
-                            self.params, self.cache, self._tail,
-                            self._temps, jnp.asarray(pack), self._rng,
-                        )
+                elif use_g:
+                    ys, acc, cache, tail, self._gstate, self._rng = op(
+                        self.params, self.cache, self._tail,
+                        self._temps, self._gstate,
+                        pack_dev, gids_dev, self._rng, self._gr_dev,
+                    )
+                else:
+                    ys, acc, cache, tail, self._rng = op(
+                        self.params, self.cache, self._tail,
+                        self._temps, pack_dev, self._rng,
+                    )
+            t_dispatched = time.perf_counter()
             self.cache, self._tail = cache, tail
             self._start_fetch(ys)
             self._start_fetch(acc)
             step_tokens = W * len(sel)
             info = {
-                "t0": t0, "W": W, "proposed": proposed,
+                **self._step_open(
+                    span, "verify", op, t0, t_dispatched, k=W, lanes=len(sel),
+                ),
+                "W": W, "proposed": proposed,
                 "n_draft": n_draft, "cursors": cursors, "pred": pred,
                 "gset": gset,
             }
@@ -6464,16 +6546,35 @@ class LLMEngine:
 
     def _process_entry(self, entry: tuple) -> None:
         """Fetch one device result (outside the lock — the blocking RTT
-        must not stall the scheduler) and emit tokens (under the lock)."""
+        must not stall the scheduler) and emit tokens (under the lock).
+        The two halves are the collector's collect.fetch and collect.emit
+        spans; both beat for the step watchdog."""
+        kind, info = entry[0], entry[-1]
+        if kind == "step":
+            arrays = (entry[1] if entry[2] else None, entry[3])
+        elif kind == "verify":
+            arrays = entry[1:3]
+        else:
+            arrays = entry[1:2]
+        with engine_span("collect.fetch", self._hb_fetch, kind=kind, seq=info["seq"]):
+            self._fault_latency()  # chaos: a wedged transfer
+            info["t_fetch"] = time.perf_counter()
+            # blocks; the device runs the next program meanwhile
+            fetched = [None if a is None else np.asarray(a) for a in arrays]
+            info["t_fetched"] = time.perf_counter()
+        with engine_span("collect.emit", self._hb_fetch, kind=kind, seq=info["seq"]):
+            self._emit_entry(entry, *fetched)
+
+    def _emit_entry(self, entry: tuple, *fetched) -> None:
         if entry[0] == "verify":
-            self._process_verify_entry(entry)
+            self._process_verify_entry(entry, *fetched)
             return
         if entry[0] == "step":
-            self._process_step_entry(entry)
+            self._process_step_entry(entry, *fetched)
             return
         if entry[0] == "prefill":
-            _, first_dev, taken, info = entry
-            first = np.asarray(first_dev)
+            _, _first_dev, taken, info = entry
+            (first,) = fetched
             # numerical watchdog: scan BEFORE any emission, outside the
             # lock (_die must not run under our own lock — the failover
             # hook submits into other engines)
@@ -6484,13 +6585,13 @@ class LLMEngine:
             )
             if tripped:
                 return
-            now = time.perf_counter()
+            now, t_dispatch = info["t_fetched"], info["t_dispatch"]
             if info["bucket"] is not None:  # miss wave: a device prefill ran
                 # (prefix-hit waves dispatch no prefill — no MFU to claim)
                 seq_lens = [
                     len(r.prompt_tokens) for _, r in taken if r is not None
                 ]
-                self._observe_tput(sum(seq_lens), now - info["t0"])
+                self._observe_tput(sum(seq_lens), now - t_dispatch)
                 self._observe_mfu(
                     "prefill",
                     tokens=sum(seq_lens),
@@ -6499,7 +6600,7 @@ class LLMEngine:
                         self._costs.params_bytes
                         + sum(seq_lens) * self._costs.kv_bytes_per_ctx_token
                     ),
-                    dt=now - info["t0"],
+                    dt=now - t_dispatch,
                 )
             if self.goodput is not None:
                 from .goodput import prefill_classes
@@ -6530,46 +6631,48 @@ class LLMEngine:
                     )
                     if pad > 0:
                         lanes.append((None, {"padding": pad}))
-                self.goodput.observe("prefill", info["t0"], now, lanes)
+                self.goodput.observe("prefill", t_dispatch, now, lanes)
             with self._lock:
+                emitted = 0
                 for j, (slot, r) in enumerate(taken):
                     if r is None:  # scrubbed by preemption: tokens dropped
                         continue
                     if r.span is not None and r.finish_reason is None:
                         self._phase_span(
-                            r, "llm.prefill", info["t0"], now,
+                            r, "llm.prefill", t_dispatch, now,
                             attrs={
                                 "llm.wave": info["nb"] or len(taken),
                                 "llm.bucket": info["bucket"] or 0,
                                 "llm.prefix_hit": r.prefix_hit,
                             },
                         )
-                    self._emit_to(r, slot, [int(first[j])], now)
+                    emitted += self._emit_to(r, slot, [int(first[j])], now)
+                self._step_close(info, first=emitted)
                 self._processing = None  # same acquisition as the emits —
                 # a separate clear would let the scheduler double-count
                 # this entry in _inflight_steps after emitted already grew
             if self.logger is not None:
                 self._flush_wide_events()
             return
-        _, toks_dev, snapshot, k, t_dispatch = entry
-        t0 = time.perf_counter()
-        toks = np.asarray(toks_dev)  # [K, S] — blocks; device runs next chunk
+        _, _toks_dev, snapshot, k, info = entry
+        (toks,) = fetched  # [K, S]
         toks, tripped = self._numeric_check_fetch(
             toks, [s for s, r in enumerate(snapshot) if r is not None],
             "decode chunk",
         )
         if tripped:
             return
-        now = time.perf_counter()
+        now, t_dispatch = info["t_fetched"], info["t_dispatch"]
         if self.metrics is not None:
             self.metrics.record_histogram(
-                "app_tpu_stats", now - t0,
+                "app_tpu_stats", now - info["t_fetch"],
                 model="llm", op="decode_chunk",
             )
         # dispatch->fetch cost per decode step, attributed once per chunk
         # (wave = active slots at dispatch, bucketed to a power of two so
         # the label set stays bounded at log2(slots) values)
-        active_n, ctx_sum = self._ctx_tokens(snapshot)
+        ctxs = [self._ctx_of(r) for r in snapshot if r is not None]
+        active_n, ctx_sum = len(ctxs), sum(ctxs)
         self._observe_tput(k * active_n, now - t_dispatch)
         step_s = (now - t_dispatch) / k
         self._phases["decode_step"].observe(step_s)
@@ -6616,6 +6719,7 @@ class LLMEngine:
             self.goodput.observe("chunk", t_dispatch, now, lanes)
         cols = toks.T  # [S, K]
         with self._lock:
+            ns = []
             for slot, r in enumerate(snapshot):
                 if r is not None:
                     if r.span is not None and r.finish_reason is None:
@@ -6624,23 +6728,21 @@ class LLMEngine:
                             attrs={"llm.chunk": k, "llm.active": active_n,
                                    "llm.slot": slot},
                         )
-                    self._emit_to(r, slot, cols[slot].tolist(), now)
+                    ns.append(self._emit_to(r, slot, cols[slot].tolist(), now))
+            self._step_close(info, zip(ctxs, ns))
             self._processing = None
         if self.logger is not None:
             self._flush_wide_events()
 
-    def _process_step_entry(self, entry: tuple) -> None:
-        """Fetch and emit one unified step: first tokens for rows whose
+    def _process_step_entry(self, entry: tuple, first, toks) -> None:
+        """Emit one fetched unified step: first tokens for rows whose
         prompt completed this step (their llm.prefill span closes here),
         then the piggybacked decode chunk's columns. MFU accounting is
         per-step — one prefill observation over the chunk spans and one
         decode observation over the chunk, both against the step's
         dispatch->fetch wall (they share the device window; read the
         window percentiles, never sum them)."""
-        _, first_dev, finishes, toks_dev, snapshot, k, info = entry
-        t0 = time.perf_counter()
-        first = np.asarray(first_dev) if finishes else None
-        toks = np.asarray(toks_dev)
+        _, _first_dev, finishes, _toks_dev, snapshot, k, info = entry
         # numerical watchdog: both fetched arrays, before any emission
         if first is not None:
             first, tripped = self._numeric_check_fetch(
@@ -6655,8 +6757,9 @@ class LLMEngine:
         if tripped:
             return
         decoded = any(r is not None for r in snapshot)
-        now = time.perf_counter()
-        step_s = now - info["t0"]
+        now, t_dispatch = info["t_fetched"], info["t_dispatch"]
+        step_s = now - t_dispatch
+        spans = [row[:2] for row in info["rows"]]  # (cursor, n) per prompt row
         self._observe_tput(
             info["prefill_tokens"]
             + k * sum(1 for r in snapshot if r is not None),
@@ -6672,20 +6775,18 @@ class LLMEngine:
             )
             if decoded:
                 self.metrics.record_histogram(
-                    "app_tpu_stats", now - t0, model="llm", op="decode_chunk",
+                    "app_tpu_stats", now - info["t_fetch"], model="llm", op="decode_chunk",
                 )
         if info["prefill_tokens"]:
             ctx_read = sum(
                 min(pos, self._costs.sliding_window) if self._costs.sliding_window
                 else pos
-                for pos, _n in info["spans"]
+                for pos, _n in spans
             )
             self._observe_mfu(
                 "prefill",
                 tokens=info["prefill_tokens"],
-                flops=self._mfu_mod.chunk_prefill_flops(
-                    self._costs, info["spans"]
-                ),
+                flops=self._mfu_mod.chunk_prefill_flops(self._costs, spans),
                 bytes_moved=(
                     self._costs.params_bytes
                     + (info["prefill_tokens"] + ctx_read)
@@ -6694,7 +6795,8 @@ class LLMEngine:
                 dt=step_s,
             )
         if decoded:
-            active_n, ctx_sum = self._ctx_tokens(snapshot)
+            active_n = sum(r is not None for r in snapshot)
+            ctx_sum = sum(self._ctx_of(r) for r in snapshot if r is not None)
             # per-token cadence requests actually experience: a fused
             # step's wall includes its prefill-append compute (a short
             # request may complete entirely inside its own step, so
@@ -6732,7 +6834,7 @@ class LLMEngine:
             # decode ran k steps over ALL slot lanes. Padding = unpacked
             # prefill rectangle + empty decode lanes.
             lanes = []
-            for r, (pos, n) in zip(info.get("rows", ()), info["spans"]):
+            for r, (pos, n) in zip(info["row_reqs"], spans):
                 lanes.append((r, prefill_classes(r._replay_pos, pos, n)))
             decode_n = 0
             for r in snapshot:
@@ -6749,36 +6851,42 @@ class LLMEngine:
             )
             if pad > 0:
                 lanes.append((None, {"padding": pad}))
-            self.goodput.observe("step", info["t0"], now, lanes)
+            self.goodput.observe("step", t_dispatch, now, lanes)
         with self._lock:
+            emitted = 0
+            dctx = []
             for j, slot, r in finishes:
                 if r.span is not None and r.finish_reason is None:
                     self._phase_span(
-                        r, "llm.prefill", r._prefill_t0 or info["t0"], now,
+                        r, "llm.prefill", r._prefill_t0 or t_dispatch, now,
                         attrs={
                             "llm.wave": info["nb"],
                             "llm.bucket": info["shape"],
                             "llm.prefix_hit": r.prefix_hit,
                         },
                     )
-                self._emit_to(r, slot, [int(first[j])], now)
+                emitted += self._emit_to(r, slot, [int(first[j])], now)
             if decoded:
                 cols = toks.T  # [S, K]
                 for slot, r in enumerate(snapshot):
                     if r is not None:
                         if r.span is not None and r.finish_reason is None:
                             self._phase_span(
-                                r, "llm.decode", info["t0"], now,
+                                r, "llm.decode", t_dispatch, now,
                                 attrs={"llm.chunk": k, "llm.active":
                                        info["active"], "llm.slot": slot},
                             )
-                        self._emit_to(r, slot, cols[slot].tolist(), now)
+                        # after the first tokens: a lane that starts in this
+                        # step decodes from its prompt + 1
+                        ctx = self._ctx_of(r)
+                        dctx.append((ctx, self._emit_to(r, slot, cols[slot].tolist(), now)))
+            self._step_close(info, dctx, first=emitted)
             self._processing = None  # same acquisition as the emits
         if self.logger is not None:
             self._flush_wide_events()
 
-    def _process_verify_entry(self, entry: tuple) -> None:
-        """Fetch and emit one speculative verify step: per selected slot,
+    def _process_verify_entry(self, entry: tuple, ys, acc) -> None:
+        """Emit one fetched speculative verify step: per selected slot,
         the accepted draft tokens plus the bonus token (``ys[:acc+1]``)
         feed the existing emit path as ONE multi-token push — max_new /
         eos truncation, load_tokens credit, and the fairness ledger all
@@ -6786,9 +6894,7 @@ class LLMEngine:
         per-request EMA that sizes the next draft, and MFU bills only
         the accepted tokens (verified-but-rejected positions are
         non-useful work — profiling.mfu.spec_verify_flops)."""
-        _, ys_dev, acc_dev, sel, info = entry
-        ys = np.asarray(ys_dev)  # [S, W]
-        acc = np.asarray(acc_dev)  # [S]
+        _, _ys_dev, _acc_dev, sel, info = entry  # ys [S, W], acc [S]
         # numerical watchdog: live lanes scanned BEFORE any emission
         # (lanes are rows here; the helper scans last-axis columns)
         ys_t, tripped = self._numeric_check_fetch(
@@ -6797,8 +6903,8 @@ class LLMEngine:
         if tripped:
             return
         ys = ys_t.T
-        now = time.perf_counter()
-        dt = now - info["t0"]
+        now, t_dispatch = info["t_fetched"], info["t_dispatch"]
+        dt = now - t_dispatch
         w = self._costs.sliding_window
         emitted_total = 0
         accepted_total = 0
@@ -6889,10 +6995,11 @@ class LLMEngine:
             pad = ys.shape[1] * (ys.shape[0] - len(sel))
             if pad > 0:
                 lanes.append((None, {"padding": pad}))
-            self.goodput.observe("verify", info["t0"], now, lanes)
+            self.goodput.observe("verify", t_dispatch, now, lanes)
         from .spec import SPEC_EMA_ALPHA
 
         with self._lock:
+            dctx = []
             for slot, r in sel:
                 a = int(acc[slot])
                 toks = [int(t) for t in ys[slot, : a + 1]]
@@ -6903,7 +7010,7 @@ class LLMEngine:
                     )
                 if r.span is not None and r.finish_reason is None:
                     self._phase_span(
-                        r, "llm.decode", info["t0"], now,
+                        r, "llm.decode", t_dispatch, now,
                         attrs={
                             "llm.spec_draft": info["n_draft"].get(slot, 0),
                             "llm.spec_accepted": a,
@@ -6928,7 +7035,9 @@ class LLMEngine:
                 else:
                     r._spec_pending = []
                 r._spec_inflight = max(0, r._spec_inflight - 1)
-                self._emit_to(r, slot, toks, now)
+                ctx = self._ctx_of(r)
+                dctx.append((ctx, self._emit_to(r, slot, toks, now)))
+            self._step_close(info, dctx)
             self._processing = None  # same acquisition as the emits
         if self.logger is not None:
             self._flush_wide_events()
@@ -6948,6 +7057,7 @@ class LLMEngine:
 
     def _schedule_loop(self) -> None:
         jnp = self._jnp
+        name_os_thread()  # the profiler names this thread's line by it
         try:
             while not self._stop:
                 if self.faults.take("replica_kill", self.label) is not None:
@@ -6960,28 +7070,24 @@ class LLMEngine:
                 if self._poison_fault():
                     break  # tagged payload killed this replica (terminal)
                 try:
-                    self._run_sched_work()
-                    if self.kv.paged:
-                        # paged-pool housekeeping, in dependency order:
-                        # publish finished session turns (needs the
-                        # blocks), return retired slots' blocks, spill
-                        # cold sessions past their device budget
-                        self._kv_session_flush()
-                        self._kv_sweep()
-                        self._kv_session_spill()
-                    did = self._admit()
+                    with engine_span("sched.housekeep"):
+                        self._run_sched_work()
+                        if self.kv.paged:
+                            # paged-pool housekeeping, in dependency order:
+                            # publish finished session turns (needs the
+                            # blocks), return retired slots' blocks, spill
+                            # cold sessions past their device budget
+                            self._kv_session_flush()
+                            self._kv_sweep()
+                            self._kv_session_spill()
+                    with engine_span("sched.admit") as span:
+                        admitted0 = self._stat_admitted
+                        did = self._admit()
+                        span.set(admitted=self._stat_admitted - admitted0)
                     if self._stop:
                         break
-                    with self._lock:
-                        depth = sum(
-                            1 for e in self._inflight
-                            if e[0] in ("chunk", "step", "verify")
-                        )
-                        if (
-                            self._processing is not None
-                            and self._processing[0] in ("chunk", "step", "verify")
-                        ):
-                            depth += 1
+                    with engine_span("sched.plan"), self._lock:
+                        depth = self._decode_depth()
                         needed = self._needed_steps()
                         prefilling = bool(self._prefilling)
                     stepped = False
@@ -6989,7 +7095,8 @@ class LLMEngine:
                         # one unified step per pass: prefill chunks packed
                         # to the token budget, decode riding along — the
                         # loop comes straight back for the next step
-                        stepped = self._dispatch_step()
+                        with engine_span("sched.dispatch") as span:
+                            stepped = self._dispatch_step(span)
                         if stepped:
                             depth += 1
                             needed = max(0, needed - self.decode_chunk)
@@ -7011,7 +7118,7 @@ class LLMEngine:
                         # advances EVERY device-active slot from the
                         # on-device tail and would double-advance a
                         # verify's slots.
-                        with self._lock:
+                        with engine_span("sched.plan"), self._lock:
                             inflight_kinds = {
                                 e[0] for e in self._inflight
                             }
@@ -7025,7 +7132,8 @@ class LLMEngine:
                             not stepped and depth < self.lookahead
                             and self._spec_hold <= 0
                         ):
-                            did_v = self._dispatch_verify()
+                            with engine_span("sched.dispatch") as span:
+                                did_v = self._dispatch_verify(span)
                             if not did_v and not dec_fly:
                                 # clean attempt, nothing drafted: plain
                                 # decode burst before the next probe
@@ -7040,11 +7148,13 @@ class LLMEngine:
                             self.lookahead - depth,
                         )
                         for _ in range(max(0, want)):
-                            needed = max(0, needed - self._dispatch(needed))
+                            with engine_span("sched.dispatch") as span:
+                                needed = max(0, needed - self._dispatch(needed, span))
                             if self.speculative:
                                 self._spec_hold -= 1
                     if not did and not stepped and not did_v and want <= 0:
-                        self._kick.wait(timeout=0.005)
+                        with engine_span("sched.wait"):
+                            self._kick.wait(timeout=0.005)
                         self._kick.clear()
                 except Exception as e:  # noqa: BLE001 — engine must not die silently
                     if self.logger is not None:
@@ -7248,8 +7358,9 @@ class LLMEngine:
                 self._die("collector thread exited unexpectedly")
 
     def _collect_loop_inner(self) -> None:
+        name_os_thread()  # the profiler names this thread's line by it
         while True:
-            with self._work_cv:
+            with engine_span("collect.wait"), self._work_cv:
                 while not self._inflight and not self._stop:
                     self._work_cv.wait(timeout=0.1)
                 if not self._inflight:
@@ -7286,9 +7397,7 @@ class LLMEngine:
                         self._jumped = False
                 self._processing = entry
             try:
-                with self._hb_fetch.beat(f"fetch:{entry[0]}"):
-                    self._fault_latency()  # chaos: a wedged transfer
-                    self._process_entry(entry)
+                self._process_entry(entry)
                 self._fetch_fail_streak = 0
             except Exception as e:  # noqa: BLE001
                 if self.logger is not None:

@@ -530,6 +530,7 @@ def decode_step(
     return logits[:, 0], new_cache
 
 
+@jax.named_scope("embed")
 def _embed_tokens(params: dict, cfg: TransformerConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     """(possibly int8) embedding gather + Gemma sqrt(d) scaling."""
     emb = params["embed"]
@@ -569,6 +570,7 @@ def _unembed_last(params: dict, cfg: TransformerConfig, x: jnp.ndarray) -> jnp.n
     return _unembed(params, cfg, x)[:, 0]
 
 
+@jax.named_scope("decode_chunk")
 def decode_chunk(
     params: dict,
     cfg: TransformerConfig,
@@ -641,46 +643,51 @@ def decode_chunk(
 
         def layer(x, lp, rest):
             kc_l, vc_l, kb_l, vb_l = rest
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _lora_mm(qmm, h, lp, "wq", aids)
-            if cfg.qkv_bias:
-                q = q + lp["bq"].astype(q.dtype)
-            q = q.reshape(b, 1, hq, hd)
-            kv = _lora_mm(qmm, h, lp, "wkv", aids)
-            if cfg.qkv_bias:
-                kv = kv + lp["bkv"].astype(kv.dtype)
-            kv = kv.reshape(b, 1, hkv, 2, hd)
-            k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k_new = apply_rope(k_new, positions, cfg.rope_theta)
-            kb_l = jax.lax.dynamic_update_slice(
-                kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0)
-            )
-            vb_l = jax.lax.dynamic_update_slice(
-                vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0)
-            )
-            attn = chunk_decode_attention(
-                q, kc_l, vc_l, kb_l, vb_l, cache.length, k_i,
-                logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-                ring=ring,
-            )
-            x = x + _lora_mm(
-                qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
-            ).astype(x.dtype)
-            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp_block(cfg, h, lp, qmm, aids)
+            with jax.named_scope("layer/attn"):
+                h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+                q = _lora_mm(qmm, h, lp, "wq", aids)
+                if cfg.qkv_bias:
+                    q = q + lp["bq"].astype(q.dtype)
+                q = q.reshape(b, 1, hq, hd)
+                kv = _lora_mm(qmm, h, lp, "wkv", aids)
+                if cfg.qkv_bias:
+                    kv = kv + lp["bkv"].astype(kv.dtype)
+                kv = kv.reshape(b, 1, hkv, 2, hd)
+                k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k_new = apply_rope(k_new, positions, cfg.rope_theta)
+            with jax.named_scope("layer/kv_write"):
+                kb_l = jax.lax.dynamic_update_slice(
+                    kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0)
+                )
+                vb_l = jax.lax.dynamic_update_slice(
+                    vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0)
+                )
+            with jax.named_scope("layer/attn"):
+                attn = chunk_decode_attention(
+                    q, kc_l, vc_l, kb_l, vb_l, cache.length, k_i,
+                    logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+                    ring=ring,
+                )
+                x = x + _lora_mm(
+                    qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
+                ).astype(x.dtype)
+            with jax.named_scope("layer/mlp"):
+                h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+                x = x + _mlp_block(cfg, h, lp, qmm, aids)
             return x, (kb_l, vb_l)
 
         x, (kb, vb) = _layer_scan(
             params["layers"], layer, x, (cache.k, cache.v, kb, vb),
             overlap=overlap,
         )
-        logits = _unembed_last(params, cfg, x)
-        if sample_state is None:
-            nt = sample_fn(logits, temps, key).astype(jnp.int32)
-        else:
-            nt, sstate = sample_fn(logits, temps, key, sstate)
-            nt = nt.astype(jnp.int32)
+        with jax.named_scope("unembed_sample"):
+            logits = _unembed_last(params, cfg, x)
+            if sample_state is None:
+                nt = sample_fn(logits, temps, key).astype(jnp.int32)
+            else:
+                nt, sstate = sample_fn(logits, temps, key, sstate)
+                nt = nt.astype(jnp.int32)
         return (nt, kb, vb, sstate), nt
 
     (last, kb, vb, out_state), toks = jax.lax.scan(
@@ -726,6 +733,7 @@ def decode_chunk(
     return out if sample_state is None else out + (out_state,)
 
 
+@jax.named_scope("decode_chunk")
 def decode_chunk_paged(
     params: dict,
     cfg: TransformerConfig,
@@ -794,35 +802,39 @@ def decode_chunk_paged(
             else:
                 kp_l, vp_l, kb_l, vb_l = rest
                 ks_l = vs_l = None
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _lora_mm(qmm, h, lp, "wq", aids)
-            if cfg.qkv_bias:
-                q = q + lp["bq"].astype(q.dtype)
-            q = q.reshape(b, 1, hq, hd)
-            kv = _lora_mm(qmm, h, lp, "wkv", aids)
-            if cfg.qkv_bias:
-                kv = kv + lp["bkv"].astype(kv.dtype)
-            kv = kv.reshape(b, 1, hkv, 2, hd)
-            k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k_new = apply_rope(k_new, positions, cfg.rope_theta)
-            kb_l = jax.lax.dynamic_update_slice(
-                kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0)
-            )
-            vb_l = jax.lax.dynamic_update_slice(
-                vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0)
-            )
-            attn = paged_chunk_decode_attention(
-                q, kp_l, vp_l, tables, kb_l, vb_l, pool.length, k_i,
-                logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-                k_scales=ks_l, v_scales=vs_l,
-                use_kernel=use_kernel, interpret=interpret, mesh=mesh,
-            )
-            x = x + _lora_mm(
-                qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
-            ).astype(x.dtype)
-            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp_block(cfg, h, lp, qmm, aids)
+            with jax.named_scope("layer/attn"):
+                h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+                q = _lora_mm(qmm, h, lp, "wq", aids)
+                if cfg.qkv_bias:
+                    q = q + lp["bq"].astype(q.dtype)
+                q = q.reshape(b, 1, hq, hd)
+                kv = _lora_mm(qmm, h, lp, "wkv", aids)
+                if cfg.qkv_bias:
+                    kv = kv + lp["bkv"].astype(kv.dtype)
+                kv = kv.reshape(b, 1, hkv, 2, hd)
+                k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k_new = apply_rope(k_new, positions, cfg.rope_theta)
+            with jax.named_scope("layer/kv_write"):
+                kb_l = jax.lax.dynamic_update_slice(
+                    kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0)
+                )
+                vb_l = jax.lax.dynamic_update_slice(
+                    vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0)
+                )
+            with jax.named_scope("layer/attn"):
+                attn = paged_chunk_decode_attention(
+                    q, kp_l, vp_l, tables, kb_l, vb_l, pool.length, k_i,
+                    logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+                    k_scales=ks_l, v_scales=vs_l,
+                    use_kernel=use_kernel, interpret=interpret, mesh=mesh,
+                )
+                x = x + _lora_mm(
+                    qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
+                ).astype(x.dtype)
+            with jax.named_scope("layer/mlp"):
+                h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+                x = x + _mlp_block(cfg, h, lp, qmm, aids)
             return x, (kb_l, vb_l)
 
         rest = (
@@ -832,12 +844,13 @@ def decode_chunk_paged(
         x, (kb, vb) = _layer_scan(
             params["layers"], layer, x, rest, overlap=overlap
         )
-        logits = _unembed_last(params, cfg, x)
-        if sample_state is None:
-            nt = sample_fn(logits, temps, key).astype(jnp.int32)
-        else:
-            nt, sstate = sample_fn(logits, temps, key, sstate)
-            nt = nt.astype(jnp.int32)
+        with jax.named_scope("unembed_sample"):
+            logits = _unembed_last(params, cfg, x)
+            if sample_state is None:
+                nt = sample_fn(logits, temps, key).astype(jnp.int32)
+            else:
+                nt, sstate = sample_fn(logits, temps, key, sstate)
+                nt = nt.astype(jnp.int32)
         return (nt, kb, vb, sstate), nt
 
     (last, kb, vb, out_state), toks = jax.lax.scan(
@@ -900,37 +913,42 @@ def _append_forward(
 
     def layer(x, xs):
         lp, kc, vc = xs  # [b, capacity, hkv, hd]
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _lora_mm(mm, h, lp, "wq", aids)
-        if cfg.qkv_bias:
-            q = q + lp["bq"].astype(q.dtype)
-        q = q.reshape(b, c, hq, hd)
-        kv = _lora_mm(mm, h, lp, "wkv", aids)
-        if cfg.qkv_bias:
-            kv = kv + lp["bkv"].astype(kv.dtype)
-        kv = kv.reshape(b, c, hkv, 2, hd)
-        k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k_new = apply_rope(k_new, positions, cfg.rope_theta)
-        write = jax.vmap(lambda cb, ub, ib: cb.at[ib].set(ub))
-        kc = write(kc, k_new.astype(kc.dtype), idx)
-        vc = write(vc, v_new.astype(vc.dtype), idx)
-        attn = chunk_prefill_attention(
-            q, kc, vc, cursors,
-            logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-            ring=ring, mesh=mesh,
-        )
-        x = x + _lora_mm(
-            mm, attn.reshape(b, c, hq * hd), lp, "wo", aids
-        ).astype(x.dtype)
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + _mlp_block(cfg, h, lp, mm, aids)
+        with jax.named_scope("layer/attn"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            q = _lora_mm(mm, h, lp, "wq", aids)
+            if cfg.qkv_bias:
+                q = q + lp["bq"].astype(q.dtype)
+            q = q.reshape(b, c, hq, hd)
+            kv = _lora_mm(mm, h, lp, "wkv", aids)
+            if cfg.qkv_bias:
+                kv = kv + lp["bkv"].astype(kv.dtype)
+            kv = kv.reshape(b, c, hkv, 2, hd)
+            k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k_new = apply_rope(k_new, positions, cfg.rope_theta)
+        with jax.named_scope("layer/kv_write"):
+            write = jax.vmap(lambda cb, ub, ib: cb.at[ib].set(ub))
+            kc = write(kc, k_new.astype(kc.dtype), idx)
+            vc = write(vc, v_new.astype(vc.dtype), idx)
+        with jax.named_scope("layer/attn"):
+            attn = chunk_prefill_attention(
+                q, kc, vc, cursors,
+                logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+                ring=ring, mesh=mesh,
+            )
+            x = x + _lora_mm(
+                mm, attn.reshape(b, c, hq * hd), lp, "wo", aids
+            ).astype(x.dtype)
+        with jax.named_scope("layer/mlp"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            x = x + _mlp_block(cfg, h, lp, mm, aids)
         return x, (kc, vc)
 
     x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], cache.k, cache.v))
     return x, (ks, vs)
 
 
+@jax.named_scope("prefill_rows")
 def prefill_append(
     params: dict,
     cfg: TransformerConfig,
@@ -973,7 +991,8 @@ def prefill_append(
     )
     last = jnp.clip(n_new - 1, 0, c - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None].astype(jnp.int32), axis=1)
-    logits = _unembed_last(params, cfg, x_last)  # [b, vocab] f32
+    with jax.named_scope("unembed_sample"):  # the caller samples from these
+        logits = _unembed_last(params, cfg, x_last)  # [b, vocab] f32
     new_cache = KVCache(k=ks, v=vs, length=cursors + n_new)
     return logits, new_cache
 

@@ -326,6 +326,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill",  # what a device trace calls the kernel
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -855,6 +856,7 @@ def _paged_decode_partials(
     )
     o, m, l = pl.pallas_call(
         kernel,
+        name="paged_decode",  # what a device trace calls the kernel
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, group, d), jnp.float32),

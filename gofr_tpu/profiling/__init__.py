@@ -31,6 +31,8 @@ Three public surfaces:
 
 MFU / roofline math lives in :mod:`gofr_tpu.profiling.mfu`; on-demand
 ``jax.profiler`` capture in :mod:`gofr_tpu.profiling.capture`.
+:class:`engine_span` puts the engine threads' own spans into a captured
+trace (docs/advanced-guide/profiling.md, "The step timeline").
 
 This module imports no jax at import time — a pure-web app can serve the
 (empty) compile registry without initializing a backend.
@@ -46,6 +48,8 @@ __all__ = [
     "CompileRegistry",
     "InstrumentedJit",
     "default_registry",
+    "engine_span",
+    "name_os_thread",
     "instrument_jit",
     "install_monitoring_listener",
     "register_compile_metrics",
@@ -280,6 +284,60 @@ def install_monitoring_listener() -> bool:
         return True
 
 
+# -- host spans on the profiler's clock ------------------------------------
+
+
+class engine_span:
+    """One ``with`` for a stretch of an engine thread: a
+    ``jax.profiler.TraceAnnotation`` (a host event in a captured trace, on
+    the same clock as the device's lines; about a microsecond while no
+    capture runs) and, where a heartbeat is given, its beat for the step
+    watchdog, named ``name`` or ``name:kind``. It writes nowhere else:
+    request spans with exporters are :mod:`gofr_tpu.tracing`'s.
+    ``set()`` adds attributes learned inside the span (a program's ``seq``)."""
+
+    __slots__ = ("_ann", "_hb", "_beat")
+    _annotation = None  # jax.profiler.TraceAnnotation, imported at the first span
+
+    def __init__(self, name: str, hb=None, **attrs):
+        if engine_span._annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            engine_span._annotation = TraceAnnotation
+        self._ann = engine_span._annotation(name, **attrs)
+        self._hb = hb
+        self._beat = f"{name}:{attrs['kind']}" if "kind" in attrs else name
+
+    def set(self, **attrs) -> None:
+        self._ann.set_metadata(**attrs)
+
+    def __enter__(self):
+        if self._hb is not None:
+            self._hb.begin(self._beat)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        if self._hb is not None:
+            self._hb.end()
+        return False
+
+
+def name_os_thread() -> None:
+    """Give the calling thread its Python name at the OS too (Linux keeps
+    15 bytes: ``llm-engine-sche``, ``llm-engine-coll``). The profiler names
+    a host line after the OS thread, read at the thread's first event, so
+    an engine thread calls this before its first span; Python sets the OS
+    name itself only from 3.14 on."""
+    try:
+        import ctypes
+
+        ctypes.CDLL(None).prctl(15, threading.current_thread().name.encode()[:15], 0, 0, 0)  # PR_SET_NAME
+    except Exception:  # noqa: BLE001 — a platform without prctl keeps the process's name
+        pass
+
+
 # -- the jit wrapper -------------------------------------------------------
 
 
@@ -431,7 +489,8 @@ class InstrumentedJit:
                     program=self.program, model=self.model,
                 )
             try:
-                return exe(*self._dyn_args(args))
+                with engine_span(self.program):
+                    return exe(*self._dyn_args(args))
             except Exception as e:
                 # Committed-device/layout drift the signature missed: fall
                 # back to jit dispatch for good rather than failing serving.
