@@ -8,7 +8,8 @@ With no arguments, in ONE process (a chip belongs to one process at a time):
    (gofr_tpu.profiling.mfu) — no accelerator, no result, non-zero exit;
 2. kernel phase: COMPILES (never interprets) the two Pallas kernels on the
    serving path — flash attention with and without ``q_offsets`` (chunked
-   prefill) and the paged-decode kernel over a bf16 and an int8 pool — at
+   prefill) and the paged-decode kernel over a bf16 and an int8 pool of
+   16-, 64- and 128-token blocks — at
    the smoke model's head shapes and at 8 kv heads, and compares each with
    its XLA reference on seeded inputs;
 3. server phase: boots ``examples/grpc-gemma``'s ``build_app()`` in-process
@@ -94,7 +95,7 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
         paged_chunk_decode_attention,
     )
 
-    b, d, chunk, block, steps = 2, 128, 64, 16, 8
+    b, d, chunk, steps = 2, 128, 64, 8
     how = "interpreted" if interpret else "compiled"
 
     def compare(name, got, want):
@@ -109,7 +110,7 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
 
     for hq, hkv in head_shapes:
         tag = f"hq={hq} hkv={hkv} d={d}"
-        keys = iter(jax.random.split(jax.random.PRNGKey(hq * 100 + hkv), 12))
+        keys = iter(jax.random.split(jax.random.PRNGKey(hq * 100 + hkv), 24))
 
         def rand(shape):
             return jax.random.normal(next(keys), shape, jnp.bfloat16)
@@ -139,36 +140,38 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
             )
         compare(f"flash+q_offsets {tag} chunk={chunk} capacity={capacity}", got, want)
 
-        # paged decode through a scrambled block table, bf16 then int8 pool
-        n_tbl = capacity // block
-        n_blocks = b * n_tbl + 3
-        pk, pv = rand((n_blocks, block, hkv, d)), rand((n_blocks, block, hkv, d))
-        tables = (
-            jax.random.permutation(next(keys), n_blocks)[: b * n_tbl]
-            .reshape(b, n_tbl).astype(jnp.int32)
-        )
+        # paged decode through a scrambled block table, bf16 then int8 pool,
+        # at the three block sizes the kernel sizes its tile for
         q1 = rand((b, 1, hq, d))
         kb, vb = rand((b, steps, hkv, d)), rand((b, steps, hkv, d))
         lengths = jnp.asarray([37, capacity - steps], jnp.int32)
         step = jnp.asarray(3, jnp.int32)
-        (qk, sk), (qv, sv) = quantize_rows(pk), quantize_rows(pv)
-        for pool, (k_pool, v_pool, k_sc, v_sc) in {
-            "bf16": (pk, pv, None, None), "int8": (qk, qv, sk, sv),
-        }.items():
+        for block in (16, 64, 128):
+            n_tbl = capacity // block
+            n_blocks = b * n_tbl + 3
+            pk, pv = rand((n_blocks, block, hkv, d)), rand((n_blocks, block, hkv, d))
+            tables = (
+                jax.random.permutation(next(keys), n_blocks)[: b * n_tbl]
+                .reshape(b, n_tbl).astype(jnp.int32)
+            )
+            (qk, sk), (qv, sv) = quantize_rows(pk), quantize_rows(pv)
+            for pool, (k_pool, v_pool, k_sc, v_sc) in {
+                "bf16": (pk, pv, None, None), "int8": (qk, qv, sk, sv),
+            }.items():
 
-            def attend(use_kernel):
-                return jax.jit(
-                    lambda q, kp, vp, ks, vs: paged_chunk_decode_attention(
-                        q, kp, vp, tables, kb, vb, lengths, step,
-                        k_scales=ks, v_scales=vs,
-                        use_kernel=use_kernel, interpret=interpret,
-                    )
-                )(q1, k_pool, v_pool, k_sc, v_sc)
+                def attend(use_kernel):
+                    return jax.jit(
+                        lambda q, kp, vp, ks, vs: paged_chunk_decode_attention(
+                            q, kp, vp, tables, kb, vb, lengths, step,
+                            k_scales=ks, v_scales=vs,
+                            use_kernel=use_kernel, interpret=interpret,
+                        )
+                    )(q1, k_pool, v_pool, k_sc, v_sc)
 
-            got = attend(True)
-            with jax.default_matmul_precision("highest"):
-                want = attend(False)  # paged_gather + chunk_decode_attention
-            compare(f"paged-decode {pool} pool {tag} block={block}", got, want)
+                got = attend(True)
+                with jax.default_matmul_precision("highest"):
+                    want = attend(False)  # paged_gather + chunk_decode_attention
+                compare(f"paged-decode {pool} pool {tag} block={block}", got, want)
 
 
 # -- server phase ----------------------------------------------------------
@@ -367,7 +370,9 @@ def server_phase(args, preset: str, on_chip: bool) -> None:
         check(paths["decode"].startswith(want_decode), f"decode traced {paths['decode']!r}")
         for shape, path in paths["prefill"].items():
             check(path.startswith(want_prefill), f"prefill chunk {shape} traced {path!r}")
-        say(f"attention traced: decode {paths['decode']}; prefill {paths['prefill']}")
+        tile = paths.get("decode_tile")  # what the paged kernel holds per step
+        say(f"attention traced: decode {paths['decode']}{f' {tile}' if tile else ''}; "
+            f"prefill {paths['prefill']}")
 
         check(compiles["degraded"] == [], f"InstrumentedJit left AOT dispatch: {compiles['degraded']}")
         after = compiles["totals"]["compiles"]

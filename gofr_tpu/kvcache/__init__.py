@@ -395,6 +395,20 @@ class CacheManager:
         self.append_slack = max(widths)
         slack = self.append_slack
         would_roll = 0 < self.window and self.window + slack < max_seq_len
+        # max_seq_len is what a REQUEST may hold (prompt + output:
+        # LLMEngine.submit admits plen + max_new <= max_seq_len). A slot
+        # holds the engine's own merge slack on top: while a request is
+        # incomplete its cursor stays <= prompt + max_new + chunk
+        # (chunk-granular rounding) and the end-of-chunk merge writes one
+        # chunk more, so no layout ever clamp-overwrites a live row.
+        self.slot_rows = max_seq_len + 2 * int(decode_chunk)
+        # A flat (paged / dense) slot is BUILT with those rows, rounded up
+        # to whole flash key blocks of 128 where max_seq_len itself was
+        # (ops.attention.chunk_prefill_why_not_flash): an engine whose
+        # max_seq_len took the Pallas prefill kernel still takes it.
+        flat_rows = self.slot_rows
+        if max_seq_len % 128 == 0:
+            flat_rows = -(-flat_rows // 128) * 128
         if session_mb is None:
             session_mb = float(os.environ.get("TPU_LLM_SESSION_MB", "0") or 0.0)
         if paged == "auto":
@@ -417,7 +431,7 @@ class CacheManager:
             if kv_int8 is None:
                 kv_int8 = os.environ.get("TPU_LLM_KV_INT8", "0") not in ("", "0")
             self.int8 = bool(kv_int8)
-            self.table_width = -(-max_seq_len // self.block)
+            self.table_width = -(-flat_rows // self.block)
             self.capacity = self.table_width * self.block
             self.ring = 0
             kv_itemsize = 1 if self.int8 else itemsize
@@ -436,10 +450,11 @@ class CacheManager:
             if pool_blocks is None:
                 pool_blocks = int(os.environ.get("TPU_LLM_KV_POOL_BLOCKS", "0"))
             if not pool_blocks:
-                # worst case with zero sharing: every slot fully grown,
+                # worst case with zero sharing: every slot grown to what
+                # its request can hold (not to the table's rounded width),
                 # plus the retained-prefix and session budgets
                 pool_blocks = (
-                    slots * self.table_width
+                    slots * self.blocks_for(self.slot_rows)
                     + -(-retain_bytes // self.block_bytes)
                     + -(-session_bytes // self.block_bytes)
                 )
@@ -476,7 +491,7 @@ class CacheManager:
             self.sessions = None
             self.share = False
             self.rolling = would_roll
-            self.capacity = self.window + slack if self.rolling else max_seq_len
+            self.capacity = self.window + slack if self.rolling else flat_rows
             # static arg for decode_chunk/attention: ring capacity, 0 = dense
             self.ring = self.capacity if self.rolling else 0
             self.slot_bytes = (
@@ -583,8 +598,10 @@ class CacheManager:
         """Worst-case rows a request can ever occupy: prompt + decode
         budget + ONE append-slack term (chunk-granular decode overshoot
         and transient speculative verify rows past the cursor), clamped
-        to the logical capacity. The single place this arithmetic lives."""
-        return min(prompt_len + max_new - 1 + self.append_slack, self.capacity)
+        to what a slot's request can hold (slot_rows: the default pool
+        counts that much a slot, so a request at max_seq_len always fits
+        it). The single place this arithmetic lives."""
+        return min(prompt_len + max_new - 1 + self.append_slack, self.slot_rows)
 
     # -- paged layout: admission ------------------------------------------
     def lookup_seed(

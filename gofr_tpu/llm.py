@@ -781,8 +781,8 @@ class LLMEngine:
 
             spec_draft = SPEC_DRAFT_DEFAULT
         # verify transiently writes draft+1 rows past a slot's length;
-        # submit()'s decode-room cap reserves 2*decode_chunk rows of
-        # slack, so the draft must fit it (dense scatters drop overflow,
+        # every slot is built with 2*decode_chunk rows of slack beyond
+        # max_seq_len, so the draft must fit it (dense scatters drop overflow,
         # but a silent clamp beats silent garbage)
         self.spec_draft = (
             max(1, min(int(spec_draft), 2 * decode_chunk))
@@ -1097,9 +1097,9 @@ class LLMEngine:
             host_cache_mb=host_cache_mb,
             metrics=metrics, model=kv_label,
         )
-        self.attention_paths = self._attention_paths()
         self._sharded = mesh is not None and param_specs is not None
         self.mesh = mesh if self._sharded else None
+        self.attention_paths = self._attention_paths()
         # Under a TP mesh every program hands its small state (chain tail,
         # masks, first tokens) back REPLICATED over the mesh, and an AOT
         # executable accepts only the input shardings it was compiled
@@ -2491,9 +2491,12 @@ class LLMEngine:
         decode, and prefill per chunk shape — decided by the predicates
         the ops themselves consult at trace time (ops.attention). A shape
         that cannot take its Pallas kernel carries the reason, so a
-        fallback to XLA attention is never silent (stats()["attention"])."""
+        fallback to XLA attention is never silent (stats()["attention"]).
+        Beside a paged-decode kernel, the tile it derived from the pool's
+        shape: pages and tokens of every local kv head per step."""
         from .ops.attention import (
-            chunk_prefill_why_not_flash, flash_why_not, paged_kernel_why_not,
+            chunk_prefill_why_not_flash, flash_why_not, paged_decode_pages,
+            paged_kernel_why_not,
         )
 
         hd = self.cfg.head_dim
@@ -2518,7 +2521,15 @@ class LLMEngine:
                 c: flash_or(chunk_prefill_why_not_flash(c, self.kv.capacity, hd))
                 for c in self.chunk_shapes
             }
-        return {"decode": decode, "prefill": prefill}
+        paths = {"decode": decode, "prefill": prefill}
+        if decode == "pallas_paged":
+            pages = paged_decode_pages(
+                self.kv.block, self.cfg.n_kv_heads, hd,
+                "int8" if self.kv.int8 else self.cfg.dtype,
+                self.kv.table_width, hq=self.cfg.n_heads, mesh=self.mesh,
+            )
+            paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+        return paths
 
     # -- public API -------------------------------------------------------
     def submit(self, req: GenRequest) -> GenRequest:
@@ -2531,17 +2542,11 @@ class LLMEngine:
             raise ValueError(
                 f"prompt of {plen} tokens exceeds max_seq_len {self.max_seq_len}"
             )
-        # Cap max_new_tokens so the slot's cursor can never clamp-overwrite
-        # its own live rows: while a request is incomplete its length stays
-        # <= prompt + max_new + chunk (chunk-granularity rounding), and the
-        # end-of-chunk merge needs a further chunk of slack. A request that
-        # cannot emit a single token is rejected outright.
-        room = self.max_seq_len - plen - 2 * self.decode_chunk
-        if room < 1:
-            raise ValueError(
-                f"prompt of {plen} tokens leaves no decode room at "
-                f"max_seq_len {self.max_seq_len} (chunk {self.decode_chunk})"
-            )
+        # max_seq_len is what a request may hold: prompt + output. The
+        # cursor's chunk-granular overshoot and the end-of-chunk merge
+        # write into the two chunks of slack every slot is built with on
+        # top of it (CacheManager.slot_rows), never over live rows.
+        room = self.max_seq_len - plen
         # emitted discounts work already done — a failover continuation
         # re-submits with its history folded into the prompt, and only
         # the REMAINING tokens need decode room (emitted == 0 for fresh

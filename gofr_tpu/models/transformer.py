@@ -713,7 +713,7 @@ def decode_chunk(
         new_k = merge(cache.k, kb, idx)
         new_v = merge(cache.v, vb, idx)
         # lengths stay ABSOLUTE (positions/RoPE/window math need them);
-        # the engine's submit() cap bounds them by max_seq_len
+        # the engine's submit() cap bounds them by max_seq_len + a chunk
         new_len = jnp.where(active, cache.length + K, cache.length)
         out = (toks, last, KVCache(k=new_k, v=new_v, length=new_len), rng)
         return out if sample_state is None else out + (out_state,)

@@ -642,14 +642,22 @@ def chunk_prefill_attention(
 # stream follows the table instead of a contiguous slab. Two paths,
 # selected at trace time exactly like the flash kernel:
 #
-# - Pallas TPU kernel (_paged_decode_partials): grid (batch, kv_head,
-#   table_slot); the block table and the per-sequence valid bounds ride
-#   as SCALAR PREFETCH operands, so each grid cell's BlockSpec index_map
-#   DMAs pool block table[b, j] directly — the pool is never gathered
-#   into a dense copy, which is the whole point (decode is HBM-bound;
-#   a gather would double the dominant stream). Returns online-softmax
-#   PARTIALS (normalized output + running max + denom) so the caller can
-#   merge the chunk ring buffer region with one rescale.
+# - Pallas TPU kernel (_paged_decode_partials): grid (batch,): one program
+#   per lane, every kv head at once. The block table and the per-sequence
+#   valid band [lo, hi) ride as SCALAR PREFETCH operands; the pool stays in
+#   HBM and the program copies pages itself (make_async_copy, double
+#   buffered): a GROUP of `pages` table slots at a time, each page the
+#   contiguous [B, hkv * d] slab of the pool's [NB, B, hkv, d] layout, so
+#   a step holds pages * B tokens of every head (paged_decode_pages sizes
+#   it from the pool's shape and dtype; qwen2: 8 pages, 128 tokens, 128 KB
+#   of K and of V). The walk covers only the groups that meet the band,
+#   from lo // (pages * B) to where hi ends, and inside an edge group only
+#   the pages that meet it: a table entry outside the band is never read,
+#   a lane with an empty band costs one empty program. The pool is never
+#   gathered into a dense copy, which is the whole point (decode is
+#   HBM-bound; a gather would double the dominant stream). Returns
+#   online-softmax PARTIALS (normalized output + running max + denom) so
+#   the caller can merge the chunk ring buffer region with one rescale.
 # - Dense-gather reference (paged_gather): jnp.take the table rows into
 #   the contiguous layout and reuse the proven attention above — the
 #   off-TPU path and the test oracle. Bit-exact with the
@@ -658,7 +666,10 @@ def chunk_prefill_attention(
 #
 # int8 KV blocks (TPU_LLM_KV_INT8): the pool stores int8 rows plus one
 # f32 scale per (row, kv_head); both paths dequantize after the read, so
-# the HBM stream the decode loop is bound by moves at half width.
+# the HBM stream the decode loop is bound by moves at half width. The
+# kernel applies a row's scale to its score column and its probability
+# (the scale factors out of the dot over d); the scales reach it gathered
+# through the table by XLA, 1/32 of the rows' bytes.
 
 
 def paged_gather(k_pool, v_pool, tables, *, k_scales=None, v_scales=None, dtype=None):
@@ -679,81 +690,155 @@ def paged_gather(k_pool, v_pool, tables, *, k_scales=None, v_scales=None, dtype=
     return take(k_pool, k_scales), take(v_pool, v_scales)
 
 
+# What one step of the paged-decode kernel holds is derived from the pool's
+# own shape: as many tokens of every local kv head as _PAGED_TILE_BYTES of K
+# hold at the pool's dtype (as much again of V, both twice over for the
+# double buffer), but no fewer than the 128 lanes a score row spans and no
+# more than 512 (a step's f32 rows and scores are values the compiler holds).
+_PAGED_TILE_BYTES = 128 * 1024
+_PAGED_TILE_TOKENS = (128, 512)
+
+
+def paged_decode_pages(
+    block: int, hkv: int, d: int, dtype, table_slots: int, *, hq: int = 0, mesh=None
+) -> int:
+    """Pages of one lane the paged-decode kernel holds per step (qwen2's
+    16-token bf16 pages of 4 heads x 128: 8 pages, 128 tokens), never more
+    than the block table has slots. Under a TP mesh the kernel runs per
+    head shard: name the mesh (and hq) to count the LOCAL kv heads."""
+    if mesh is not None and mesh.size > 1:
+        ka = _head_axes(mesh, hq, hkv)[1]
+        hkv //= mesh.shape[ka] if ka else 1
+    least, most = _PAGED_TILE_TOKENS
+    row_bytes = hkv * d * jnp.dtype(dtype).itemsize
+    tokens = min(max(_PAGED_TILE_BYTES // row_bytes, least), most)
+    return max(1, min(tokens // block, table_slots))
+
+
 def _paged_decode_kernel(
     # scalar prefetch: block tables + per-sequence valid bounds
     tbl_ref, lo_ref, hi_ref,
-    # inputs (q, k block, v block[, k scales, v scales]), outputs, scratch
+    # q, the pools left in HBM (k, v), [the lane's k and v scales, one row
+    # per page group], outputs, page buffers, DMA semaphores
     *refs,
-    block: int,
+    pages: int,
     scale: float,
     logit_cap: float,
     quantized: bool,
 ):
     if quantized:
-        q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, m_s, l_s, acc_s = refs
+        q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
     else:
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, m_s, l_s, acc_s = refs
+        q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
         ks_ref = vs_ref = None
+    n_pool, block = k_hbm.shape[0], k_hbm.shape[1]
+    n_tbl = tbl_ref.shape[1]
+    hkv, group, d = q_ref.shape[1:]
+    tile = pages * block  # tokens a step
     bi = pl.program_id(0)
-    head = pl.program_id(1)
-    ji = pl.program_id(2)
-    nj = pl.num_programs(2)
+    # the band, held to what the table can name: a wild bound walks no further
+    lo = jnp.maximum(lo_ref[bi], 0)
+    hi = jnp.minimum(hi_ref[bi], n_tbl * block)
+    # the lane's walk: page groups [first, first + n) cover the band [lo, hi)
+    first = lo // tile
+    n = jnp.where(hi > lo, pl.cdiv(hi, tile) - first, 0)
 
-    @pl.when(ji == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
+    def page_copies(g, slot, go):
+        """start (go=True) or wait for the copies of group g's LIVE pages
+        into buffer `slot`: a page outside the band is never looked up, so
+        a stale table entry is never dereferenced."""
+        j0 = jnp.maximum(g * pages, lo // block)
+        j1 = jnp.minimum((g + 1) * pages, pl.cdiv(hi, block))
 
-    lo = lo_ref[bi]
-    hi = hi_ref[bi]
-    base = ji * block  # logical position of this table slot's first row
-    live = jnp.logical_and(base < hi, base + block > lo)
+        def one(j, carry):
+            # a wait only needs the copy's shape, not its source
+            page = jnp.clip(tbl_ref[bi, j], 0, n_pool - 1) if go else 0
+            for kv, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                cp = pltpu.make_async_copy(
+                    src.at[page], dst.at[slot, j - g * pages], sems.at[kv, slot]
+                )
+                cp.start() if go else cp.wait()
+            return carry
 
-    def head_scale(s_ref):
-        # [block, hkv] scales of every kv head -> this head's [block, 1]
-        # column (masked lane reduce: the head is a grid coordinate, and
-        # a one-lane block of the scales array has no TPU lowering)
-        sc = s_ref[0]
-        lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        return jnp.sum(
-            jnp.where(lane == head, sc, 0.0), axis=1, keepdims=True
-        )
+        jax.lax.fori_loop(j0, j1, one, 0)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [group, d]
-        k = k_ref[0].astype(jnp.float32)  # [block, d]
-        v = v_ref[0].astype(jnp.float32)
-        if ks_ref is not None:
-            k = k * head_scale(ks_ref)
-            v = v * head_scale(vs_ref)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [group, block]
+    @pl.when(n > 0)
+    def _first():
+        page_copies(first, 0, True)
+
+    def rows_of(buf, slot):
+        """the step's pages as [tile, hkv * d] f32 rows: a head's are the
+        lane-aligned columns [head * d, (head + 1) * d)"""
+        return buf[slot].astype(jnp.float32).reshape(tile, hkv * d)
+
+    heads = range(hkv)
+    qs = [q_ref[0, h].astype(jnp.float32) * scale for h in heads]  # [group, d]
+
+    # One online-softmax update of every head from one group of pages. The
+    # running max / denominator / accumulator are loop-carried VALUES and
+    # each phase is written for all heads before the next, so nothing
+    # orders one head's matmuls behind another's: on the v5e the same
+    # arithmetic through per-head VMEM accumulators took half as long again.
+    def body(i, carry):
+        m, l, acc = carry
+        g = first + i
+        slot = i % 2
+
+        @pl.when(i + 1 < n)
+        def _next():
+            page_copies(g + 1, 1 - slot, True)
+
+        page_copies(g, slot, False)
+        base = g * tile  # logical position of the group's first row
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (group, tile), 1)
+        in_band = (pos >= lo) & (pos < hi)
+        row = base + jax.lax.broadcasted_iota(jnp.int32, (tile, hkv * d), 0)
+        row_in_band = (row >= lo) & (row < hi)
+        k = rows_of(k_buf, slot)
+        s = [
+            jax.lax.dot_general(
+                qs[h], k[:, h * d:(h + 1) * d], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h in heads
+        ]  # [group, tile] each
+        if quantized:
+            # int8 rows: one f32 scale per (row, head) factors out of the
+            # dot over d, onto the score's column
+            s = [s[h] * ks_ref[0, h, pl.ds(g, 1), :] for h in heads]
         if logit_cap > 0.0:
-            s = logit_cap * jnp.tanh(s / logit_cap)
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(jnp.logical_and(pos >= lo, pos < hi), s, NEG_INF)
-        m_prev = m_s[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_s[:] = jnp.broadcast_to(
-            alpha * l_s[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_s.shape
-        )
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            s = [logit_cap * jnp.tanh(x / logit_cap) for x in s]
+        s = [jnp.where(in_band, x, NEG_INF) for x in s]
+        m_new = [jnp.maximum(m[h], jnp.max(s[h], axis=-1, keepdims=True)) for h in heads]
+        p = [jnp.exp(s[h] - m_new[h]) for h in heads]
+        alpha = [jnp.exp(m[h] - m_new[h]) for h in heads]
+        l_new = [alpha[h] * l[h] + jnp.sum(p[h], axis=-1, keepdims=True) for h in heads]
+        if quantized:
+            p = [
+                jnp.where(in_band, p[h] * vs_ref[0, h, pl.ds(g, 1), :], 0.0)
+                for h in heads
+            ]
+        # rows outside the band may be pages never copied: whatever the
+        # buffer holds there (NaN included) must not reach 0 * v
+        v = jnp.where(row_in_band, rows_of(v_buf, slot), 0.0)
+        acc_new = [
+            acc[h] * alpha[h] + jax.lax.dot_general(
+                p[h], v[:, h * d:(h + 1) * d], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h in heads
+        ]
+        return tuple(m_new), tuple(l_new), tuple(acc_new)
 
-    @pl.when(ji == nj - 1)
-    def _finalize():
-        denom = l_s[:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0, 0] = (acc_s[:] / denom).astype(o_ref.dtype)
-        m_ref[0, 0] = m_s[:].astype(m_ref.dtype)
-        l_ref[0, 0] = l_s[:].astype(l_ref.dtype)
+    m, l, acc = jax.lax.fori_loop(0, n, body, (
+        tuple(jnp.full((group, 1), NEG_INF, jnp.float32) for _ in heads),
+        tuple(jnp.zeros((group, 1), jnp.float32) for _ in heads),
+        tuple(jnp.zeros((group, d), jnp.float32) for _ in heads),
+    ))
+    for h in heads:
+        o_ref[0, h] = acc[h] / jnp.where(l[h] == 0.0, 1.0, l[h])
+        m_ref[0, h] = jnp.broadcast_to(m[h], m_ref.shape[2:])
+        l_ref[0, h] = jnp.broadcast_to(l[h], l_ref.shape[2:])
 
 
 def _paged_decode_partials(
@@ -774,85 +859,88 @@ def _paged_decode_partials(
     """Pallas paged-attention decode over the valid band [lo, hi):
     returns (o [b, hq, d] f32 normalized, m [b, hq] f32, l [b, hq] f32)
     online-softmax partials for region merging."""
+    hq, hkv = q.shape[1], k_pool.shape[2]
+    quantized = k_scales is not None
+    call = functools.partial(
+        _paged_decode_call, scale=scale, logit_cap=logit_cap, interpret=interpret
+    )
+    if mesh is None or mesh.size == 1:
+        return call(q, k_pool, v_pool, tables, lo, hi, k_scales, v_scales)
+    qa, ka = _head_axes(mesh, hq, hkv)
+    pool_spec, sc_spec = P(None, None, ka, None), P(None, None, ka)
+    return jax.shard_map(
+        lambda q, k_pool, v_pool, tables, lo, hi, *scales: call(
+            q, k_pool, v_pool, tables, lo, hi, *(scales or (None, None))
+        ),
+        mesh=mesh,
+        in_specs=(
+            P(None, qa, None), pool_spec, pool_spec, P(), P(), P(),
+            *((sc_spec, sc_spec) if quantized else ()),
+        ),
+        out_specs=(P(None, qa, None), P(None, qa), P(None, qa)),
+        check_vma=False,
+    )(q, k_pool, v_pool, tables, lo, hi,
+      *((k_scales, v_scales) if quantized else ()))
+
+
+# jitted so that every program that attends at the same shapes (an engine's
+# two dozen step and chunk programs) shares ONE trace of the kernel's body
+@functools.partial(jax.jit, static_argnames=("scale", "logit_cap", "interpret"))
+def _paged_decode_call(
+    q, k_pool, v_pool, tables, lo, hi, k_scales, v_scales,
+    *, scale: float, logit_cap: float, interpret: bool,
+):
+    """_paged_decode_partials on one device (or one head shard)."""
     b, hq, d = q.shape
     NB, B, hkv, _ = k_pool.shape
     MB = tables.shape[1]
     quantized = k_scales is not None
-    if mesh is not None and mesh.size > 1:
-        qa, ka = _head_axes(mesh, hq, hkv)
-        pool_spec, sc_spec = P(None, None, ka, None), P(None, None, ka)
-
-        def local(q, k_pool, v_pool, tables, lo, hi, *scales):
-            ks, vs = scales or (None, None)
-            return _paged_decode_partials(
-                q, k_pool, v_pool, tables, lo, hi, scale=scale,
-                logit_cap=logit_cap, k_scales=ks, v_scales=vs,
-                interpret=interpret,
-            )
-
-        return jax.shard_map(
-            local, mesh=mesh,
-            in_specs=(
-                P(None, qa, None), pool_spec, pool_spec, P(), P(), P(),
-                *((sc_spec, sc_spec) if quantized else ()),
-            ),
-            out_specs=(P(None, qa, None), P(None, qa), P(None, qa)),
-            check_vma=False,
-        )(q, k_pool, v_pool, tables, lo, hi,
-          *((k_scales, v_scales) if quantized else ()))
     group = hq // hkv
+    pages = paged_decode_pages(B, hkv, d, k_pool.dtype, MB)
 
-    qt = q.reshape(b, hkv, group, d)
+    def lane(bi, tbl, lo_, hi_):
+        return (bi, 0, 0, 0)
 
-    def q_index(bi, hi_, ji, tbl, lo_, hi__):
-        return (bi, hi_, 0, 0)
-
-    # TPU blocks must tile (8, 128) over an array's last two dims or span
-    # them whole, so a one-head (1, B, 1, d) block of the [NB, B, hkv, d]
-    # pool has no lowering for hkv > 1. Viewed as [NB, B, hkv * d] (a
-    # free reshape) the same rows are the (1, B, d) block at lane-block
-    # index `head`: B spans its dim, d is a multiple of 128.
-    def kv_index(bi, hi_, ji, tbl, lo_, hi__):
-        return (tbl[bi, ji], 0, hi_)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, group, d), q_index),
-        pl.BlockSpec((1, B, d), kv_index),
-        pl.BlockSpec((1, B, d), kv_index),
-    ]
+    # The pools stay in HBM and the kernel copies whole pages itself: in
+    # the pool's [NB, B, hkv, d] layout a page is B * hkv * d contiguous
+    # elements, viewed [B, hkv * d] (a free reshape) so that a head's rows
+    # are the lane-aligned columns [head * d, (head + 1) * d) of the tile.
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, hkv, group, d), lane), hbm, hbm]
     operands = [
-        qt, k_pool.reshape(NB, B, hkv * d), v_pool.reshape(NB, B, hkv * d)
+        q.reshape(b, hkv, group, d),
+        k_pool.reshape(NB, B, hkv * d), v_pool.reshape(NB, B, hkv * d),
     ]
+    page_buf = pltpu.VMEM((2, pages, B, hkv * d), k_pool.dtype)
     if quantized:
-        # scales [NB, B, hkv]: the block spans every head (hkv f32 lanes
-        # per row) and the kernel selects its own head's column
+        # A page's [B, hkv] scales are too narrow a slab to copy from HBM
+        # (Mosaic wants whole 128-lane rows), so XLA gathers the lane's
+        # scales through the table, 1/32 of the int8 rows' bytes, and the
+        # kernel holds them as one [tile] row per (head, page group).
+        n_groups = pl.cdiv(MB, pages)
 
-        def sc_index(bi, hi_, ji, tbl, lo_, hi__):
-            return (tbl[bi, ji], 0, 0)
+        def group_rows(sc):  # [NB, B, hkv] -> [b, hkv, n_groups, pages * B]
+            sc = jnp.take(sc, tables, axis=0, mode="clip")  # [b, MB, B, hkv]
+            sc = jnp.pad(sc, ((0, 0), (0, n_groups * pages - MB), (0, 0), (0, 0)))
+            return sc.reshape(b, n_groups, pages * B, hkv).transpose(0, 3, 1, 2)
 
-        in_specs += [
-            pl.BlockSpec((1, B, hkv), sc_index),
-            pl.BlockSpec((1, B, hkv), sc_index),
-        ]
-        operands += [k_scales, v_scales]
+        in_specs += [pl.BlockSpec((1, hkv, n_groups, pages * B), lane)] * 2
+        operands += [group_rows(k_scales), group_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, MB),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, group, d), q_index),
-            pl.BlockSpec((1, 1, group, 128), q_index),
-            pl.BlockSpec((1, 1, group, 128), q_index),
+            pl.BlockSpec((1, hkv, group, d), lane),
+            pl.BlockSpec((1, hkv, group, 128), lane),
+            pl.BlockSpec((1, hkv, group, 128), lane),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
+        # one DMA semaphore per (k | v, buffer)
+        scratch_shapes=[page_buf, page_buf, pltpu.SemaphoreType.DMA((2, 2))],
     )
     kernel = functools.partial(
         _paged_decode_kernel,
-        block=B, scale=scale, logit_cap=logit_cap, quantized=quantized,
+        pages=pages, scale=scale, logit_cap=logit_cap, quantized=quantized,
     )
     o, m, l = pl.pallas_call(
         kernel,

@@ -114,7 +114,8 @@ class TestRollingEngine:
         assert rolling.cache.k.shape[2] == rolling.kv.capacity
         assert rolling.kv.capacity == 8 + max(8, max(rolling.chunk_shapes))
         assert dense.kv.stats()["layout"] == "dense"
-        assert dense.cache.k.shape[2] == 64
+        # max_seq_len + the two decode chunks of merge slack a slot holds
+        assert dense.cache.k.shape[2] == dense.kv.capacity == 64 + 2 * 8
 
     @pytest.mark.parametrize("plen", [4, 20, 30])  # straddle the window (8)
     def test_rolling_matches_dense_and_reference(self, engines, params_w, plen):

@@ -162,26 +162,102 @@ class TestPallasLowersForTpu:
             q, cache, cache, S((2,), jnp.int32),
         )
 
+    # (block, lanes, table slots); the last is qwen2-7b.reason-closed's own
+    # (with hkv 4 and d 128: hq 28, 16 lanes, a table of 120)
     @pytest.mark.parametrize("window", [0, 512])
     @pytest.mark.parametrize("pool", [jnp.bfloat16, jnp.int8])
-    @pytest.mark.parametrize("block", [16, 64, 128])
-    def test_paged_decode(self, hkv, d, block, pool, window):
+    @pytest.mark.parametrize(
+        "block,lanes,n_tbl", [(16, 2, 64), (64, 2, 16), (128, 2, 8), (16, 16, 120)]
+    )
+    def test_paged_decode(self, hkv, d, block, lanes, n_tbl, pool, window):
         from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
         hq = 28 if hkv == 4 else 8 * hkv
         S = jax.ShapeDtypeStruct
-        n_blocks, n_tbl, steps = 40, 1024 // block, 8
+        n_blocks, steps = 40, 8
         kp = S((n_blocks, block, hkv, d), pool)
         sc = S((n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
-        buf = S((2, steps, hkv, d), jnp.bfloat16)
+        buf = S((lanes, steps, hkv, d), jnp.bfloat16)
         self._lower(
             lambda q, kp, vp, t, kb, vb, n, s, ks, vs: paged_chunk_decode_attention(
                 q, kp, vp, t, kb, vb, n, s, window=window,
                 k_scales=ks, v_scales=vs, use_kernel=True,
             ),
-            S((2, 1, hq, d), jnp.bfloat16), kp, kp, S((2, n_tbl), jnp.int32),
-            buf, buf, S((2,), jnp.int32), S((), jnp.int32), sc, sc,
+            S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
+            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), sc, sc,
         )
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e host (none is attached): libtpu compiles
+    for it ahead of time, which applies what lowering alone does not —
+    Mosaic's own rules (slices aligned to the tiling, VMEM that fits).
+    Described inside a fixture, in this one file: only the worker that
+    runs these tests loads the TPU's library."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# lanes, hq, hkv, d, block, table slots, pool blocks, pool dtype, window
+@pytest.mark.parametrize(
+    "lanes,hq,hkv,d,block,n_tbl,n_blocks,pool,window",
+    [
+        (16, 28, 4, 128, 16, 120, 2394, jnp.bfloat16, 0),  # qwen2-7b.reason-closed
+        (16, 28, 4, 128, 16, 120, 2394, jnp.int8, 0),
+        (16, 28, 4, 128, 16, 113, 2394, jnp.bfloat16, 0),  # a last page group of one
+        (16, 28, 4, 128, 64, 30, 600, jnp.bfloat16, 0),
+        (16, 28, 4, 128, 128, 15, 300, jnp.int8, 0),
+        (2, 8, 1, 128, 64, 16, 40, jnp.int8, 512),  # MQA, or one head of a TP shard
+        (2, 64, 8, 256, 128, 8, 40, jnp.bfloat16, 512),
+    ],
+)
+def test_paged_decode_compiles_for_the_v5e(
+    v5e_chip, lanes, hq, hkv, d, block, n_tbl, n_blocks, pool, window
+):
+    """The paged-decode kernel through Mosaic for a v5e, and what the
+    benchmark's roofline reader matches it by: the custom call is named
+    paged_decode and its first operand is the 2-D s32 block table."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from gofr_tpu.ops.attention import paged_chunk_decode_attention
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    kp = S((n_blocks, block, hkv, d), pool)
+    sc = S((n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
+    buf = S((lanes, 8, hkv, d), jnp.bfloat16)
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep it out
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            lambda q, kp, vp, t, kb, vb, n, s, ks, vs: paged_chunk_decode_attention(
+                q, kp, vp, t, kb, vb, n, s, window=window,
+                k_scales=ks, v_scales=vs, use_kernel=True,
+            )
+        ).lower(
+            S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
+            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), sc, sc,
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
+    assert "%paged_decode" in call
+    assert f"operand_layout_constraints={{s32[{lanes},{n_tbl}]" in call
 
 
 @pytest.mark.parametrize("hkv", [4, 1, 2])  # kv sharded / MQA / replicated

@@ -277,7 +277,8 @@ class TestManagerPaged:
         )
         assert kv.paged and not kv.rolling and kv.ring == 0
         assert kv.append_slack == 16  # ONE max over every append width
-        assert kv.capacity == 64 and kv.table_width == 16
+        # 64 rows a request may hold + two decode chunks of the engine's own
+        assert kv.slot_rows == 80 and kv.capacity == 80 and kv.table_width == 20
         # contiguous rolling derives its capacity from the SAME term
         kvr = CacheManager(CFGW, 2, 64, 8, append_widths=(8, 16, 5))
         assert kvr.rolling and kvr.capacity == CFGW.sliding_window + 16
@@ -544,19 +545,34 @@ class TestPagedAttentionKernel:
     """The Pallas paged-decode kernel vs the dense-gather reference —
     interpret mode runs the real kernel logic on CPU."""
 
-    @pytest.mark.parametrize("window", [0, 9])
-    def test_kernel_matches_reference(self, window):
+    # (hq, hkv, table slots, contexts). At 8-token blocks of head_dim 16 a
+    # step of the kernel holds 16 pages, 128 tokens, or the whole table.
+    SHAPES = {
+        "one-group": (4, 2, 6, [13, 0, 37]),
+        # none, one row, mid-page, a page-group boundary, the whole table;
+        # 20 slots are one group of 16 pages and a rest of 4
+        "edges": (4, 2, 20, [0, 1, 13, 128, 160]),
+        "group7": (28, 4, 20, [5, 129, 77]),
+        "mqa": (4, 1, 20, [160, 0, 64]),
+    }
+
+    @staticmethod
+    def _inputs(seed, hq, hkv, MB, lengths, *, NB=40, d=16, Bk=8, chunk=4):
+        rng = np.random.RandomState(seed)
+        b = len(lengths)
+        f = lambda *shape: jnp.asarray(rng.randn(*shape).astype(np.float32))  # noqa: E731
+        q, pk, pv = f(b, 1, hq, d), f(NB, Bk, hkv, d), f(NB, Bk, hkv, d)
+        tables = jnp.asarray(rng.randint(0, NB, size=(b, MB)).astype(np.int32))
+        kb, vb = f(b, chunk, hkv, d), f(b, chunk, hkv, d)
+        return q, pk, pv, tables, kb, vb, jnp.asarray(lengths, jnp.int32)
+
+    # 9 ends inside a page; 40 spans pages and, from 128 on, two groups
+    @pytest.mark.parametrize("window", [0, 9, 40])
+    @pytest.mark.parametrize("shape", sorted(SHAPES))
+    def test_kernel_matches_reference(self, shape, window):
         from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
-        rng = np.random.RandomState(0)
-        b, hq, hkv, d, Bk, MB, NB, chunk = 3, 4, 2, 16, 8, 6, 40, 4
-        q = jnp.asarray(rng.randn(b, 1, hq, d).astype(np.float32))
-        pk = jnp.asarray(rng.randn(NB, Bk, hkv, d).astype(np.float32))
-        pv = jnp.asarray(rng.randn(NB, Bk, hkv, d).astype(np.float32))
-        tables = jnp.asarray(rng.randint(0, NB, size=(b, MB)).astype(np.int32))
-        kb = jnp.asarray(rng.randn(b, chunk, hkv, d).astype(np.float32))
-        vb = jnp.asarray(rng.randn(b, chunk, hkv, d).astype(np.float32))
-        lengths = jnp.asarray([13, 0, 37], jnp.int32)
+        q, pk, pv, tables, kb, vb, lengths = self._inputs(0, *self.SHAPES[shape])
         step = jnp.asarray(2, jnp.int32)
         ref = paged_chunk_decode_attention(
             q, pk, pv, tables, kb, vb, lengths, step,
@@ -570,32 +586,165 @@ class TestPagedAttentionKernel:
             np.asarray(kern), np.asarray(ref), atol=2e-6
         )
 
-    def test_kernel_int8(self):
+    @pytest.mark.parametrize("window", [0, 40])
+    @pytest.mark.parametrize("shape", ["one-group", "edges", "group7"])
+    def test_kernel_int8(self, shape, window):
         from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
-        rng = np.random.RandomState(1)
-        b, hq, hkv, d, Bk, MB, NB, chunk = 2, 4, 2, 16, 8, 4, 24, 4
-        q = jnp.asarray(rng.randn(b, 1, hq, d).astype(np.float32))
-        pk = jnp.asarray(rng.randn(NB, Bk, hkv, d).astype(np.float32))
-        pv = jnp.asarray(rng.randn(NB, Bk, hkv, d).astype(np.float32))
+        q, pk, pv, tables, kb, vb, lengths = self._inputs(1, *self.SHAPES[shape])
         qk, sk = quantize_rows(pk)
         qv, sv = quantize_rows(pv)
-        tables = jnp.asarray(rng.randint(0, NB, size=(b, MB)).astype(np.int32))
-        kb = jnp.asarray(rng.randn(b, chunk, hkv, d).astype(np.float32))
-        vb = jnp.asarray(rng.randn(b, chunk, hkv, d).astype(np.float32))
-        lengths = jnp.asarray([11, 20], jnp.int32)
         step = jnp.asarray(1, jnp.int32)
         ref = paged_chunk_decode_attention(
-            q, qk, qv, tables, kb, vb, lengths, step,
+            q, qk, qv, tables, kb, vb, lengths, step, window=window,
             k_scales=sk, v_scales=sv, use_kernel=False,
         )
         kern = paged_chunk_decode_attention(
-            q, qk, qv, tables, kb, vb, lengths, step,
+            q, qk, qv, tables, kb, vb, lengths, step, window=window,
             k_scales=sk, v_scales=sv, use_kernel=True, interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(kern), np.asarray(ref), atol=2e-6
         )
+
+    @pytest.mark.parametrize("window", [0, 40])
+    @pytest.mark.parametrize("pool", ["f32", "int8"])
+    def test_dead_pages_are_never_read(self, pool, window):
+        """Every pool block that no live table entry names holds NaN (an
+        int8 pool: NaN scales) and every table entry outside a lane's band
+        an id outside the pool: the kernel's output is finite and equals
+        the reference's over the clean pool and table."""
+        from gofr_tpu.ops.attention import paged_chunk_decode_attention
+
+        hq, hkv, MB, lengths = self.SHAPES["edges"]
+        Bk, NB = 8, 128
+        q, pk, pv, _, kb, vb, lens = self._inputs(2, hq, hkv, MB, lengths, NB=NB)
+        step = jnp.asarray(1, jnp.int32)
+        # each lane's live pages are its own, scattered over the pool
+        rng = np.random.RandomState(3)
+        tables = rng.permutation(NB)[: len(lengths) * MB].reshape(-1, MB).astype(np.int32)
+        slot = np.arange(MB)[None, :]
+        hi = np.asarray(lengths)[:, None]
+        lo = np.maximum(hi + 1 - window + 1, 0) if window else np.zeros_like(hi)
+        live = (slot * Bk < hi) & ((slot + 1) * Bk > lo)
+        dead_blocks = np.setdiff1d(np.arange(NB), tables[live])
+        poisoned_tables = np.where(live, tables, rng.choice([-7, NB, NB + 1000], tables.shape))
+        if pool == "int8":
+            (pk, sk), (pv, sv) = quantize_rows(pk), quantize_rows(pv)
+            clean = dict(k_scales=sk, v_scales=sv)
+            dirty = dict(k_scales=sk.at[dead_blocks].set(jnp.nan),
+                         v_scales=sv.at[dead_blocks].set(jnp.nan))
+            dirty_k, dirty_v = pk, pv
+        else:
+            clean = dirty = {}
+            dirty_k = pk.at[dead_blocks].set(jnp.nan)
+            dirty_v = pv.at[dead_blocks].set(jnp.nan)
+        ref = paged_chunk_decode_attention(
+            q, pk, pv, jnp.asarray(tables), kb, vb, lens, step,
+            window=window, use_kernel=False, **clean,
+        )
+        kern = paged_chunk_decode_attention(
+            q, dirty_k, dirty_v, jnp.asarray(poisoned_tables), kb, vb, lens, step,
+            window=window, use_kernel=True, interpret=True, **dirty,
+        )
+        assert np.isfinite(np.asarray(kern)).all()
+        np.testing.assert_allclose(
+            np.asarray(kern), np.asarray(ref), atol=2e-6
+        )
+
+    # (block, table slots): at 4 or 8 bf16 heads of 128 a step holds 128
+    # tokens, so 8, 2 and 1 pages. 112 is the width max_seq_len 1,792 gave
+    # a 16-token table before a slot held the engine's merge slack, 113
+    # what a request can fill since, 120 the width it is built with; the
+    # widths at 64 and 128 are the same rows. 113 and 29 leave the last
+    # page group partial.
+    @pytest.mark.parametrize("window", [0, 300])
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    @pytest.mark.parametrize("hkv", [4, 8])
+    @pytest.mark.parametrize(
+        "block,n_tbl",
+        [(16, 112), (16, 113), (16, 120), (64, 28), (64, 29), (64, 30), (128, 14), (128, 15)],
+    )
+    def test_kernel_at_serving_blocks(self, block, n_tbl, hkv, pool, window):
+        """The kernel against paged_gather + chunk_decode_attention at the
+        block sizes, head counts and table widths engines serve with:
+        contexts of none, one row, mid-page, mid-table across page groups,
+        one short of the table and the whole table."""
+        from gofr_tpu.ops.attention import paged_chunk_decode_attention, paged_decode_pages
+
+        rows = n_tbl * block
+        lengths = [0, 1, block + block // 2 + 1, rows // 2 + 3, rows - 1, rows]
+        q, pk, pv, tables, kb, vb, lens = self._inputs(
+            4, 2 * hkv, hkv, n_tbl, lengths, NB=24, d=128, Bk=block,
+        )
+        scales = {}
+        if pool == "int8":
+            (pk, sk), (pv, sv) = quantize_rows(pk), quantize_rows(pv)
+            scales = dict(k_scales=sk, v_scales=sv)
+        else:
+            pk, pv = pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16)
+        assert paged_decode_pages(block, hkv, 128, pk.dtype, n_tbl) * block == (
+            256 if pool == "int8" and hkv == 4 else 128
+        )
+        step = jnp.asarray(3, jnp.int32)
+        # the reference reads the bf16 pool's own values widened to f32 (it
+        # would otherwise round its probabilities to the pool's dtype)
+        wide = (lambda a: a) if pool == "int8" else (lambda a: a.astype(jnp.float32))
+        ref = paged_chunk_decode_attention(
+            q, wide(pk), wide(pv), tables, kb, vb, lens, step, window=window,
+            use_kernel=False, **scales,
+        )
+        kern = paged_chunk_decode_attention(
+            q, pk, pv, tables, kb, vb, lens, step, window=window,
+            use_kernel=True, interpret=True, **scales,
+        )
+        np.testing.assert_allclose(np.asarray(kern), np.asarray(ref), atol=5e-6)
+
+    def test_tile_follows_the_pool_shape(self):
+        """paged_decode_pages: a step's pages come from block size, local
+        kv heads, head_dim and the pool's dtype alone."""
+        from gofr_tpu.ops.attention import paged_decode_pages
+
+        bf16, i8 = jnp.bfloat16, jnp.int8
+        assert paged_decode_pages(16, 4, 128, bf16, 112) == 8  # qwen2: 128 tokens
+        assert paged_decode_pages(16, 4, 128, i8, 112) == 16  # half the bytes a row
+        assert paged_decode_pages(64, 4, 128, bf16, 28) == 2
+        assert paged_decode_pages(128, 4, 128, bf16, 14) == 1
+        assert paged_decode_pages(16, 1, 128, bf16, 112) == 32  # MQA: 512 tokens at most
+        assert paged_decode_pages(16, 8, 256, bf16, 64) == 8  # never under 128 tokens
+        assert paged_decode_pages(16, 4, 128, bf16, 5) == 5  # nor over the table
+        # under a TP mesh the kernel runs per head shard: the LOCAL kv heads
+        from gofr_tpu.parallel import make_mesh
+
+        tp4 = make_mesh({"data": 1, "model": 4}, devices=jax.devices()[:4])
+        assert paged_decode_pages(16, 4, 128, bf16, 120, hq=28, mesh=tp4) == 32  # 1 head a shard
+        assert paged_decode_pages(16, 8, 128, bf16, 120, hq=32, mesh=tp4) == 16  # 2 heads a shard
+        assert paged_decode_pages(16, 2, 128, bf16, 120, hq=32, mesh=tp4) == 16  # replicated: both
+
+    @pytest.mark.parametrize("int8,tile", [(False, {"pages": 8, "tokens": 128}), (True, {"pages": 16, "tokens": 256})])
+    def test_engine_reports_the_tile(self, monkeypatch, int8, tile):
+        """stats()["attention"] names the kernel's tile beside `decode:
+        pallas_paged` (the string the benchmark compares), from the
+        engine's own pool: here 16-token blocks of 2 f32 / int8 heads of
+        128 (the int8 tile is held to the table's 16 slots). The CPU has no such kernel, so the
+        REPORT is asked as a TPU would answer; nothing is traced."""
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=256, n_layers=1, n_heads=2, n_kv_heads=2,
+            head_dim=128, d_ff=64, dtype=jnp.float32,
+        )
+        eng = LLMEngine(
+            cfg, init_params(jax.random.PRNGKey(0), cfg), slots=1, max_seq_len=240,
+            warmup=False, kv_paged=True, kv_block=16, kv_int8=int8,
+        )
+        try:
+            assert eng.stats()["attention"]["decode"].startswith("xla_gather")
+            assert "decode_tile" not in eng.stats()["attention"]
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            paths = eng._attention_paths()
+            assert paths["decode"] == "pallas_paged"
+            assert eng.kv.table_width == 16 and paths["decode_tile"] == tile
+        finally:
+            eng.close()
 
     def test_paged_decode_chunk_matches_gather_path(self, params):
         """transformer.decode_chunk_paged (per-layer paged attention,
