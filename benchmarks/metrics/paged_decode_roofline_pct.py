@@ -2,7 +2,6 @@
 the KV bytes the sub-window's decode tokens had to read (each token its whole
 context, from the benchmark's own records, whatever implements the read) over
 the peak bandwidth, divided by the kernel's summed device time."""
-import costs
 import stats as S
 import trace as T
 
@@ -20,6 +19,6 @@ def read(run):
     if not count or kernel_s <= 0:
         return None
     contexts = S.decode_contexts(run["records"], tr["ta"], tr["tb"])
-    bytes_per_s = costs.decode_kv_read_bytes(run["model"], contexts) / (tr["tb"] - tr["ta"])
+    bytes_per_s = run["family"].decode_kv_read_bytes(run["model"], contexts) / (tr["tb"] - tr["ta"])
     least_share = bytes_per_s / run["peaks"]["hbm_bytes_per_s"]  # of each second
     return 100.0 * least_share / (kernel_s / tr["reduced"]["span_s"])  # of each second the ops span

@@ -8,7 +8,6 @@ benchmark's own request records: decode tokens by their yield stamps, prompt
 tokens spread evenly between a request's admission and its first token
 (`stats.prefill_contexts`), because the program does not say which rows a
 step carried (PERF.md, Open questions)."""
-import costs
 import stats as S
 
 META = {"name": "step_mfu_pct", "unit": "%", "better": "higher", "source": "device_trace",
@@ -19,7 +18,7 @@ META = {"name": "step_mfu_pct", "unit": "%", "better": "higher", "source": "devi
 def read(run):
     tr = run["trace"]
     ta, tb = tr["ta"], tr["tb"]
-    least = costs.least_step_seconds(
+    least = run["family"].least_step_seconds(
         run["model"], run["peaks"],
         prefill_contexts=S.prefill_contexts(run["records"], ta, tb),
         decode_contexts=S.decode_contexts(run["records"], ta, tb),

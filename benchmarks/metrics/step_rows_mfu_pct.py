@@ -1,5 +1,5 @@
 """step_mfu_pct with its numerator READ, not counted: the same formula and
-denominator (`costs.least_step_seconds` over the traced device window and the
+denominator (the family's `least_step_seconds` over the traced device window and the
 chips), the prompt and decode tokens taken from the `rows` and `decode_ctx` of
 the engine's step records (`stats()["step_log"]`) of the programs whose
 executions the trace holds (joined by hostspans.py). A row (start, n, shape) is
@@ -7,7 +7,6 @@ n prompt tokens at contexts start+1..start+n; a decoding lane (context, n) emitt
 n tokens at contexts context+1..context+n. The executions that the trace cuts at
 its two ends count by the share of them it holds: their recorded time over the
 mean time of the whole executions of the same program."""
-import costs
 import hostspans
 
 META = {"name": "step_rows_mfu_pct", "unit": "%", "better": "higher", "source": "device_trace",
@@ -33,7 +32,7 @@ def read(run):
         times = whole.get(rec["program"])
         if cut and not times:
             continue  # nothing to measure its share by
-        least = costs.least_step_seconds(
+        least = run["family"].least_step_seconds(
             run["model"], run["peaks"],
             prefill_contexts=[c for start_, n, _shape in rec["rows"] for c in range(start_ + 1, start_ + n + 1)],
             decode_contexts=[c for ctx, n in rec["decode_ctx"] for c in range(ctx + 1, ctx + n + 1)],

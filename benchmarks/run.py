@@ -3,10 +3,14 @@
 
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Finds `workloads/<name>.json` -> `configs/<config>.json` -> every
-`metrics/*.py` whose META lists the cell, all by name: a later PR adds a
-cell, a configuration or a per-layer metric by adding a file and an entry in
-BENCHMARK.json, and edits nothing here.
+Finds `workloads/<name>.json` -> `configs/<config>.json` ->
+`families/<family>.py` (the one place that knows the configuration's
+architecture: the program's config and weights, the plain reference, the
+cost functions) -> every `metrics/*.py` whose META lists the cell, all by
+name: a later PR adds a cell, a configuration, an architecture or a per-layer
+metric by adding files and entries in BENCHMARK.json, and edits nothing here.
+Of the configuration's `model` group the harness reads `vocab_size` (the
+traffic's ids) and nothing else.
 
 Set-up (weights from the seed on the device, the engine built through
 `app.container.tpu().register_llm`, its warm-up, the ramp of the load) ends
@@ -69,6 +73,35 @@ def load_cell(name: str, rehearse: bool) -> tuple[dict, dict]:
     return workload, config
 
 
+def load_file(kind: str, path: str):
+    """The module in one file of `metrics/` or `families/`."""
+    stem = os.path.basename(path)[:-3]
+    spec = importlib.util.spec_from_file_location(kind + "_" + re.sub(r"\W", "_", stem), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+FAMILY_INTERFACE = ("program_config", "program_params", "gaps", "forward_logits",
+                    "least_step_seconds", "decode_kv_read_bytes")
+
+
+def load_family(config: dict):
+    """`families/<family>.py`, by the name in the configuration's file. No
+    default: a configuration says which architecture it is."""
+    name = config.get("family")
+    if not name:
+        raise RunFault("the configuration's file names no `family` (benchmarks/families/<family>.py)")
+    path = os.path.join(HERE, "families", name + ".py")
+    if not os.path.isfile(path):
+        raise RunFault(f"the configuration names the family {name!r}, and there is no {path}")
+    mod = load_file("family", path)
+    missing = [n for n in FAMILY_INTERFACE if not callable(getattr(mod, n, None))]
+    if missing:
+        raise RunFault(f"{path} lacks {missing} of the family's interface {list(FAMILY_INTERFACE)}")
+    return mod
+
+
 def load_metrics(cell: str) -> list:
     """Every metrics/<name>.py whose META['workloads'] names the cell (or
     has none: then every cell that reports what it moves)."""
@@ -77,10 +110,7 @@ def load_metrics(cell: str) -> list:
     for fn in sorted(os.listdir(folder)):
         if not fn.endswith(".py") or fn.startswith("_"):
             continue
-        spec = importlib.util.spec_from_file_location("metric_" + re.sub(r"\W", "_", fn[:-3]),
-                                                      os.path.join(folder, fn))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = load_file("metric", os.path.join(folder, fn))
         cells = mod.META.get("workloads")
         if cells is None or cell in cells:
             out.append(mod)
@@ -88,48 +118,6 @@ def load_metrics(cell: str) -> list:
 
 
 # -- the system under test ---------------------------------------------------
-
-
-def transformer_config(model: dict):
-    import jax.numpy as jnp
-
-    from gofr_tpu.models import TransformerConfig
-
-    hd = int(model.get("head_dim") or model["hidden_size"] // model["num_attention_heads"])
-    return TransformerConfig(
-        vocab_size=model["vocab_size"], d_model=model["hidden_size"],
-        n_layers=model["num_hidden_layers"], n_heads=model["num_attention_heads"],
-        n_kv_heads=model["num_key_value_heads"], head_dim=hd,
-        d_ff=model["intermediate_size"], rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]), act=model.get("hidden_act", "silu"),
-        scale_embed=False, sliding_window=int(model.get("sliding_window") or 0),
-        qkv_bias=bool(model.get("qkv_bias")),
-        dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[model.get("dtype", "bfloat16")],
-    )
-
-
-def program_params(model: dict, seed: int):
-    """The seed's weights in the program's tree: ONE jitted call on the
-    device, int8 as served."""
-    import jax
-
-    import weights as W
-    from gofr_tpu.models.quant import QTensor
-
-    dt = W.dtype_of(model)
-    fan_in = W.fan_ins(model)
-
-    def qtensor(q, name):
-        """The program's scale leaf is [..., 1, out] (tables: [1, d])."""
-        return QTensor(q=q, s=jax.numpy.full(q.shape[:-2] + (1, q.shape[-1]), W.scale_of(fan_in[name], dt), dt))
-
-    def build(key):
-        a = W.all_arrays(model, key)
-        layers = {name: qtensor(x, name) if name in fan_in else x for name, x in a["layers"].items()}
-        return {"embed": qtensor(a["embed"], "embed"), "unembed": qtensor(a["unembed"], "unembed"),
-                "final_norm": a["final_norm"], "layers": layers}
-
-    return jax.jit(build)(W.base_key(seed))
 
 
 def expected_paths_ok(paths: dict, expect: dict) -> str:
@@ -163,6 +151,16 @@ def run(args) -> int:
     cell = args.workload
     chips = int(workload.get("chips", 1))
     model, engine_kw = config["model"], dict(config["engine"])
+    family = load_family(config)
+    plan = traffic.Plan(workload, args.seed, model["vocab_size"])
+    longest = max(plan.distinct(), key=lambda r: r.prompt_len + r.output_len)
+    held = longest.prompt_len + longest.output_len
+    if held > int(engine_kw["max_seq_len"]):
+        # the engine would cap it, and a request that returns another count than it asked has
+        # failed: a faster engine then fails a request that a slower one never reached (PERF.md, PR 26)
+        raise RunFault(f"the mix holds a request with a prompt of {longest.prompt_len} tokens asking "
+                       f"{longest.output_len}, {held} together: over the engine's max_seq_len "
+                       f"{engine_kw['max_seq_len']}")
 
     import jax
 
@@ -194,8 +192,8 @@ def run(args) -> int:
 
     # -- set-up: weights, engine, warm-up ------------------------------------
     t = time.perf_counter()
-    cfg = transformer_config(model)
-    params = program_params(model, args.seed)
+    cfg = family.program_config(model)
+    params = family.program_params(model, args.seed)
     jax.block_until_ready(params)
     t_weights = time.perf_counter() - t
     t = time.perf_counter()
@@ -206,7 +204,6 @@ def run(args) -> int:
     why = expected_paths_ok(handle.stats()["attention"], config["expect"]["attention"])
     if why:
         raise RunFault(why)
-    plan = traffic.Plan(workload, args.seed, model["vocab_size"])
     say("traffic " + json.dumps(plan.describe()))
 
     def make_request(tokens, max_new):
@@ -307,10 +304,8 @@ def run(args) -> int:
              "compiles_in_window": [new_compiles, 0],
              "degraded_programs": [len(degraded), 0]}
     if samples:
-        import reference
-
-        res = reference.gaps(model, args.seed, samples, int(engine_kw["max_seq_len"]),
-                             control=bool(args.control))
+        res = family.gaps(model, args.seed, samples, int(engine_kw["max_seq_len"]),
+                          control=bool(args.control))
         say(f"reference: {len(samples)} requests, {len(res['gap'])} served tokens in "
             f"{time.perf_counter() - t:.1f} s; agree with the reference's first choice "
             f"{sum(res['agree'])}/{len(res['agree'])}; gap per request {res['per_request']}")
@@ -331,7 +326,7 @@ def run(args) -> int:
 
     # -- metrics ------------------------------------------------------------------
     ctx = {
-        "cell": cell, "workload": workload, "config": config, "model": model,
+        "cell": cell, "workload": workload, "config": config, "model": model, "family": family,
         "peaks": pk, "chips": chips, "records": records, "summary": summary,
         "stats0": stats0, "stats1": stats1, "kv_samples": kv_samples,
         "engine": engine_facts, "t0": t0, "t1": t1, "trace": None,

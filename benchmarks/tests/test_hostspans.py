@@ -8,6 +8,7 @@ import types
 import costs
 import hostspans as H
 import pytest
+import run as R
 import trace as T
 import traffic
 
@@ -149,10 +150,11 @@ def metric(name):
 
 def a_run(data, monkeypatch, step_log=True):
     monkeypatch.setattr(T, "load", lambda _dir: data)
-    model = traffic.load(os.path.join(costs.HERE, "configs", "mistral-7b.json"))["model"]
+    config = traffic.load(os.path.join(costs.HERE, "configs", "mistral-7b.json"))
+    model = config["model"]
     stats1 = {"step_log": {"fields": RECORDS[0][1], "records": tuple(r for r, _f in RECORDS)}} if step_log else {}
     return {"trace": {"dir": "unused", "reduced": reduced(data), "ta": 0.0, "tb": 0.052}, "stats1": stats1,
-            "model": model, "peaks": {"bf16_flops": 200e12, "int8_ops": 400e12},
+            "model": model, "family": R.load_family(config), "peaks": {"bf16_flops": 200e12, "int8_ops": 400e12},
             "config": {"engine": {"quantize": True}}, "chips": 1}
 
 

@@ -130,6 +130,12 @@ class Plan:
             n += int(self.prefix["prefix_tokens"])
         return Spec(index, client, n, int(self.output_lens[slot]), group, session, turn, due)
 
+    def distinct(self) -> list[Spec]:
+        """Every distinct request the mix cycles through, each session's
+        turns included, whatever the arrival: what the engine has to hold."""
+        return [self._spec(0, s * self.turns + t, s, t, 0.0)
+                for s in range(self.pool) for t in range(self.turns)]
+
     def client_request(self, client: int, k: int) -> Spec:
         """Closed loop: the k-th request of a client. Its first asks for a
         fraction of its drawn output, so the clients are out of step when
