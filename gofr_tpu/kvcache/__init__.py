@@ -316,6 +316,28 @@ def paged_default():
     return "auto" if os.environ.get("TPU_LLM_KV_PAGED", "1") != "0" else False
 
 
+def row_shapes(cfg) -> tuple[tuple, tuple]:
+    """What ONE token of ONE layer keeps in the cache: the trailing dims of
+    the cache's two arrays (`KVCache.k`, `KVCache.v`), read from the config
+    here and nowhere else. GQA: keys and values, [n_kv_heads, head_dim]
+    each. Latent attention (cfg.latent): ONE row [c_kv | k_rope] of
+    kv_lora_rank + qk_rope_head_dim values and no array of values at all,
+    kept as its two parts, `k` = the normalized latent [1, kv_lora_rank]
+    (keys AND values of the absorbed form) and `v` = the shared rotated
+    rope key [1, R], R = qk_rope_head_dim padded up to whole 128-lane rows
+    (ops.latent_rope_width): the TPU tiles a bf16 array's last dim to 128
+    lanes whatever it says, so the padding costs no memory that 576 columns
+    in one array would save (576 tiles to 640 too), each part is a slab
+    the decode kernel can copy and slice at lane 0, and every pool helper
+    (gather_slots / scatter_rows / copy_blocks, host spill) moves a pair of
+    arrays already."""
+    if getattr(cfg, "latent", False):
+        from ..ops import latent_rope_width
+
+        return (1, cfg.kv_lora_rank), (1, latent_rope_width(cfg.qk_rope_head_dim))
+    return (cfg.n_kv_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)
+
+
 class CacheManager:
     """Owns the serving engine's KV layout, residency, and reuse policy.
 
@@ -402,6 +424,10 @@ class CacheManager:
         # (chunk-granular rounding) and the end-of-chunk merge writes one
         # chunk more, so no layout ever clamp-overwrites a live row.
         self.slot_rows = max_seq_len + 2 * int(decode_chunk)
+        # ...and what one of those rows holds (row_shapes: the one place)
+        self.row_shapes = row_shapes(cfg)
+        self.row_heads = self.row_shapes[0][0]
+        self.row_values = sum(h * d for h, d in self.row_shapes)
         # A flat (paged / dense) slot is BUILT with those rows, rounded up
         # to whole flash key blocks of 128 where max_seq_len itself was
         # (ops.attention.chunk_prefill_why_not_flash): an engine whose
@@ -435,12 +461,15 @@ class CacheManager:
             self.capacity = self.table_width * self.block
             self.ring = 0
             kv_itemsize = 1 if self.int8 else itemsize
-            self.block_bytes = (
-                2 * cfg.n_layers * self.block * cfg.n_kv_heads
-                * cfg.head_dim * kv_itemsize
-                + (2 * cfg.n_layers * self.block * cfg.n_kv_heads * 4
-                   if self.int8 else 0)
+            if self.int8 and getattr(cfg, "latent", False):
+                raise ValueError(
+                    "an int8 KV pool is not supported with latent attention: "
+                    "its per-(row, head) scales assume a key and a value array"
+                )
+            self.row_bytes = self.row_values * kv_itemsize + (
+                2 * self.row_heads * 4 if self.int8 else 0
             )
+            self.block_bytes = cfg.n_layers * self.block * self.row_bytes
             retain_bytes = int(prefix_cache_mb * 1024 * 1024)
             if host_cache_mb is None:
                 host_cache_mb = float(
@@ -494,10 +523,8 @@ class CacheManager:
             self.capacity = self.window + slack if self.rolling else flat_rows
             # static arg for decode_chunk/attention: ring capacity, 0 = dense
             self.ring = self.capacity if self.rolling else 0
-            self.slot_bytes = (
-                2 * cfg.n_layers * slots * self.capacity * cfg.n_kv_heads
-                * cfg.head_dim * itemsize
-            )
+            self.row_bytes = self.row_values * itemsize
+            self.slot_bytes = cfg.n_layers * slots * self.capacity * self.row_bytes
             self.prefix = (
                 PrefixCache(int(prefix_cache_mb * 1024 * 1024), metrics, model)
                 if prefix_cache_mb > 0
@@ -578,16 +605,17 @@ class CacheManager:
         from ..models.transformer import KVCache
 
         cfg = self.cfg
-        shape = (cfg.n_layers, self.pool.n_blocks, self.block,
-                 cfg.n_kv_heads, cfg.head_dim)
+        lead = (cfg.n_layers, self.pool.n_blocks, self.block)
+        k_row, v_row = self.row_shapes
         dtype = jnp.int8 if self.int8 else cfg.dtype
         cache = KVCache(
-            k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
+            k=jnp.zeros(lead + k_row, dtype),
+            v=jnp.zeros(lead + v_row, dtype),
             length=jnp.zeros((self.slots,), jnp.int32),
         )
         scales = (
-            jnp.zeros((2,) + shape[:-1], jnp.float32) if self.int8 else None
+            jnp.zeros((2,) + lead + (self.row_heads,), jnp.float32)
+            if self.int8 else None
         )
         return cache, scales
 
@@ -1090,6 +1118,7 @@ class CacheManager:
                 "capacity": self.capacity,
                 "window": self.window,
                 "slot_bytes": self.slot_bytes,
+                "row_bytes": self.row_bytes,
                 "prefix": self.prefix.stats() if self.prefix is not None else None,
             }
         with self._plock:
@@ -1105,6 +1134,8 @@ class CacheManager:
                 "blocks_reserved": self.pool.reserved,
                 "cow_copies": self.pool.cow_copies,
                 "block_bytes": self.block_bytes,
+                # one token of one layer, as stored (row_shapes; scales included)
+                "row_bytes": self.row_bytes,
                 # single source of truth for resident KV bytes: the pool
                 "slot_bytes": self.pool.bytes_in_use(),
                 "prefix": self.radix.stats() if self.radix is not None else None,

@@ -142,10 +142,54 @@ _EOS_DEFAULT = -1  # no EOS cut by default (random-weight models)
 STEP_FIELDS = (
     "seq", "kind", "program", "k", "depth", "lanes", "decode_ctx", "rows",
     "t_dispatch", "t_dispatched", "t_fetch", "t_fetched", "t_emitted", "emitted",
+    # routed experts (0 for a dense model): token-expert pairs the program
+    # computed and experts that were given a row, summed over its layer calls
+    "moe_pairs", "moe_touched",
 )
 # the ring holds two benchmark windows of programs (50 s each) down to 25 ms a program
 STEP_LOG_LEN = 4096
 _DECODE_KINDS = ("chunk", "step", "verify")
+
+def latent_refusal(
+    *, mesh, chunked: bool, kv_paged, speculative: bool, constrained, lora_slots,
+) -> str | None:
+    """What a latent-attention model (cfg.latent) is refused at engine build,
+    as a sentence, or None: every path that was not written for a latent
+    cache row says so here instead of falling back silently."""
+    if mesh is not None:
+        return (
+            "latent-attention models serve on one chip: tensor or expert "
+            "parallelism (a mesh, tp > 1) is not written for them, one chip "
+            "holds each layer whole"
+        )
+    if kv_paged is False:
+        return (
+            "latent-attention models serve from the paged KV pool only: the "
+            "contiguous layout (kv_paged=False) is not written for a latent row"
+        )
+    if not chunked:
+        return (
+            "latent-attention models need the token-budget step scheduler: "
+            "the wave scheduler (step_token_budget=0) prefills through a "
+            "contiguous cache"
+        )
+    if speculative:
+        return (
+            "speculative decoding (verify_chunk) is not written for "
+            "latent-attention models: serve with speculative=False"
+        )
+    if constrained:
+        return (
+            "constrained decoding is not written for latent-attention models: "
+            "serve with constrained=False"
+        )
+    if lora_slots:
+        return (
+            "LoRA adapters on the latent projections are not supported: serve "
+            "with lora_slots=0"
+        )
+    return None
+
 
 # Serializes app_llm_* registration across engines (ReplicatedLLMEngine
 # builds N engines on parallel threads; same rationale as the kvcache
@@ -788,6 +832,23 @@ class LLMEngine:
             max(1, min(int(spec_draft), 2 * decode_chunk))
             if self.speculative else 0
         )
+        if lora_slots is None:
+            lora_slots = int(_os.environ.get("TPU_LLM_LORA_SLOTS", "0") or 0)
+        if getattr(cfg, "latent", False):
+            why = latent_refusal(
+                mesh=mesh, chunked=self.chunked, kv_paged=kv_paged,
+                speculative=self.speculative, constrained=constrained,
+                lora_slots=lora_slots,
+            )
+            if why:
+                raise ValueError(why)
+            constrained, kv_paged = False, True
+        elif len(getattr(cfg, "group_sizes", ())) > 1 and (mesh is not None or lora_slots):
+            raise ValueError(
+                "a model of two layer groups (leading dense layers, then routed "
+                "ones) serves on one chip and without LoRA slots: the sharding "
+                "specs and the adapter tables are written for one stacked group"
+            )
         # SLO-aware overload control (both optional, both mutable at
         # runtime): max_queue bounds requests waiting for a slot — beyond
         # it submit() raises EngineOverloaded (-> 429) instead of letting
@@ -1197,8 +1258,6 @@ class LLMEngine:
         # per-tenant compile, and a hot-load is one table-slice rewrite.
         # Chunked-scheduler only, like constrained decoding: the wave path
         # packs prefill rows != slots, so adapter ids cannot ride it.
-        if lora_slots is None:
-            lora_slots = int(_os.environ.get("TPU_LLM_LORA_SLOTS", "0") or 0)
         if lora_rank is None:
             lora_rank = int(_os.environ.get("TPU_LLM_LORA_RANK_MAX", "8") or 8)
         self.lora_slots = max(0, int(lora_slots)) if self.chunked else 0
@@ -1810,7 +1869,21 @@ class LLMEngine:
             Bp = self.kv.block
             _cap = self.kv.capacity
             _int8 = self.kv.int8
-            _use_kernel = self.attention_paths["decode"] == "pallas_paged"
+            _latent = bool(getattr(cfg, "latent", False))
+            _use_kernel = self.attention_paths["decode"] in (
+                "pallas_paged", "pallas_mla_paged",
+            )
+            # a latent cache has no contiguous decode chunk to fall back to:
+            # decode_chunk_paged gathers through the table itself off the TPU
+            _paged_fn = _use_kernel or _latent
+            _moe = int(getattr(cfg, "n_experts", 0) or 0) > 0
+
+            def _with_moe(out: tuple, moe: list) -> tuple:
+                """A routed model's program returns what its experts did
+                beside its results: ONE int32 vector [pairs, touched, rows
+                per expert...] summed over its layer calls. A dense model's
+                programs return what they always did."""
+                return out + (sum(moe),) if _moe else out
 
             def _sc(scales):
                 return scales if _int8 else None
@@ -1840,21 +1913,23 @@ class LLMEngine:
             def _make_paged_chunk_op(K: int):
                 def _chunk(params, tail, cache, scales, tables, live, active, temps, rng):
                     eff = jnp.logical_and(active, live)
-                    if _use_kernel:
+                    moe: list = []
+                    if _paged_fn:
                         toks, last, cache, sc_out, rng = decode_chunk_paged(
                             params, cfg, tail, cache, (scales if _int8 else None),
                             tables, eff, temps, rng,
                             n_steps=K, sample_fn=_sample, block=Bp,
-                            overlap=self._tp_gather, mesh=self.mesh,
+                            use_kernel=_use_kernel,
+                            overlap=self._tp_gather, mesh=self.mesh, moe_out=moe,
                         )
-                        return toks, last, cache, (
+                        return _with_moe((toks, last, cache, (
                             sc_out if _int8 else scales
-                        ), rng
+                        ), rng), moe)
                     dense = _gather_view(cache, scales, tables, cache.length)
                     toks, last, nd, rng = chunk_fn(
                         params, cfg, tail, dense, eff, temps, rng,
                         n_steps=K, sample_fn=_sample, ring=0,
-                        overlap=self._tp_gather,
+                        overlap=self._tp_gather, moe_out=moe,
                     )
                     pos = cache.length[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]
                     valid = eff[:, None] & (pos < _cap)
@@ -1862,7 +1937,9 @@ class LLMEngine:
                         cache, scales, tables,
                         _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
                     )
-                    return toks, last, cache._replace(length=nd.length), scales, rng
+                    return _with_moe(
+                        (toks, last, cache._replace(length=nd.length), scales, rng), moe
+                    )
 
                 return instrument_jit(
                     f"llm.decode_chunk{K}", _chunk, model=self.label,
@@ -1955,9 +2032,10 @@ class LLMEngine:
                         tables, jnp.clip(slot_idx, 0, slots - 1), axis=0
                     )
                     sub = _gather_view(cache, scales, tsub, cursors)
+                    moe: list = []
                     logits, sub2 = prefill_append(
                         params, cfg, tokens, sub, cursors, n_new, ring=0,
-                        aids=aids_row, mesh=self.mesh,
+                        aids=aids_row, mesh=self.mesh, moe_out=moe,
                     )
                     c = shape
                     pos_a = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
@@ -1983,12 +2061,13 @@ class LLMEngine:
                     temps = temps.at[fin_slot].set(req_temps, mode="drop")
                     kept = logits if keep_logits else None
                     eff = jnp.logical_and(active, live)
-                    if _use_kernel:
+                    if _paged_fn:
                         toks, last, cache, sc, rng = decode_chunk_paged(
                             params, cfg, tail, cache, (scales if _int8 else None),
                             tables, eff, temps, rng,
                             n_steps=K, sample_fn=_sample, block=Bp,
-                            overlap=self._tp_gather, mesh=self.mesh,
+                            use_kernel=_use_kernel,
+                            overlap=self._tp_gather, mesh=self.mesh, moe_out=moe,
                         )
                         scales = sc if _int8 else scales
                     else:
@@ -1996,7 +2075,7 @@ class LLMEngine:
                         toks, last, nd, rng = chunk_fn(
                             params, cfg, tail, dense, eff, temps, rng,
                             n_steps=K, sample_fn=_sample, ring=0,
-                            overlap=self._tp_gather,
+                            overlap=self._tp_gather, moe_out=moe,
                         )
                         pos = cache.length[:, None] + jnp.arange(
                             K, dtype=jnp.int32
@@ -2007,7 +2086,9 @@ class LLMEngine:
                             _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
                         )
                         cache = cache._replace(length=nd.length)
-                    return first, kept, toks, last, cache, scales, active, temps, rng
+                    return _with_moe(
+                        (first, kept, toks, last, cache, scales, active, temps, rng), moe
+                    )
 
                 name = f"llm.step_p{shape}_d{K}"
                 return instrument_jit(
@@ -2455,6 +2536,13 @@ class LLMEngine:
         self._step_seq = 0
         self._stat_admitted = 0  # slots assigned: sched.admit's count per pass
         self._step_log: deque = deque(maxlen=STEP_LOG_LEN)
+        # what the routed experts did, summed over the paged programs' records
+        # (stats()["moe"]): [pairs, experts touched, rows per expert...]
+        _E = int(getattr(cfg, "n_experts", 0) or 0)
+        self._moe_totals = np.zeros((2 + _E,), np.int64)
+        self._moe_layer_calls = 0
+        self._moe_layers = cfg.group_sizes[-1] if _E else 0  # the routed group is the last
+        self.moe_path = self._moe_path() if _E else None
         # Two engine threads: the SCHEDULER owns every device dispatch
         # (admission prefills, inserts, decode chunks); the COLLECTOR owns
         # the blocking device->host fetches and token emission. One
@@ -2486,6 +2574,26 @@ class LLMEngine:
             # so cold compiles can never trip a seconds-scale threshold
             self.watchdog = StepWatchdog(self, self.step_watchdog_s)
 
+    def _moe_path(self) -> str:
+        """The grouped matmul the routed FFN traces (stats()["moe"]["experts"]),
+        decided by the predicate ops.grouped consults at trace time: the
+        Pallas kernel over the int8 stacks, or `jax.lax.ragged_dot` with the
+        reason. ragged_dot widens a layer's whole int8 stack before every
+        call, so on a TPU that fallback is listed among the registry's
+        degraded programs (snapshot()["degraded"]) and not only named."""
+        import jax
+
+        from .ops.grouped import grouped_kernel_why_not
+
+        d, fe = self.cfg.d_model, self.cfg.moe_d_ff or self.cfg.d_ff
+        # 16: the narrowest row tile the routed FFN lays out on the TPU
+        why = grouped_kernel_why_not(d, fe, 16) or grouped_kernel_why_not(fe, d, 16)
+        if not why:
+            return "pallas_grouped"
+        if jax.default_backend() == "tpu":
+            self._registry.note_degraded("moe_grouped_matmul", self.label, why)
+        return f"ragged_dot ({why})"
+
     def _attention_paths(self) -> dict:
         """The attention implementation each program family traces —
         decode, and prefill per chunk shape — decided by the predicates
@@ -2504,6 +2612,27 @@ class LLMEngine:
         def flash_or(why: str) -> str:
             return f"xla ({why})" if why else "pallas_flash"
 
+        if getattr(self.cfg, "latent", False):
+            # one latent row for every head: its own paged-decode kernel, and
+            # absorbed XLA attention over the gathered rows for prompt chunks
+            from .ops.attention import mla_kernel_why_not
+
+            (_, C), (_, R) = self.kv.row_shapes
+            why = mla_kernel_why_not(C, R, self.kv.block)
+            decode = f"xla_gather ({why})" if why else "pallas_mla_paged"
+            paths = {
+                "decode": decode,
+                "prefill": {
+                    c: "xla_latent_absorbed (no latent flash kernel)"
+                    for c in self.chunk_shapes
+                },
+            }
+            if not why:
+                pages = paged_decode_pages(
+                    self.kv.block, 1, C, self.cfg.dtype, self.kv.table_width
+                )
+                paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+            return paths
         if self.kv.paged:
             why = paged_kernel_why_not(hd, self.kv.block)
             decode = f"xla_gather ({why})" if why else "pallas_paged"
@@ -2867,6 +2996,19 @@ class LLMEngine:
                     "rank_max": self.lora_rank if self.lora_slots else 0,
                 },
                 "moe_experts": int(getattr(self.cfg, "n_experts", 0) or 0),
+                # what the routed experts did (the paged programs' step records,
+                # summed): pairs computed, experts given a row and layer calls
+                # (touched / (experts x calls) is the share of the expert stream
+                # a step reads), rows per expert over all layers (load balance)
+                "moe": {
+                    # the grouped matmul the experts traced (Pallas kernel,
+                    # or ragged_dot and why); None for a dense model
+                    "experts": self.moe_path,
+                    "pairs": int(self._moe_totals[0]),
+                    "touched": int(self._moe_totals[1]),
+                    "layer_calls": int(self._moe_layer_calls),
+                    "tokens_per_expert": [int(x) for x in self._moe_totals[2:]],
+                },
                 "load_tokens": self.load_tokens(),
                 "rejected": self.rejected,
                 "shed": self.shed,
@@ -3950,7 +4092,8 @@ class LLMEngine:
                     for nb in nbs:
                         pack = jnp.zeros((nb, shape + 3), jnp.int32)
                         smeta = jnp.full((2, nb), self.slots, jnp.int32).at[1].set(0)
-                        _f, _kept, _toks, tail, cache, scales, active, temps, _ = op(
+                        (_f, _kept, _toks, tail, cache, scales, active, temps,
+                         *_rng_moe) = op(
                             self.params, cache, scales, tables, live,
                             tail, active, temps, pack, smeta, zero_rng,
                         )
@@ -3963,7 +4106,7 @@ class LLMEngine:
                         vpack, zero_rng,
                     )
                 for op in self._chunk_ops.values():
-                    toks, last, cache, scales, _ = op(
+                    toks, last, cache, scales, *_rng_moe = op(
                         self.params, tail, cache, scales, tables, live,
                         active, temps, zero_rng,
                     )
@@ -5506,18 +5649,24 @@ class LLMEngine:
 
     def _step_open(
         self, span, kind: str, op, t_dispatch: float, t_dispatched: float,
-        *, k: int = 0, lanes: int = 0, rows: tuple = (),
+        *, k: int = 0, lanes: int = 0, rows: tuple = (), moe=(),
     ) -> dict:
         """The record of the program just dispatched (STEP_FIELDS): it rides
         the in-flight entry's info and the collector finishes it. `span` is
         the open sched.dispatch span, which learns which program it was
         (None for an admission wave: its programs go out inside sched.admit).
-        Call with the lock held, before the entry is appended."""
+        Call with the lock held, before the entry is appended. `moe` holds
+        the device vector a routed model's program returned beside its
+        tokens (llm._with_moe), or nothing."""
         self._step_seq += 1
         program = getattr(op, "program", "")
         if span is not None:
             span.set(seq=self._step_seq, kind=kind, program=program)
+        moe_dev = moe[0] if moe else None
+        if moe_dev is not None:
+            self._start_fetch(moe_dev)
         return {
+            "moe_dev": moe_dev, "moe_pairs": 0, "moe_touched": 0,
             "seq": self._step_seq, "kind": kind, "program": program, "k": k,
             "depth": self._decode_depth(), "lanes": lanes, "decode_ctx": (),
             "rows": rows, "t_dispatch": t_dispatch, "t_dispatched": t_dispatched,
@@ -5533,6 +5682,15 @@ class LLMEngine:
         info["decode_ctx"] = tuple(decode_ctx)
         info["emitted"] = first + sum(n for _ctx, n in info["decode_ctx"])
         info["t_emitted"] = time.perf_counter()
+        moe_dev = info.pop("moe_dev", None)
+        if moe_dev is not None:
+            # the program's tokens are here already, so this small vector is too
+            vec = np.asarray(moe_dev).astype(np.int64)
+            info["moe_pairs"], info["moe_touched"] = int(vec[0]), int(vec[1])
+            self._moe_totals += vec
+            self._moe_layer_calls += self._moe_layers * (
+                info["k"] + (1 if info["kind"] == "step" else 0)
+            )
         self._step_log.append(tuple(info[f] for f in STEP_FIELDS))
 
     def _ctx_of(self, r: GenRequest) -> int:
@@ -5907,6 +6065,7 @@ class LLMEngine:
         queued chunk fetches. The saturated path is unchanged (full chunks
         either way). `span` is the scheduler's open sched.dispatch span."""
         self._ship_aids()
+        moe_dev: list = []  # a routed model's paged programs return their moe stats last
         with self._work_cv:
             # partial-prefill occupants are resident but NOT decoding:
             # the chunk's tokens for their slots are garbage (device
@@ -5970,7 +6129,8 @@ class LLMEngine:
                             gids, self._rng, self._gr_dev,
                         )
                     else:
-                        toks, last, self.cache, self._kv_scales, self._rng = op(
+                        (toks, last, self.cache, self._kv_scales, self._rng,
+                         *moe_dev) = op(
                             self.params, self._tail, self.cache,
                             self._kv_scales, td, live_dev,
                             self._active, self._temps, self._rng,
@@ -5990,6 +6150,7 @@ class LLMEngine:
                         )
             info = self._step_open(
                 span, "chunk", op, t0, time.perf_counter(), k=k, lanes=active_n,
+                moe=moe_dev,
             )
             self._tail = last
             self._start_fetch(toks)
@@ -6136,6 +6297,7 @@ class LLMEngine:
             else:
                 op = self._step_ops[shape]
             t0 = time.perf_counter()
+            moe_dev: list = []  # see _dispatch
             if self.kv.paged:
                 steps_cov = self._inflight_steps()
                 live = np.zeros((self.slots,), bool)
@@ -6176,7 +6338,7 @@ class LLMEngine:
                         )
                     else:
                         (first_dev, logits_dev, toks_dev, last, cache,
-                         self._kv_scales, active, temps, rng) = op(
+                         self._kv_scales, active, temps, rng, *moe_dev) = op(
                             self.params, self.cache, self._kv_scales, td,
                             live_dev, self._tail, self._active,
                             self._temps, pack_dev, meta_dev, self._rng,
@@ -6240,7 +6402,7 @@ class LLMEngine:
             info = {
                 **self._step_open(
                     span, "step", op, t0, t_dispatched,
-                    k=K, lanes=decode_n, rows=tuple(spans),
+                    k=K, lanes=decode_n, rows=tuple(spans), moe=moe_dev,
                 ),
                 "shape": shape, "nb": nb,
                 "prefill_tokens": prefill_tokens, "active": active_n,

@@ -29,6 +29,7 @@ wiring, derived from annotations.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 from ..ops import apply_rope, multi_head_attention, rms_norm
 
 __all__ = [
+    "routed_ffn",
     "MoEConfig",
     "moe_init",
     "moe_ffn",
@@ -108,6 +110,102 @@ def _deq(w):
     if isinstance(w, QTensor):
         return w.q.astype(jnp.float32) * w.s.astype(jnp.float32)
     return w
+
+
+def route(cfg, h: jnp.ndarray, lp: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The router of one layer over token rows h [T, d], in float32 whatever
+    the activations' dtype: -> (experts [T, k] int32, weights [T, k] f32).
+    What differs between checkpoints is configuration: the score function
+    (softmax | sigmoid), a correction bias that moves the CHOICE and never the
+    weight (`router_bias` leaf), normalising the chosen weights, a scale."""
+    # "highest": the TPU's default float32 matmul is one bfloat16 pass, and a
+    # near tie decided in bfloat16 picks another expert than the weights say
+    logits = jnp.dot(
+        h.astype(jnp.float32), _deq(lp["w_router"]).astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    if cfg.moe_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif cfg.moe_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown moe_score {cfg.moe_score!r}; expected softmax or sigmoid")
+    choose = scores
+    if "router_bias" in lp:
+        choose = scores + lp["router_bias"].astype(jnp.float32)
+    _, experts = jax.lax.top_k(choose, cfg.moe_top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.moe_norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * cfg.moe_scale
+
+
+def _row_tile(pairs: int, experts: int, on_tpu: bool) -> int:
+    """Rows a tile of the sorted layout holds: whole bf16 sublane tiles on
+    the TPU, wider once an expert sees that many rows on average."""
+    if not on_tpu:
+        return 8
+    return 16 if pairs <= 16 * experts else 64
+
+
+def routed_ffn(cfg, h: jnp.ndarray, lp: dict, mm, *, use_kernel=None, interpret=False):
+    """The ONE routed FFN of serving: dropless top-k experts (+ shared ones).
+
+    h [T, d] -> (y [T, d], counts [E] int32: rows each expert was given).
+    Route (`route`), sort the (token, expert) pairs by expert into a layout
+    where each expert's rows start on a tile boundary, three grouped matmuls
+    over the expert stacks as they are stored (ops.grouped: int8 stays int8
+    in HBM), and gather each token's k rows back with its weights. No pair
+    is ever dropped, and a row's arithmetic does not depend on which other
+    rows share its tile, so a token's output is its own whatever the batch.
+    Shared experts (`ws_gate`/`ws_up`/`ws_down`) are a dense gated FFN over
+    every token through `mm` (qmm | qmm_a8)."""
+    from ..ops.grouped import LayerOf, grouped_matmul
+
+    T = h.shape[0]
+    E, k = cfg.n_experts, cfg.moe_top_k
+    act_fn = _MOE_ACTS[cfg.act]
+    with jax.named_scope("layer/moe_route"):
+        experts, weights = route(cfg, h, lp)
+        pairs = T * k
+        tm = _row_tile(pairs, E, interpret or jax.default_backend() == "tpu")
+        flat_e = experts.reshape(pairs)  # pair p = token p // k, choice p % k
+        counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+        padded = -(-counts // tm) * tm
+        ends_pad = jnp.cumsum(padded)
+        starts, starts_pad = jnp.cumsum(counts) - counts, ends_pad - padded
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        dest_sorted = starts_pad[sorted_e] + jnp.arange(pairs, dtype=jnp.int32) - starts[sorted_e]
+        dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)  # pair -> row
+        n_tiles = -(-pairs // tm) + min(E, pairs)  # every non-empty expert wastes under a tile
+        rows = n_tiles * tm
+        src = jnp.full((rows,), T, jnp.int32).at[dest].set(
+            jnp.arange(pairs, dtype=jnp.int32) // k
+        )  # row -> token; T (out of range) = no token
+        used_tiles = ends_pad[-1] // tm
+        last = jnp.max(jnp.where(counts > 0, jnp.arange(E, dtype=jnp.int32), 0))
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(ends_pad, jnp.arange(n_tiles, dtype=jnp.int32) * tm, side="right"),
+            last,
+        ).astype(jnp.int32)  # spare tiles name the last live expert: no fetch of their own
+    with jax.named_scope("layer/moe_experts"):
+        x = jnp.take(h, src, axis=0, mode="fill", fill_value=0)  # row T: no token, zeros
+        gmm = functools.partial(
+            grouped_matmul, tile_expert=tile_expert, used_tiles=used_tiles,
+            padded_counts=padded, tile_rows=tm, use_kernel=use_kernel, interpret=interpret,
+        )
+        w_gate, w_up, w_down = (LayerOf.of(lp[n]) for n in ("w_gate", "w_up", "w_down"))
+        a = act_fn(gmm(x, w_gate)) * gmm(x, w_up)
+        out = gmm(a, w_down)  # [rows, d]
+        picked = jnp.take(out, dest.reshape(T, k), axis=0).astype(jnp.float32)  # [T, k, d]
+        y = jnp.sum(picked * weights[..., None], axis=1)
+    if "ws_gate" in lp:
+        with jax.named_scope("layer/moe_shared"):
+            y = y + mm(act_fn(mm(h, lp["ws_gate"])) * mm(h, lp["ws_up"]), lp["ws_down"]).astype(
+                jnp.float32
+            )
+    return y.astype(h.dtype), counts
 
 
 def moe_ffn(

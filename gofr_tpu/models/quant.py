@@ -101,7 +101,20 @@ def is_quantized(params: dict) -> bool:
     return isinstance(params.get("embed"), QTensor)
 
 
-_QUANT_KEYS = ("wq", "wkv", "wo", "w_gate", "w_up", "w_down")
+_QUANT_KEYS = (
+    "wq", "wkv", "wo", "w_gate", "w_up", "w_down",
+    # latent attention's projections and the shared experts (the router and
+    # its correction bias stay as they are: routing is decided in float32)
+    "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down",
+)
+
+
+def _map_groups(fn, layers):
+    """fn over params["layers"]: one stacked dict, or a tuple of them
+    (models.transformer.layer_groups)."""
+    if isinstance(layers, (tuple, list)):
+        return tuple(fn(g) for g in layers)
+    return fn(layers)
 
 
 def quantize_params(params: dict, dtype=jnp.bfloat16) -> dict:
@@ -109,10 +122,10 @@ def quantize_params(params: dict, dtype=jnp.bfloat16) -> dict:
     Layer-stacked weights [L, in, out] get per-(L, out) scales."""
     if is_quantized(params):
         return params
-    layers = {
-        k: (quantize(v, dtype) if k in _QUANT_KEYS else v)
-        for k, v in params["layers"].items()
-    }
+    layers = _map_groups(
+        lambda g: {k: (quantize(v, dtype) if k in _QUANT_KEYS else v) for k, v in g.items()},
+        params["layers"],
+    )
     out = {
         "embed": quantize(params["embed"], dtype),
         "final_norm": params["final_norm"],
@@ -191,9 +204,10 @@ def quantize_param_specs(specs: dict) -> dict:
     def qspec(spec):
         return QTensor(q=spec, s=P(*([None] * (len(spec) - 1) + [spec[-1]])))
 
-    layers = {
-        k: (qspec(v) if k in _QUANT_KEYS else v) for k, v in specs["layers"].items()
-    }
+    layers = _map_groups(
+        lambda g: {k: (qspec(v) if k in _QUANT_KEYS else v) for k, v in g.items()},
+        specs["layers"],
+    )
     out = {
         "embed": qspec(specs["embed"]),
         "final_norm": specs["final_norm"],

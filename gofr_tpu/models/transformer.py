@@ -58,12 +58,44 @@ class TransformerConfig:
     sliding_window: int = 0  # Mistral-style local attention; 0 = global
     qkv_bias: bool = False  # Qwen2-style bias on the q/k/v projections
     # Mixture-of-experts MLP (0 = dense). Experts replace the dense GeGLU
-    # with a routed top-k dispatch (models.moe.moe_ffn) inside the same
-    # scanned layer body; attention is unchanged.
+    # with the dropless routed FFN (models.moe.routed_ffn) inside the same
+    # scanned layer body. What differs between checkpoints is read here:
+    # the router's score function, whether the chosen weights are
+    # normalised and scaled, shared experts (`ws_*` leaves) and the experts'
+    # own width (0 = d_ff). A correction bias on the choice is a leaf
+    # (`router_bias`), not a field: the router adds it where the layer has it.
     n_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity: float = 1.25
+    moe_score: str = "softmax"  # | "sigmoid"
+    moe_norm_topk: bool = False
+    moe_scale: float = 1.0
+    n_shared_experts: int = 0
+    moe_d_ff: int = 0
+    # Leading layers that keep the dense MLP before the routed ones begin:
+    # params["layers"] is then a tuple of stacked groups, scanned in turn
+    # (layer_groups).
+    n_dense_layers: int = 0
+    # Multi-head latent attention (kv_lora_rank > 0; GQA otherwise): queries
+    # through a q_lora_rank bottleneck, keys and values from ONE normalized
+    # latent row of kv_lora_rank values plus a rope key of qk_rope_head_dim
+    # that every head shares. n_kv_heads and head_dim are then unused.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     dtype: Any = jnp.bfloat16
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def group_sizes(self) -> tuple:
+        """Layers in each stacked group of params["layers"]."""
+        if self.n_experts > 0 and 0 < self.n_dense_layers < self.n_layers:
+            return (self.n_dense_layers, self.n_layers - self.n_dense_layers)
+        return (self.n_layers,)
 
     # ---- presets -------------------------------------------------------
     @staticmethod
@@ -150,6 +182,21 @@ class TransformerConfig:
         )
 
     @staticmethod
+    def tiny_latent_moe(vocab_size: int = 512) -> "TransformerConfig":
+        """CI-sized latent-attention MoE: one dense layer, then two layers
+        of 8 sigmoid-routed experts (top-2, normalised, scaled, correction
+        bias) with a shared one; latent 32 + rope 8, 4 heads."""
+        return TransformerConfig(
+            vocab_size=vocab_size, d_model=64, n_layers=3, n_heads=4,
+            n_kv_heads=1, head_dim=24, d_ff=128, rope_theta=1_000_000.0,
+            norm_eps=1e-5, act="silu", scale_embed=False, dtype=jnp.float32,
+            n_experts=8, moe_top_k=2, moe_score="sigmoid", moe_norm_topk=True,
+            moe_scale=1.8, n_shared_experts=1, moe_d_ff=32,
+            n_dense_layers=1, kv_lora_rank=32, q_lora_rank=48,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=24,
+        )
+
+    @staticmethod
     def tiny_moe(vocab_size: int = 512) -> "TransformerConfig":
         """CI-sized sparse config: 4 experts, top-2 routing — expert count
         divisible by TP=2/4 for the 8-virtual-device CPU mesh tests."""
@@ -169,10 +216,12 @@ class KVCache(NamedTuple):
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> KVCache:
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    from ..kvcache import row_shapes
+
+    k_row, v_row = row_shapes(cfg)
     return KVCache(
-        k=jnp.zeros(shape, cfg.dtype),
-        v=jnp.zeros(shape, cfg.dtype),
+        k=jnp.zeros((cfg.n_layers, batch, max_len) + k_row, cfg.dtype),
+        v=jnp.zeros((cfg.n_layers, batch, max_len) + v_row, cfg.dtype),
         length=jnp.zeros((batch,), jnp.int32),
     )
 
@@ -198,42 +247,98 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         if cfg.qkv_bias
         else {}
     )
-    if cfg.n_experts > 0:
-        # Sparse MLP: experts batched on a leading E axis (the EP shard
-        # axis — parallel.sharding.param_specs) plus a replicated router.
-        E = cfg.n_experts
-        mlp = {
-            "w_router": w(jax.random.fold_in(keys[3], 1), (L, d, E), d),
-            "w_gate": w(keys[4], (L, E, d, ff), d),
-            "w_up": w(jax.random.fold_in(keys[4], 1), (L, E, d, ff), d),
-            "w_down": w(keys[5], (L, E, ff, d), ff),
+    if cfg.latent:
+        C, ql = cfg.kv_lora_rank, cfg.q_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        ka = jax.random.split(keys[1], 4)
+        attn = {
+            "attn_norm": jnp.zeros((L, d), cfg.dtype),
+            "wq_a": w(ka[0], (L, d, ql), d),
+            "q_norm": jnp.zeros((L, ql), cfg.dtype),
+            "wq_b": w(ka[1], (L, ql, hq * (dn + dr)), ql),
+            "wkv_a": w(ka[2], (L, d, C + dr), d),
+            "kv_norm": jnp.zeros((L, C), cfg.dtype),
+            # per head [k_nope | v]: W_uk and W_uv of the absorbed form
+            "wkv_b": w(ka[3], (L, C, hq * (dn + dv)), C),
+            "wo": w(keys[3], (L, hq * dv, d), hq * dv),
         }
     else:
-        mlp = {
-            # gate and up are SEPARATE tensors, not a fused [d, 2*ff] matmul:
-            # both get identical column-parallel shardings (so the
-            # gelu(gate)*up product is TP-collective-free), and each matmul
-            # keeps a contiguous MXU-friendly layout — a fused-then-split
-            # layout costs either a mid-layer reshard (contiguous halves
-            # under TP) or a ~3x decode slowdown (interleaved pairs force a
-            # strided relayout; measured on v5e).
-            "w_gate": w(keys[4], (L, d, ff), d),
-            "w_up": w(jax.random.fold_in(keys[4], 1), (L, d, ff), d),
-            "w_down": w(keys[5], (L, ff, d), ff),
-        }
-    return {
-        "embed": w(keys[0], (cfg.vocab_size, d), d),
-        "final_norm": jnp.zeros((d,), cfg.dtype),
-        "layers": {
+        attn = {
             **bias,
             "attn_norm": jnp.zeros((L, d), cfg.dtype),
             "wq": w(keys[1], (L, d, hq * hd), d),
             "wkv": w(keys[2], (L, d, 2 * hkv * hd), d),
             "wo": w(keys[3], (L, hq * hd, d), hq * hd),
-            "mlp_norm": jnp.zeros((L, d), cfg.dtype),
-            **mlp,
-        },
+        }
+    sizes = cfg.group_sizes
+    dense_mlp = {} if cfg.n_experts > 0 and len(sizes) == 1 else {
+        # gate and up are SEPARATE tensors, not a fused [d, 2*ff] matmul:
+        # both get identical column-parallel shardings (so the
+        # gelu(gate)*up product is TP-collective-free), and each matmul
+        # keeps a contiguous MXU-friendly layout — a fused-then-split
+        # layout costs either a mid-layer reshard (contiguous halves
+        # under TP) or a ~3x decode slowdown (interleaved pairs force a
+        # strided relayout; measured on v5e).
+        "w_gate": w(keys[4], (L, d, ff), d),
+        "w_up": w(jax.random.fold_in(keys[4], 1), (L, d, ff), d),
+        "w_down": w(keys[5], (L, ff, d), ff),
     }
+    if cfg.n_experts > 0:
+        # Sparse MLP: experts batched on a leading E axis (the EP shard
+        # axis — parallel.sharding.param_specs) plus a replicated router.
+        E, fe = cfg.n_experts, cfg.moe_d_ff or ff
+        mlp = {
+            "w_router": w(jax.random.fold_in(keys[3], 1), (L, d, E), d),
+            "w_gate": w(keys[4], (L, E, d, fe), d),
+            "w_up": w(jax.random.fold_in(keys[4], 1), (L, E, d, fe), d),
+            "w_down": w(keys[5], (L, E, fe, d), fe),
+        }
+        if cfg.moe_score == "sigmoid":  # sigmoid scores are chosen with a correction bias
+            mlp["router_bias"] = 0.01 * jax.random.normal(
+                jax.random.fold_in(keys[3], 2), (L, E), jnp.float32
+            )
+        if cfg.n_shared_experts > 0:
+            fs = fe * cfg.n_shared_experts
+            ks = jax.random.split(jax.random.fold_in(keys[5], 1), 3)
+            mlp.update(
+                ws_gate=w(ks[0], (L, d, fs), d), ws_up=w(ks[1], (L, d, fs), d),
+                ws_down=w(ks[2], (L, fs, d), fs),
+            )
+    else:
+        mlp = dense_mlp
+    stacked = {**attn, "mlp_norm": jnp.zeros((L, d), cfg.dtype)}
+    if len(sizes) == 1:
+        layers = {**stacked, **mlp}
+    else:  # leading dense layers, then the routed ones: two stacked groups
+        n0 = sizes[0]
+        layers = (
+            jax.tree.map(lambda a: a[:n0], {**stacked, **dense_mlp}),
+            jax.tree.map(lambda a: a[n0:], {**stacked, **mlp}),
+        )
+    return {
+        "embed": w(keys[0], (cfg.vocab_size, d), d),
+        "final_norm": jnp.zeros((d,), cfg.dtype),
+        "layers": layers,
+    }
+
+
+LAYER_KEY = "_layer"  # in a layer's params under the indexed scan: its index in the whole stack
+
+
+def _expert_stacks(group: dict) -> dict:
+    """The routed experts' stacks of one group, [L, E, in, out]: what the
+    indexed scan leaves whole."""
+    return {
+        k: group[k] for k in ("w_gate", "w_up", "w_down")
+        if k in group and getattr(group[k], "q", group[k]).ndim >= 4
+    }
+
+
+def layer_groups(layers) -> tuple:
+    """params["layers"] as its stacked groups: one dict of [L, ...] leaves
+    for a homogeneous stack, or a tuple of them (TransformerConfig.
+    group_sizes) scanned in turn."""
+    return tuple(layers) if isinstance(layers, (tuple, list)) else (layers,)
 
 
 _ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
@@ -253,7 +358,46 @@ def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
     compute is bit-identical to the single-device forward — no
     partial-product psum, hence no collective reduction-order drift.
     The final layer prefetches itself (clamped index); one redundant
-    gather, zero extra compute."""
+    gather, zero extra compute.
+
+    ``layers`` may be a tuple of stacked groups (layer_groups): each is
+    scanned in turn, its layers indexing ``rest`` at their own offset (a
+    dynamic index into the whole stack, as the scan's own slicing is, so no
+    group's share of a cache is copied out), and the ys are joined. That
+    indexed scan also serves one group that holds expert stacks
+    (_expert_stacks) or latent attention."""
+    groups = layer_groups(layers)
+    latent = any("wkv_a" in g for g in groups)  # its kernel reads the pool whole, at LAYER_KEY
+    if (len(groups) > 1 or latent) and overlap is not None:
+        raise ValueError("layer groups and latent attention do not run under the TP gather overlap")
+    if overlap is None and (len(groups) > 1 or latent or any(_expert_stacks(g) for g in groups)):
+        from ..ops.grouped import LayerOf
+
+        parts, l0 = [], 0
+        for g in groups:
+            n = jax.tree.leaves(g)[0].shape[0]
+            whole = _expert_stacks(g)
+            scanned = {k: v for k, v in g.items() if k not in whole}
+
+            def body(x, xs, l0=l0, whole=whole):
+                lp, i = xs
+                at = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, l0 + i, 0, keepdims=False),
+                    tuple(rest),
+                )
+                # expert stacks stay whole (a kernel reads its blocks at the
+                # layer's index; a slice would be copied out first), and a
+                # layer may read `rest` whole the same way, at LAYER_KEY
+                lp = {**lp, **{k: LayerOf(v, i) for k, v in whole.items()}, LAYER_KEY: l0 + i}
+                return layer_fn(x, lp, at)
+
+            x, ys = jax.lax.scan(body, x, (scanned, jnp.arange(n, dtype=jnp.int32)))
+            parts.append(ys)
+            l0 += n
+        if len(parts) == 1:
+            return x, parts[0]
+        return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
+    layers = groups[0]
     if overlap is None:
 
         def body(x, xs):
@@ -321,24 +465,136 @@ def _lora_mm(mm, h, lp, name, aids):
 
 
 def _mlp_block(cfg, h, lp, mm, aids=None):
-    """Post-norm MLP output (the caller adds the residual): dense GeGLU
-    with optional per-row LoRA deltas, or the routed top-k mixture when
-    the layer carries a router (MoE checkpoints — models.moe). LoRA
+    """Post-norm MLP output (the caller adds the residual) and what the
+    experts did: dense GeGLU with optional per-row LoRA deltas (stats
+    None), or the dropless routed FFN when the layer carries a router
+    (models.moe.routed_ffn; stats = moe_layer_stats of its counts). LoRA
     skips expert weights by construction (lora.target_dims drops 4-D
     stacks), so the two features compose on attention projections."""
     if "w_router" in lp:
-        from .moe import moe_ffn
+        from .moe import routed_ffn
 
         b, s, d = h.shape
-        y, _ = moe_ffn(
-            h.reshape(b * s, d), lp["w_router"], lp["w_gate"], lp["w_up"],
-            lp["w_down"], n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity, act=cfg.act,
-        )
-        return y.reshape(b, s, d).astype(h.dtype)
+        y, counts = routed_ffn(cfg, h.reshape(b * s, d), lp, mm)
+        return y.reshape(b, s, d).astype(h.dtype), moe_layer_stats(counts)
     g = _lora_mm(mm, h, lp, "w_gate", aids)
     u = _lora_mm(mm, h, lp, "w_up", aids)
-    return _lora_mm(mm, _act_fn(cfg)(g) * u, lp, "w_down", aids)
+    y = _lora_mm(mm, _act_fn(cfg)(g) * u, lp, "w_down", aids)
+    # a dense layer of a routed model (a leading group): nothing routed
+    return y, (jnp.zeros((2 + cfg.n_experts,), jnp.int32) if cfg.n_experts > 0 else None)
+
+
+def moe_layer_stats(counts: jnp.ndarray) -> jnp.ndarray:
+    """One routed layer call as [pairs, experts touched, rows per expert...]
+    int32: summed over a program's layer calls it is what the engine's step
+    record and stats()["moe"] report."""
+    return jnp.concatenate(
+        [jnp.sum(counts)[None], jnp.sum(counts > 0)[None].astype(jnp.int32), counts]
+    )
+
+
+def _mlp_residual(cfg, x, lp, mm, aids=None):
+    """x + MLP(norm(x)), and the layer's moe stats as a tuple to append to
+    a scanned layer's ys: empty for a dense model, so its programs carry
+    nothing new."""
+    with jax.named_scope("layer/mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        y, stats = _mlp_block(cfg, h, lp, mm, aids)
+        x = x + y
+    return x, (() if stats is None else (stats,))
+
+
+def _latent_up(cfg, w):
+    """wkv_b [C, hq * (nope + v)] as the absorbed form's two halves:
+    (W_uk [C, hq, nope], its column scales [hq, nope] or None, W_uv
+    [C, hq, v], its scales). An int8 tensor stays int8: the scale of a
+    column that is CONTRACTED (W_uk's) goes onto the query instead."""
+    hq, dn, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    quant = isinstance(w, QTensor)
+    wq = (w.q if quant else w).reshape(cfg.kv_lora_rank, hq, dn + dv)
+    sc = w.s.reshape(hq, dn + dv) if quant else None
+    return (
+        wq[..., :dn], None if sc is None else sc[:, :dn],
+        wq[..., dn:], None if sc is None else sc[:, dn:],
+    )
+
+
+def _attn_block(cfg, x, lp, positions, mm, aids, attend):
+    """The ONE attention block of every program: norm, projections, RoPE,
+    ``attend(q, k_new, v_new) -> (attn, carry)`` (the program's own cache
+    write and attention), output projection, residual. Returns (x, carry).
+
+    GQA: q [b, s, hq, hd], k_new / v_new [b, s, hkv, hd]. Latent
+    (cfg.latent), in the absorbed form the cache forces: q is
+    [q_nope W_uk^T | RoPE(q_rope)] per head, zero-padded to the pool's rope
+    width, k_new the normalized latent c_kv [b, s, 1, C], v_new the shared
+    rotated rope key [b, s, 1, R]; ``attend`` returns o_lat [b, s, hq, C]
+    and W_uv is applied here. LoRA on the latent projections is refused at
+    engine build."""
+    b, s, _ = x.shape
+    hq = cfg.n_heads
+    if cfg.latent:
+        from ..ops import latent_rope_width
+
+        C, dn, dr = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        pad = latent_rope_width(dr) - dr
+        with jax.named_scope("layer/attn_latent"):
+            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+            cq = rms_norm(mm(h, lp["wq_a"]), lp["q_norm"], cfg.norm_eps)
+            q = mm(cq, lp["wq_b"]).reshape(b, s, hq, dn + dr)
+            kv = mm(h, lp["wkv_a"])  # [b, s, C + dr]
+            c_kv = rms_norm(kv[..., :C], lp["kv_norm"], cfg.norm_eps)[:, :, None, :]
+            k_rope = apply_rope(kv[:, :, None, C:], positions, cfg.rope_theta)
+            q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+            w_uk, s_uk, w_uv, s_uv = _latent_up(cfg, lp["wkv_b"])
+            q_nope = q[..., :dn] if s_uk is None else q[..., :dn] * s_uk.astype(q.dtype)
+            q_abs = jnp.einsum(
+                "bshn,chn->bshc", q_nope, w_uk.astype(q.dtype),
+                preferred_element_type=jnp.float32,
+            ).astype(q.dtype)
+            widen = ((0, 0), (0, 0), (0, 0), (0, pad))
+            q = jnp.concatenate([q_abs, jnp.pad(q_rope, widen)], axis=-1)
+            k_rope = jnp.pad(k_rope, widen)
+        o_lat, carry = attend(q, c_kv, k_rope)
+        with jax.named_scope("layer/attn_latent"):
+            o = jnp.einsum(
+                "bshc,chv->bshv", o_lat, w_uv.astype(o_lat.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            if s_uv is not None:
+                o = o * s_uv.astype(jnp.float32)
+            o = o.astype(x.dtype).reshape(b, s, hq * cfg.v_head_dim)
+        with jax.named_scope("layer/attn"):
+            x = x + mm(o, lp["wo"]).astype(x.dtype)
+        return x, carry
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    with jax.named_scope("layer/attn"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = _lora_mm(mm, h, lp, "wq", aids)
+        if cfg.qkv_bias:  # Qwen2: bias rides the flat output (pre-reshape)
+            q = q + lp["bq"].astype(q.dtype)
+        q = q.reshape(b, s, hq, hd)
+        # wkv packs heads OUTERMOST ([hkv, 2, hd] per output column block) so a
+        # TP shard of the flat output dim holds whole (k, v) head pairs — keeps
+        # Megatron column-parallel layout collective-free inside the layer.
+        kv = _lora_mm(mm, h, lp, "wkv", aids)
+        if cfg.qkv_bias:
+            kv = kv + lp["bkv"].astype(kv.dtype)
+        kv = kv.reshape(b, s, hkv, 2, hd)
+        k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k_new = apply_rope(k_new, positions, cfg.rope_theta)
+    # Gemma queries are scaled by 1/sqrt(head_dim) (applied inside attention).
+    attn, carry = attend(q, k_new, v_new)
+    with jax.named_scope("layer/attn"):
+        x = x + _lora_mm(mm, attn.reshape(b, s, hq * hd), lp, "wo", aids).astype(
+            x.dtype
+        )
+    return x, carry
+
+
+def _latent_scale(cfg) -> float:
+    return 1.0 / (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
 
 
 def _layer_body(
@@ -354,43 +610,37 @@ def _layer_body(
     prefill_attn=None,  # optional (q, k, v) -> attn override (ring/SP path)
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
 ):
-    b, s, d = x.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-
     # Prefill (many token rows, MXU-bound) uses the W8A8 integer dot when
     # weights are quantized; decode (one row, HBM-bound) dequantizes into
     # the dot. Plain-array weights are unaffected by either.
     mm = qmm if decode else qmm_a8
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = _lora_mm(mm, h, lp, "wq", aids)
-    if cfg.qkv_bias:  # Qwen2: bias rides the flat output (pre-reshape)
-        q = q + lp["bq"].astype(q.dtype)
-    q = q.reshape(b, s, hq, hd)
-    # wkv packs heads OUTERMOST ([hkv, 2, hd] per output column block) so a
-    # TP shard of the flat output dim holds whole (k, v) head pairs — keeps
-    # Megatron column-parallel layout collective-free inside the layer.
-    kv = _lora_mm(mm, h, lp, "wkv", aids)
-    if cfg.qkv_bias:
-        kv = kv + lp["bkv"].astype(kv.dtype)
-    kv = kv.reshape(b, s, hkv, 2, hd)
-    k, v = kv[:, :, :, 0], kv[:, :, :, 1]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    # Gemma queries are scaled by 1/sqrt(head_dim) (applied inside attention).
+    def attend(q, k, v):
+        if cfg.latent:
+            if decode or prefill_attn is not None:
+                raise ValueError(
+                    "latent attention serves through the paged engine "
+                    "(decode_chunk_paged / prefill_append); this forward pass "
+                    "is its whole-sequence form only"
+                )
+            from ..ops import latent_chunk_prefill_attention
 
-    if decode:
-        # Write this step's k/v at each sequence's cursor, then attend over
-        # the valid prefix. vmap'd dynamic_update_slice = per-batch scatter.
-        upd = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (i, 0, 0)))
-        k_cache = upd(k_cache, k.astype(k_cache.dtype), cache_length)
-        v_cache = upd(v_cache, v.astype(v_cache.dtype), cache_length)
-        attn = decode_attention(
-            q, k_cache, v_cache, cache_length + 1,
-            logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-        )
-        new_k, new_v = k_cache, v_cache
-    else:
+            zero = jnp.zeros((x.shape[0],), jnp.int32)
+            attn = latent_chunk_prefill_attention(
+                q, k, v, zero, scale=_latent_scale(cfg)
+            )
+            return attn, (k, v)
+        if decode:
+            # Write this step's k/v at each sequence's cursor, then attend over
+            # the valid prefix. vmap'd dynamic_update_slice = per-batch scatter.
+            upd = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (i, 0, 0)))
+            kc = upd(k_cache, k.astype(k_cache.dtype), cache_length)
+            vc = upd(v_cache, v.astype(v_cache.dtype), cache_length)
+            attn = decode_attention(
+                q, kc, vc, cache_length + 1,
+                logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+            )
+            return attn, (kc, vc)
         # Right-padded prompts need no kv_mask here: pads sit AFTER real
         # tokens, so causal masking already hides them from every real query;
         # pad-position outputs are discarded (loss-masked / never read) and
@@ -404,14 +654,10 @@ def _layer_body(
                 window=cfg.sliding_window,
             )
         # Prefill fills the cache from position 0 (right-padded batches).
-        new_k, new_v = k, v
+        return attn, (k, v)
 
-    x = x + _lora_mm(mm, attn.reshape(b, s, hq * hd), lp, "wo", aids).astype(
-        x.dtype
-    )
-
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    x = x + _mlp_block(cfg, h, lp, mm, aids)
+    x, (new_k, new_v) = _attn_block(cfg, x, lp, positions, mm, aids, attend)
+    x, _stats = _mlp_residual(cfg, x, lp, mm, aids)
     return x, new_k, new_v
 
 
@@ -439,32 +685,28 @@ def transformer_forward(
     if decode:
         assert cache is not None
 
-        def body(xc, layer_in):
-            lp, kc, vc = layer_in
-            x, _ = xc
+        def layer(x, lp, rest):
+            kc, vc = rest
             x, nk, nv = _layer_body(
                 cfg, x, lp, positions,
                 k_cache=kc, v_cache=vc, cache_length=cache.length, decode=True,
                 aids=aids,
             )
-            return (x, None), (nk, nv)
+            return x, (nk, nv)
 
-        (x, _), (ks, vs) = jax.lax.scan(
-            body, (x, None), (params["layers"], cache.k, cache.v)
-        )
+        x, (ks, vs) = _layer_scan(params["layers"], layer, x, (cache.k, cache.v))
         new_cache = KVCache(k=ks, v=vs, length=cache.length + 1)
     else:
 
-        def body(xc, lp):
-            x, _ = xc
+        def layer(x, lp, _rest):
             x, nk, nv = _layer_body(
                 cfg, x, lp, positions,
                 k_cache=None, v_cache=None, cache_length=None, decode=False,
                 prefill_attn=prefill_attn, aids=aids,
             )
-            return (x, None), (nk, nv)
+            return x, (nk, nv)
 
-        (x, _), (ks, vs) = jax.lax.scan(body, (x, None), params["layers"])
+        x, (ks, vs) = _layer_scan(params["layers"], layer, x, ())
         if cache is not None:
             max_len = cache.k.shape[2]
             s = tokens.shape[1]
@@ -570,6 +812,25 @@ def _unembed_last(params: dict, cfg: TransformerConfig, x: jnp.ndarray) -> jnp.n
     return _unembed(params, cfg, x)[:, 0]
 
 
+def _chunk_buffer_write(kb_l, vb_l, k_new, v_new, k_i):
+    """This step's new rows at the uniform position `k_i` of the chunk's
+    small buffers (decode_chunk's docstring says why)."""
+    with jax.named_scope("layer/kv_write"):
+        kb_l = jax.lax.dynamic_update_slice(kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0))
+        vb_l = jax.lax.dynamic_update_slice(vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0))
+    return kb_l, vb_l
+
+
+def _note_moe(moe_out: list | None, stats) -> None:
+    """Hand a routed model's summed moe_layer_stats to the caller's list
+    (a trace-time side channel beside the program's own results: the
+    engine's programs return it, nothing else asks). `stats` is empty for a
+    dense model; arrays with leading axes are summed over them."""
+    if moe_out is not None:
+        for st in stats:
+            moe_out.append(st.reshape(-1, st.shape[-1]).sum(axis=0))
+
+
 @jax.named_scope("decode_chunk")
 def decode_chunk(
     params: dict,
@@ -586,6 +847,7 @@ def decode_chunk(
     ring: int = 0,  # >0: cache is a rolling ring of this capacity (kvcache)
     overlap=None,  # TP collective-compute overlap (see _layer_scan)
     sample_state=None,  # stateful sampler: carried pytree (see below)
+    moe_out: list | None = None,  # a routed model appends its moe stats (_note_moe)
 ) -> tuple[jnp.ndarray, jnp.ndarray, KVCache, jax.Array]:
     """n_steps fused decode steps — the serving engine's hot loop.
 
@@ -623,16 +885,20 @@ def decode_chunk(
     Returns (tokens [n_steps, b], last [b], new cache, rng)
     [+ sample_state when one was passed].
     """
+    if cfg.latent:
+        raise ValueError(
+            "latent attention has no contiguous decode chunk: it serves from "
+            "the paged pool (decode_chunk_paged)"
+        )
     L, b = cfg.n_layers, tokens.shape[0]
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     max_len = cache.k.shape[2]
     K = n_steps
     # LoRA engines carry per-slot adapter ids beside the weights; chunk
     # lanes ARE engine slots, so the vector applies row-for-row (absent on
     # plain engines — static pytree structure, program unchanged).
     aids = params.get("aids")
-    kb0 = jnp.zeros((L, b, K, hkv, hd), cache.k.dtype)
-    vb0 = jnp.zeros((L, b, K, hkv, hd), cache.v.dtype)
+    kb0 = jnp.zeros((L, b, K) + cache.k.shape[3:], cache.k.dtype)
+    vb0 = jnp.zeros((L, b, K) + cache.v.shape[3:], cache.v.dtype)
     rng, sub = jax.random.split(rng)
     keys = jax.random.split(sub, K)
     def step(carry, inp):
@@ -643,44 +909,26 @@ def decode_chunk(
 
         def layer(x, lp, rest):
             kc_l, vc_l, kb_l, vb_l = rest
-            with jax.named_scope("layer/attn"):
-                h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-                q = _lora_mm(qmm, h, lp, "wq", aids)
-                if cfg.qkv_bias:
-                    q = q + lp["bq"].astype(q.dtype)
-                q = q.reshape(b, 1, hq, hd)
-                kv = _lora_mm(qmm, h, lp, "wkv", aids)
-                if cfg.qkv_bias:
-                    kv = kv + lp["bkv"].astype(kv.dtype)
-                kv = kv.reshape(b, 1, hkv, 2, hd)
-                k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k_new = apply_rope(k_new, positions, cfg.rope_theta)
-            with jax.named_scope("layer/kv_write"):
-                kb_l = jax.lax.dynamic_update_slice(
-                    kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0)
-                )
-                vb_l = jax.lax.dynamic_update_slice(
-                    vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0)
-                )
-            with jax.named_scope("layer/attn"):
-                attn = chunk_decode_attention(
-                    q, kc_l, vc_l, kb_l, vb_l, cache.length, k_i,
-                    logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-                    ring=ring,
-                )
-                x = x + _lora_mm(
-                    qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
-                ).astype(x.dtype)
-            with jax.named_scope("layer/mlp"):
-                h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-                x = x + _mlp_block(cfg, h, lp, qmm, aids)
-            return x, (kb_l, vb_l)
 
-        x, (kb, vb) = _layer_scan(
+            def attend(q, k_new, v_new):
+                kb_n, vb_n = _chunk_buffer_write(kb_l, vb_l, k_new, v_new, k_i)
+                with jax.named_scope("layer/attn"):
+                    attn = chunk_decode_attention(
+                        q, kc_l, vc_l, kb_n, vb_n, cache.length, k_i,
+                        logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+                        ring=ring,
+                    )
+                return attn, (kb_n, vb_n)
+
+            x, bufs = _attn_block(cfg, x, lp, positions, qmm, aids, attend)
+            x, stats = _mlp_residual(cfg, x, lp, qmm, aids)
+            return x, bufs + stats
+
+        x, ys = _layer_scan(
             params["layers"], layer, x, (cache.k, cache.v, kb, vb),
             overlap=overlap,
         )
+        kb, vb = ys[:2]
         with jax.named_scope("unembed_sample"):
             logits = _unembed_last(params, cfg, x)
             if sample_state is None:
@@ -688,13 +936,14 @@ def decode_chunk(
             else:
                 nt, sstate = sample_fn(logits, temps, key, sstate)
                 nt = nt.astype(jnp.int32)
-        return (nt, kb, vb, sstate), nt
+        return (nt, kb, vb, sstate), (nt,) + tuple(y.sum(axis=0) for y in ys[2:])
 
-    (last, kb, vb, out_state), toks = jax.lax.scan(
+    (last, kb, vb, out_state), (toks, *stats) = jax.lax.scan(
         step, (tokens, kb0, vb0, sample_state),
         (jnp.arange(K, dtype=jnp.int32), keys),
         unroll=unroll,
     )
+    _note_moe(moe_out, stats)
 
     if ring > 0:
         # rolling merge: the chunk's K rows land at (length + i) mod C —
@@ -753,6 +1002,7 @@ def decode_chunk_paged(
     overlap=None,  # TP collective-compute overlap (see _layer_scan)
     sample_state=None,  # stateful sampler (see decode_chunk)
     mesh=None,  # TP mesh: the paged kernel runs per head shard (ops.attention)
+    moe_out: list | None = None,  # a routed model appends its moe stats (_note_moe)
 ) -> tuple[jnp.ndarray, jnp.ndarray, KVCache, jnp.ndarray | None, jax.Array]:
     """decode_chunk against a BLOCK-PAGED pool (gofr_tpu.kvcache.paged).
 
@@ -776,15 +1026,14 @@ def decode_chunk_paged(
     Returns (tokens [n_steps, b], last [b], pool', scales', rng).
     """
     from ..kvcache.paged import scatter_rows
-    from ..ops import paged_chunk_decode_attention
+    from ..ops import mla_paged_chunk_decode_attention, paged_chunk_decode_attention
 
     L, b = cfg.n_layers, tokens.shape[0]
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     K = n_steps
     aids = params.get("aids")  # per-slot adapter ids (see decode_chunk)
     quant = scales is not None and scales.size > 0
-    kb0 = jnp.zeros((L, b, K, hkv, hd), cfg.dtype)
-    vb0 = jnp.zeros((L, b, K, hkv, hd), cfg.dtype)
+    kb0 = jnp.zeros((L, b, K) + pool.k.shape[3:], cfg.dtype)
+    vb0 = jnp.zeros((L, b, K) + pool.v.shape[3:], cfg.dtype)
     rng, sub = jax.random.split(rng)
     keys = jax.random.split(sub, K)
     ks_all = scales[0] if quant else None  # [L, NB, B, hkv]
@@ -802,48 +1051,39 @@ def decode_chunk_paged(
             else:
                 kp_l, vp_l, kb_l, vb_l = rest
                 ks_l = vs_l = None
-            with jax.named_scope("layer/attn"):
-                h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-                q = _lora_mm(qmm, h, lp, "wq", aids)
-                if cfg.qkv_bias:
-                    q = q + lp["bq"].astype(q.dtype)
-                q = q.reshape(b, 1, hq, hd)
-                kv = _lora_mm(qmm, h, lp, "wkv", aids)
-                if cfg.qkv_bias:
-                    kv = kv + lp["bkv"].astype(kv.dtype)
-                kv = kv.reshape(b, 1, hkv, 2, hd)
-                k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k_new = apply_rope(k_new, positions, cfg.rope_theta)
-            with jax.named_scope("layer/kv_write"):
-                kb_l = jax.lax.dynamic_update_slice(
-                    kb_l, k_new.astype(kb_l.dtype), (0, k_i, 0, 0)
-                )
-                vb_l = jax.lax.dynamic_update_slice(
-                    vb_l, v_new.astype(vb_l.dtype), (0, k_i, 0, 0)
-                )
-            with jax.named_scope("layer/attn"):
-                attn = paged_chunk_decode_attention(
-                    q, kp_l, vp_l, tables, kb_l, vb_l, pool.length, k_i,
-                    logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-                    k_scales=ks_l, v_scales=vs_l,
-                    use_kernel=use_kernel, interpret=interpret, mesh=mesh,
-                )
-                x = x + _lora_mm(
-                    qmm, attn.reshape(b, 1, hq * hd), lp, "wo", aids
-                ).astype(x.dtype)
-            with jax.named_scope("layer/mlp"):
-                h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-                x = x + _mlp_block(cfg, h, lp, qmm, aids)
-            return x, (kb_l, vb_l)
+
+            def attend(q, k_new, v_new):
+                kb_n, vb_n = _chunk_buffer_write(kb_l, vb_l, k_new, v_new, k_i)
+                with jax.named_scope("layer/attn"):
+                    if cfg.latent:
+                        # the whole pool and the layer's index: the kernel copies
+                        # its pages from there, no layer's pool is sliced out
+                        attn = mla_paged_chunk_decode_attention(
+                            q, pool.k, pool.v, tables, kb_n, vb_n, pool.length, k_i,
+                            scale=_latent_scale(cfg), layer=lp[LAYER_KEY],
+                            use_kernel=use_kernel, interpret=interpret,
+                        )
+                    else:
+                        attn = paged_chunk_decode_attention(
+                            q, kp_l, vp_l, tables, kb_n, vb_n, pool.length, k_i,
+                            logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+                            k_scales=ks_l, v_scales=vs_l,
+                            use_kernel=use_kernel, interpret=interpret, mesh=mesh,
+                        )
+                return attn, (kb_n, vb_n)
+
+            x, bufs = _attn_block(cfg, x, lp, positions, qmm, aids, attend)
+            x, stats = _mlp_residual(cfg, x, lp, qmm, aids)
+            return x, bufs + stats
 
         rest = (
             (pool.k, pool.v, ks_all, vs_all, kb, vb)
             if quant else (pool.k, pool.v, kb, vb)
         )
-        x, (kb, vb) = _layer_scan(
+        x, ys = _layer_scan(
             params["layers"], layer, x, rest, overlap=overlap
         )
+        kb, vb = ys[:2]
         with jax.named_scope("unembed_sample"):
             logits = _unembed_last(params, cfg, x)
             if sample_state is None:
@@ -851,12 +1091,13 @@ def decode_chunk_paged(
             else:
                 nt, sstate = sample_fn(logits, temps, key, sstate)
                 nt = nt.astype(jnp.int32)
-        return (nt, kb, vb, sstate), nt
+        return (nt, kb, vb, sstate), (nt,) + tuple(y.sum(axis=0) for y in ys[2:])
 
-    (last, kb, vb, out_state), toks = jax.lax.scan(
+    (last, kb, vb, out_state), (toks, *stats) = jax.lax.scan(
         step, (tokens, kb0, vb0, sample_state),
         (jnp.arange(K, dtype=jnp.int32), keys),
     )
+    _note_moe(moe_out, stats)
 
     # merge: the chunk's K rows scatter through the table at positions
     # [length, length + K) — private (refcount-1) blocks by the engine's
@@ -887,6 +1128,7 @@ def _append_forward(
     ring: int = 0,
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
     mesh=None,  # TP mesh: the flash kernel runs per head shard (ops.attention)
+    moe_out: list | None = None,  # a routed model appends its moe stats (_note_moe)
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """Shared write-then-attend chunk append (prefill_append and
     verify_chunk): write the chunk's K/V rows at the per-sequence cursor,
@@ -897,8 +1139,9 @@ def _append_forward(
     params["aids"] directly): the unified step ops prefill a PACKED
     subset of engine slots, so the caller gathers the per-slot vector
     down to the rows actually present."""
+    from ..ops import latent_chunk_prefill_attention
+
     b, c = tokens.shape
-    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     capacity = cache.k.shape[2]
     positions = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     i = jnp.arange(c, dtype=jnp.int32)[None, :]
@@ -911,41 +1154,34 @@ def _append_forward(
 
     x = _embed_tokens(params, cfg, tokens)
 
-    def layer(x, xs):
-        lp, kc, vc = xs  # [b, capacity, hkv, hd]
-        with jax.named_scope("layer/attn"):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            q = _lora_mm(mm, h, lp, "wq", aids)
-            if cfg.qkv_bias:
-                q = q + lp["bq"].astype(q.dtype)
-            q = q.reshape(b, c, hq, hd)
-            kv = _lora_mm(mm, h, lp, "wkv", aids)
-            if cfg.qkv_bias:
-                kv = kv + lp["bkv"].astype(kv.dtype)
-            kv = kv.reshape(b, c, hkv, 2, hd)
-            k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k_new = apply_rope(k_new, positions, cfg.rope_theta)
-        with jax.named_scope("layer/kv_write"):
-            write = jax.vmap(lambda cb, ub, ib: cb.at[ib].set(ub))
-            kc = write(kc, k_new.astype(kc.dtype), idx)
-            vc = write(vc, v_new.astype(vc.dtype), idx)
-        with jax.named_scope("layer/attn"):
-            attn = chunk_prefill_attention(
-                q, kc, vc, cursors,
-                logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-                ring=ring, mesh=mesh,
-            )
-            x = x + _lora_mm(
-                mm, attn.reshape(b, c, hq * hd), lp, "wo", aids
-            ).astype(x.dtype)
-        with jax.named_scope("layer/mlp"):
-            h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + _mlp_block(cfg, h, lp, mm, aids)
-        return x, (kc, vc)
+    def layer(x, lp, rest):
+        kc, vc = rest  # [b, capacity, hkv, hd]
 
-    x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], cache.k, cache.v))
-    return x, (ks, vs)
+        def attend(q, k_new, v_new):
+            with jax.named_scope("layer/kv_write"):
+                write = jax.vmap(lambda cb, ub, ib: cb.at[ib].set(ub))
+                kc2 = write(kc, k_new.astype(kc.dtype), idx)
+                vc2 = write(vc, v_new.astype(vc.dtype), idx)
+            with jax.named_scope("layer/attn"):
+                if cfg.latent:
+                    attn = latent_chunk_prefill_attention(
+                        q, kc2, vc2, cursors, scale=_latent_scale(cfg)
+                    )
+                else:
+                    attn = chunk_prefill_attention(
+                        q, kc2, vc2, cursors,
+                        logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
+                        ring=ring, mesh=mesh,
+                    )
+            return attn, (kc2, vc2)
+
+        x, rows = _attn_block(cfg, x, lp, positions, mm, aids, attend)
+        x, stats = _mlp_residual(cfg, x, lp, mm, aids)
+        return x, rows + stats
+
+    x, ys = _layer_scan(params["layers"], layer, x, (cache.k, cache.v))
+    _note_moe(moe_out, ys[2:])
+    return x, ys[:2]
 
 
 @jax.named_scope("prefill_rows")
@@ -960,6 +1196,7 @@ def prefill_append(
     ring: int = 0,  # >0: cache is a rolling ring of this capacity
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
     mesh=None,  # TP mesh (see _append_forward)
+    moe_out: list | None = None,  # a routed model appends its moe stats (_note_moe)
 ) -> tuple[jnp.ndarray, KVCache]:
     """Append one prefill chunk into an existing per-slot KV cache.
 
@@ -987,7 +1224,7 @@ def prefill_append(
     b, c = tokens.shape
     x, (ks, vs) = _append_forward(
         params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids,
-        mesh=mesh,
+        mesh=mesh, moe_out=moe_out,
     )
     last = jnp.clip(n_new - 1, 0, c - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None].astype(jnp.int32), axis=1)

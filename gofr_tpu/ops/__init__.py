@@ -14,7 +14,12 @@ from .attention import (
     chunk_prefill_why_not_flash,
     decode_attention,
     flash_attention,
+    latent_attention,
+    latent_chunk_prefill_attention,
+    latent_rope_width,
     mha_reference,
+    mla_kernel_why_not,
+    mla_paged_chunk_decode_attention,
     multi_head_attention,
     paged_chunk_decode_attention,
     paged_gather,
@@ -32,6 +37,11 @@ __all__ = [
     "chunk_decode_attention",
     "chunk_prefill_attention",
     "paged_chunk_decode_attention",
+    "latent_attention",
+    "latent_chunk_prefill_attention",
+    "latent_rope_width",
+    "mla_kernel_why_not",
+    "mla_paged_chunk_decode_attention",
     "paged_gather",
     "paged_kernel_why_not",
     "chunk_prefill_why_not_flash",

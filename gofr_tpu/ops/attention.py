@@ -1067,6 +1067,278 @@ def paged_chunk_decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# Latent (MLA) attention, absorbed: every query head over ONE shared row
+# ---------------------------------------------------------------------------
+#
+# A latent cache row is [c_kv | k_rope]: the normalized compressed key/value
+# (kv_lora_rank values) and the rotated rope key that every head shares. With
+# the up-projection absorbed into the query (q_abs = q_nope W_uk^T) attention
+# is multi-query over that one row: score = (q_abs . c_kv + q_rope . k_rope)
+# * scale, and the probabilities weigh c_kv itself (o_lat, kv_lora_rank wide;
+# the caller applies W_uv). The pool keeps the two parts as two arrays,
+# c_kv [.., 1, C] and k_rope [.., 1, R] with R padded to whole 128-lane
+# rows (latent_rope_width), so that a page of either is a slab Mosaic
+# copies and every (k, v)-shaped pool helper moves them unchanged; no array
+# holds values. Queries arrive as ONE array [.., hq, C + R], split here.
+
+
+def latent_rope_width(rope_dim: int) -> int:
+    """Columns the pool keeps for the shared rope key: whole 128-lane rows."""
+    return -(-int(rope_dim) // 128) * 128
+
+
+def latent_attention(
+    q: jnp.ndarray,  # [b, s, hq, C + R]  (q_abs | q_rope, zero-padded to R)
+    rows_c: jnp.ndarray,  # [b, n, 1, C]
+    rows_r: jnp.ndarray,  # [b, n, 1, R]
+    mask: jnp.ndarray,  # [b, s, n] bool: which rows each query attends
+    *,
+    scale: float,
+) -> jnp.ndarray:
+    """Absorbed latent attention in XLA: -> o_lat [b, s, hq, C]. Dots run at
+    the rows' stored dtype with f32 accumulation (the decode_attention
+    convention). The prefill path inside the fused step, the off-TPU decode
+    path and the kernel's test oracle."""
+    C = rows_c.shape[-1]
+    rc, rr = rows_c[:, :, 0], rows_r[:, :, 0]
+    qs = (q.astype(jnp.float32) * scale).astype(rc.dtype)
+    s = jnp.einsum(
+        "bshc,bnc->bhsn", qs[..., :C], rc, preferred_element_type=jnp.float32
+    ) + jnp.einsum(
+        "bshr,bnr->bhsn", qs[..., C:], rr, preferred_element_type=jnp.float32
+    )
+    s = jnp.where(mask[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(rc.dtype)
+    out = jnp.einsum("bhsn,bnc->bshc", p, rc, preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def latent_chunk_prefill_attention(q, c_cache, r_cache, cursors, *, scale):
+    """chunk_prefill_attention for latent rows: queries at positions
+    [cursors, cursors + c) over every resident row (the chunk's own already
+    written), causal by position."""
+    c, capacity = q.shape[1], c_cache.shape[1]
+    qpos = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    kpos = jnp.arange(capacity, dtype=jnp.int32)[None, None, :]
+    return latent_attention(q, c_cache, r_cache, kpos <= qpos[:, :, None], scale=scale)
+
+
+def mla_kernel_why_not(latent: int, rope: int, block: int, *, interpret: bool = False) -> str:
+    """paged_kernel_why_not for the latent kernel: lane-aligned parts and a
+    page that is whole bf16 sublane tiles."""
+    if not interpret and jax.default_backend() != "tpu":
+        return f"backend {jax.default_backend()} is not tpu"
+    if latent % 128 or rope % 128:
+        return f"latent row ({latent} | {rope}) is not whole 128-lane parts"
+    if block % 16:
+        return f"kv block {block} is not a multiple of 16"
+    return ""
+
+
+def _mla_paged_decode_kernel(
+    # scalar prefetch: block tables + per-sequence valid bounds + the layer
+    tbl_ref, lo_ref, hi_ref, layer_ref,
+    # the lane's queries (pre-scaled, the pool's dtype), the two pools left in
+    # HBM whole ([L, NB, B, C | R]), outputs, page buffers, DMA semaphores
+    qc_ref, qr_ref, c_hbm, r_hbm, o_ref, m_ref, l_ref, c_buf, r_buf, sems,
+    *,
+    pages: int,
+):
+    """_paged_decode_kernel for latent rows: one program a lane, pages
+    copied by the kernel (double buffered), only the lane's band. Every
+    query head meets the same rows, so a step is two matmuls of all heads:
+    scores [hq, tile] from the c_kv and rope parts, then p @ c_kv."""
+    n_pool, block = c_hbm.shape[1], c_hbm.shape[2]
+    n_tbl = tbl_ref.shape[1]
+    hq, C = qc_ref.shape[1:]
+    R = qr_ref.shape[2]
+    tile = pages * block
+    bi = pl.program_id(0)
+    layer = layer_ref[0]
+    lo = jnp.maximum(lo_ref[bi], 0)
+    hi = jnp.minimum(hi_ref[bi], n_tbl * block)
+    first = lo // tile
+    n = jnp.where(hi > lo, pl.cdiv(hi, tile) - first, 0)
+
+    def page_copies(g, slot, go):
+        j0 = jnp.maximum(g * pages, lo // block)
+        j1 = jnp.minimum((g + 1) * pages, pl.cdiv(hi, block))
+
+        def one(j, carry):
+            page = jnp.clip(tbl_ref[bi, j], 0, n_pool - 1) if go else 0
+            for part, (src, dst) in enumerate(((c_hbm, c_buf), (r_hbm, r_buf))):
+                cp = pltpu.make_async_copy(
+                    src.at[layer, page], dst.at[slot, j - g * pages], sems.at[part, slot]
+                )
+                cp.start() if go else cp.wait()
+            return carry
+
+        jax.lax.fori_loop(j0, j1, one, 0)
+
+    @pl.when(n > 0)
+    def _first():
+        page_copies(first, 0, True)
+
+    qc, qr = qc_ref[0], qr_ref[0]  # [hq, C], [hq, R]
+    nt = (((1,), (1,)), ((), ()))
+
+    def body(i, carry):
+        m, l, acc = carry
+        g = first + i
+        slot = i % 2
+
+        @pl.when(i + 1 < n)
+        def _next():
+            page_copies(g + 1, 1 - slot, True)
+
+        page_copies(g, slot, False)
+        base = g * tile
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (hq, tile), 1)
+        in_band = (pos >= lo) & (pos < hi)
+        row = base + jax.lax.broadcasted_iota(jnp.int32, (tile, C), 0)
+        c = c_buf[slot].reshape(tile, C)
+        r = r_buf[slot].reshape(tile, R)
+        s = jax.lax.dot_general(qc, c, nt, preferred_element_type=jnp.float32)
+        s = s + jax.lax.dot_general(qr, r, nt, preferred_element_type=jnp.float32)
+        s = jnp.where(in_band, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        # rows outside the band may be pages never copied: whatever the
+        # buffer holds there (NaN included) must not reach 0 * v
+        v = jnp.where((row >= lo) & (row < hi), c, jnp.zeros_like(c))
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc_new
+
+    m, l, acc = jax.lax.fori_loop(0, n, body, (
+        jnp.full((hq, 1), NEG_INF, jnp.float32),
+        jnp.zeros((hq, 1), jnp.float32),
+        jnp.zeros((hq, C), jnp.float32),
+    ))
+    o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
+    m_ref[0] = jnp.broadcast_to(m, m_ref.shape[1:])
+    l_ref[0] = jnp.broadcast_to(l, l_ref.shape[1:])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _mla_paged_decode_call(q, c_pool, r_pool, tables, lo, hi, layer, *, scale: float, interpret: bool):
+    """Partials of the main region of layer `layer` of the WHOLE pools
+    [L, NB, B, 1, C | R]: (o [b, hq, C] f32 normalized, m, l [b, hq] f32).
+    Query heads are padded to whole bf16 sublane tiles."""
+    b, hq, _ = q.shape
+    L, NB, B, _, C = c_pool.shape
+    R = r_pool.shape[-1]
+    MB = tables.shape[1]
+    hp = -(-hq // 16) * 16
+    pages = paged_decode_pages(B, 1, C, c_pool.dtype, MB)
+    qs = jnp.pad((q.astype(jnp.float32) * scale).astype(c_pool.dtype),
+                 ((0, 0), (0, hp - hq), (0, 0)))
+
+    def lane(bi, tbl, lo_, hi_, layer_):
+        return (bi, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hp, C), lane), pl.BlockSpec((1, hp, R), lane), hbm, hbm],
+        out_specs=[
+            pl.BlockSpec((1, hp, C), lane),
+            pl.BlockSpec((1, hp, 128), lane),
+            pl.BlockSpec((1, hp, 128), lane),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, B, C), c_pool.dtype),
+            pltpu.VMEM((2, pages, B, R), r_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    o, m, l = pl.pallas_call(
+        functools.partial(_mla_paged_decode_kernel, pages=pages),
+        name="mla_paged_decode",  # what a device trace calls the kernel
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hp, C), jnp.float32),
+            jax.ShapeDtypeStruct((b, hp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, hp, 128), jnp.float32),
+        ],
+        interpret=interpret,
+    )(
+        tables.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        qs[..., :C], qs[..., C:], c_pool.reshape(L, NB, B, C), r_pool.reshape(L, NB, B, R),
+    )
+    return o[:, :hq], m[:, :hq, 0], l[:, :hq, 0]
+
+
+def mla_paged_chunk_decode_attention(
+    q: jnp.ndarray,  # [b, 1, hq, C + R]
+    c_pool: jnp.ndarray,  # [L, NB, B, 1, C]: the WHOLE pool, every layer's
+    r_pool: jnp.ndarray,  # [L, NB, B, 1, R]
+    tables: jnp.ndarray,  # [b, MB] int32
+    c_buf: jnp.ndarray,  # [b, chunk, 1, C] — this chunk's new rows
+    r_buf: jnp.ndarray,  # [b, chunk, 1, R]
+    lengths: jnp.ndarray,  # [b] valid pool prefix (at chunk START)
+    step: jnp.ndarray,  # scalar int32 — current step within the chunk
+    *,
+    scale: float,
+    layer,  # scalar int32: which layer of the pools this is
+    use_kernel: bool | None = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """paged_chunk_decode_attention for latent rows -> o_lat [b, 1, hq, C]:
+    pool rows hold positions [0, lengths) through the table, the chunk
+    buffer [lengths, lengths + step]. Kernel path: the main region's
+    partials merged with the dense buffer region by one rescale; else the
+    rows are gathered and both regions share one softmax. The pools come
+    whole and the kernel copies its pages from `layer` of them: a layer's
+    pool sliced out of the stack is a copy of it before every call (a custom
+    call's operand is a whole array)."""
+    b, _, hq, _ = q.shape
+    B, C = c_pool.shape[-3], c_pool.shape[-1]
+    chunk = c_buf.shape[1]
+    buf_mask = jnp.broadcast_to(jnp.arange(chunk)[None, None, :] <= step, (b, 1, chunk))
+    if use_kernel is None:
+        use_kernel = not mla_kernel_why_not(C, r_pool.shape[-1], B, interpret=interpret)
+    if not use_kernel:
+        cc, rc = paged_gather(
+            *(jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False) for a in (c_pool, r_pool)),
+            tables,
+        )
+        main_mask = jnp.arange(cc.shape[1])[None, None, :] < lengths[:, None, None]
+        return latent_attention(
+            q, jnp.concatenate([cc, c_buf.astype(cc.dtype)], axis=1),
+            jnp.concatenate([rc, r_buf.astype(rc.dtype)], axis=1),
+            jnp.concatenate([main_mask, buf_mask], axis=-1), scale=scale,
+        )
+    o_m, m_m, l_m = _mla_paged_decode_call(
+        q[:, 0], c_pool, r_pool, tables, jnp.zeros_like(lengths), lengths, layer,
+        scale=scale, interpret=interpret,
+    )
+    qs = q[:, 0].astype(jnp.float32) * scale
+    cb, rb = c_buf[:, :, 0].astype(jnp.float32), r_buf[:, :, 0].astype(jnp.float32)
+    s_buf = jnp.einsum("bhc,bkc->bhk", qs[..., :C], cb) + jnp.einsum(
+        "bhr,bkr->bhk", qs[..., C:], rb
+    )
+    s_buf = jnp.where(buf_mask, s_buf, NEG_INF)
+    m_b = jnp.max(s_buf, axis=-1)
+    p_buf = jnp.exp(s_buf - m_b[..., None])
+    l_b = jnp.sum(p_buf, axis=-1)
+    o_b = jnp.einsum("bhk,bkc->bhc", p_buf, cb)  # unnormalized
+    m = jnp.maximum(m_m, m_b)
+    a_m = jnp.exp(m_m - m) * l_m
+    a_b = jnp.exp(m_b - m)
+    denom = a_m + a_b * l_b
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    out = (o_m * a_m[..., None] + o_b * a_b[..., None]) / denom[..., None]
+    return out[:, None].astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Dispatcher
 # ---------------------------------------------------------------------------
 

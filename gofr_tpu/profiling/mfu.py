@@ -129,22 +129,55 @@ def model_costs(cfg, *, quantized: bool = False) -> ModelCosts:
     Matches the parameter accounting bench.py's raw probes use (attention
     projections with GQA, the 3-matrix gated MLP, one vocab x d embed
     matrix) so the two never disagree about what "2·params" means."""
-    layer_params = (
-        cfg.d_model * (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim  # qkv
-        + cfg.n_heads * cfg.head_dim * cfg.d_model  # attention out
-        + 3 * cfg.d_model * cfg.d_ff  # gate/up/down
-    ) * cfg.n_layers
+    d = cfg.d_model
+    if getattr(cfg, "latent", False):
+        # absorbed latent attention: every head meets one row [c_kv | k_rope]
+        C, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        attn_params = (
+            d * cfg.q_lora_rank
+            + cfg.q_lora_rank * cfg.n_heads * (cfg.qk_nope_head_dim + dr)
+            + d * (C + dr)
+            + C * cfg.n_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+            + cfg.n_heads * cfg.v_head_dim * d
+        )
+        attn_per_ctx = 2 * cfg.n_heads * (2 * C + dr)
+        kv_values = C + dr
+    else:
+        attn_params = (
+            d * (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim  # qkv
+            + cfg.n_heads * cfg.head_dim * d  # attention out
+        )
+        attn_per_ctx = 4 * cfg.n_heads * cfg.head_dim
+        kv_values = 2 * cfg.n_kv_heads * cfg.head_dim
+    dense_mlp = 3 * d * cfg.d_ff  # gate/up/down
+    n_experts = int(getattr(cfg, "n_experts", 0) or 0)
+    if n_experts:
+        # a token multiplies its top-k experts and the shared ones; every
+        # expert is resident
+        fe = int(getattr(cfg, "moe_d_ff", 0) or cfg.d_ff)
+        shared = int(getattr(cfg, "n_shared_experts", 0) or 0)
+        n_dense = cfg.n_dense_layers if len(cfg.group_sizes) > 1 else 0
+        n_moe = cfg.n_layers - n_dense
+        active = n_dense * dense_mlp + n_moe * (
+            3 * d * fe * (cfg.moe_top_k + shared) + d * n_experts
+        )
+        resident = n_dense * dense_mlp + n_moe * (
+            3 * d * fe * (n_experts + shared) + d * n_experts
+        )
+    else:
+        active = resident = dense_mlp * cfg.n_layers
+    layer_params = attn_params * cfg.n_layers + active
     embed_params = cfg.vocab_size * cfg.d_model
     itemsize = 1 if quantized else _dtype_itemsize(cfg.dtype)
     kv_itemsize = _dtype_itemsize(cfg.dtype)  # KV cache stays cfg.dtype
     return ModelCosts(
-        params=layer_params + embed_params,
+        params=attn_params * cfg.n_layers + resident + embed_params,
         layer_params=layer_params,
         embed_params=embed_params,
         matmul_flops_per_token=2 * (layer_params + embed_params),
-        attn_flops_per_token_per_ctx=4 * cfg.n_layers * cfg.n_heads * cfg.head_dim,
-        kv_bytes_per_ctx_token=2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * kv_itemsize,
-        params_bytes=(layer_params + embed_params) * itemsize,
+        attn_flops_per_token_per_ctx=cfg.n_layers * attn_per_ctx,
+        kv_bytes_per_ctx_token=cfg.n_layers * kv_values * kv_itemsize,
+        params_bytes=(attn_params * cfg.n_layers + resident + embed_params) * itemsize,
         sliding_window=int(getattr(cfg, "sliding_window", 0) or 0),
     )
 
