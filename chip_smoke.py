@@ -88,7 +88,7 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
     import jax
     import jax.numpy as jnp
 
-    from gofr_tpu.kvcache.paged import quantize_rows
+    from gofr_tpu.kvcache.paged import quantize_rows, stored_rows
     from gofr_tpu.ops.attention import (
         flash_attention,
         mha_reference,
@@ -141,7 +141,8 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
         compare(f"flash+q_offsets {tag} chunk={chunk} capacity={capacity}", got, want)
 
         # paged decode through a scrambled block table, bf16 then int8 pool,
-        # at the three block sizes the kernel sizes its tile for
+        # at the three block sizes the kernel sizes its tile for; the pool a
+        # stack of two layers as the engine stores it, read at the second
         q1 = rand((b, 1, hq, d))
         kb, vb = rand((b, steps, hkv, d)), rand((b, steps, hkv, d))
         lengths = jnp.asarray([37, capacity - steps], jnp.int32)
@@ -149,7 +150,7 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
         for block in (16, 64, 128):
             n_tbl = capacity // block
             n_blocks = b * n_tbl + 3
-            pk, pv = rand((n_blocks, block, hkv, d)), rand((n_blocks, block, hkv, d))
+            pk, pv = rand((2, n_blocks, block, hkv, d)), rand((2, n_blocks, block, hkv, d))
             tables = (
                 jax.random.permutation(next(keys), n_blocks)[: b * n_tbl]
                 .reshape(b, n_tbl).astype(jnp.int32)
@@ -158,12 +159,13 @@ def kernel_phase(head_shapes, *, capacity: int, interpret: bool) -> None:
             for pool, (k_pool, v_pool, k_sc, v_sc) in {
                 "bf16": (pk, pv, None, None), "int8": (qk, qv, sk, sv),
             }.items():
+                k_pool, v_pool = stored_rows(k_pool), stored_rows(v_pool)
 
                 def attend(use_kernel):
                     return jax.jit(
                         lambda q, kp, vp, ks, vs: paged_chunk_decode_attention(
                             q, kp, vp, tables, kb, vb, lengths, step,
-                            k_scales=ks, v_scales=vs,
+                            layer=1, k_scales=ks, v_scales=vs,
                             use_kernel=use_kernel, interpret=interpret,
                         )
                     )(q1, k_pool, v_pool, k_sc, v_sc)

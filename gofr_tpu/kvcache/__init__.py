@@ -317,7 +317,7 @@ def paged_default():
 
 
 def row_shapes(cfg) -> tuple[tuple, tuple]:
-    """What ONE token of ONE layer keeps in the cache: the trailing dims of
+    """What ONE token of ONE layer keeps in the cache, as [heads, dim] of
     the cache's two arrays (`KVCache.k`, `KVCache.v`), read from the config
     here and nowhere else. GQA: keys and values, [n_kv_heads, head_dim]
     each. Latent attention (cfg.latent): ONE row [c_kv | k_rope] of
@@ -330,7 +330,16 @@ def row_shapes(cfg) -> tuple[tuple, tuple]:
     in one array would save (576 tiles to 640 too), each part is a slab
     the decode kernel can copy and slice at lane 0, and every pool helper
     (gather_slots / scatter_rows / copy_blocks, host spill) moves a pair of
-    arrays already."""
+    arrays already.
+
+    The contiguous cache keeps a row as these trailing dims [heads, dim].
+    The PAGED POOL stores it as its decode kernel reads a page: flat,
+    heads * dim columns, head h the columns [h * dim, (h + 1) * dim)
+    (CacheManager.pool_arrays: [L, NB, B, heads * dim]), so that the array
+    the engine owns is the kernel's HBM operand and the scatter's target
+    with no layout in between. Who needs heads (a gathered view, rows about
+    to be written, a block on its way out of the process) reshapes those
+    rows (paged.viewed_rows / stored_rows), never the pool."""
     if getattr(cfg, "latent", False):
         from ..ops import latent_rope_width
 
@@ -598,23 +607,28 @@ class CacheManager:
         return ring_pack(cache, self.capacity) if self.rolling else cache
 
     # -- paged layout: pool geometry --------------------------------------
+    def pool_shapes(self) -> tuple[tuple, tuple]:
+        """The pool's two arrays as stored, [L, NB, B, heads * dim] each: a
+        row flat, as its decode kernel reads a page (row_shapes)."""
+        lead = (self.cfg.n_layers, self.pool.n_blocks, self.block)
+        return tuple(lead + (h * d,) for h, d in self.row_shapes)
+
     def pool_arrays(self, jnp):
         """Zeroed device pool (KVCache pool-layout) + int8 scales (or
         None). The ENGINE owns these arrays — they are donated through
-        every jitted program; this manager only does the bookkeeping."""
+        every jitted program; this manager only does the bookkeeping.
+        k / v are pool_shapes(); the scales [2, L, NB, B, heads]."""
         from ..models.transformer import KVCache
 
-        cfg = self.cfg
-        lead = (cfg.n_layers, self.pool.n_blocks, self.block)
-        k_row, v_row = self.row_shapes
-        dtype = jnp.int8 if self.int8 else cfg.dtype
+        k_shape, v_shape = self.pool_shapes()
+        dtype = jnp.int8 if self.int8 else self.cfg.dtype
         cache = KVCache(
-            k=jnp.zeros(lead + k_row, dtype),
-            v=jnp.zeros(lead + v_row, dtype),
+            k=jnp.zeros(k_shape, dtype),
+            v=jnp.zeros(v_shape, dtype),
             length=jnp.zeros((self.slots,), jnp.int32),
         )
         scales = (
-            jnp.zeros((2,) + lead + (self.row_heads,), jnp.float32)
+            jnp.zeros((2,) + k_shape[:3] + (self.row_heads,), jnp.float32)
             if self.int8 else None
         )
         return cache, scales

@@ -59,6 +59,8 @@ __all__ = [
     "scatter_rows",
     "copy_blocks",
     "gather_blocks_host",
+    "stored_rows",
+    "viewed_rows",
     "quantize_rows",
     "dequantize_rows",
 ]
@@ -518,12 +520,28 @@ class RadixTree:
 
 
 def _flat(a):
-    """[L, NB, B, h, d] -> [L, NB*B, h, d] (metadata-only reshape)."""
-    L, NB, B, h, d = a.shape
-    return a.reshape(L, NB * B, h, d)
+    """[L, NB, B, W] -> [L * NB * B, W]: a merge of the MAJOR dimensions; the
+    row's width stays the minor one, so with a block of whole sublane tiles
+    the pool's tiled layout is untouched. One row axis and no batch axis:
+    a scatter over it lands in place, where XLA's TPU scatter given the
+    layers as a batch axis ([L, NB * B, W].at[:, rows]) transposes the whole
+    pool to bring the scattered axis outermost, and back."""
+    return a.reshape(-1, a.shape[-1])
 
 
-def gather_slots(pool_k, pool_v, tables, lengths, *, scales=None, dtype=None):
+def stored_rows(rows):
+    """Rows as a token keeps them [..., h, d] (kvcache.row_shapes) -> as the
+    pool stores them [..., h * d], head h the columns [h * d, (h + 1) * d).
+    For rows that were gathered or are about to be written, never the pool."""
+    return rows.reshape(rows.shape[:-2] + (-1,))
+
+
+def viewed_rows(rows, row):
+    """stored_rows undone: [..., h * d] -> [..., h, d] for ``row`` = (h, d)."""
+    return rows.reshape(rows.shape[:-1] + tuple(row))
+
+
+def gather_slots(pool_k, pool_v, tables, lengths, *, rows, scales=None, dtype=None):
     """Materialize the dense per-slot KV view THROUGH the block tables:
     logical row ``p`` of slot ``s`` comes from pool block
     ``tables[s, p // B]``, row ``p % B``. This is the dense-gather
@@ -535,23 +553,30 @@ def gather_slots(pool_k, pool_v, tables, lengths, *, scales=None, dtype=None):
     sequence's valid length and is masked by the exact same positional
     masks the contiguous path uses.
 
-    Returns a models.transformer.KVCache of shape [L, S, MB*B, h, d].
-    With ``scales`` (int8 pool), rows are dequantized to ``dtype``."""
+    The pools are [L, NB, B, W] as stored; ``rows`` (kvcache.row_shapes)
+    says what [h, d] the GATHERED rows of each are viewed as. Returns a
+    models.transformer.KVCache of shape [L, S, MB*B, h, d]. With ``scales``
+    (int8 pool), rows are dequantized to ``dtype``."""
     import jax.numpy as jnp
 
     from ..models.transformer import KVCache
+    from ..ops.attention import take_pages
 
-    def take(pool, sc):
-        g = jnp.take(pool, tables, axis=1, mode="clip")  # [L, S, MB, B, h, d]
-        L, S, MB, B, h, d = g.shape
-        g = g.reshape(L, S, MB * B, h, d)
+    def take(pool, row, sc):
+        L, _, B, _ = pool.shape
+        every_layer = jnp.arange(L, dtype=jnp.int32)[:, None, None]
+        g = take_pages(pool, every_layer, tables)  # [L, S, MB, B, W]
+        S, MB = tables.shape
+        g = g.reshape((L, S, MB * B) + tuple(row))
         if sc is not None:
-            s = jnp.take(sc, tables, axis=1, mode="clip").reshape(L, S, MB * B, h)
+            s = jnp.take(sc, tables, axis=1, mode="clip").reshape(L, S, MB * B, row[0])
             g = g.astype(dtype) * s[..., None].astype(dtype)
         return g
 
     ks, vs = (None, None) if scales is None else (scales[0], scales[1])
-    return KVCache(k=take(pool_k, ks), v=take(pool_v, vs), length=lengths)
+    return KVCache(
+        k=take(pool_k, rows[0], ks), v=take(pool_v, rows[1], vs), length=lengths
+    )
 
 
 def quantize_rows(rows, *, axis=-1):
@@ -572,8 +597,10 @@ def dequantize_rows(q, scale, dtype):
 
 
 def scatter_rows(pool_k, pool_v, tables, rows_k, rows_v, positions, valid, *, scales=None):
-    """Write per-slot K/V rows through the block tables. ``rows_k/v`` are
-    [L, S, W, h, d], ``positions`` [S, W] logical row indices (computed
+    """Write per-slot K/V rows through the block tables into the pools
+    [L, NB, B, h * d] as stored. ``rows_k/v`` are [L, S, W, h, d] as a token
+    keeps them (flattened here, the rows and never the pool),
+    ``positions`` [S, W] logical row indices (computed
     from DEVICE state — lengths/cursors — so pipelined speculative
     verifies and rollbacks can never mis-aim a host-computed window),
     ``valid`` [S, W] bool. Invalid lanes push their flat index out of
@@ -587,29 +614,25 @@ def scatter_rows(pool_k, pool_v, tables, rows_k, rows_v, positions, valid, *, sc
     Returns the updated (pool_k, pool_v[, scales]) arrays."""
     import jax.numpy as jnp
 
-    L, NB, B, h, d = pool_k.shape
+    L, NB, B, _ = pool_k.shape
     bi = jnp.clip(positions // B, 0, tables.shape[1] - 1)
     blk = jnp.take_along_axis(tables, bi, axis=1)  # [S, W]
-    flat = blk * B + positions % B
-    oob = NB * B
-    flat = jnp.where(valid, flat, oob)
+    # every layer's copy of a row, in the pool's merged row axis (_flat); an
+    # invalid lane points past the LAST layer's rows, so it is dropped and
+    # not written into the next layer
+    layer0 = jnp.arange(L, dtype=jnp.int32)[:, None, None] * (NB * B)
+    flat = jnp.where(valid, blk * B + positions % B + layer0, L * NB * B).reshape(-1)
+
+    def put(pool, rows):
+        rows = rows.astype(pool.dtype).reshape(-1, pool.shape[-1])
+        return _flat(pool).at[flat].set(rows, mode="drop").reshape(pool.shape)
 
     if scales is None:
-        k = _flat(pool_k).at[:, flat].set(rows_k.astype(pool_k.dtype), mode="drop")
-        v = _flat(pool_v).at[:, flat].set(rows_v.astype(pool_v.dtype), mode="drop")
-        return k.reshape(pool_k.shape), v.reshape(pool_v.shape), None
+        return put(pool_k, stored_rows(rows_k)), put(pool_v, stored_rows(rows_v)), None
     qk, sk = quantize_rows(rows_k)
     qv, sv = quantize_rows(rows_v)
-    k = _flat(pool_k).at[:, flat].set(qk, mode="drop").reshape(pool_k.shape)
-    v = _flat(pool_v).at[:, flat].set(qv, mode="drop").reshape(pool_v.shape)
-    L_, NB_, B_, h_ = scales.shape[1:]
-    fs = scales.reshape(2, L_, NB_ * B_, h_)
-    # per-component updates: `at[0, :, flat]` would be mixed
-    # basic/advanced indexing (integer + slice + array), which reorders
-    # the result dims and breaks the value-shape match
-    fs0 = fs[0].at[:, flat].set(sk, mode="drop")
-    fs1 = fs[1].at[:, flat].set(sv, mode="drop")
-    return k, v, jnp.stack([fs0, fs1]).reshape(scales.shape)
+    k, v = put(pool_k, stored_rows(qk)), put(pool_v, stored_rows(qv))
+    return k, v, jnp.stack([put(scales[0], sk), put(scales[1], sv)])
 
 
 def copy_blocks(pool_k, pool_v, srcs, dsts, *, scales=None):
@@ -629,14 +652,16 @@ def copy_blocks(pool_k, pool_v, srcs, dsts, *, scales=None):
     return k, v, scales.at[:, :, dsts].set(rows, mode="drop")
 
 
-def gather_blocks_host(pool_k, pool_v, blocks, *, scales=None):
+def gather_blocks_host(pool_k, pool_v, blocks, *, rows, scales=None):
     """Fetch specific pool blocks to host numpy (session spill / tests):
-    returns (k [L, n, B, h, d], v [...], scales or None) as np arrays."""
+    returns (k [L, n, B, h, d], v [...], scales or None) as np arrays, the
+    shape a block has outside this process whatever the pool stores
+    (``rows``: kvcache.row_shapes)."""
     import jax.numpy as jnp
 
     idx = jnp.asarray(np.asarray(blocks, np.int32))
-    k = np.asarray(jnp.take(pool_k, idx, axis=1, mode="clip"))
-    v = np.asarray(jnp.take(pool_v, idx, axis=1, mode="clip"))
+    k = viewed_rows(np.asarray(jnp.take(pool_k, idx, axis=1, mode="clip")), rows[0])
+    v = viewed_rows(np.asarray(jnp.take(pool_v, idx, axis=1, mode="clip")), rows[1])
     s = (
         None
         if scales is None

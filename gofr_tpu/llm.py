@@ -10,7 +10,8 @@ Design (all shapes static; a bounded set of compiled executables):
 
 - **Slots over a paged block pool (default).** A fixed decode batch of
   S slots whose KV lives in ONE device-resident pool of fixed-size
-  blocks [n_layers, NB, block, hkv, hd], read and written through
+  blocks [n_layers, NB, block, hkv * hd] (a row flat, as its decode
+  kernel reads a page: kvcache.row_shapes), read and written through
   per-slot block tables (gofr_tpu.kvcache.paged): blocks materialize as
   each cursor advances, sibling prompts share every common prefix block
   in place (refcounted, copy-on-write), and decode attention goes
@@ -1862,7 +1863,7 @@ class LLMEngine:
         # someone else.
         if self.kv.paged:
             from .kvcache.paged import (
-                copy_blocks, gather_slots, scatter_rows,
+                copy_blocks, gather_slots, scatter_rows, stored_rows,
             )
             from .models.transformer import decode_chunk_paged
 
@@ -1891,7 +1892,7 @@ class LLMEngine:
             def _gather_view(cache, scales, tables, lengths):
                 sc = scales if _int8 else None
                 return gather_slots(
-                    cache.k, cache.v, tables, lengths,
+                    cache.k, cache.v, tables, lengths, rows=self.kv.row_shapes,
                     scales=(None if sc is None else (sc[0], sc[1])),
                     dtype=cfg.dtype,
                 )
@@ -2001,10 +2002,11 @@ class LLMEngine:
             )
 
             def _restore(cache, scales, hk, hv, hs, dsts):
-                """Session restore: host-fetched blocks land back in the
-                pool at freshly-allocated ids (byte-identical h2d)."""
-                k2 = cache.k.at[:, dsts].set(hk, mode="drop")
-                v2 = cache.v.at[:, dsts].set(hv, mode="drop")
+                """Session restore: host-fetched blocks [L, n, B, h, d] land
+                back in the pool at freshly-allocated ids (byte-identical
+                h2d), their rows flattened to the stored width."""
+                k2 = cache.k.at[:, dsts].set(stored_rows(hk), mode="drop")
+                v2 = cache.v.at[:, dsts].set(stored_rows(hv), mode="drop")
                 if _int8:
                     scales = scales.at[:, :, dsts].set(hs, mode="drop")
                 return cache._replace(k=k2, v=v2), scales
@@ -2601,10 +2603,12 @@ class LLMEngine:
         that cannot take its Pallas kernel carries the reason, so a
         fallback to XLA attention is never silent (stats()["attention"]).
         Beside a paged-decode kernel, the tile it derived from the pool's
-        shape: pages and tokens of every local kv head per step."""
+        shape (pages and tokens of every local kv head per step) and what
+        its pool operand is (ops.attention.paged_pool_operand: the stack
+        as this engine stores it, or the call is refused)."""
         from .ops.attention import (
             chunk_prefill_why_not_flash, flash_why_not, paged_decode_pages,
-            paged_kernel_why_not,
+            paged_kernel_why_not, paged_pool_operand,
         )
 
         hd = self.cfg.head_dim
@@ -2632,6 +2636,7 @@ class LLMEngine:
                     self.kv.block, 1, C, self.cfg.dtype, self.kv.table_width
                 )
                 paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+                paths["pool_operand"] = paged_pool_operand(self.kv.pool_shapes()[0], C)
             return paths
         if self.kv.paged:
             why = paged_kernel_why_not(hd, self.kv.block)
@@ -2658,6 +2663,7 @@ class LLMEngine:
                 self.kv.table_width, hq=self.cfg.n_heads, mesh=self.mesh,
             )
             paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+            paths["pool_operand"] = paged_pool_operand(self.kv.pool_shapes()[0], hd)
         return paths
 
     # -- public API -------------------------------------------------------
@@ -5124,7 +5130,8 @@ class LLMEngine:
                 continue
             sc = self._kv_scales if self.kv.int8 else None
             k, v, scales = gather_blocks_host(
-                self.cache.k, self.cache.v, blocks, scales=sc
+                self.cache.k, self.cache.v, blocks, rows=self.kv.row_shapes,
+                scales=sc,
             )
             payload = {
                 "tokens": path["tokens"], "k": k, "v": v, "sc": scales,
@@ -5282,6 +5289,8 @@ class LLMEngine:
         Runs on the scheduler thread (the pool arrays are donated)."""
         if not self.kv.paged or self.kv.radix is None:
             return None
+        from .kvcache.paged import viewed_rows
+
         jnp = self._jnp
 
         def work():
@@ -5300,8 +5309,11 @@ class LLMEngine:
                 if not all_blocks:
                     return None
                 idx = jnp.asarray(np.asarray(all_blocks, np.int32))
-                k = jnp.take(self.cache.k, idx, axis=1)
-                v = jnp.take(self.cache.v, idx, axis=1)
+                # a block leaves the process as [L, n, B, h, d], whatever
+                # the pool stores
+                k_row, v_row = self.kv.row_shapes
+                k = viewed_rows(jnp.take(self.cache.k, idx, axis=1), k_row)
+                v = viewed_rows(jnp.take(self.cache.v, idx, axis=1), v_row)
                 sc = (
                     jnp.take(self._kv_scales, idx, axis=2)
                     if self.kv.int8 else None

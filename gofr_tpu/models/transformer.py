@@ -344,7 +344,7 @@ def layer_groups(layers) -> tuple:
 _ACTIVATIONS = {"gelu": jax.nn.gelu, "silu": jax.nn.silu}
 
 
-def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
+def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None, index: bool = False):
     """Scan ``layer_fn(x, lp, rest_i) -> (x, ys_i)`` over the stacked
     [n_layers, ...] weights.
 
@@ -365,12 +365,15 @@ def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
     dynamic index into the whole stack, as the scan's own slicing is, so no
     group's share of a cache is copied out), and the ys are joined. That
     indexed scan also serves one group that holds expert stacks
-    (_expert_stacks) or latent attention."""
+    (_expert_stacks).
+
+    ``index=True`` (every paged decode) gives each layer its index in the
+    whole stack as ``lp[LAYER_KEY]``, whichever scan runs: the paged pool is
+    NOT in ``rest``; the layer's kernel reads it whole, at that index."""
     groups = layer_groups(layers)
-    latent = any("wkv_a" in g for g in groups)  # its kernel reads the pool whole, at LAYER_KEY
-    if (len(groups) > 1 or latent) and overlap is not None:
-        raise ValueError("layer groups and latent attention do not run under the TP gather overlap")
-    if overlap is None and (len(groups) > 1 or latent or any(_expert_stacks(g) for g in groups)):
+    if len(groups) > 1 and overlap is not None:
+        raise ValueError("layer groups do not run under the TP gather overlap")
+    if overlap is None and (len(groups) > 1 or any(_expert_stacks(g) for g in groups)):
         from ..ops.grouped import LayerOf
 
         parts, l0 = [], 0
@@ -386,8 +389,8 @@ def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
                     tuple(rest),
                 )
                 # expert stacks stay whole (a kernel reads its blocks at the
-                # layer's index; a slice would be copied out first), and a
-                # layer may read `rest` whole the same way, at LAYER_KEY
+                # layer's index; a slice would be copied out first), as the
+                # paged pool does, read at LAYER_KEY
                 lp = {**lp, **{k: LayerOf(v, i) for k, v in whole.items()}, LAYER_KEY: l0 + i}
                 return layer_fn(x, lp, at)
 
@@ -398,6 +401,13 @@ def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
             return x, parts[0]
         return x, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
     layers = groups[0]
+    L = jax.tree.leaves(layers)[0].shape[0]
+    if overlap is None and index:
+
+        def body(x, xs):
+            return layer_fn(x, {**xs[0], LAYER_KEY: xs[1]}, xs[2:])
+
+        return jax.lax.scan(body, x, (layers, jnp.arange(L, dtype=jnp.int32)) + tuple(rest))
     if overlap is None:
 
         def body(x, xs):
@@ -406,7 +416,6 @@ def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
 
         return jax.lax.scan(body, x, (layers,) + tuple(rest))
 
-    L = jax.tree.leaves(layers)[0].shape[0]
 
     def at(i):
         return jax.tree.map(
@@ -417,7 +426,7 @@ def _layer_scan(layers: dict, layer_fn, x, rest: tuple, overlap=None):
     def body(carry, xs):
         x, g = carry
         g_next = overlap(at(jnp.minimum(xs[0] + 1, L - 1)))
-        x, ys = layer_fn(x, g, xs[1:])
+        x, ys = layer_fn(x, {**g, LAYER_KEY: xs[0]} if index else g, xs[1:])
         return (x, g_next), ys
 
     (x, _), ys = jax.lax.scan(
@@ -987,7 +996,7 @@ def decode_chunk_paged(
     params: dict,
     cfg: TransformerConfig,
     tokens: jnp.ndarray,  # [b] last sampled token per sequence
-    pool: KVCache,  # k/v [L, NB, B, hkv, hd] block pool; length [b]
+    pool: KVCache,  # k/v [L, NB, B, hkv * hd] block pool as stored; length [b]
     scales: jnp.ndarray | None,  # [2, L, NB, B, hkv] f32 (int8 pool) or None
     tables: jnp.ndarray,  # [b, MB] int32 — logical block -> pool block
     active: jnp.ndarray,  # [b] bool — only active slots advance/write
@@ -1012,7 +1021,12 @@ def decode_chunk_paged(
     the main-region attention READS THROUGH THE BLOCK TABLE
     (ops.paged_chunk_decode_attention: Pallas paged kernel on TPU,
     dense-gather fallback elsewhere) and the merge scatters the chunk's
-    rows through the table into pool blocks. Write indices derive from
+    rows through the table into pool blocks. The pool reaches its kernel as
+    the engine stores it: the layer scan carries the chunk's buffers and
+    each layer's INDEX, the layers close over the whole pool and read it
+    there, so no iteration slices a layer's pool out or lays it out again
+    (a custom call's operand is a whole array: either would be written to
+    HBM before every one of L x n_steps kernel calls). Write indices derive from
     DEVICE lengths, so pipelined dispatches and speculative rollbacks
     can never mis-aim a write; `active` must already exclude slots whose
     request retired (their table entries may point at reassigned
@@ -1025,6 +1039,7 @@ def decode_chunk_paged(
 
     Returns (tokens [n_steps, b], last [b], pool', scales', rng).
     """
+    from ..kvcache import row_shapes
     from ..kvcache.paged import scatter_rows
     from ..ops import mla_paged_chunk_decode_attention, paged_chunk_decode_attention
 
@@ -1032,8 +1047,9 @@ def decode_chunk_paged(
     K = n_steps
     aids = params.get("aids")  # per-slot adapter ids (see decode_chunk)
     quant = scales is not None and scales.size > 0
-    kb0 = jnp.zeros((L, b, K) + pool.k.shape[3:], cfg.dtype)
-    vb0 = jnp.zeros((L, b, K) + pool.v.shape[3:], cfg.dtype)
+    k_row, v_row = row_shapes(cfg)
+    kb0 = jnp.zeros((L, b, K) + k_row, cfg.dtype)
+    vb0 = jnp.zeros((L, b, K) + v_row, cfg.dtype)
     rng, sub = jax.random.split(rng)
     keys = jax.random.split(sub, K)
     ks_all = scales[0] if quant else None  # [L, NB, B, hkv]
@@ -1046,18 +1062,14 @@ def decode_chunk_paged(
         x = _embed_tokens(params, cfg, tok[:, None])
 
         def layer(x, lp, rest):
-            if quant:
-                kp_l, vp_l, ks_l, vs_l, kb_l, vb_l = rest
-            else:
-                kp_l, vp_l, kb_l, vb_l = rest
-                ks_l = vs_l = None
+            kb_l, vb_l = rest
 
             def attend(q, k_new, v_new):
                 kb_n, vb_n = _chunk_buffer_write(kb_l, vb_l, k_new, v_new, k_i)
+                # the whole pool and the layer's index: the kernel copies its
+                # pages from there, no layer's pool is sliced out
                 with jax.named_scope("layer/attn"):
                     if cfg.latent:
-                        # the whole pool and the layer's index: the kernel copies
-                        # its pages from there, no layer's pool is sliced out
                         attn = mla_paged_chunk_decode_attention(
                             q, pool.k, pool.v, tables, kb_n, vb_n, pool.length, k_i,
                             scale=_latent_scale(cfg), layer=lp[LAYER_KEY],
@@ -1065,9 +1077,10 @@ def decode_chunk_paged(
                         )
                     else:
                         attn = paged_chunk_decode_attention(
-                            q, kp_l, vp_l, tables, kb_n, vb_n, pool.length, k_i,
+                            q, pool.k, pool.v, tables, kb_n, vb_n, pool.length, k_i,
+                            layer=lp[LAYER_KEY],
                             logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
-                            k_scales=ks_l, v_scales=vs_l,
+                            k_scales=ks_all, v_scales=vs_all,
                             use_kernel=use_kernel, interpret=interpret, mesh=mesh,
                         )
                 return attn, (kb_n, vb_n)
@@ -1076,12 +1089,8 @@ def decode_chunk_paged(
             x, stats = _mlp_residual(cfg, x, lp, qmm, aids)
             return x, bufs + stats
 
-        rest = (
-            (pool.k, pool.v, ks_all, vs_all, kb, vb)
-            if quant else (pool.k, pool.v, kb, vb)
-        )
         x, ys = _layer_scan(
-            params["layers"], layer, x, rest, overlap=overlap
+            params["layers"], layer, x, (kb, vb), overlap=overlap, index=True
         )
         kb, vb = ys[:2]
         with jax.named_scope("unembed_sample"):

@@ -23,6 +23,7 @@ from .attention import (
     multi_head_attention,
     paged_chunk_decode_attention,
     paged_gather,
+    paged_pool_operand,
     paged_kernel_why_not,
     ring_positions,
 )
@@ -43,6 +44,7 @@ __all__ = [
     "mla_kernel_why_not",
     "mla_paged_chunk_decode_attention",
     "paged_gather",
+    "paged_pool_operand",
     "paged_kernel_why_not",
     "chunk_prefill_why_not_flash",
     "ring_positions",

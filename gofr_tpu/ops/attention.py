@@ -647,7 +647,9 @@ def chunk_prefill_attention(
 #   valid band [lo, hi) ride as SCALAR PREFETCH operands; the pool stays in
 #   HBM and the program copies pages itself (make_async_copy, double
 #   buffered): a GROUP of `pages` table slots at a time, each page the
-#   contiguous [B, hkv * d] slab of the pool's [NB, B, hkv, d] layout, so
+#   [B, hkv * d] slab at [layer, page] of the pool as it is STORED, the whole
+#   stack [L, NB, B, hkv * d] (the layer rides as a fourth scalar prefetch:
+#   no layer's pool is sliced out or laid out again before a call), so
 #   a step holds pages * B tokens of every head (paged_decode_pages sizes
 #   it from the pool's shape and dtype; qwen2: 8 pages, 128 tokens, 128 KB
 #   of K and of V). The walk covers only the groups that meet the band,
@@ -658,8 +660,8 @@ def chunk_prefill_attention(
 #   HBM-bound; a gather would double the dominant stream). Returns
 #   online-softmax PARTIALS (normalized output + running max + denom) so
 #   the caller can merge the chunk ring buffer region with one rescale.
-# - Dense-gather reference (paged_gather): jnp.take the table rows into
-#   the contiguous layout and reuse the proven attention above — the
+# - Dense-gather reference (paged_gather): jnp.take the layer's table rows
+#   into the contiguous layout and reuse the proven attention above — the
 #   off-TPU path and the test oracle. Bit-exact with the
 #   contiguous engine because gathering blocks in table order
 #   reconstructs the same slab.
@@ -672,22 +674,62 @@ def chunk_prefill_attention(
 # through the table by XLA, 1/32 of the rows' bytes.
 
 
-def paged_gather(k_pool, v_pool, tables, *, k_scales=None, v_scales=None, dtype=None):
-    """[NB, B, hkv, d] pools -> dense [b, MB*B, hkv, d] views through
-    [b, MB] block tables (the reference read path). Stale table entries
-    gather stale blocks — callers mask by position exactly as on the
-    contiguous layout."""
+def paged_pool_operand(pool_shape, cols: int) -> str:
+    """What a paged-decode kernel takes as its HBM operand, named for
+    stats()["attention"]["pool_operand"]: the pool as it is stored, every
+    layer's, [L, NB, B, W] with a row W = whole heads of `cols` columns
+    each (the latent kernel: the one part). Both kernels build their call
+    through here, so the array the engine owns IS the operand (pages copied
+    from [layer, page] of it); a pool kept in another shape would need a
+    view of it written to HBM before every call, and is refused instead."""
+    if len(pool_shape) != 4 or pool_shape[-1] % cols:
+        raise ValueError(
+            f"paged decode reads the pool as stored, [L, NB, B, n * {cols}]; "
+            f"got {tuple(pool_shape)}"
+        )
+    return "whole stack"
 
-    def take(pool, sc):
-        g = jnp.take(pool, tables, axis=0, mode="clip")  # [b, MB, B, hkv, d]
-        b, MB, B, hkv, d = g.shape
-        g = g.reshape(b, MB * B, hkv, d)
+
+def take_pages(pool, layer, tables):
+    """[L, NB, B, W] at `layer` through [b, MB] tables -> [b, MB, B, W]:
+    ONE gather over the merged (layer, block) axis, so the layer's pool is
+    never sliced out first. Table entries clip to the pool like mode="clip".
+    `layer` broadcasts against the tables: arange(L)[:, None, None] gathers
+    every layer's, [L, b, MB, B, W], the layers outermost as they are wanted
+    (a gather along axis 1 with the layers as a batch axis comes out
+    blocks-first on the TPU and is transposed after)."""
+    L, NB = pool.shape[:2]
+    idx = layer * NB + jnp.clip(tables, 0, NB - 1)
+    return jnp.take(pool.reshape((L * NB,) + pool.shape[2:]), idx, axis=0)
+
+
+def _take_scales(sc, layer, tables):
+    """The int8 pool's scales [L, NB, B, hkv] at `layer` through the tables
+    -> [b, MB, B, hkv]. The layer's slab is indexed out first: f32 of a few
+    heads, 1/128 of the layer's rows' bytes, where the stack merged as
+    take_pages does would be laid out again whole (its minor dim is too
+    narrow for the TPU to keep it minor)."""
+    slab = jax.lax.dynamic_index_in_dim(sc, layer, 0, keepdims=False)
+    return jnp.take(slab, tables, axis=0, mode="clip")
+
+
+def paged_gather(k_pool, v_pool, tables, layer, rows, *, k_scales=None, v_scales=None, dtype=None):
+    """Layer `layer` of the stored [L, NB, B, h * d] pools -> dense
+    [b, MB*B, h, d] views through [b, MB] block tables (the reference read
+    path); `rows` = the (h, d) of each pool's rows (kvcache.row_shapes),
+    scales [L, NB, B, h]. Stale table entries gather stale blocks — callers
+    mask by position exactly as on the contiguous layout."""
+
+    def take(pool, row, sc):
+        g = take_pages(pool, layer, tables)  # [b, MB, B, h * d]
+        b, MB, B, _ = g.shape
+        g = g.reshape((b, MB * B) + tuple(row))
         if sc is not None:
-            s = jnp.take(sc, tables, axis=0, mode="clip").reshape(b, MB * B, hkv)
+            s = _take_scales(sc, layer, tables).reshape(b, MB * B, row[0])
             g = g.astype(dtype) * s[..., None].astype(dtype)
         return g
 
-    return take(k_pool, k_scales), take(v_pool, v_scales)
+    return take(k_pool, rows[0], k_scales), take(v_pool, rows[1], v_scales)
 
 
 # What one step of the paged-decode kernel holds is derived from the pool's
@@ -716,10 +758,11 @@ def paged_decode_pages(
 
 
 def _paged_decode_kernel(
-    # scalar prefetch: block tables + per-sequence valid bounds
-    tbl_ref, lo_ref, hi_ref,
-    # q, the pools left in HBM (k, v), [the lane's k and v scales, one row
-    # per page group], outputs, page buffers, DMA semaphores
+    # scalar prefetch: block tables + per-sequence valid bounds + the layer
+    tbl_ref, lo_ref, hi_ref, layer_ref,
+    # q, the pools left in HBM whole ([L, NB, B, hkv * d]: k, v), [the lane's
+    # k and v scales, one row per page group], outputs, page buffers, DMA
+    # semaphores
     *refs,
     pages: int,
     scale: float,
@@ -731,11 +774,12 @@ def _paged_decode_kernel(
     else:
         q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
         ks_ref = vs_ref = None
-    n_pool, block = k_hbm.shape[0], k_hbm.shape[1]
+    n_pool, block = k_hbm.shape[1], k_hbm.shape[2]
     n_tbl = tbl_ref.shape[1]
     hkv, group, d = q_ref.shape[1:]
     tile = pages * block  # tokens a step
     bi = pl.program_id(0)
+    layer = layer_ref[0]
     # the band, held to what the table can name: a wild bound walks no further
     lo = jnp.maximum(lo_ref[bi], 0)
     hi = jnp.minimum(hi_ref[bi], n_tbl * block)
@@ -755,7 +799,7 @@ def _paged_decode_kernel(
             page = jnp.clip(tbl_ref[bi, j], 0, n_pool - 1) if go else 0
             for kv, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                 cp = pltpu.make_async_copy(
-                    src.at[page], dst.at[slot, j - g * pages], sems.at[kv, slot]
+                    src.at[layer, page], dst.at[slot, j - g * pages], sems.at[kv, slot]
                 )
                 cp.start() if go else cp.wait()
             return carry
@@ -843,15 +887,16 @@ def _paged_decode_kernel(
 
 def _paged_decode_partials(
     q: jnp.ndarray,  # [b, hq, d] one query per sequence
-    k_pool: jnp.ndarray,  # [NB, B, hkv, d]
+    k_pool: jnp.ndarray,  # [L, NB, B, hkv * d]: the WHOLE pool, as stored
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [b, MB] int32 pool block per logical slot
     lo: jnp.ndarray,  # [b] int32 first valid logical position (window)
     hi: jnp.ndarray,  # [b] int32 one past the last valid position
+    layer,  # scalar int32: which layer of the pools this is
     *,
     scale: float,
     logit_cap: float = 0.0,
-    k_scales=None,  # [NB, B, hkv] f32 (int8 pool)
+    k_scales=None,  # [L, NB, B, hkv] f32 (int8 pool)
     v_scales=None,
     interpret: bool = False,
     mesh=None,  # TP mesh: the kernel runs per head shard (_head_axes)
@@ -859,27 +904,30 @@ def _paged_decode_partials(
     """Pallas paged-attention decode over the valid band [lo, hi):
     returns (o [b, hq, d] f32 normalized, m [b, hq] f32, l [b, hq] f32)
     online-softmax partials for region merging."""
-    hq, hkv = q.shape[1], k_pool.shape[2]
+    hq, hkv = q.shape[1], k_pool.shape[-1] // q.shape[2]
     quantized = k_scales is not None
+    layer = jnp.asarray(layer, jnp.int32)
     call = functools.partial(
         _paged_decode_call, scale=scale, logit_cap=logit_cap, interpret=interpret
     )
     if mesh is None or mesh.size == 1:
-        return call(q, k_pool, v_pool, tables, lo, hi, k_scales, v_scales)
+        return call(q, k_pool, v_pool, tables, lo, hi, layer, k_scales, v_scales)
     qa, ka = _head_axes(mesh, hq, hkv)
-    pool_spec, sc_spec = P(None, None, ka, None), P(None, None, ka)
+    # a head's columns are contiguous in the stored row: the same heads a
+    # shard held as [.., hkv, d] it holds as [.., hkv * d]
+    pool_spec = P(None, None, None, ka)
     return jax.shard_map(
-        lambda q, k_pool, v_pool, tables, lo, hi, *scales: call(
-            q, k_pool, v_pool, tables, lo, hi, *(scales or (None, None))
+        lambda q, k_pool, v_pool, tables, lo, hi, layer, *scales: call(
+            q, k_pool, v_pool, tables, lo, hi, layer, *(scales or (None, None))
         ),
         mesh=mesh,
         in_specs=(
-            P(None, qa, None), pool_spec, pool_spec, P(), P(), P(),
-            *((sc_spec, sc_spec) if quantized else ()),
+            P(None, qa, None), pool_spec, pool_spec, P(), P(), P(), P(),
+            *((pool_spec, pool_spec) if quantized else ()),
         ),
         out_specs=(P(None, qa, None), P(None, qa), P(None, qa)),
         check_vma=False,
-    )(q, k_pool, v_pool, tables, lo, hi,
+    )(q, k_pool, v_pool, tables, lo, hi, layer,
       *((k_scales, v_scales) if quantized else ()))
 
 
@@ -887,31 +935,33 @@ def _paged_decode_partials(
 # two dozen step and chunk programs) shares ONE trace of the kernel's body
 @functools.partial(jax.jit, static_argnames=("scale", "logit_cap", "interpret"))
 def _paged_decode_call(
-    q, k_pool, v_pool, tables, lo, hi, k_scales, v_scales,
+    q, k_pool, v_pool, tables, lo, hi, layer, k_scales, v_scales,
     *, scale: float, logit_cap: float, interpret: bool,
 ):
     """_paged_decode_partials on one device (or one head shard)."""
     b, hq, d = q.shape
-    NB, B, hkv, _ = k_pool.shape
+    paged_pool_operand(k_pool.shape, d)
+    B, W = k_pool.shape[2:]
+    hkv = W // d
     MB = tables.shape[1]
     quantized = k_scales is not None
     group = hq // hkv
     pages = paged_decode_pages(B, hkv, d, k_pool.dtype, MB)
 
-    def lane(bi, tbl, lo_, hi_):
+    def lane(bi, tbl, lo_, hi_, layer_):
         return (bi, 0, 0, 0)
 
-    # The pools stay in HBM and the kernel copies whole pages itself: in
-    # the pool's [NB, B, hkv, d] layout a page is B * hkv * d contiguous
-    # elements, viewed [B, hkv * d] (a free reshape) so that a head's rows
-    # are the lane-aligned columns [head * d, (head + 1) * d) of the tile.
+    # The pools stay in HBM, every layer's, and are the operands AS STORED:
+    # the kernel copies whole pages itself from [layer, page], each the
+    # [B, hkv * d] slab whose columns [head * d, (head + 1) * d) are a
+    # head's rows, lane-aligned. Nothing between the program's parameter and
+    # the page copy writes a layer's pool: a custom call's operand is a whole
+    # array, so a slice or a view in another tiling would be written to HBM
+    # before every call.
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [pl.BlockSpec((1, hkv, group, d), lane), hbm, hbm]
-    operands = [
-        q.reshape(b, hkv, group, d),
-        k_pool.reshape(NB, B, hkv * d), v_pool.reshape(NB, B, hkv * d),
-    ]
-    page_buf = pltpu.VMEM((2, pages, B, hkv * d), k_pool.dtype)
+    operands = [q.reshape(b, hkv, group, d), k_pool, v_pool]
+    page_buf = pltpu.VMEM((2, pages, B, W), k_pool.dtype)
     if quantized:
         # A page's [B, hkv] scales are too narrow a slab to copy from HBM
         # (Mosaic wants whole 128-lane rows), so XLA gathers the lane's
@@ -919,15 +969,15 @@ def _paged_decode_call(
         # kernel holds them as one [tile] row per (head, page group).
         n_groups = pl.cdiv(MB, pages)
 
-        def group_rows(sc):  # [NB, B, hkv] -> [b, hkv, n_groups, pages * B]
-            sc = jnp.take(sc, tables, axis=0, mode="clip")  # [b, MB, B, hkv]
+        def group_rows(sc):  # [L, NB, B, hkv] -> [b, hkv, n_groups, pages * B]
+            sc = _take_scales(sc, layer, tables)  # [b, MB, B, hkv]
             sc = jnp.pad(sc, ((0, 0), (0, n_groups * pages - MB), (0, 0), (0, 0)))
             return sc.reshape(b, n_groups, pages * B, hkv).transpose(0, 3, 1, 2)
 
         in_specs += [pl.BlockSpec((1, hkv, n_groups, pages * B), lane)] * 2
         operands += [group_rows(k_scales), group_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b,),
         in_specs=in_specs,
         out_specs=[
@@ -954,6 +1004,7 @@ def _paged_decode_call(
         interpret=interpret,
     )(
         tables.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),
+        layer.reshape(1),
         *operands,
     )
     return (
@@ -980,7 +1031,7 @@ def paged_kernel_why_not(head_dim: int, block: int, *, interpret: bool = False) 
 
 def paged_chunk_decode_attention(
     q: jnp.ndarray,  # [b, 1, hq, d]
-    k_pool: jnp.ndarray,  # [NB, B, hkv, d] (one layer's pool)
+    k_pool: jnp.ndarray,  # [L, NB, B, hkv * d]: the WHOLE pool, as stored
     v_pool: jnp.ndarray,
     tables: jnp.ndarray,  # [b, MB] int32
     k_buf: jnp.ndarray,  # [b, chunk, hkv, d] — this chunk's new K rows
@@ -988,6 +1039,7 @@ def paged_chunk_decode_attention(
     lengths: jnp.ndarray,  # [b] valid pool prefix (at chunk START)
     step: jnp.ndarray,  # scalar int32 — current step within the chunk
     *,
+    layer,  # scalar int32: which layer of the pools (and scales) this is
     scale: float | None = None,
     logit_cap: float = 0.0,
     window: int = 0,
@@ -1004,15 +1056,18 @@ def paged_chunk_decode_attention(
     with the dense buffer region by one rescale); the reference path
     gathers and defers to chunk_decode_attention — both produce the
     contiguous path's exact masks and dot products, which is what the
-    paged==contiguous token-equality tests pin."""
+    paged==contiguous token-equality tests pin. The pools (and the int8
+    pool's scales [L, NB, B, hkv]) come whole, as the engine stores them:
+    the kernel copies its pages from `layer` of them, the reference gathers
+    the layer's blocks through the table; neither slices a layer's pool."""
     b, sq, hq, d = q.shape
-    B = k_pool.shape[1]
+    B = k_pool.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     if use_kernel is None:
         use_kernel = not paged_kernel_why_not(d, B, interpret=interpret)
     if not use_kernel:
         kc, vc = paged_gather(
-            k_pool, v_pool, tables,
+            k_pool, v_pool, tables, layer, (k_buf.shape[2:], v_buf.shape[2:]),
             k_scales=k_scales, v_scales=v_scales, dtype=q.dtype,
         )
         return chunk_decode_attention(
@@ -1026,7 +1081,7 @@ def paged_chunk_decode_attention(
     else:
         lo = jnp.zeros_like(lengths)
     o_m, m_m, l_m = _paged_decode_partials(
-        q[:, 0], k_pool, v_pool, tables, lo, hi,
+        q[:, 0], k_pool, v_pool, tables, lo, hi, layer,
         scale=scale, logit_cap=logit_cap,
         k_scales=k_scales, v_scales=v_scales, interpret=interpret, mesh=mesh,
     )
@@ -1076,9 +1131,10 @@ def paged_chunk_decode_attention(
 # is multi-query over that one row: score = (q_abs . c_kv + q_rope . k_rope)
 # * scale, and the probabilities weigh c_kv itself (o_lat, kv_lora_rank wide;
 # the caller applies W_uv). The pool keeps the two parts as two arrays,
-# c_kv [.., 1, C] and k_rope [.., 1, R] with R padded to whole 128-lane
-# rows (latent_rope_width), so that a page of either is a slab Mosaic
-# copies and every (k, v)-shaped pool helper moves them unchanged; no array
+# c_kv [L, NB, B, C] and k_rope [L, NB, B, R] with R padded to whole
+# 128-lane rows (latent_rope_width), so that a page of either is a slab
+# Mosaic copies and every (k, v)-shaped pool helper moves them unchanged (a
+# row outside the pool is [1, C] / [1, R]: kvcache.row_shapes); no array
 # holds values. Queries arrive as ONE array [.., hq, C + R], split here.
 
 
@@ -1227,11 +1283,13 @@ def _mla_paged_decode_kernel(
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _mla_paged_decode_call(q, c_pool, r_pool, tables, lo, hi, layer, *, scale: float, interpret: bool):
     """Partials of the main region of layer `layer` of the WHOLE pools
-    [L, NB, B, 1, C | R]: (o [b, hq, C] f32 normalized, m, l [b, hq] f32).
-    Query heads are padded to whole bf16 sublane tiles."""
+    [L, NB, B, C | R], the operands as stored: (o [b, hq, C] f32
+    normalized, m, l [b, hq] f32). Query heads are padded to whole bf16
+    sublane tiles."""
     b, hq, _ = q.shape
-    L, NB, B, _, C = c_pool.shape
     R = r_pool.shape[-1]
+    paged_pool_operand(c_pool.shape, q.shape[-1] - R)
+    B, C = c_pool.shape[2:]
     MB = tables.shape[1]
     hp = -(-hq // 16) * 16
     pages = paged_decode_pages(B, 1, C, c_pool.dtype, MB)
@@ -1270,15 +1328,15 @@ def _mla_paged_decode_call(q, c_pool, r_pool, tables, lo, hi, layer, *, scale: f
     )(
         tables.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
-        qs[..., :C], qs[..., C:], c_pool.reshape(L, NB, B, C), r_pool.reshape(L, NB, B, R),
+        qs[..., :C], qs[..., C:], c_pool, r_pool,
     )
     return o[:, :hq], m[:, :hq, 0], l[:, :hq, 0]
 
 
 def mla_paged_chunk_decode_attention(
     q: jnp.ndarray,  # [b, 1, hq, C + R]
-    c_pool: jnp.ndarray,  # [L, NB, B, 1, C]: the WHOLE pool, every layer's
-    r_pool: jnp.ndarray,  # [L, NB, B, 1, R]
+    c_pool: jnp.ndarray,  # [L, NB, B, C]: the WHOLE pool, as stored
+    r_pool: jnp.ndarray,  # [L, NB, B, R]
     tables: jnp.ndarray,  # [b, MB] int32
     c_buf: jnp.ndarray,  # [b, chunk, 1, C] — this chunk's new rows
     r_buf: jnp.ndarray,  # [b, chunk, 1, R]
@@ -1299,15 +1357,14 @@ def mla_paged_chunk_decode_attention(
     pool sliced out of the stack is a copy of it before every call (a custom
     call's operand is a whole array)."""
     b, _, hq, _ = q.shape
-    B, C = c_pool.shape[-3], c_pool.shape[-1]
+    B, C = c_pool.shape[-2:]
     chunk = c_buf.shape[1]
     buf_mask = jnp.broadcast_to(jnp.arange(chunk)[None, None, :] <= step, (b, 1, chunk))
     if use_kernel is None:
         use_kernel = not mla_kernel_why_not(C, r_pool.shape[-1], B, interpret=interpret)
     if not use_kernel:
         cc, rc = paged_gather(
-            *(jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False) for a in (c_pool, r_pool)),
-            tables,
+            c_pool, r_pool, tables, layer, (c_buf.shape[2:], r_buf.shape[2:])
         )
         main_mask = jnp.arange(cc.shape[1])[None, None, :] < lengths[:, None, None]
         return latent_attention(

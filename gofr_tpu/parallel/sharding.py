@@ -110,17 +110,17 @@ def kv_specs(
     paged: bool = False,
 ) -> P:
     """PartitionSpec for the serving engine's KV arrays — the slot slab
-    [L, slots, rows, hkv, hd] or the paged block pool
-    [L, n_blocks, block, hkv, hd] (same rank, kv-heads at axis 3 either
-    way). Sharded along heads when the TP degree divides n_kv_heads;
-    REPLICATED under MQA/GQA remainders (the standard layout — with one
-    KV head there is nothing to split, and decode all-gathers then ride
-    ICI only for Q/O). ``paged`` is accepted for call-site clarity; both
-    layouts share the geometry."""
-    del paged  # same rank/axis order for the slab and the pool
+    [L, slots, rows, hkv, hd] or (``paged``) the block pool as stored,
+    [L, n_blocks, block, hkv * hd]: kv-heads at axis 3 either way, and in
+    the pool's flat row a head's columns are contiguous, so a shard of
+    that axis is whole heads. Sharded along heads when the TP degree
+    divides n_kv_heads; REPLICATED under MQA/GQA remainders (the standard
+    layout — with one KV head there is nothing to split, and decode
+    all-gathers then ride ICI only for Q/O)."""
     tp = mesh.shape.get(model_axis, 1)
     shard = tp > 1 and cfg.n_kv_heads % tp == 0
-    return P(None, None, None, model_axis if shard else None, None)
+    heads = model_axis if shard else None
+    return P(None, None, None, heads) if paged else P(None, None, None, heads, None)
 
 
 def replicate_gather(mesh: Mesh):

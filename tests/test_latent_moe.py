@@ -19,6 +19,7 @@ program. Tolerances, and why:
 """
 
 import hashlib
+import math
 import json
 import os
 import sys
@@ -113,8 +114,8 @@ def _serve_paged(cfg, params, seqs, prompt_lens, tables, *, chunk=8, K=4, shared
     b, total = seqs.shape
     (k_row, v_row), L = row_shapes(cfg), cfg.n_layers
     NB = int(tables.max()) + 1
-    pool = T.KVCache(k=jnp.zeros((L, NB, BLOCK) + k_row, cfg.dtype), v=jnp.zeros((L, NB, BLOCK) + v_row, cfg.dtype),
-                     length=jnp.zeros((b,), jnp.int32))
+    pool = T.KVCache(k=jnp.zeros((L, NB, BLOCK, math.prod(k_row)), cfg.dtype),  # as stored: a row flat
+                     v=jnp.zeros((L, NB, BLOCK, math.prod(v_row)), cfg.dtype), length=jnp.zeros((b,), jnp.int32))
     tables = jnp.asarray(tables)
     out = {}
     cursors = np.array([0] + [shared] * (b - 1)) if shared else np.zeros((b,), np.int64)
@@ -126,7 +127,7 @@ def _serve_paged(cfg, params, seqs, prompt_lens, tables, *, chunk=8, K=4, shared
         for i in range(b):
             toks[i, : n_new[i]] = seqs[i, cursors[i] : cursors[i] + n_new[i]]
         cur, nn = jnp.asarray(cursors, jnp.int32), jnp.asarray(n_new, jnp.int32)
-        sub = gather_slots(pool.k, pool.v, tables, cur)
+        sub = gather_slots(pool.k, pool.v, tables, cur, rows=(k_row, v_row))
         logits, sub2 = T.prefill_append(params, cfg, jnp.asarray(toks), sub, cur, nn)
         pos = cur[:, None] + jnp.arange(chunk, dtype=jnp.int32)[None, :]
         rows = [jnp.take_along_axis(a, pos[None, :, :, None, None], axis=2) for a in (sub2.k, sub2.v)]
@@ -224,8 +225,8 @@ def test_the_engine_serves_it_and_shares_a_prefix(twin):
 def test_the_latent_kernel_in_interpret_mode_equals_the_dense_gather():
     b, hq, C, R, MB = 3, 5, 128, 128, 12
     keys = jax.random.split(jax.random.PRNGKey(1), 5)
-    c_pool = jax.random.normal(keys[0], (b * MB, BLOCK, 1, C), jnp.float32)
-    r_pool = jnp.pad(jax.random.normal(keys[1], (b * MB, BLOCK, 1, 8), jnp.float32), ((0, 0),) * 3 + ((0, R - 8),))
+    c_pool = jax.random.normal(keys[0], (b * MB, BLOCK, C), jnp.float32)
+    r_pool = jnp.pad(jax.random.normal(keys[1], (b * MB, BLOCK, 8), jnp.float32), ((0, 0),) * 2 + ((0, R - 8),))
     q = jax.random.normal(keys[2], (b, 1, hq, C + R), jnp.float32)
     tables = jnp.asarray(np.random.default_rng(0).permutation(b * MB).reshape(b, MB).astype(np.int32))
     lengths = jnp.asarray([0, 37, 190], jnp.int32)  # an empty band, a part page, the last page group
@@ -363,7 +364,9 @@ def test_tiny_moe_goes_through_the_same_routed_ffn():
 
 # tests/data/dense_programs_pr29.json: sha256 (first 16) of `jax.jit(f).lower(...).as_text()` for the
 # model programs of four dense presets, plain and int8, as the PARENT of the PR that brought layer
-# groups lowered them (PR 29: this file's `_dense_programs`, run in a checkout of that parent)
+# groups lowered them (PR 29: this file's `_dense_programs`, run in a checkout of that parent). PR 30
+# re-pinned the 8 `decode_chunk_paged` entries, whose text changes by design (the pool stored flat and
+# read whole at a layer's index); the 32 contiguous programs are PR 29's parent's, byte for byte.
 
 
 def _dense_programs(name, quant):
@@ -372,8 +375,8 @@ def _dense_programs(name, quant):
     if quant:
         params = quantize_params(params, cfg.dtype)
     L, b, cap, NB, K = cfg.n_layers, 3, 64, 12, 4
-    row = (cfg.n_kv_heads, cfg.head_dim)
-    pool = T.KVCache(k=jnp.zeros((L, NB, BLOCK) + row, cfg.dtype), v=jnp.zeros((L, NB, BLOCK) + row, cfg.dtype),
+    width = cfg.n_kv_heads * cfg.head_dim  # the pool as stored: a row flat
+    pool = T.KVCache(k=jnp.zeros((L, NB, BLOCK, width), cfg.dtype), v=jnp.zeros((L, NB, BLOCK, width), cfg.dtype),
                      length=jnp.zeros((b,), jnp.int32))
     dense = T.init_cache(cfg, b, cap)
     tok, rng = jnp.zeros((b,), jnp.int32), jax.random.PRNGKey(1)
@@ -433,10 +436,13 @@ def test_the_cache_row_is_described_once():
     assert row_shapes(latent) == ((1, 32), (1, 128)) and row_shapes(dense) == ((2, 16), (2, 16))
     kv = CacheManager(latent, 2, 96, 4, paged=True, block=16)
     pool, scales = kv.pool_arrays(jnp)
-    assert pool.k.shape[3:] == (1, 32) and pool.v.shape[3:] == (1, 128) and scales is None
+    # stored as a page is read: a row flat, [L, NB, B, heads * dim], and that is all pool_shapes says
+    assert (pool.k.shape, pool.v.shape) == kv.pool_shapes() and scales is None
+    assert pool.k.shape[2:] == (16, 32) and pool.v.shape[2:] == (16, 128)
     assert kv.row_bytes == 160 * 4 and kv.block_bytes == latent.n_layers * 16 * kv.row_bytes
     assert kv.stats()["row_bytes"] == kv.row_bytes
     qkv = CacheManager(dense, 2, 96, 4, paged=True, block=16)
+    assert qkv.pool_shapes() == ((dense.n_layers, qkv.pool.n_blocks, 16, 2 * 16),) * 2
     assert qkv.row_bytes == 2 * 2 * 16 * 4 and qkv.block_bytes == 2 * dense.n_layers * 16 * 2 * 16 * 4
     with pytest.raises(ValueError, match="int8 KV pool is not supported with latent"):
         CacheManager(latent, 2, 96, 4, paged=True, block=16, kv_int8=True)

@@ -2,6 +2,8 @@
 XLA reference — the test-oracle pattern the reference repo uses for its SQL
 mocks (SURVEY.md §4: seams tested against a stand-in implementation)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -174,17 +176,17 @@ class TestPallasLowersForTpu:
 
         hq = 28 if hkv == 4 else 8 * hkv
         S = jax.ShapeDtypeStruct
-        n_blocks, steps = 40, 8
-        kp = S((n_blocks, block, hkv, d), pool)
-        sc = S((n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
+        layers, n_blocks, steps = 3, 40, 8
+        kp = S((layers, n_blocks, block, hkv * d), pool)  # the stack, as stored
+        sc = S((layers, n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
         buf = S((lanes, steps, hkv, d), jnp.bfloat16)
         self._lower(
-            lambda q, kp, vp, t, kb, vb, n, s, ks, vs: paged_chunk_decode_attention(
-                q, kp, vp, t, kb, vb, n, s, window=window,
+            lambda q, kp, vp, t, kb, vb, n, s, ly, ks, vs: paged_chunk_decode_attention(
+                q, kp, vp, t, kb, vb, n, s, layer=ly, window=window,
                 k_scales=ks, v_scales=vs, use_kernel=True,
             ),
             S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
-            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), sc, sc,
+            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32), sc, sc,
         )
 
 
@@ -226,7 +228,9 @@ def test_paged_decode_compiles_for_the_v5e(
 ):
     """The paged-decode kernel through Mosaic for a v5e, and what the
     benchmark's roofline reader matches it by: the custom call is named
-    paged_decode and its first operand is the 2-D s32 block table."""
+    paged_decode and its first operand is the 2-D s32 block table. Its pool
+    operands are the program's own parameters, the stack as stored: the
+    compiled program holds no array of a layer's pool's size but them."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from gofr_tpu.ops.attention import paged_chunk_decode_attention
@@ -234,8 +238,9 @@ def test_paged_decode_compiles_for_the_v5e(
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
-    kp = S((n_blocks, block, hkv, d), pool)
-    sc = S((n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
+    layers = 28  # nothing is allocated; a few layers' pool XLA would park in VMEM
+    kp = S((layers, n_blocks, block, hkv * d), pool)
+    sc = S((layers, n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
     buf = S((lanes, 8, hkv, d), jnp.bfloat16)
     # a compile for a described chip can be written to the persistent
     # cache but not read back without one: keep it out
@@ -244,13 +249,13 @@ def test_paged_decode_compiles_for_the_v5e(
     compilation_cache.reset_cache()
     try:
         text = jax.jit(
-            lambda q, kp, vp, t, kb, vb, n, s, ks, vs: paged_chunk_decode_attention(
-                q, kp, vp, t, kb, vb, n, s, window=window,
+            lambda q, kp, vp, t, kb, vb, n, s, ly, ks, vs: paged_chunk_decode_attention(
+                q, kp, vp, t, kb, vb, n, s, layer=ly, window=window,
                 k_scales=ks, v_scales=vs, use_kernel=True,
             )
         ).lower(
             S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
-            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), sc, sc,
+            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32), sc, sc,
         ).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
@@ -258,6 +263,70 @@ def test_paged_decode_compiles_for_the_v5e(
     call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
     assert "%paged_decode" in call
     assert f"operand_layout_constraints={{s32[{lanes},{n_tbl}]" in call
+    # the stack reaches the call as the parameter it is (k and v), and no
+    # instruction of the compiled program yields an array as large as one
+    # layer of it: nothing is sliced out or laid out again on the way
+    dt = {jnp.bfloat16: "bf16", jnp.int8: "s8"}[pool]
+    stack = f"{dt}[{layers},{n_blocks},{block},{hkv * d}]"
+    assert call.split("operand_layout_constraints", 1)[1].count(stack) == 2
+    pool_sized = [
+        (op, shape) for op, shape in _hlo_results(text)
+        if math.prod(shape) >= n_blocks * block * hkv * d
+    ]
+    assert sorted(pool_sized) == [("parameter", (layers, n_blocks, block, hkv * d))] * 2
+
+
+def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
+    """The latent kernel at glm-4.7-flash.think-closed's own shapes (13 layers
+    of 5,104 blocks, rows of 512 | 128, 20 heads, 16 lanes, a table of 200):
+    through Mosaic for a v5e, its pool operands the program's parameters as
+    stored, and nothing else in the program as large as a layer of them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from gofr_tpu.ops.attention import mla_paged_chunk_decode_attention
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    layers, n_blocks, block, C, R, lanes, hq, n_tbl = 13, 5104, 16, 512, 128, 16, 20, 200
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            lambda q, cp, rp, t, cb, rb, n, s, ly: mla_paged_chunk_decode_attention(
+                q, cp, rp, t, cb, rb, n, s, scale=0.1, layer=ly, use_kernel=True,
+            )
+        ).lower(
+            S((lanes, 1, hq, C + R), jnp.bfloat16),
+            S((layers, n_blocks, block, C), jnp.bfloat16), S((layers, n_blocks, block, R), jnp.bfloat16),
+            S((lanes, n_tbl), jnp.int32), S((lanes, 8, 1, C), jnp.bfloat16), S((lanes, 8, 1, R), jnp.bfloat16),
+            S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
+    assert "%mla_paged_decode" in call
+    pool_sized = [
+        (op, shape) for op, shape in _hlo_results(text) if math.prod(shape) >= n_blocks * block * R
+    ]
+    assert sorted(pool_sized) == [
+        ("parameter", (layers, n_blocks, block, R)), ("parameter", (layers, n_blocks, block, C)),
+    ]
+
+
+def _hlo_results(text: str):
+    """(operation, result shape) of every instruction in a compiled
+    program's text, a tuple's members each."""
+    import re
+
+    inst = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (?P<type>.*?) (?P<op>[a-z][a-z\-]*)\(")
+    for ln in text.splitlines():
+        m = inst.match(ln)
+        if m:
+            for dims in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]*)\]", m["type"]):
+                yield m["op"], tuple(int(x) for x in dims.split(",") if x)
 
 
 @pytest.mark.parametrize("hkv", [4, 1, 2])  # kv sharded / MQA / replicated
@@ -267,7 +336,7 @@ def test_kernels_under_a_tp_mesh_match_single_device(hkv):
     Interpret mode on the virtual mesh: same values as with no mesh."""
     import numpy as np
 
-    from gofr_tpu.kvcache.paged import quantize_rows
+    from gofr_tpu.kvcache.paged import quantize_rows, stored_rows
     from gofr_tpu.ops.attention import paged_chunk_decode_attention
     from gofr_tpu.parallel import make_mesh
 
@@ -286,19 +355,21 @@ def test_kernels_under_a_tp_mesh_match_single_device(hkv):
 
     rng = np.random.RandomState(0)
     block, n_tbl, n_blocks, steps = 16, 4, 12, 4
-    pk = jnp.asarray(rng.randn(n_blocks, block, hkv, d).astype(np.float32))
-    pv = jnp.asarray(rng.randn(n_blocks, block, hkv, d).astype(np.float32))
+    layers = 2
+    pk = jnp.asarray(rng.randn(layers, n_blocks, block, hkv, d).astype(np.float32))
+    pv = jnp.asarray(rng.randn(layers, n_blocks, block, hkv, d).astype(np.float32))
     tables = jnp.asarray(rng.randint(0, n_blocks, size=(b, n_tbl)).astype(np.int32))
     kb = jnp.asarray(rng.randn(b, steps, hkv, d).astype(np.float32))
     vb = jnp.asarray(rng.randn(b, steps, hkv, d).astype(np.float32))
     lengths, step = jnp.asarray([13, 50], jnp.int32), jnp.asarray(2, jnp.int32)
     (qk, sk), (qv, sv) = quantize_rows(pk), quantize_rows(pv)
     for kp, vp, ks, vs in ((pk, pv, None, None), (qk, qv, sk, sv)):
+        kp, vp = stored_rows(kp), stored_rows(vp)  # the stack as stored
 
         def attend(mesh):
             return jax.jit(
                 lambda q, kp, vp, ks, vs: paged_chunk_decode_attention(
-                    q, kp, vp, tables, kb, vb, lengths, step, k_scales=ks,
+                    q, kp, vp, tables, kb, vb, lengths, step, layer=1, k_scales=ks,
                     v_scales=vs, use_kernel=True, interpret=True, mesh=mesh,
                 )
             )(q[:, :1], kp, vp, ks, vs)

@@ -33,6 +33,7 @@ from gofr_tpu.kvcache.paged import (
     gather_slots,
     quantize_rows,
     scatter_rows,
+    stored_rows,
 )
 from gofr_tpu.llm import GenRequest, LLMEngine
 from gofr_tpu.models import TransformerConfig, generate, init_params
@@ -219,23 +220,25 @@ class TestDeviceHelpers:
     def test_gather_reconstructs_contiguous(self):
         rng = np.random.default_rng(1)
         L, NB, hkv, hd, S, MB = 2, 10, 2, 4, 3, 2
-        pk = jnp.asarray(rng.normal(size=(L, NB, B, hkv, hd)).astype(np.float32))
-        pv = jnp.asarray(rng.normal(size=(L, NB, B, hkv, hd)).astype(np.float32))
+        # the pool as stored: a row flat, head h the columns [h * hd, (h + 1) * hd)
+        pk = jnp.asarray(rng.normal(size=(L, NB, B, hkv * hd)).astype(np.float32))
+        pv = jnp.asarray(rng.normal(size=(L, NB, B, hkv * hd)).astype(np.float32))
         tables = jnp.asarray(rng.integers(0, NB, (S, MB)).astype(np.int32))
         lens = jnp.asarray([3, 8, 0], jnp.int32)
-        c = gather_slots(pk, pv, tables, lens)
+        c = gather_slots(pk, pv, tables, lens, rows=((hkv, hd), (hkv, hd)))
         assert c.k.shape == (L, S, MB * B, hkv, hd)
         t = np.asarray(tables)
         for s in range(S):
             for p in range(MB * B):
                 np.testing.assert_array_equal(
-                    np.asarray(c.k)[:, s, p], np.asarray(pk)[:, t[s, p // B], p % B]
+                    np.asarray(c.k)[:, s, p],
+                    np.asarray(pk)[:, t[s, p // B], p % B].reshape(L, hkv, hd),
                 )
 
     def test_scatter_respects_valid_mask(self):
         L, NB, hkv, hd, S, W = 1, 6, 1, 2, 2, 3
-        pk = jnp.zeros((L, NB, B, hkv, hd))
-        pv = jnp.zeros((L, NB, B, hkv, hd))
+        pk = jnp.zeros((L, NB, B, hkv * hd))
+        pv = jnp.zeros((L, NB, B, hkv * hd))
         tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
         rows = jnp.ones((L, S, W, hkv, hd))
         pos = jnp.asarray([[0, 1, 2], [4, 5, 6]], jnp.int32)
@@ -258,14 +261,18 @@ class TestDeviceHelpers:
         """Device blocks -> host numpy -> device blocks: exact bytes."""
         rng = np.random.default_rng(3)
         L, NB, hkv, hd = 2, 8, 2, 4
-        pk = jnp.asarray(rng.normal(size=(L, NB, B, hkv, hd)).astype(np.float32))
-        pv = jnp.asarray(rng.normal(size=(L, NB, B, hkv, hd)).astype(np.float32))
+        rows = ((hkv, hd), (hkv, hd))
+        pk = jnp.asarray(rng.normal(size=(L, NB, B, hkv * hd)).astype(np.float32))
+        pv = jnp.asarray(rng.normal(size=(L, NB, B, hkv * hd)).astype(np.float32))
         blocks = [5, 2, 7]
-        hk, hv, _ = gather_blocks_host(pk, pv, blocks)
+        hk, hv, _ = gather_blocks_host(pk, pv, blocks, rows=rows)
+        # outside the process a block keeps its heads, whatever the pool stores
+        assert hk.shape == (L, 3, B, hkv, hd)
+        np.testing.assert_array_equal(hk.reshape(L, 3, B, -1), np.asarray(pk)[:, blocks])
         # restore into different block ids on a fresh pool
         dst = jnp.asarray([1, 3, 4], jnp.int32)
-        nk = jnp.zeros_like(pk).at[:, dst].set(jnp.asarray(hk))
-        rk, _, _ = gather_blocks_host(nk, nk, [1, 3, 4])
+        nk = jnp.zeros_like(pk).at[:, dst].set(stored_rows(jnp.asarray(hk)))
+        rk, _, _ = gather_blocks_host(nk, nk, [1, 3, 4], rows=rows)
         np.testing.assert_array_equal(rk, hk)
 
 
@@ -556,64 +563,121 @@ class TestPagedAttentionKernel:
         "mqa": (4, 1, 20, [160, 0, 64]),
     }
 
+    LAYERS = 3  # the kernel takes the WHOLE stack and a layer's index
+
     @staticmethod
     def _inputs(seed, hq, hkv, MB, lengths, *, NB=40, d=16, Bk=8, chunk=4):
+        """q, the pools as a token keeps them [L, NB, Bk, hkv, d] (every
+        layer's rows its own), tables, the chunk's buffers, lengths."""
         rng = np.random.RandomState(seed)
-        b = len(lengths)
+        b, L = len(lengths), TestPagedAttentionKernel.LAYERS
         f = lambda *shape: jnp.asarray(rng.randn(*shape).astype(np.float32))  # noqa: E731
-        q, pk, pv = f(b, 1, hq, d), f(NB, Bk, hkv, d), f(NB, Bk, hkv, d)
+        q, pk, pv = f(b, 1, hq, d), f(L, NB, Bk, hkv, d), f(L, NB, Bk, hkv, d)
         tables = jnp.asarray(rng.randint(0, NB, size=(b, MB)).astype(np.int32))
         kb, vb = f(b, chunk, hkv, d), f(b, chunk, hkv, d)
         return q, pk, pv, tables, kb, vb, jnp.asarray(lengths, jnp.int32)
 
+    @staticmethod
+    def _slab(layer, *arrays):
+        """that layer's slab of each [L, ...] array, as a stack of ONE layer:
+        what the call took before it took the stack"""
+        return tuple(a[layer:layer + 1] for a in arrays)
+
     # 9 ends inside a page; 40 spans pages and, from 128 on, two groups
+    @pytest.mark.parametrize("layer", range(LAYERS))
     @pytest.mark.parametrize("window", [0, 9, 40])
     @pytest.mark.parametrize("shape", sorted(SHAPES))
-    def test_kernel_matches_reference(self, shape, window):
+    def test_kernel_matches_reference(self, shape, window, layer):
+        """The kernel over layer `layer` of the whole stored stack == the
+        gather reference over that layer's slab alone."""
         from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
         q, pk, pv, tables, kb, vb, lengths = self._inputs(0, *self.SHAPES[shape])
+        pk, pv = stored_rows(pk), stored_rows(pv)
         step = jnp.asarray(2, jnp.int32)
         ref = paged_chunk_decode_attention(
-            q, pk, pv, tables, kb, vb, lengths, step,
-            window=window, use_kernel=False,
+            q, *self._slab(layer, pk, pv), tables, kb, vb, lengths, step,
+            layer=0, window=window, use_kernel=False,
         )
         kern = paged_chunk_decode_attention(
             q, pk, pv, tables, kb, vb, lengths, step,
+            layer=jnp.asarray(layer, jnp.int32),
             window=window, use_kernel=True, interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(kern), np.asarray(ref), atol=2e-6
         )
 
+    @pytest.mark.parametrize("layer", range(LAYERS))
     @pytest.mark.parametrize("window", [0, 40])
     @pytest.mark.parametrize("shape", ["one-group", "edges", "group7"])
-    def test_kernel_int8(self, shape, window):
+    def test_kernel_int8(self, shape, window, layer):
         from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
         q, pk, pv, tables, kb, vb, lengths = self._inputs(1, *self.SHAPES[shape])
         qk, sk = quantize_rows(pk)
         qv, sv = quantize_rows(pv)
+        qk, qv = stored_rows(qk), stored_rows(qv)
         step = jnp.asarray(1, jnp.int32)
+        s_k, s_v = self._slab(layer, sk, sv)
         ref = paged_chunk_decode_attention(
-            q, qk, qv, tables, kb, vb, lengths, step, window=window,
-            k_scales=sk, v_scales=sv, use_kernel=False,
+            q, *self._slab(layer, qk, qv), tables, kb, vb, lengths, step,
+            layer=0, window=window, k_scales=s_k, v_scales=s_v, use_kernel=False,
         )
         kern = paged_chunk_decode_attention(
-            q, qk, qv, tables, kb, vb, lengths, step, window=window,
+            q, qk, qv, tables, kb, vb, lengths, step, layer=layer, window=window,
             k_scales=sk, v_scales=sv, use_kernel=True, interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(kern), np.asarray(ref), atol=2e-6
         )
 
+    @pytest.mark.parametrize("layer", range(LAYERS))
+    @pytest.mark.parametrize("pool", ["f32", "int8"])
+    def test_whole_stack_partials_equal_the_slabs(self, pool, layer):
+        """_paged_decode_partials over layer `layer` of the stack == over
+        that layer's slab given alone (o, m, l to the bit: the same pages
+        reach the same arithmetic), with a band that starts past 0, table
+        entries past the band that name no block, and an empty band."""
+        from gofr_tpu.ops.attention import _paged_decode_partials
+
+        hq, hkv, MB, _ = self.SHAPES["edges"]
+        q, pk, pv, tables, _, _, _ = self._inputs(5, hq, hkv, MB, [0] * 4)
+        lo = jnp.asarray([0, 21, 7, 50], jnp.int32)
+        hi = jnp.asarray([13, 150, 7, 129], jnp.int32)  # lane 2: empty
+        live = (np.arange(MB)[None, :] + 1) * 8 > np.asarray(lo)[:, None]
+        live &= np.arange(MB)[None, :] * 8 < np.asarray(hi)[:, None]
+        tables = jnp.where(jnp.asarray(live), tables, 40 + 1000)  # stale: outside the pool
+        scales, slab_scales = {}, {}
+        if pool == "int8":
+            (pk, sk), (pv, sv) = quantize_rows(pk), quantize_rows(pv)
+            scales = dict(k_scales=sk, v_scales=sv)
+            slab_scales = dict(zip(("k_scales", "v_scales"), self._slab(layer, sk, sv)))
+        pk, pv = stored_rows(pk), stored_rows(pv)
+        kw = dict(scale=0.25, interpret=True)
+        whole = _paged_decode_partials(q[:, 0], pk, pv, tables, lo, hi, layer, **kw, **scales)
+        alone = _paged_decode_partials(
+            q[:, 0], *self._slab(layer, pk, pv), tables, lo, hi, 0, **kw, **slab_scales
+        )
+        for w, a in zip(whole, alone):
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(a))
+        o, m, l = (np.asarray(x) for x in whole)
+        assert (l[2] == 0).all() and (o[2] == 0).all()  # the empty band
+        assert (l[[0, 1, 3]] > 0).all() and np.isfinite(o).all()
+        other = _paged_decode_partials(
+            q[:, 0], pk, pv, tables, lo, hi, (layer + 1) % self.LAYERS, **kw, **scales
+        )
+        assert not np.allclose(np.asarray(other[0])[0], o[0])  # another layer's rows
+
+    @pytest.mark.parametrize("layer", range(LAYERS))
     @pytest.mark.parametrize("window", [0, 40])
     @pytest.mark.parametrize("pool", ["f32", "int8"])
-    def test_dead_pages_are_never_read(self, pool, window):
+    def test_dead_pages_are_never_read(self, pool, window, layer):
         """Every pool block that no live table entry names holds NaN (an
-        int8 pool: NaN scales) and every table entry outside a lane's band
-        an id outside the pool: the kernel's output is finite and equals
-        the reference's over the clean pool and table."""
+        int8 pool: NaN scales), in EVERY layer but the one asked for whole,
+        and every table entry outside a lane's band an id outside the pool:
+        the kernel's output is finite and equals the reference's over the
+        clean pool and table."""
         from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
         hq, hkv, MB, lengths = self.SHAPES["edges"]
@@ -629,23 +693,27 @@ class TestPagedAttentionKernel:
         live = (slot * Bk < hi) & ((slot + 1) * Bk > lo)
         dead_blocks = np.setdiff1d(np.arange(NB), tables[live])
         poisoned_tables = np.where(live, tables, rng.choice([-7, NB, NB + 1000], tables.shape))
+        def poison(a):
+            """NaN in the layer's dead blocks and all of every other layer"""
+            return jnp.full_like(a, jnp.nan).at[layer].set(a[layer].at[dead_blocks].set(jnp.nan))
+
         if pool == "int8":
             (pk, sk), (pv, sv) = quantize_rows(pk), quantize_rows(pv)
             clean = dict(k_scales=sk, v_scales=sv)
-            dirty = dict(k_scales=sk.at[dead_blocks].set(jnp.nan),
-                         v_scales=sv.at[dead_blocks].set(jnp.nan))
+            dirty = dict(k_scales=poison(sk), v_scales=poison(sv))
+            pk, pv = stored_rows(pk), stored_rows(pv)
             dirty_k, dirty_v = pk, pv
         else:
             clean = dirty = {}
-            dirty_k = pk.at[dead_blocks].set(jnp.nan)
-            dirty_v = pv.at[dead_blocks].set(jnp.nan)
+            pk, pv = stored_rows(pk), stored_rows(pv)
+            dirty_k, dirty_v = poison(pk), poison(pv)
         ref = paged_chunk_decode_attention(
-            q, pk, pv, jnp.asarray(tables), kb, vb, lens, step,
+            q, pk, pv, jnp.asarray(tables), kb, vb, lens, step, layer=layer,
             window=window, use_kernel=False, **clean,
         )
         kern = paged_chunk_decode_attention(
             q, dirty_k, dirty_v, jnp.asarray(poisoned_tables), kb, vb, lens, step,
-            window=window, use_kernel=True, interpret=True, **dirty,
+            layer=layer, window=window, use_kernel=True, interpret=True, **dirty,
         )
         assert np.isfinite(np.asarray(kern)).all()
         np.testing.assert_allclose(
@@ -683,6 +751,8 @@ class TestPagedAttentionKernel:
             scales = dict(k_scales=sk, v_scales=sv)
         else:
             pk, pv = pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16)
+        pk, pv = stored_rows(pk), stored_rows(pv)
+        layer = n_tbl % self.LAYERS
         assert paged_decode_pages(block, hkv, 128, pk.dtype, n_tbl) * block == (
             256 if pool == "int8" and hkv == 4 else 128
         )
@@ -691,11 +761,11 @@ class TestPagedAttentionKernel:
         # would otherwise round its probabilities to the pool's dtype)
         wide = (lambda a: a) if pool == "int8" else (lambda a: a.astype(jnp.float32))
         ref = paged_chunk_decode_attention(
-            q, wide(pk), wide(pv), tables, kb, vb, lens, step, window=window,
-            use_kernel=False, **scales,
+            q, wide(pk), wide(pv), tables, kb, vb, lens, step, layer=layer,
+            window=window, use_kernel=False, **scales,
         )
         kern = paged_chunk_decode_attention(
-            q, pk, pv, tables, kb, vb, lens, step, window=window,
+            q, pk, pv, tables, kb, vb, lens, step, layer=layer, window=window,
             use_kernel=True, interpret=True, **scales,
         )
         np.testing.assert_allclose(np.asarray(kern), np.asarray(ref), atol=5e-6)
@@ -739,10 +809,14 @@ class TestPagedAttentionKernel:
         try:
             assert eng.stats()["attention"]["decode"].startswith("xla_gather")
             assert "decode_tile" not in eng.stats()["attention"]
+            assert "pool_operand" not in eng.stats()["attention"]
             monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
             paths = eng._attention_paths()
             assert paths["decode"] == "pallas_paged"
             assert eng.kv.table_width == 16 and paths["decode_tile"] == tile
+            # ...and what the kernel's pool operand is: the engine's own array
+            assert paths["pool_operand"] == "whole stack"
+            assert eng.cache.k.shape == eng.kv.pool_shapes()[0] == (1, eng.kv.pool.n_blocks, 16, 256)
         finally:
             eng.close()
 
@@ -765,11 +839,12 @@ class TestPagedAttentionKernel:
         # lay the dense rows out as pool blocks 3,1,5,0 (scrambled)
         Bk = 8
         order = [3, 1, 5, 0]
-        pool_k = jnp.zeros((CFG.n_layers, 8, Bk, CFG.n_kv_heads, CFG.head_dim))
+        rows = ((CFG.n_kv_heads, CFG.head_dim),) * 2
+        pool_k = jnp.zeros((CFG.n_layers, 8, Bk, CFG.n_kv_heads * CFG.head_dim))
         pool_v = jnp.zeros_like(pool_k)
         for j, blk in enumerate(order):
-            pool_k = pool_k.at[:, blk].set(dense.k[:, 0, j * Bk : (j + 1) * Bk])
-            pool_v = pool_v.at[:, blk].set(dense.v[:, 0, j * Bk : (j + 1) * Bk])
+            pool_k = pool_k.at[:, blk].set(stored_rows(dense.k[:, 0, j * Bk : (j + 1) * Bk]))
+            pool_v = pool_v.at[:, blk].set(stored_rows(dense.v[:, 0, j * Bk : (j + 1) * Bk]))
         tables = jnp.asarray([order], jnp.int32)
         pool = KVCache(k=pool_k, v=pool_v, length=dense.length)
         active = jnp.asarray([True])
@@ -782,7 +857,7 @@ class TestPagedAttentionKernel:
             n_steps=4, sample_fn=sample, block=Bk,
             use_kernel=True, interpret=True,
         )
-        view = gather_slots(pool.k, pool.v, tables, pool.length)
+        view = gather_slots(pool.k, pool.v, tables, pool.length, rows=rows)
         toks_d, last_d, _, _ = decode_chunk(
             params, CFG, t0, view, active, temps, rng0,
             n_steps=4, sample_fn=sample,
@@ -790,7 +865,7 @@ class TestPagedAttentionKernel:
         np.testing.assert_array_equal(np.asarray(toks_p), np.asarray(toks_d))
         # merged rows land in the right blocks (positions 12..15 -> block
         # order[1], rows 4..7)
-        view2 = gather_slots(pool2.k, pool2.v, tables, pool2.length)
+        view2 = gather_slots(pool2.k, pool2.v, tables, pool2.length, rows=rows)
         np.testing.assert_allclose(
             np.asarray(view2.k[:, 0, 12:16]),
             np.asarray(
@@ -801,3 +876,80 @@ class TestPagedAttentionKernel:
             ),
             atol=2e-6,
         )
+
+
+# -- the pool reaches its decode kernel as it is stored ----------------------
+
+
+def _walk(jaxpr, origin, inside_steps, found):
+    """Every equation of `jaxpr` and of the programs nested in it. `origin`
+    maps a variable to the top-level input it IS (passed down unchanged: a
+    scan's constants, a jit's arguments); `found` collects the pallas calls
+    (their operands' origins) and, inside the scan over decode steps, the
+    largest result any equation yields."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            found["calls"].append([
+                (tuple(v.aval.shape), None if isinstance(v, Literal) else origin.get(v))
+                for v in eqn.invars
+            ])
+            continue
+        if inside_steps:
+            for v in eqn.outvars:
+                size = int(np.prod(getattr(v.aval, "shape", ())))
+                if size > found["largest"][0]:
+                    found["largest"] = (size, name, tuple(v.aval.shape))
+        subs = [
+            j.jaxpr if isinstance(j, ClosedJaxpr) else j
+            for p in eqn.params.values() for j in (p if isinstance(p, (tuple, list)) else (p,))
+            if isinstance(j, (ClosedJaxpr, Jaxpr))
+        ]
+        straight = {"scan": eqn.params.get("num_consts", 0), "jit": len(eqn.invars),
+                    "pjit": len(eqn.invars)}.get(name, 0)
+        for sub in subs:
+            inner = {
+                iv: origin[ov] for iv, ov in zip(sub.invars[:straight], eqn.invars[:straight])
+                if not isinstance(ov, Literal) and ov in origin
+            }
+            _walk(sub, inner, inside_steps or name == "scan", found)
+
+
+@pytest.mark.parametrize("preset", ["tiny_qwen2", "tiny_latent_moe"])
+def test_the_pool_reaches_its_decode_kernel_as_it_is_stored(preset):
+    """decode_chunk_paged on the kernel path, traced: each pallas call's pool
+    operands have the WHOLE pool's shape and are the program's own inputs
+    (nothing computed them: no slice, no view), and no equation inside the
+    scan over decode steps, the layer scan's body with it, yields an array
+    as large as one layer's pool (NB * B * W elements)."""
+    from gofr_tpu.models.transformer import KVCache, decode_chunk_paged
+
+    cfg = getattr(TransformerConfig, preset)()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    kv = CacheManager(cfg, 2, 96, 4, paged=True, block=16, pool_blocks=256)
+    pool, _ = kv.pool_arrays(jnp)
+    k_shape, v_shape = kv.pool_shapes()
+    b, K = 2, 4
+    args = (
+        params, jnp.zeros((b,), jnp.int32), pool, jnp.zeros((b, kv.table_width), jnp.int32),
+        jnp.ones((b,), bool), jnp.zeros((b,)), jax.random.PRNGKey(1),
+    )
+    jaxpr = jax.make_jaxpr(
+        lambda p, tok, pool, tables, act, temps, rng: decode_chunk_paged(
+            p, cfg, tok, pool, None, tables, act, temps, rng, n_steps=K, block=16,
+            sample_fn=lambda lg, t, k: jnp.argmax(lg, -1), use_kernel=True, interpret=True,
+        )
+    )(*args).jaxpr
+    n_params = len(jax.tree.leaves(params))
+    pool_in = {jaxpr.invars[n_params + 1]: "pool.k", jaxpr.invars[n_params + 2]: "pool.v"}
+    assert [tuple(v.aval.shape) for v in pool_in] == [k_shape, v_shape]
+    found = {"calls": [], "largest": (0, None, None)}
+    _walk(jaxpr, dict(pool_in), False, found)
+    assert found["calls"], "no pallas call was traced"
+    for operands in found["calls"]:
+        pools = [(shape, src) for shape, src in operands if len(shape) == 4 and shape[:3] == k_shape[:3]]
+        assert pools == [(k_shape, "pool.k"), (v_shape, "pool.v")], operands
+    layer_pool = min(int(np.prod(s[1:])) for s in (k_shape, v_shape))
+    assert 0 < found["largest"][0] < layer_pool, found["largest"]
