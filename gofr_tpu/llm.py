@@ -97,6 +97,11 @@ Design (all shapes static; a bounded set of compiled executables):
   decode chunk feeds analytic-FLOPs MFU, tokens/s/chip, and a roofline
   compute-vs-HBM classification (stats()["mfu"], app_llm_mfu gauges;
   docs/advanced-guide/profiling.md).
+- **Programs.** What the scheduler dispatches is built in
+  gofr_tpu.llm_programs: the decode chunk, the unified step and the
+  speculative verify are each written once there, over this engine's KV
+  layout and a plain or grammar sampler, and that module is the one place
+  that knows a program's argument list (LLMEngine._run).
 
 Tensor parallelism: pass mesh + param_specs (or TPU_LLM_TP via
 register_llm) and the engine serves the model across an ICI submesh —
@@ -431,23 +436,6 @@ class PoisonedRequestError(RuntimeError):
     status_code = 500
 
 
-def finite_guard(logits, toks):
-    """Numerical-watchdog sentinel: replace each sampled token whose
-    logits row contains NaN/Inf with ``-1`` — an id no sampler can
-    produce (argmax and top-k indices are >= 0), so the sentinel rides
-    the existing token fetch at zero extra transfer cost and the
-    collector converts it into a replica death instead of streaming
-    garbage with status 200. One cheap on-device reduction per sampled
-    row, trivially amortized against the matmuls that produced the
-    logits. Traced into the engine's jitted programs when
-    ``TPU_LLM_NUMERIC_CHECK`` is on; module-level so tests drive it with
-    hand-built NaN logits."""
-    import jax.numpy as jnp
-
-    ok = jnp.isfinite(logits).all(axis=-1)
-    return jnp.where(ok, toks, jnp.int32(-1))
-
-
 @dataclass(eq=False)  # identity semantics: requests are handles, and the
 # engine's error path collects them in sets (dataclass __eq__ would make
 # them unhashable and value-compared)
@@ -754,8 +742,6 @@ class LLMEngine:
         import jax.numpy as jnp
 
         from .kvcache import CacheManager
-        from .models.transformer import decode_chunk as chunk_fn
-        from .models.transformer import prefill
         from .profiling import default_registry, instrument_jit
         from .profiling import mfu as mfu_mod
         from .utils import enable_compilation_cache
@@ -1300,1109 +1286,40 @@ class LLMEngine:
         self.params = params
         self.device = device
 
-        # -- jitted programs (one dispatch each) --------------------------
-        topk = min(64, cfg.vocab_size)
+        # -- jitted programs (gofr_tpu.llm_programs: one dispatch each) ----
+        # chunk, step and verify are each written once there, over this
+        # engine's cache layout and, per dispatch, the plain or the grammar
+        # sampler. The plain family and the wave path's programs exist from
+        # here on under the names tests and tools reach them by (raw call
+        # signatures: llm_programs.Programs._signature); the grammar family
+        # is built at the first constrained dispatch (_ops).
+        from .llm_programs import Programs
 
-        _numeric_check = self.numeric_check
-
-        def _sample_raw(logits, temps, key):
-            """Greedy for temp==0; temperature sampling restricted to the
-            top-k logits otherwise. Full-vocab categorical would generate
-            batch x vocab Gumbel draws per step (millions of threefry
-            rounds for a 256k vocab) and dominates decode time; top-k keeps
-            the RNG work at batch x 64."""
-            greedy = jnp.argmax(logits, axis=-1)
-            topv, topi = jax.lax.approx_max_k(logits, topk)
-            local = jax.random.categorical(
-                key, topv / jnp.maximum(temps, 1e-4)[:, None], axis=-1
-            )
-            sampled = jnp.take_along_axis(topi, local[:, None], axis=1)[:, 0]
-            return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
-
-        def _sample(logits, temps, key):
-            """_sample_raw plus the numerical watchdog: a row whose logits
-            went NaN/Inf samples the -1 sentinel instead (finite_guard) —
-            the collector converts it to a replica death before anything
-            is emitted."""
-            out = _sample_raw(logits, temps, key)
-            return finite_guard(logits, out) if _numeric_check else out
-
-        # -- grammar-constrained sampling (gofr_tpu.structured;
-        # docs/advanced-guide/structured-decoding.md) ---------------------
-        # gtab [G, Smax, V] int32 is the resident-grammar transition
-        # table (entry < 0 = token not admitted in that state); gid [B]
-        # selects each lane's grammar (-1 = unconstrained) and gstate [B]
-        # its current DFA state. Masking uses a large-negative bias, not
-        # -inf (an all-masked padding row must stay NaN-free), and the
-        # watchdog guard runs on the RAW logits — a grammar mask is not a
-        # numerical fault. Unconstrained lanes take their logits
-        # UNTOUCHED (a jnp.where select, not a +0 bias), which is what
-        # pins mixed-batch token-identity with the unconstrained programs.
-        _G_NEG = jnp.float32(-1e30)
-
-        def _g_rows(gtab, gid, gstate):
-            G, Smax = gtab.shape[0], gtab.shape[1]
-            rows = gtab[
-                jnp.clip(gid, 0, G - 1), jnp.clip(gstate, 0, Smax - 1)
-            ]  # [B, V] next state per token, or < 0
-            on = (gid >= 0) & (gstate >= 0) & (gstate < Smax)
-            return rows, on
-
-        def _g_mask(logits, rows, on):
-            return jnp.where(on[:, None] & (rows < 0), _G_NEG, logits)
-
-        def _g_sample(logits, temps, key, gtab, gid, gstate):
-            """One masked sample + DFA advance for per-lane grammar
-            states: the stateful sampler the constrained program family
-            threads through decode chunks (models.transformer
-            sample_state seam), unified steps, and verify positions."""
-            rows, on = _g_rows(gtab, gid, gstate)
-            out = _sample_raw(_g_mask(logits, rows, on), temps, key)
-            out = finite_guard(logits, out) if _numeric_check else out
-            nxt = jnp.take_along_axis(
-                rows, jnp.clip(out, 0)[:, None], axis=1
-            )[:, 0]
-            return out, jnp.where(on, nxt, gstate)
-
-        # last-token logits ride the prefill programs whenever ANY prefix
-        # index can serve exact hits from them: the contiguous PrefixCache
-        # or the paged radix tree (kvcache.paged)
-        keep_logits = self.kv.prefix is not None or (
-            self.kv.paged and self.kv.share
+        self._programs = Programs(
+            cfg, self.kv, slots=slots, decode_chunk=decode_chunk,
+            chunk_shapes=(self.chunk_shapes if self.chunked else ()),
+            spec_draft=self.spec_draft, mesh=self.mesh,
+            tp_gather=self._tp_gather,
+            kernel=self.attention_paths["decode"] in (
+                "pallas_paged", "pallas_mla_paged",
+            ),
+            numeric_check=self.numeric_check, label=self.label,
+            metrics=metrics,
         )
-
-        def _prefill_op(params, pack, rng):
-            """pack [nb, bucket+2] int32: tokens | lengths | temps-as-bits.
-            One packed host->device transfer per wave: every h2d array
-            costs host-blocking latency regardless of its size, so the
-            engine never ships loose vectors.
-            For windowed configs the dense banded prefill is ring-packed to
-            the rolling slot width; when the prefix cache is on, the last-
-            token logits ride along so hits can re-sample first tokens."""
-            tokens = pack[:, :-2]
-            lengths = pack[:, -2]
-            temps = jax.lax.bitcast_convert_type(pack[:, -1], jnp.float32)
-            last_logits, cache = prefill(
-                params, cfg, tokens, lengths,
-                self.kv.prefill_cache_len(tokens.shape[1]),
-            )
-            cache = self.kv.pack_prefill(cache)
-            rng, sub = jax.random.split(rng)
-            first = _sample(last_logits, temps, sub)
-            return first, cache, (last_logits if keep_logits else None), rng
-
-        def _hit_first(logits, temps, rng):
-            """First token for prefix-cache hits: the stored last-token
-            logits sampled at each request's own temperature — greedy hits
-            reproduce the uncached stream bit-for-bit."""
-            rng, sub = jax.random.split(rng)
-            return _sample(logits, temps, sub), rng
-
-        def _make_chunk_op(K: int):
-            def _chunk_op(params, tokens, cache, active, temps, rng):
-                return chunk_fn(
-                    params, cfg, tokens, cache, active, temps, rng,
-                    n_steps=K, sample_fn=_sample, ring=self.kv.ring,
-                    overlap=self._tp_gather,
-                )
-
-            return instrument_jit(
-                f"llm.decode_chunk{K}", _chunk_op, model=self.label,
-                metrics=metrics, donate_argnums=(2,),
-            )
-
-        M = self.admit_cap
-
-        def _insert_many(slot_cache, new_cache, meta):
-            """Copy new_cache row meta[1][i] into slot meta[0][i] for i < M.
-            Padding entries duplicate entry 0 (idempotent rewrite)."""
-
-            def body(c, xs):
-                si, row = xs
-                k = jax.lax.dynamic_update_slice(
-                    c.k,
-                    jax.lax.dynamic_slice_in_dim(new_cache.k, row, 1, axis=1),
-                    (0, si, 0, 0, 0),
-                )
-                v = jax.lax.dynamic_update_slice(
-                    c.v,
-                    jax.lax.dynamic_slice_in_dim(new_cache.v, row, 1, axis=1),
-                    (0, si, 0, 0, 0),
-                )
-                length = jax.lax.dynamic_update_slice(
-                    c.length,
-                    jax.lax.dynamic_slice_in_dim(new_cache.length, row, 1, axis=0),
-                    (si,),
-                )
-                return c._replace(k=k, v=v, length=length), None
-
-            cache, _ = jax.lax.scan(body, slot_cache, (meta[0], meta[1]))
-            return cache
-
-        def _admit_update(tail, active, temps, first, meta):
-            """Scatter freshly-prefilled first tokens into the on-device
-            chain tail and mark the slots active with their temperatures —
-            admission never forces a host round trip. meta [3, M] int32:
-            slot_idx | rows | temps-as-bits; padding entries repeat index 0
-            (idempotent)."""
-            slot_idx, rows = meta[0], meta[1]
-            req_temps = jax.lax.bitcast_convert_type(meta[2], jnp.float32)
-            tail = tail.at[slot_idx].set(first[rows])
-            active = active.at[slot_idx].set(True)
-            temps = temps.at[slot_idx].set(req_temps)
-            return tail, active, temps
-
-        # Every serving executable goes through the compile observatory:
-        # per-signature compile wall time + cost_analysis into the process
-        # registry (/.well-known/debug/compiles), app_jax_* metrics when a
-        # manager is wired. Dispatch semantics (donation, shardings) are
-        # identical to the bare jax.jit these wrappers replace.
-        self._prefill_op = instrument_jit(
-            "llm.prefill", _prefill_op, model=self.label, metrics=metrics,
+        self._prefill_op = self._programs.prefill_op
+        self._insert_many = self._programs.insert_many
+        self._admit_update = self._programs.admit_update
+        self._hit_first_op = self._programs.hit_first_op
+        self._seed_op = self._programs.seed_op
+        self._chunk_short = self._programs.chunk_short
+        self._chunk_ops, self._step_ops, self._verify_op = (
+            self._programs.family(grammar=False)
         )
-        # Two chunk lengths: the full chunk amortizes dispatch and is
-        # chained eagerly to cover remaining demand (an 8-token completion
-        # costs ~2 RTTs); the short variant (quarter length) only serves
-        # tail ends where even one full chunk overshoots the whole batch's
-        # remaining need (_dispatch).
-        self._chunk_short = max(1, decode_chunk // 4)
-        self._chunk_ops = {decode_chunk: _make_chunk_op(decode_chunk)}
-        if self._chunk_short != decode_chunk:
-            self._chunk_ops[self._chunk_short] = _make_chunk_op(self._chunk_short)
-        self._insert_many = instrument_jit(
-            "llm.insert_many", _insert_many, model=self.label,
-            metrics=metrics, donate_argnums=(0,),
-        )
-        self._admit_update = instrument_jit(
-            "llm.admit_update", _admit_update, model=self.label,
-            metrics=metrics, donate_argnums=(0, 1, 2),
-        )
-        self._hit_first_op = (
-            instrument_jit(
-                "llm.hit_first", _hit_first, model=self.label, metrics=metrics,
-            )
-            if keep_logits else None
-        )
-
-        # -- unified step programs (token-budget scheduler) ---------------
-        # ONE jitted program per chunk shape: gather the prefilling
-        # slots' KV rows, append one chunk per row
-        # (models.transformer.prefill_append), scatter the rows back,
-        # activate rows whose prompt just completed (their first token
-        # sampled from the chunk's last-token logits, merged into the
-        # on-device tail — no host round trip), then, in the SAME
-        # program, advance every active slot one decode chunk. Decode is
-        # ALWAYS fused — rows that finish this step decode immediately
-        # (no extra dispatch for the first chunk), and an all-inactive
-        # decode part costs one bounded masked chunk during cold prefill
-        # ramp only. Executable count: shapes x pow2-widths — it replaces
-        # the monolithic path's buckets x widths prefill family plus its
-        # separate insert/admit programs on the miss path.
-        from .models.transformer import prefill_append
-
-        _slots_oob = slots  # out-of-range slot index: scatters are dropped
-
-        def _make_step_op(shape: int):
-            K = decode_chunk
-
-            def _step(params, cache, tail, active, temps, pack, meta, rng):
-                """pack [nb, shape+3] int32: tokens | cursor | n_new |
-                temp-bits. meta [2, nb] int32: slot (= `slots` for inert
-                padding lanes) | finish flag. One packed h2d per step."""
-                tokens = pack[:, :shape]
-                cursors = pack[:, shape]
-                n_new = pack[:, shape + 1]
-                req_temps = jax.lax.bitcast_convert_type(
-                    pack[:, shape + 2], jnp.float32
-                )
-                slot_idx, finish = meta[0], meta[1]
-                # per-row adapter ids (LoRA engines only — static pytree
-                # check): packed prefill lanes gather their slot's id; the
-                # fused decode below reads the full per-slot vector itself
-                aids_row = (
-                    jnp.take(params["aids"], slot_idx, mode="clip")
-                    if "aids" in params else None
-                )
-                # gather the target slots' resident rows (padding lanes
-                # clip to a real slot but never write back)
-                sub = cache._replace(
-                    k=jnp.take(cache.k, slot_idx, axis=1, mode="clip"),
-                    v=jnp.take(cache.v, slot_idx, axis=1, mode="clip"),
-                    length=cursors,
-                )
-                logits, sub = prefill_append(
-                    params, cfg, tokens, sub, cursors, n_new,
-                    ring=self.kv.ring, aids=aids_row,
-                    mesh=self.mesh,
-                )
-                cache = cache._replace(
-                    k=cache.k.at[:, slot_idx].set(sub.k, mode="drop"),
-                    v=cache.v.at[:, slot_idx].set(sub.v, mode="drop"),
-                    length=cache.length.at[slot_idx].set(
-                        cursors + n_new, mode="drop"
-                    ),
-                )
-                rng, sub_rng = jax.random.split(rng)
-                first = _sample(logits, req_temps, sub_rng)
-                fin_slot = jnp.where(finish == 1, slot_idx, _slots_oob)
-                # Mid-prefill rows must deactivate their slot: the device
-                # flag may still be True from the slot's PREVIOUS occupant
-                # (nothing clears it at finish), and the decode merge
-                # advances length for active slots — on a rolling ring the
-                # stale advance between two appends can wrap past the
-                # capacity slack and overwrite this prompt's in-window
-                # rows. (Writes BEFORE the first chunk are harmless: the
-                # first append resets length, and rows beyond it are
-                # position-masked.) Disjoint from fin_slot — a pack row
-                # either finishes or not.
-                mid_slot = jnp.where(finish == 1, _slots_oob, slot_idx)
-                active = active.at[mid_slot].set(False, mode="drop")
-                tail = tail.at[fin_slot].set(first, mode="drop")
-                active = active.at[fin_slot].set(True, mode="drop")
-                temps = temps.at[fin_slot].set(req_temps, mode="drop")
-                kept = logits if keep_logits else None
-                toks, last, cache, rng = chunk_fn(
-                    params, cfg, tail, cache, active, temps, rng,
-                    n_steps=K, sample_fn=_sample, ring=self.kv.ring,
-                    overlap=self._tp_gather,
-                )
-                return first, kept, toks, last, cache, active, temps, rng
-
-            name = f"llm.step_p{shape}_d{K}"
-            return instrument_jit(
-                name, _step, model=self.label, metrics=metrics,
-                donate_argnums=(1, 2, 3, 4),
-            )
-
-        self._step_ops: dict[int, Any] = {}
-        if self.chunked:
-            for shape in self.chunk_shapes:
-                self._step_ops[shape] = _make_step_op(shape)
-
-        # -- fused speculative verify program (gofr_tpu.spec) -------------
-        # ONE full-batch program in the step family (llm.step_v{W}):
-        # score all W = draft+1 positions of every selected slot's draft
-        # in one write-then-attend forward pass
-        # (models.transformer.verify_chunk), sample each position with
-        # the engine's regular _sample, accept the longest agreeing
-        # prefix ON DEVICE, advance tail/length to the accepted state —
-        # so the device batch state stays chained exactly as decode
-        # chunks leave it, and the host fetch only feeds emission and the
-        # drafter. Rejected rows stay above the rolled-back cursor,
-        # masked until overwritten (ops.chunk_prefill_attention's
-        # rollback contract). Built ONLY when speculation is on: spec-off
-        # engines compile and register nothing new.
         self.drafter = None
-        self._verify_op = None
         if self.speculative:
-            from .models.transformer import verify_chunk as verify_fn
             from .spec import NGramDrafter
 
             self.drafter = NGramDrafter()
-            Kd = self.spec_draft
-            Wv = Kd + 1
-
-            def _verify(params, cache, tail, temps, pack, rng):
-                """pack [S, Kd+2] int32: draft tokens | n_draft | selected.
-                Unselected lanes write nothing (n_in 0 drops every
-                scatter index) and keep their tail/length — the program
-                is safe to run over the full slot batch."""
-                drafts = pack[:, :Kd]
-                n_draft = pack[:, Kd]
-                sel = pack[:, Kd + 1] == 1
-                n_in = jnp.where(sel, n_draft + 1, 0)
-                toks = jnp.concatenate([tail[:, None], drafts], axis=1)
-                logits, new_cache = verify_fn(
-                    params, cfg, toks, cache, cache.length, n_in,
-                    ring=self.kv.ring, aids=params.get("aids"),
-                    mesh=self.mesh,
-                )
-                rng, sub = jax.random.split(rng)
-                keys = jax.random.split(sub, Wv)
-                ys = jnp.stack(
-                    [
-                        _sample(logits[:, j], temps, keys[j])
-                        for j in range(Wv)
-                    ],
-                    axis=1,
-                )  # [S, W] int32
-                # longest-agreeing-prefix acceptance (== Leviathan
-                # rejection sampling for the deterministic drafter:
-                # ys[j] ~ p_j via _sample, so draft j is accepted with
-                # probability p_j(draft) and a rejection emits the
-                # residual-distribution sample)
-                agree = (ys[:, :Kd] == drafts) & (
-                    jnp.arange(Kd, dtype=jnp.int32)[None, :]
-                    < n_draft[:, None]
-                )
-                acc = jnp.cumprod(agree.astype(jnp.int32), axis=1).sum(
-                    axis=1
-                )  # [S] accepted draft tokens
-                bonus = jnp.take_along_axis(ys, acc[:, None], axis=1)[:, 0]
-                new_len = jnp.where(
-                    sel, cache.length + acc + 1, cache.length
-                )
-                cache = new_cache._replace(length=new_len)
-                tail = jnp.where(sel, bonus, tail)
-                return ys, acc, cache, tail, rng
-
-            self._verify_op = instrument_jit(
-                f"llm.step_v{Wv}", _verify, model=self.label,
-                metrics=metrics, donate_argnums=(1, 2),
-            )
-
-        # -- constrained program family (gofr_tpu.structured) -------------
-        # Parallel variants of the chunk/step/verify programs that carry
-        # the grammar machinery: gtab (the resident-grammar transition
-        # table, read-only, retraced when its padded shape grows), gids
-        # (per-slot grammar selector, shipped per dispatch — it only
-        # changes at admission) and gstate (per-slot DFA state,
-        # device-persistent and donated exactly like the chain tail, so
-        # pipelined dispatches chain states without a host round trip).
-        # FACTORIES only — nothing compiles until the first constrained
-        # request admits (a constrained-free engine builds zero new
-        # programs); the paged block below overrides them with the
-        # pool-layout variants.
-        #
-        # MIRROR CONTRACT: each variant copies its plain factory's body
-        # (same gather/scatter, pack/meta unpack, finish bookkeeping)
-        # plus the grammar threading — the same deliberate duplication
-        # the dense/paged pairs already carry, chosen over one factory
-        # branching on every argument list and return tuple. A change to
-        # step packing or scatter semantics in a plain factory MUST be
-        # mirrored here (the cross-layout equality tests in
-        # tests/test_structured.py are the tripwire).
-
-        def _make_chunk_op_c(K: int):
-            def _chunk_c(params, tokens, cache, active, temps, gstate,
-                         gids, rng, gtab):
-                sampler = (
-                    lambda lg, tp, k, st: _g_sample(lg, tp, k, gtab, gids, st)
-                )
-                toks, last, cache, rng, gstate = chunk_fn(
-                    params, cfg, tokens, cache, active, temps, rng,
-                    n_steps=K, sample_fn=sampler, ring=self.kv.ring,
-                    overlap=self._tp_gather, sample_state=gstate,
-                )
-                return toks, last, cache, gstate, rng
-
-            return instrument_jit(
-                f"llm.decode_chunk{K}g", _chunk_c, model=self.label,
-                metrics=metrics, donate_argnums=(2, 5),
-            )
-
-        def _make_step_op_c(shape: int):
-            K = decode_chunk
-
-            def _step_c(params, cache, tail, active, temps, gstate,
-                        pack, meta, gids, rng, gtab):
-                """_step plus grammar threading. meta [4, nb] int32:
-                slot | finish | grammar id | start DFA state — a row
-                whose prompt completes this step samples its FIRST token
-                masked by its start state (0 fresh; the host mirror's
-                state for a preemption/failover continuation) and seeds
-                the slot's device state; the fused decode chunk then
-                advances every lane's state token-by-token."""
-                tokens = pack[:, :shape]
-                cursors = pack[:, shape]
-                n_new = pack[:, shape + 1]
-                req_temps = jax.lax.bitcast_convert_type(
-                    pack[:, shape + 2], jnp.float32
-                )
-                slot_idx, finish = meta[0], meta[1]
-                gid_row, gstart = meta[2], meta[3]
-                aids_row = (
-                    jnp.take(params["aids"], slot_idx, mode="clip")
-                    if "aids" in params else None
-                )
-                sub = cache._replace(
-                    k=jnp.take(cache.k, slot_idx, axis=1, mode="clip"),
-                    v=jnp.take(cache.v, slot_idx, axis=1, mode="clip"),
-                    length=cursors,
-                )
-                logits, sub = prefill_append(
-                    params, cfg, tokens, sub, cursors, n_new,
-                    ring=self.kv.ring, aids=aids_row,
-                    mesh=self.mesh,
-                )
-                cache = cache._replace(
-                    k=cache.k.at[:, slot_idx].set(sub.k, mode="drop"),
-                    v=cache.v.at[:, slot_idx].set(sub.v, mode="drop"),
-                    length=cache.length.at[slot_idx].set(
-                        cursors + n_new, mode="drop"
-                    ),
-                )
-                rng, sub_rng = jax.random.split(rng)
-                rows_g, on_r = _g_rows(gtab, gid_row, gstart)
-                on_r = on_r & (finish == 1)
-                first = _sample_raw(
-                    _g_mask(logits, rows_g, on_r), req_temps, sub_rng
-                )
-                first = finite_guard(logits, first) if _numeric_check else first
-                st1 = jnp.take_along_axis(
-                    rows_g, jnp.clip(first, 0)[:, None], axis=1
-                )[:, 0]
-                fin_slot = jnp.where(finish == 1, slot_idx, _slots_oob)
-                mid_slot = jnp.where(finish == 1, _slots_oob, slot_idx)
-                active = active.at[mid_slot].set(False, mode="drop")
-                tail = tail.at[fin_slot].set(first, mode="drop")
-                active = active.at[fin_slot].set(True, mode="drop")
-                temps = temps.at[fin_slot].set(req_temps, mode="drop")
-                gstate = gstate.at[fin_slot].set(
-                    jnp.where(on_r, st1, 0), mode="drop"
-                )
-                kept = logits if keep_logits else None
-                sampler = (
-                    lambda lg, tp, k, st: _g_sample(lg, tp, k, gtab, gids, st)
-                )
-                toks, last, cache, rng, gstate = chunk_fn(
-                    params, cfg, tail, cache, active, temps, rng,
-                    n_steps=K, sample_fn=sampler, ring=self.kv.ring,
-                    overlap=self._tp_gather, sample_state=gstate,
-                )
-                return (
-                    first, kept, toks, last, cache, active, temps, gstate, rng
-                )
-
-            return instrument_jit(
-                f"llm.step_p{shape}_d{K}g", _step_c, model=self.label,
-                metrics=metrics, donate_argnums=(1, 2, 3, 4, 5),
-            )
-
-        def _make_verify_op_c():
-            from .models.transformer import verify_chunk as verify_fn_c
-
-            Kd = self.spec_draft
-            Wv = Kd + 1
-
-            def _verify_c(params, cache, tail, temps, gstate, pack, gids,
-                          rng, gtab):
-                """Verify with per-position grammar masks: position j's
-                context is tail + draft[:j], so its mask derives from the
-                state reached by advancing the slot state through the
-                DRAFT tokens (known at trace time — a tiny unrolled
-                chain). An inadmissible draft token sends the chain state
-                dead, but the masked sample at its own position is
-                guaranteed to disagree with it, so acceptance always
-                stops before a dead state can matter; the post-accept
-                state advances from the accepted prefix's state by the
-                bonus token."""
-                drafts = pack[:, :Kd]
-                n_draft = pack[:, Kd]
-                sel = pack[:, Kd + 1] == 1
-                n_in = jnp.where(sel, n_draft + 1, 0)
-                toks = jnp.concatenate([tail[:, None], drafts], axis=1)
-                logits, new_cache = verify_fn_c(
-                    params, cfg, toks, cache, cache.length, n_in,
-                    ring=self.kv.ring, aids=params.get("aids"),
-                    mesh=self.mesh,
-                )
-                rng, sub = jax.random.split(rng)
-                keys = jax.random.split(sub, Wv)
-                s = gstate
-                states = [s]
-                ys_list = []
-                for j in range(Wv):
-                    rows, on = _g_rows(gtab, gids, s)
-                    yj = _sample_raw(
-                        _g_mask(logits[:, j], rows, on), temps, keys[j]
-                    )
-                    yj = (
-                        finite_guard(logits[:, j], yj)
-                        if _numeric_check else yj
-                    )
-                    ys_list.append(yj)
-                    if j < Kd:
-                        nxt = jnp.take_along_axis(
-                            rows, jnp.clip(drafts[:, j], 0)[:, None], axis=1
-                        )[:, 0]
-                        s = jnp.where(on, nxt, s)
-                        states.append(s)
-                ys = jnp.stack(ys_list, axis=1)  # [S, W] int32
-                agree = (ys[:, :Kd] == drafts) & (
-                    jnp.arange(Kd, dtype=jnp.int32)[None, :]
-                    < n_draft[:, None]
-                )
-                acc = jnp.cumprod(agree.astype(jnp.int32), axis=1).sum(axis=1)
-                bonus = jnp.take_along_axis(ys, acc[:, None], axis=1)[:, 0]
-                st_stack = jnp.stack(states, axis=1)  # [S, Wv]
-                st_acc = jnp.take_along_axis(
-                    st_stack, acc[:, None], axis=1
-                )[:, 0]
-                rows_a, on_a = _g_rows(gtab, gids, st_acc)
-                nxt_a = jnp.take_along_axis(
-                    rows_a, jnp.clip(bonus, 0)[:, None], axis=1
-                )[:, 0]
-                gstate = jnp.where(sel & on_a, nxt_a, gstate)
-                new_len = jnp.where(sel, cache.length + acc + 1, cache.length)
-                cache = new_cache._replace(length=new_len)
-                tail = jnp.where(sel, bonus, tail)
-                return ys, acc, cache, tail, gstate, rng
-
-            return instrument_jit(
-                f"llm.step_v{Wv}g", _verify_c, model=self.label,
-                metrics=metrics, donate_argnums=(1, 2, 4),
-            )
-
-        self._mk_chunk_c = _make_chunk_op_c
-        self._mk_step_c = _make_step_op_c
-        self._mk_verify_c = _make_verify_op_c
-
-        # -- paged-pool program family (kvcache.paged; docs/advanced-guide/
-        # kv-cache.md). Same scheduler contracts as the contiguous family
-        # above, but the slot KV lives in ONE block pool read/written
-        # through per-slot block tables: decode attention goes through
-        # ops.paged_chunk_decode_attention (Pallas paged kernel on TPU,
-        # dense-gather fallback elsewhere), appends/verifies gather the
-        # dense per-slot view at the program boundary and scatter exactly
-        # the rows they wrote back through the table (write indices from
-        # DEVICE lengths — rollback/pipeline safe). A host `live` mask
-        # rides every decode-bearing program: the contiguous path could
-        # afford clamped garbage writes for stale-active lanes, but a
-        # paged stale lane's table may point at blocks that now belong to
-        # someone else.
-        if self.kv.paged:
-            from .kvcache.paged import (
-                copy_blocks, gather_slots, scatter_rows, stored_rows,
-            )
-            from .models.transformer import decode_chunk_paged
-
-            Bp = self.kv.block
-            _cap = self.kv.capacity
-            _int8 = self.kv.int8
-            _latent = bool(getattr(cfg, "latent", False))
-            _use_kernel = self.attention_paths["decode"] in (
-                "pallas_paged", "pallas_mla_paged",
-            )
-            # a latent cache has no contiguous decode chunk to fall back to:
-            # decode_chunk_paged gathers through the table itself off the TPU
-            _paged_fn = _use_kernel or _latent
-            _moe = int(getattr(cfg, "n_experts", 0) or 0) > 0
-
-            def _with_moe(out: tuple, moe: list) -> tuple:
-                """A routed model's program returns what its experts did
-                beside its results: ONE int32 vector [pairs, touched, rows
-                per expert...] summed over its layer calls. A dense model's
-                programs return what they always did."""
-                return out + (sum(moe),) if _moe else out
-
-            def _sc(scales):
-                return scales if _int8 else None
-
-            def _gather_view(cache, scales, tables, lengths):
-                sc = scales if _int8 else None
-                return gather_slots(
-                    cache.k, cache.v, tables, lengths, rows=self.kv.row_shapes,
-                    scales=(None if sc is None else (sc[0], sc[1])),
-                    dtype=cfg.dtype,
-                )
-
-            def _pool_scatter(cache, scales, tables, rows_k, rows_v, pos, valid):
-                k2, v2, sc2 = scatter_rows(
-                    cache.k, cache.v, tables, rows_k, rows_v, pos, valid,
-                    scales=_sc(scales),
-                )
-                return cache._replace(k=k2, v=v2), (sc2 if _int8 else scales)
-
-            def _rows_at(stack, pos):
-                """[L, S, C, hkv, hd] rows at per-slot positions [S, W]."""
-                idx = jnp.clip(pos, 0, stack.shape[2] - 1)
-                return jnp.take_along_axis(
-                    stack, idx[None, :, :, None, None], axis=2
-                )
-
-            def _make_paged_chunk_op(K: int):
-                def _chunk(params, tail, cache, scales, tables, live, active, temps, rng):
-                    eff = jnp.logical_and(active, live)
-                    moe: list = []
-                    if _paged_fn:
-                        toks, last, cache, sc_out, rng = decode_chunk_paged(
-                            params, cfg, tail, cache, (scales if _int8 else None),
-                            tables, eff, temps, rng,
-                            n_steps=K, sample_fn=_sample, block=Bp,
-                            use_kernel=_use_kernel,
-                            overlap=self._tp_gather, mesh=self.mesh, moe_out=moe,
-                        )
-                        return _with_moe((toks, last, cache, (
-                            sc_out if _int8 else scales
-                        ), rng), moe)
-                    dense = _gather_view(cache, scales, tables, cache.length)
-                    toks, last, nd, rng = chunk_fn(
-                        params, cfg, tail, dense, eff, temps, rng,
-                        n_steps=K, sample_fn=_sample, ring=0,
-                        overlap=self._tp_gather, moe_out=moe,
-                    )
-                    pos = cache.length[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]
-                    valid = eff[:, None] & (pos < _cap)
-                    cache, scales = _pool_scatter(
-                        cache, scales, tables,
-                        _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
-                    )
-                    return _with_moe(
-                        (toks, last, cache._replace(length=nd.length), scales, rng), moe
-                    )
-
-                return instrument_jit(
-                    f"llm.decode_chunk{K}", _chunk, model=self.label,
-                    metrics=metrics,
-                    donate_argnums=((2, 3) if _int8 else (2,)),
-                )
-
-            self._chunk_ops = {decode_chunk: _make_paged_chunk_op(decode_chunk)}
-            if self._chunk_short != decode_chunk:
-                self._chunk_ops[self._chunk_short] = _make_paged_chunk_op(
-                    self._chunk_short
-                )
-
-            def _insert_paged(cache, scales, new_cache, meta, tables):
-                """Wave-admission insert: scatter each prefilled row's
-                valid prefix through its slot's block table and set the
-                device lengths. meta [2, M]: slot | row (pads repeat
-                entry 0 — duplicate writes carry identical values)."""
-                slot_idx, rowsel = meta[0], meta[1]
-                tsub = jnp.take(
-                    tables, jnp.clip(slot_idx, 0, slots - 1), axis=0
-                )  # [M, MB]
-                nk = jnp.take(new_cache.k, rowsel, axis=1)  # [L, M, W, ...]
-                nv = jnp.take(new_cache.v, rowsel, axis=1)
-                lens = jnp.take(new_cache.length, rowsel, axis=0)  # [M]
-                W = nk.shape[2]
-                pos = jnp.broadcast_to(
-                    jnp.arange(W, dtype=jnp.int32)[None, :],
-                    (slot_idx.shape[0], W),
-                )
-                valid = pos < jnp.minimum(lens, _cap)[:, None]
-                cache, scales = _pool_scatter(
-                    cache, scales, tsub, nk, nv, pos, valid
-                )
-                length = cache.length.at[slot_idx].set(lens, mode="drop")
-                return cache._replace(length=length), scales
-
-            self._insert_paged_op = instrument_jit(
-                "llm.insert_many", _insert_paged, model=self.label,
-                metrics=metrics, donate_argnums=((0, 1) if _int8 else (0,)),
-            )
-
-            def _seed(cache, scales, srcs, dsts, slot_idx, seed_lens):
-                """Exact-hit/session seeding: block-copy partial tails
-                (srcs -> dsts; pad lanes dst >= NB are dropped) and set
-                device lengths (pad lanes slot >= slots are dropped)."""
-                k2, v2, sc2 = copy_blocks(
-                    cache.k, cache.v, srcs, dsts, scales=_sc(scales)
-                )
-                length = cache.length.at[slot_idx].set(seed_lens, mode="drop")
-                return (
-                    cache._replace(k=k2, v=v2, length=length),
-                    (sc2 if _int8 else scales),
-                )
-
-            self._seed_op = instrument_jit(
-                "llm.kv_seed", _seed, model=self.label, metrics=metrics,
-                donate_argnums=((0, 1) if _int8 else (0,)),
-            )
-
-            def _restore(cache, scales, hk, hv, hs, dsts):
-                """Session restore: host-fetched blocks [L, n, B, h, d] land
-                back in the pool at freshly-allocated ids (byte-identical
-                h2d), their rows flattened to the stored width."""
-                k2 = cache.k.at[:, dsts].set(stored_rows(hk), mode="drop")
-                v2 = cache.v.at[:, dsts].set(stored_rows(hv), mode="drop")
-                if _int8:
-                    scales = scales.at[:, :, dsts].set(hs, mode="drop")
-                return cache._replace(k=k2, v=v2), scales
-
-            self._restore_base = _restore
-            self._restore_ops: dict[int, Any] = {}
-
-            def _make_paged_step_op(shape: int):
-                K = decode_chunk
-
-                def _step(params, cache, scales, tables, live, tail, active,
-                          temps, pack, meta, rng):
-                    tokens = pack[:, :shape]
-                    cursors = pack[:, shape]
-                    n_new = pack[:, shape + 1]
-                    req_temps = jax.lax.bitcast_convert_type(
-                        pack[:, shape + 2], jnp.float32
-                    )
-                    slot_idx, finish = meta[0], meta[1]
-                    aids_row = (
-                        jnp.take(params["aids"], slot_idx, mode="clip")
-                        if "aids" in params else None
-                    )
-                    tsub = jnp.take(
-                        tables, jnp.clip(slot_idx, 0, slots - 1), axis=0
-                    )
-                    sub = _gather_view(cache, scales, tsub, cursors)
-                    moe: list = []
-                    logits, sub2 = prefill_append(
-                        params, cfg, tokens, sub, cursors, n_new, ring=0,
-                        aids=aids_row, mesh=self.mesh, moe_out=moe,
-                    )
-                    c = shape
-                    pos_a = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-                    valid_a = (
-                        jnp.arange(c, dtype=jnp.int32)[None, :] < n_new[:, None]
-                    ) & (pos_a < _cap)
-                    cache, scales = _pool_scatter(
-                        cache, scales, tsub,
-                        _rows_at(sub2.k, pos_a), _rows_at(sub2.v, pos_a),
-                        pos_a, valid_a,
-                    )
-                    length = cache.length.at[slot_idx].set(
-                        cursors + n_new, mode="drop"
-                    )
-                    cache = cache._replace(length=length)
-                    rng, sub_rng = jax.random.split(rng)
-                    first = _sample(logits, req_temps, sub_rng)
-                    fin_slot = jnp.where(finish == 1, slot_idx, _slots_oob)
-                    mid_slot = jnp.where(finish == 1, _slots_oob, slot_idx)
-                    active = active.at[mid_slot].set(False, mode="drop")
-                    tail = tail.at[fin_slot].set(first, mode="drop")
-                    active = active.at[fin_slot].set(True, mode="drop")
-                    temps = temps.at[fin_slot].set(req_temps, mode="drop")
-                    kept = logits if keep_logits else None
-                    eff = jnp.logical_and(active, live)
-                    if _paged_fn:
-                        toks, last, cache, sc, rng = decode_chunk_paged(
-                            params, cfg, tail, cache, (scales if _int8 else None),
-                            tables, eff, temps, rng,
-                            n_steps=K, sample_fn=_sample, block=Bp,
-                            use_kernel=_use_kernel,
-                            overlap=self._tp_gather, mesh=self.mesh, moe_out=moe,
-                        )
-                        scales = sc if _int8 else scales
-                    else:
-                        dense = _gather_view(cache, scales, tables, cache.length)
-                        toks, last, nd, rng = chunk_fn(
-                            params, cfg, tail, dense, eff, temps, rng,
-                            n_steps=K, sample_fn=_sample, ring=0,
-                            overlap=self._tp_gather, moe_out=moe,
-                        )
-                        pos = cache.length[:, None] + jnp.arange(
-                            K, dtype=jnp.int32
-                        )[None, :]
-                        valid = eff[:, None] & (pos < _cap)
-                        cache, scales = _pool_scatter(
-                            cache, scales, tables,
-                            _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
-                        )
-                        cache = cache._replace(length=nd.length)
-                    return _with_moe(
-                        (first, kept, toks, last, cache, scales, active, temps, rng), moe
-                    )
-
-                name = f"llm.step_p{shape}_d{K}"
-                return instrument_jit(
-                    name, _step, model=self.label, metrics=metrics,
-                    donate_argnums=((1, 2, 6, 7) if _int8 else (1, 6, 7)),
-                )
-
-            if self.chunked:
-                self._step_ops = {
-                    shape: _make_paged_step_op(shape)
-                    for shape in self.chunk_shapes
-                }
-
-            if self.speculative:
-                from .models.transformer import verify_chunk as verify_fn
-
-                Kd = self.spec_draft
-                Wv = Kd + 1
-
-                def _verify_paged(params, cache, scales, tables, tail, temps, pack, rng):
-                    drafts = pack[:, :Kd]
-                    n_draft = pack[:, Kd]
-                    sel = pack[:, Kd + 1] == 1
-                    n_in = jnp.where(sel, n_draft + 1, 0)
-                    toks = jnp.concatenate([tail[:, None], drafts], axis=1)
-                    dense = _gather_view(cache, scales, tables, cache.length)
-                    logits, nd = verify_fn(
-                        params, cfg, toks, dense, cache.length, n_in, ring=0,
-                        aids=params.get("aids"), mesh=self.mesh,
-                    )
-                    pos = cache.length[:, None] + jnp.arange(
-                        Wv, dtype=jnp.int32
-                    )[None, :]
-                    valid = (
-                        jnp.arange(Wv, dtype=jnp.int32)[None, :] < n_in[:, None]
-                    ) & (pos < _cap)
-                    cache, scales = _pool_scatter(
-                        cache, scales, tables,
-                        _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
-                    )
-                    rng, sub = jax.random.split(rng)
-                    keys = jax.random.split(sub, Wv)
-                    ys = jnp.stack(
-                        [_sample(logits[:, j], temps, keys[j]) for j in range(Wv)],
-                        axis=1,
-                    )
-                    agree = (ys[:, :Kd] == drafts) & (
-                        jnp.arange(Kd, dtype=jnp.int32)[None, :]
-                        < n_draft[:, None]
-                    )
-                    acc = jnp.cumprod(agree.astype(jnp.int32), axis=1).sum(axis=1)
-                    bonus = jnp.take_along_axis(ys, acc[:, None], axis=1)[:, 0]
-                    new_len = jnp.where(sel, cache.length + acc + 1, cache.length)
-                    cache = cache._replace(length=new_len)
-                    tail = jnp.where(sel, bonus, tail)
-                    return ys, acc, cache, scales, tail, rng
-
-                self._verify_op = instrument_jit(
-                    f"llm.step_v{Wv}", _verify_paged, model=self.label,
-                    metrics=metrics,
-                    donate_argnums=((1, 2, 4) if _int8 else (1, 4)),
-                )
-
-            # constrained variants over the pool layout (same grammar
-            # machinery as the dense factories above; lazily compiled)
-            def _make_paged_chunk_op_c(K: int):
-                def _chunk_c(params, tail, cache, scales, tables, live,
-                             active, temps, gstate, gids, rng, gtab):
-                    eff = jnp.logical_and(active, live)
-                    sampler = (
-                        lambda lg, tp, k, st:
-                        _g_sample(lg, tp, k, gtab, gids, st)
-                    )
-                    if _use_kernel:
-                        toks, last, cache, sc_out, rng, gstate = (
-                            decode_chunk_paged(
-                                params, cfg, tail, cache,
-                                (scales if _int8 else None),
-                                tables, eff, temps, rng,
-                                n_steps=K, sample_fn=sampler, block=Bp,
-                                overlap=self._tp_gather, sample_state=gstate,
-                                mesh=self.mesh,
-                            )
-                        )
-                        return toks, last, cache, (
-                            sc_out if _int8 else scales
-                        ), gstate, rng
-                    dense = _gather_view(cache, scales, tables, cache.length)
-                    toks, last, nd, rng, gstate = chunk_fn(
-                        params, cfg, tail, dense, eff, temps, rng,
-                        n_steps=K, sample_fn=sampler, ring=0,
-                        overlap=self._tp_gather, sample_state=gstate,
-                    )
-                    pos = cache.length[:, None] + jnp.arange(
-                        K, dtype=jnp.int32
-                    )[None, :]
-                    valid = eff[:, None] & (pos < _cap)
-                    cache, scales = _pool_scatter(
-                        cache, scales, tables,
-                        _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
-                    )
-                    return (
-                        toks, last, cache._replace(length=nd.length),
-                        scales, gstate, rng,
-                    )
-
-                return instrument_jit(
-                    f"llm.decode_chunk{K}g", _chunk_c, model=self.label,
-                    metrics=metrics,
-                    donate_argnums=((2, 3, 8) if _int8 else (2, 8)),
-                )
-
-            def _make_paged_step_op_c(shape: int):
-                K = decode_chunk
-
-                def _step_c(params, cache, scales, tables, live, tail,
-                            active, temps, gstate, pack, meta, gids, rng,
-                            gtab):
-                    tokens = pack[:, :shape]
-                    cursors = pack[:, shape]
-                    n_new = pack[:, shape + 1]
-                    req_temps = jax.lax.bitcast_convert_type(
-                        pack[:, shape + 2], jnp.float32
-                    )
-                    slot_idx, finish = meta[0], meta[1]
-                    gid_row, gstart = meta[2], meta[3]
-                    aids_row = (
-                        jnp.take(params["aids"], slot_idx, mode="clip")
-                        if "aids" in params else None
-                    )
-                    tsub = jnp.take(
-                        tables, jnp.clip(slot_idx, 0, slots - 1), axis=0
-                    )
-                    sub = _gather_view(cache, scales, tsub, cursors)
-                    logits, sub2 = prefill_append(
-                        params, cfg, tokens, sub, cursors, n_new, ring=0,
-                        aids=aids_row, mesh=self.mesh,
-                    )
-                    c = shape
-                    pos_a = cursors[:, None] + jnp.arange(
-                        c, dtype=jnp.int32
-                    )[None, :]
-                    valid_a = (
-                        jnp.arange(c, dtype=jnp.int32)[None, :]
-                        < n_new[:, None]
-                    ) & (pos_a < _cap)
-                    cache, scales = _pool_scatter(
-                        cache, scales, tsub,
-                        _rows_at(sub2.k, pos_a), _rows_at(sub2.v, pos_a),
-                        pos_a, valid_a,
-                    )
-                    length = cache.length.at[slot_idx].set(
-                        cursors + n_new, mode="drop"
-                    )
-                    cache = cache._replace(length=length)
-                    rng, sub_rng = jax.random.split(rng)
-                    rows_g, on_r = _g_rows(gtab, gid_row, gstart)
-                    on_r = on_r & (finish == 1)
-                    first = _sample_raw(
-                        _g_mask(logits, rows_g, on_r), req_temps, sub_rng
-                    )
-                    first = (
-                        finite_guard(logits, first)
-                        if _numeric_check else first
-                    )
-                    st1 = jnp.take_along_axis(
-                        rows_g, jnp.clip(first, 0)[:, None], axis=1
-                    )[:, 0]
-                    fin_slot = jnp.where(finish == 1, slot_idx, _slots_oob)
-                    mid_slot = jnp.where(finish == 1, _slots_oob, slot_idx)
-                    active = active.at[mid_slot].set(False, mode="drop")
-                    tail = tail.at[fin_slot].set(first, mode="drop")
-                    active = active.at[fin_slot].set(True, mode="drop")
-                    temps = temps.at[fin_slot].set(req_temps, mode="drop")
-                    gstate = gstate.at[fin_slot].set(
-                        jnp.where(on_r, st1, 0), mode="drop"
-                    )
-                    kept = logits if keep_logits else None
-                    eff = jnp.logical_and(active, live)
-                    sampler = (
-                        lambda lg, tp, k, st:
-                        _g_sample(lg, tp, k, gtab, gids, st)
-                    )
-                    if _use_kernel:
-                        toks, last, cache, sc, rng, gstate = (
-                            decode_chunk_paged(
-                                params, cfg, tail, cache,
-                                (scales if _int8 else None),
-                                tables, eff, temps, rng,
-                                n_steps=K, sample_fn=sampler, block=Bp,
-                                overlap=self._tp_gather, sample_state=gstate,
-                                mesh=self.mesh,
-                            )
-                        )
-                        scales = sc if _int8 else scales
-                    else:
-                        dense = _gather_view(
-                            cache, scales, tables, cache.length
-                        )
-                        toks, last, nd, rng, gstate = chunk_fn(
-                            params, cfg, tail, dense, eff, temps, rng,
-                            n_steps=K, sample_fn=sampler, ring=0,
-                            overlap=self._tp_gather, sample_state=gstate,
-                        )
-                        pos = cache.length[:, None] + jnp.arange(
-                            K, dtype=jnp.int32
-                        )[None, :]
-                        valid = eff[:, None] & (pos < _cap)
-                        cache, scales = _pool_scatter(
-                            cache, scales, tables,
-                            _rows_at(nd.k, pos), _rows_at(nd.v, pos),
-                            pos, valid,
-                        )
-                        cache = cache._replace(length=nd.length)
-                    return (
-                        first, kept, toks, last, cache, scales, active,
-                        temps, gstate, rng,
-                    )
-
-                return instrument_jit(
-                    f"llm.step_p{shape}_d{K}g", _step_c, model=self.label,
-                    metrics=metrics,
-                    donate_argnums=(
-                        (1, 2, 6, 7, 8) if _int8 else (1, 6, 7, 8)
-                    ),
-                )
-
-            def _make_paged_verify_op_c():
-                from .models.transformer import verify_chunk as verify_fn_c
-
-                Kd = self.spec_draft
-                Wv = Kd + 1
-
-                def _verify_c(params, cache, scales, tables, tail, temps,
-                              gstate, pack, gids, rng, gtab):
-                    drafts = pack[:, :Kd]
-                    n_draft = pack[:, Kd]
-                    sel = pack[:, Kd + 1] == 1
-                    n_in = jnp.where(sel, n_draft + 1, 0)
-                    toks = jnp.concatenate([tail[:, None], drafts], axis=1)
-                    dense = _gather_view(cache, scales, tables, cache.length)
-                    logits, nd = verify_fn_c(
-                        params, cfg, toks, dense, cache.length, n_in, ring=0,
-                        aids=params.get("aids"), mesh=self.mesh,
-                    )
-                    pos = cache.length[:, None] + jnp.arange(
-                        Wv, dtype=jnp.int32
-                    )[None, :]
-                    valid = (
-                        jnp.arange(Wv, dtype=jnp.int32)[None, :]
-                        < n_in[:, None]
-                    ) & (pos < _cap)
-                    cache, scales = _pool_scatter(
-                        cache, scales, tables,
-                        _rows_at(nd.k, pos), _rows_at(nd.v, pos), pos, valid,
-                    )
-                    rng, sub = jax.random.split(rng)
-                    keys = jax.random.split(sub, Wv)
-                    s = gstate
-                    states = [s]
-                    ys_list = []
-                    for j in range(Wv):
-                        rows, on = _g_rows(gtab, gids, s)
-                        yj = _sample_raw(
-                            _g_mask(logits[:, j], rows, on), temps, keys[j]
-                        )
-                        yj = (
-                            finite_guard(logits[:, j], yj)
-                            if _numeric_check else yj
-                        )
-                        ys_list.append(yj)
-                        if j < Kd:
-                            nxt = jnp.take_along_axis(
-                                rows, jnp.clip(drafts[:, j], 0)[:, None],
-                                axis=1,
-                            )[:, 0]
-                            s = jnp.where(on, nxt, s)
-                            states.append(s)
-                    ys = jnp.stack(ys_list, axis=1)
-                    agree = (ys[:, :Kd] == drafts) & (
-                        jnp.arange(Kd, dtype=jnp.int32)[None, :]
-                        < n_draft[:, None]
-                    )
-                    acc = jnp.cumprod(
-                        agree.astype(jnp.int32), axis=1
-                    ).sum(axis=1)
-                    bonus = jnp.take_along_axis(ys, acc[:, None], axis=1)[:, 0]
-                    st_stack = jnp.stack(states, axis=1)
-                    st_acc = jnp.take_along_axis(
-                        st_stack, acc[:, None], axis=1
-                    )[:, 0]
-                    rows_a, on_a = _g_rows(gtab, gids, st_acc)
-                    nxt_a = jnp.take_along_axis(
-                        rows_a, jnp.clip(bonus, 0)[:, None], axis=1
-                    )[:, 0]
-                    gstate = jnp.where(sel & on_a, nxt_a, gstate)
-                    new_len = jnp.where(
-                        sel, cache.length + acc + 1, cache.length
-                    )
-                    cache = cache._replace(length=new_len)
-                    tail = jnp.where(sel, bonus, tail)
-                    return ys, acc, cache, scales, tail, gstate, rng
-
-                return instrument_jit(
-                    f"llm.step_v{Wv}g", _verify_c, model=self.label,
-                    metrics=metrics,
-                    donate_argnums=((1, 2, 4, 6) if _int8 else (1, 4, 6)),
-                )
-
-            self._mk_chunk_c = _make_paged_chunk_op_c
-            self._mk_step_c = _make_paged_step_op_c
-            self._mk_verify_c = _make_paged_verify_op_c
         self._rng = jax.random.PRNGKey(0)
 
         if self.kv.paged:
@@ -2487,9 +1404,6 @@ class LLMEngine:
         self.constrained_requests = 0  # lifetime constrained submissions
         self.spec_proposed_c = 0  # spec drafts proposed for constrained lanes
         self.spec_accepted_c = 0  # spec drafts accepted for constrained lanes
-        self._chunk_ops_c: dict[int, Any] = {}  # built on first use
-        self._step_ops_c: dict[int, Any] = {}
-        self._verify_op_c = None
         self.adapter_requests = 0  # lifetime adapter-attributed submissions
         if device is not None:
             (
@@ -3667,22 +2581,35 @@ class LLMEngine:
         with self._lock:
             return self._lora_pool.snapshot()
 
-    def _ensure_c_ops(self) -> None:
-        """Build (and on first dispatch, compile) the constrained program
-        family. Lazy by design: engines that never see a grammar build
+    def _ops(self, use_g: bool) -> tuple:
+        """(chunk programs by K, step programs by shape, the verify program)
+        a dispatch draws from, looked up at every dispatch. The grammar
+        family is lazy by design: engines that never see a grammar build
         nothing, and the first constrained request pays the compile the
         way the monolithic prefill family already does in chunked mode."""
-        if self._chunk_ops_c:
-            return
-        self._chunk_ops_c = {
-            k: self._mk_chunk_c(k) for k in self._chunk_ops
-        }
-        if self.chunked:
-            self._step_ops_c = {
-                s: self._mk_step_c(s) for s in self._step_ops
-            }
-        if self._verify_op is not None:
-            self._verify_op_c = self._mk_verify_c()
+        if use_g:
+            return self._programs.family(grammar=True)
+        return self._chunk_ops, self._step_ops, self._verify_op
+
+    # a program's operand (llm_programs.Programs._signature) -> the attribute
+    # that holds it between dispatches
+    _DEVICE_STATE = (
+        ("params", "params"), ("cache", "cache"), ("scales", "_kv_scales"),
+        ("tail", "_tail"), ("active", "_active"), ("temps", "_temps"),
+        ("gstate", "_gstate"), ("gtab", "_gr_dev"), ("rng", "_rng"),
+    )
+
+    def _run(self, kind: str, use_g: bool, op, **inputs) -> dict:
+        """One program call: the engine's device state and this dispatch's
+        `inputs` in, the state the program hands back adopted, what is left
+        (tokens, first tokens, kept logits, a routed model's expert vector)
+        returned by name. Call with the lock held."""
+        env = {name: getattr(self, attr) for name, attr in self._DEVICE_STATE}
+        out = self._programs.call(kind, use_g, op, {**env, **inputs})
+        for name, attr in self._DEVICE_STATE:
+            if name in out:
+                setattr(self, attr, out.pop(name))
+        return out
 
     def load(self) -> int:
         """Cheap routing signal for the replica router: occupants plus
@@ -4066,83 +2993,66 @@ class LLMEngine:
             serializes the step-program compiles; the wave path's
             prefill-family overlap does not apply here and the cost lands
             in warmup_s.)"""
-            cache = self.cache
-            tail = jnp.zeros((self.slots,), jnp.int32)
-            active = jnp.zeros((self.slots,), bool)
-            temps = jnp.zeros((self.slots,), jnp.float32)
+            env = {
+                "params": self.params, "cache": self.cache, "rng": zero_rng,
+                "tail": jnp.zeros((self.slots,), jnp.int32),
+                "active": jnp.zeros((self.slots,), bool),
+                "temps": jnp.zeros((self.slots,), jnp.float32),
+            }
             if self.kv.paged:
-                # paged program family: same chain, pool-layout operands.
-                # Zero tables/live/packs make every write a dropped
-                # scatter — block 0 is never touched, state stays zeros.
-                scales = self._kv_scales
-                tables = jnp.zeros(
+                # pool-layout operands. Zero tables/live/packs make every
+                # write a dropped scatter — block 0 is never touched, state
+                # stays zeros.
+                env["scales"] = self._kv_scales
+                env["tables"] = jnp.zeros(
                     (self.slots, self.kv.table_width), jnp.int32
                 )
-                live = jnp.zeros((self.slots,), bool)
-                M = self.admit_cap
-                oob_b = self.kv.pool.n_blocks
-                for nb in nbs:
-                    scratch = self.kv.init_cache(nb)
-                    cache, scales = self._insert_paged_op(
-                        cache, scales, scratch, meta[:2], tables
+                env["live"] = jnp.zeros((self.slots,), bool)
+
+            def run(kind: str, op, **inputs) -> None:
+                """One program of the chain: what it hands back of the
+                cache and the batch state feeds the next (the rng does
+                not chain: every program warms on zero_rng)."""
+                out = self._programs.call(kind, False, op, {**env, **inputs})
+                env.update((k, v) for k, v in out.items() if k in env and k != "rng")
+
+            M = self.admit_cap
+            for nb in nbs:
+                scratch = self.kv.init_cache(nb)
+                if self.kv.paged:
+                    oob_b = self.kv.pool.n_blocks
+                    env["cache"], env["scales"] = self._insert_many(
+                        env["cache"], env["scales"], scratch, meta[:2], env["tables"]
                     )
-                    cache, scales = self._seed_op(
-                        cache, scales,
+                    env["cache"], env["scales"] = self._seed_op(
+                        env["cache"], env["scales"],
                         jnp.full((M,), oob_b, jnp.int32),
                         jnp.full((M,), oob_b, jnp.int32),
                         jnp.full((M,), self.slots, jnp.int32),
                         jnp.zeros((M,), jnp.int32),
                     )
-                    warm_admit_update(nb)
-                for shape, op in sorted(self._step_ops.items()):
-                    for nb in nbs:
-                        pack = jnp.zeros((nb, shape + 3), jnp.int32)
-                        smeta = jnp.full((2, nb), self.slots, jnp.int32).at[1].set(0)
-                        (_f, _kept, _toks, tail, cache, scales, active, temps,
-                         *_rng_moe) = op(
-                            self.params, cache, scales, tables, live,
-                            tail, active, temps, pack, smeta, zero_rng,
-                        )
-                if self._verify_op is not None:
-                    vpack = jnp.zeros(
-                        (self.slots, self.spec_draft + 2), jnp.int32
-                    )
-                    _ys, _acc, cache, scales, tail, _ = self._verify_op(
-                        self.params, cache, scales, tables, tail, temps,
-                        vpack, zero_rng,
-                    )
-                for op in self._chunk_ops.values():
-                    toks, last, cache, scales, *_rng_moe = op(
-                        self.params, tail, cache, scales, tables, live,
-                        active, temps, zero_rng,
-                    )
-                self._kv_scales = scales
-                return last, cache
-            for nb in nbs:
-                scratch = self.kv.init_cache(nb)
-                cache = self._insert_many(cache, scratch, meta)
+                else:
+                    env["cache"] = self._insert_many(env["cache"], scratch, meta)
                 warm_admit_update(nb)
             for shape, op in sorted(self._step_ops.items()):
                 for nb in nbs:
-                    pack = jnp.zeros((nb, shape + 3), jnp.int32)
-                    smeta = jnp.full((2, nb), self.slots, jnp.int32).at[1].set(0)
-                    _f, _kept, _toks, tail, cache, active, temps, _ = op(
-                        self.params, cache, tail, active, temps,
-                        pack, smeta, zero_rng,
+                    run(
+                        "step", op, pack=jnp.zeros((nb, shape + 3), jnp.int32),
+                        meta=jnp.full((2, nb), self.slots, jnp.int32).at[1].set(0),
                     )
             if self._verify_op is not None:
                 # speculative verify program: one full-batch executable,
                 # chained through the donated cache/tail like the rest.
                 # All-unselected pack: no lane writes, state unchanged.
-                vpack = jnp.zeros((self.slots, self.spec_draft + 2), jnp.int32)
-                _ys, _acc, cache, tail, _ = self._verify_op(
-                    self.params, cache, tail, temps, vpack, zero_rng,
+                run(
+                    "verify", self._verify_op,
+                    pack=jnp.zeros((self.slots, self.spec_draft + 2), jnp.int32),
                 )
             for op in self._chunk_ops.values():
-                toks, last, cache, _ = op(
-                    self.params, tail, cache, active, temps, zero_rng,
-                )
-            return last, cache
+                run("chunk", op)
+            if self.kv.paged:
+                self._kv_scales = env["scales"]
+            return env["tail"], env["cache"]
 
         n_step_tasks = len(self._step_ops) * len(nbs)
         if self.chunked:
@@ -4163,12 +3073,10 @@ class LLMEngine:
         # for device workers another execution holds). Live serving is
         # unaffected — the scheduler is the only thread that executes
         # programs. Real-TPU warms keep the full overlap.
-        workers = (
-            1 if self._sharded and self._jax.default_backend() == "cpu"
-            else n_tasks
-        )
-        with ThreadPoolExecutor(max_workers=workers) as pool:
-            futs = [pool.submit(warm_cache_ops)]
+        sequential = self._sharded and self._jax.default_backend() == "cpu"
+        with ThreadPoolExecutor(max_workers=1 if sequential else n_tasks) as pool:
+            # (a sequential warm queues the chain on the one worker, first)
+            futs = [pool.submit(warm_cache_ops)] if sequential else []
             if not self.chunked:
                 for b in self.prefill_buckets:
                     for nb in nbs:
@@ -4176,8 +3084,12 @@ class LLMEngine:
             if self._hit_first_op is not None:
                 for nb in nbs:
                     futs.append(pool.submit(warm_hit_first, nb))
-            last, cache = futs[0].result()
-            for f in futs[1:]:
+            # The chain runs HERE, on the calling thread, beside the pool's
+            # tasks: on the chip's host a pool worker traces a third slower
+            # and reads the compile cache three times slower than the
+            # thread that built the engine (PERF.md §6, PR 31).
+            last, cache = futs.pop(0).result() if sequential else warm_cache_ops()
+            for f in futs:
                 f.result()
         _ = np.asarray(last)  # sync: the warm chain ran to its end
         # the chain donated self.cache; adopt the output (zeros in, zeros
@@ -5182,16 +4094,7 @@ class LLMEngine:
         jnp = self._jnp
         n = len(ids)
         width = 1 << max(0, n - 1).bit_length()  # pow-2 compile shapes
-        op = self._restore_ops.get(width)
-        if op is None:
-            from .profiling import instrument_jit
-
-            op = instrument_jit(
-                f"llm.kv_restore{width}", self._restore_base,
-                model=self.label, metrics=self.metrics,
-                donate_argnums=((0, 1) if self.kv.int8 else (0,)),
-            )
-            self._restore_ops[width] = op
+        op = self._programs.restore_op(width)
         pad = width - n
 
         def padd(a, axis):
@@ -5611,7 +4514,7 @@ class LLMEngine:
                 meta[:, j] = meta[:, 0]
             md = jnp.asarray(meta)  # ONE packed h2d per wave
             if self.kv.paged:
-                self.cache, self._kv_scales = self._insert_paged_op(
+                self.cache, self._kv_scales = self._insert_many(
                     self.cache, self._kv_scales, new_cache, md[:2],
                     self._tables_device(),
                 )
@@ -5661,24 +4564,23 @@ class LLMEngine:
 
     def _step_open(
         self, span, kind: str, op, t_dispatch: float, t_dispatched: float,
-        *, k: int = 0, lanes: int = 0, rows: tuple = (), moe=(),
+        *, k: int = 0, lanes: int = 0, rows: tuple = (), moe=None,
     ) -> dict:
         """The record of the program just dispatched (STEP_FIELDS): it rides
         the in-flight entry's info and the collector finishes it. `span` is
         the open sched.dispatch span, which learns which program it was
         (None for an admission wave: its programs go out inside sched.admit).
-        Call with the lock held, before the entry is appended. `moe` holds
+        Call with the lock held, before the entry is appended. `moe` is
         the device vector a routed model's program returned beside its
-        tokens (llm._with_moe), or nothing."""
+        tokens (llm_programs.Programs._signature), or None."""
         self._step_seq += 1
         program = getattr(op, "program", "")
         if span is not None:
             span.set(seq=self._step_seq, kind=kind, program=program)
-        moe_dev = moe[0] if moe else None
-        if moe_dev is not None:
-            self._start_fetch(moe_dev)
+        if moe is not None:
+            self._start_fetch(moe)
         return {
-            "moe_dev": moe_dev, "moe_pairs": 0, "moe_touched": 0,
+            "moe_dev": moe, "moe_pairs": 0, "moe_touched": 0,
             "seq": self._step_seq, "kind": kind, "program": program, "k": k,
             "depth": self._decode_depth(), "lanes": lanes, "decode_ctx": (),
             "rows": rows, "t_dispatch": t_dispatch, "t_dispatched": t_dispatched,
@@ -6077,7 +4979,6 @@ class LLMEngine:
         queued chunk fetches. The saturated path is unchanged (full chunks
         either way). `span` is the scheduler's open sched.dispatch span."""
         self._ship_aids()
-        moe_dev: list = []  # a routed model's paged programs return their moe stats last
         with self._work_cv:
             # partial-prefill occupants are resident but NOT decoding:
             # the chunk's tokens for their slots are garbage (device
@@ -6100,10 +5001,11 @@ class LLMEngine:
             # unconstrained neighbors stay token-identical, and the
             # device DFA state chain stays coherent across dispatches
             use_g = self.constrained and self._grammar_live()
+            inputs = {}
             if use_g:
-                self._ensure_c_ops()
-                gids = self._jnp.asarray(self._gids_np())
-            op = (self._chunk_ops_c if use_g else self._chunk_ops)[k]
+                inputs["gids"] = self._jnp.asarray(self._gids_np())
+            chunk_ops, _step_ops, _verify_op = self._ops(use_g)
+            op = chunk_ops[k]
             if self.kv.paged:
                 # allocate blocks ahead of the chunk's cursor advance and
                 # build the host liveness mask. Two exclusions: stale
@@ -6127,44 +5029,15 @@ class LLMEngine:
                     )
                     self.kv.ensure(i, self._kv_hi[i])
                 with engine_span("dispatch.inputs"):
-                    td = self._tables_device()
-                    live_dev = self._jnp.asarray(live)
-                with engine_span("dispatch.call", self._hb_dispatch, kind="chunk"):
-                    if use_g:
-                        (
-                            toks, last, self.cache, self._kv_scales,
-                            self._gstate, self._rng,
-                        ) = op(
-                            self.params, self._tail, self.cache,
-                            self._kv_scales, td, live_dev,
-                            self._active, self._temps, self._gstate,
-                            gids, self._rng, self._gr_dev,
-                        )
-                    else:
-                        (toks, last, self.cache, self._kv_scales, self._rng,
-                         *moe_dev) = op(
-                            self.params, self._tail, self.cache,
-                            self._kv_scales, td, live_dev,
-                            self._active, self._temps, self._rng,
-                        )
-            else:
-                with engine_span("dispatch.call", self._hb_dispatch, kind="chunk"):
-                    if use_g:
-                        toks, last, self.cache, self._gstate, self._rng = op(
-                            self.params, self._tail, self.cache,
-                            self._active, self._temps, self._gstate,
-                            gids, self._rng, self._gr_dev,
-                        )
-                    else:
-                        toks, last, self.cache, self._rng = op(
-                            self.params, self._tail, self.cache,
-                            self._active, self._temps, self._rng,
-                        )
+                    inputs["tables"] = self._tables_device()
+                    inputs["live"] = self._jnp.asarray(live)
+            with engine_span("dispatch.call", self._hb_dispatch, kind="chunk"):
+                out = self._run("chunk", use_g, op, **inputs)
+            toks = out["toks"]
             info = self._step_open(
                 span, "chunk", op, t0, time.perf_counter(), k=k, lanes=active_n,
-                moe=moe_dev,
+                moe=out.get("moe"),
             )
-            self._tail = last
             self._start_fetch(toks)
             self._inflight.append(("chunk", toks, snapshot, k, info))
             self._stat_chunks += 1
@@ -6302,14 +5175,12 @@ class LLMEngine:
                 self._grammar_live()
                 or any(m >= 0 for m in meta[2, : len(rows)])
             )
+            _chunk_ops, step_ops, _verify_op = self._ops(use_g)
+            op = step_ops[shape]
+            inputs = {}
             if use_g:
-                self._ensure_c_ops()
-                op = self._step_ops_c[shape]
-                gids = self._jnp.asarray(self._gids_np())
-            else:
-                op = self._step_ops[shape]
+                inputs["gids"] = self._jnp.asarray(self._gids_np())
             t0 = time.perf_counter()
-            moe_dev: list = []  # see _dispatch
             if self.kv.paged:
                 steps_cov = self._inflight_steps()
                 live = np.zeros((self.slots,), bool)
@@ -6332,49 +5203,15 @@ class LLMEngine:
                         )
                         self.kv.ensure(i, self._kv_hi[i])
             with engine_span("dispatch.inputs"):
-                pack_dev = jnp.asarray(pack)
-                meta_dev = jnp.asarray(meta if use_g else meta[:2])
+                inputs["pack"] = jnp.asarray(pack)
+                inputs["meta"] = jnp.asarray(meta if use_g else meta[:2])
                 if self.kv.paged:
-                    td = self._tables_device()
-                    live_dev = jnp.asarray(live)
+                    inputs["tables"] = self._tables_device()
+                    inputs["live"] = jnp.asarray(live)
             with engine_span("dispatch.call", self._hb_dispatch, kind="step"):
-                if self.kv.paged:
-                    if use_g:
-                        (first_dev, logits_dev, toks_dev, last, cache,
-                         self._kv_scales, active, temps, self._gstate,
-                         rng) = op(
-                            self.params, self.cache, self._kv_scales, td,
-                            live_dev, self._tail, self._active,
-                            self._temps, self._gstate, pack_dev,
-                            meta_dev, gids, self._rng, self._gr_dev,
-                        )
-                    else:
-                        (first_dev, logits_dev, toks_dev, last, cache,
-                         self._kv_scales, active, temps, rng, *moe_dev) = op(
-                            self.params, self.cache, self._kv_scales, td,
-                            live_dev, self._tail, self._active,
-                            self._temps, pack_dev, meta_dev, self._rng,
-                        )
-                elif use_g:
-                    (first_dev, logits_dev, toks_dev, last, cache,
-                     active, temps, self._gstate, rng) = op(
-                        self.params, self.cache, self._tail,
-                        self._active, self._temps, self._gstate,
-                        pack_dev, meta_dev, gids,
-                        self._rng, self._gr_dev,
-                    )
-                else:
-                    (first_dev, logits_dev, toks_dev, last, cache,
-                     active, temps, rng) = op(
-                        self.params, self.cache, self._tail,
-                        self._active, self._temps, pack_dev,
-                        meta_dev, self._rng,
-                    )
+                out = self._run("step", use_g, op, **inputs)
             t_dispatched = time.perf_counter()
-            self._tail = last
-            self.cache, self._active, self._temps, self._rng = (
-                cache, active, temps, rng,
-            )
+            first_dev, logits_dev, toks_dev = out["first"], out["kept"], out["toks"]
             if finishes:
                 self._start_fetch(first_dev)
             self._start_fetch(toks_dev)
@@ -6399,8 +5236,8 @@ class LLMEngine:
                     )
                     self.kv.prefix.put(
                         self.kv.prefix.key_for(r.prompt_tokens),
-                        cache.k[:, slot : slot + 1, :keep_rows],
-                        cache.v[:, slot : slot + 1, :keep_rows],
+                        self.cache.k[:, slot : slot + 1, :keep_rows],
+                        self.cache.v[:, slot : slot + 1, :keep_rows],
                         len(r.prompt_tokens), logits_dev[j : j + 1],
                     )
             # snapshot AFTER the rows loop: rows finishing this step have
@@ -6414,7 +5251,7 @@ class LLMEngine:
             info = {
                 **self._step_open(
                     span, "step", op, t0, t_dispatched,
-                    k=K, lanes=decode_n, rows=tuple(spans), moe=moe_dev,
+                    k=K, lanes=decode_n, rows=tuple(spans), moe=out.get("moe"),
                 ),
                 "shape": shape, "nb": nb,
                 "prefill_tokens": prefill_tokens, "active": active_n,
@@ -6633,9 +5470,9 @@ class LLMEngine:
             gset = {slot for slot, r in sel if r.grammar is not None}
             proposed_c = sum(n_draft[s] for s in gset)
             use_g = self.constrained and self._grammar_live()
+            inputs = {}
             if use_g:
-                self._ensure_c_ops()
-                gids_dev = jnp.asarray(self._gids_np())
+                inputs["gids"] = jnp.asarray(self._gids_np())
             t0 = time.perf_counter()
             if self.kv.paged:
                 # blocks for the verify's transient rows: [length,
@@ -6649,38 +5486,15 @@ class LLMEngine:
                         r._kv_limit or self.kv.capacity,
                     )
                     self.kv.ensure(slot, self._kv_hi[slot])
-            op = self._verify_op_c if use_g else self._verify_op
+            _chunk_ops, _step_ops, op = self._ops(use_g)
             with engine_span("dispatch.inputs"):
-                pack_dev = jnp.asarray(pack)
+                inputs["pack"] = jnp.asarray(pack)
                 if self.kv.paged:
-                    td = self._tables_device()
+                    inputs["tables"] = self._tables_device()
             with engine_span("dispatch.call", self._hb_dispatch, kind="verify"):
-                if self.kv.paged:
-                    if use_g:
-                        (ys, acc, cache, self._kv_scales, tail,
-                         self._gstate, self._rng) = op(
-                            self.params, self.cache, self._kv_scales, td,
-                            self._tail, self._temps, self._gstate,
-                            pack_dev, gids_dev, self._rng, self._gr_dev,
-                        )
-                    else:
-                        ys, acc, cache, self._kv_scales, tail, self._rng = op(
-                            self.params, self.cache, self._kv_scales,
-                            td, self._tail, self._temps, pack_dev, self._rng,
-                        )
-                elif use_g:
-                    ys, acc, cache, tail, self._gstate, self._rng = op(
-                        self.params, self.cache, self._tail,
-                        self._temps, self._gstate,
-                        pack_dev, gids_dev, self._rng, self._gr_dev,
-                    )
-                else:
-                    ys, acc, cache, tail, self._rng = op(
-                        self.params, self.cache, self._tail,
-                        self._temps, pack_dev, self._rng,
-                    )
+                out = self._run("verify", use_g, op, **inputs)
             t_dispatched = time.perf_counter()
-            self.cache, self._tail = cache, tail
+            ys, acc = out["ys"], out["acc"]
             self._start_fetch(ys)
             self._start_fetch(acc)
             step_tokens = W * len(sel)

@@ -294,7 +294,10 @@ class engine_span:
     capture runs) and, where a heartbeat is given, its beat for the step
     watchdog, named ``name`` or ``name:kind``. It writes nowhere else:
     request spans with exporters are :mod:`gofr_tpu.tracing`'s.
-    ``set()`` adds attributes learned inside the span (a program's ``seq``)."""
+    ``set()`` adds attributes learned inside the span (a program's ``seq``).
+    A capture holds the spans that begin inside it: one that is open when it
+    starts (an idle scheduler's sched.admit, waiting for a request) is not
+    in it, whatever ``set()`` gives it later."""
 
     __slots__ = ("_ann", "_hb", "_beat")
     _annotation = None  # jax.profiler.TraceAnnotation, imported at the first span

@@ -25,8 +25,8 @@ from gofr_tpu.llm import (
     LLMEngine,
     PoisonedRequestError,
     ReplicatedLLMEngine,
-    finite_guard,
 )
+from gofr_tpu.llm_programs import finite_guard
 from gofr_tpu.metrics import new_metrics_manager
 from gofr_tpu.models import TransformerConfig, generate, init_params
 from gofr_tpu.resilience import (

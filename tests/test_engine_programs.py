@@ -1,0 +1,168 @@
+"""The engine's device programs, held to the text they lowered to at PR 30.
+
+PR 31 moved program construction out of `LLMEngine.__init__` into
+`gofr_tpu/llm_programs.py` and wrote each of chunk, step and verify once, over
+a layout and a sampler, where twelve mirrored factories stood. The claim is
+that no program changed. Every program of six tiny engines is lowered here
+with the arguments `_warm` passes (`InstrumentedJit.lower(*args).as_text()`)
+and its sha256 compared with `tests/data/engine_programs_pr30.json`, which this
+file's `__main__` wrote on commit 1bef508, before the refactor. Three engines
+are shaped like the benchmark's cells (paged GQA with int8 weights and a
+prefix index; a windowed ring; latent + routed, paged), three cover what
+those leave out (an int8 pool, a slab with a prefix cache and LoRA slots, a
+routed GQA model). The pins retire with PR 29's (ROADMAP D12) at the first PR
+that means to change a program.
+"""
+
+from __future__ import annotations
+
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gofr_tpu.llm import LLMEngine
+from gofr_tpu.models.quant import quantize_params
+from gofr_tpu.models.transformer import TransformerConfig, init_params
+
+_PINS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "engine_programs_pr30.json")
+
+_KW = dict(
+    slots=4, max_seq_len=128, prefill_buckets=(16, 64), decode_chunk=8,
+    speculative=True, warmup=False,
+)
+# engine -> (preset, keywords); the first three are the cells' shapes
+ENGINES = {
+    "qwen2": ("tiny_qwen2", dict(_KW, quantize=True, prefix_cache_mb=1, kv_paged=True)),
+    "mistral": ("tiny_mistral", dict(_KW, quantize=True, kv_paged=False)),
+    "glm": ("tiny_latent_moe", dict(_KW, quantize=True, prefix_cache_mb=1, speculative=False)),
+    "kv8": ("tiny_qwen2", dict(_KW, kv_paged=True, kv_int8=True, session_mb=1)),
+    "slab": ("tiny_llama", dict(_KW, kv_paged=False, prefix_cache_mb=1, lora_slots=2)),
+    "moe": ("tiny_moe", dict(_KW, kv_paged=True)),
+}
+# The ONLY programs whose text may differ from the parent's, and why: the
+# parent's constrained paged copies were not given PR 29's `moe_out`, so a
+# routed model's grammar programs returned no expert vector and
+# stats()["moe"] missed their layer calls. The one body returns it for both
+# samplers. (A dense or latent model's programs carry nothing of the kind.)
+CHANGED = {
+    f"moe.{p}": "a routed model's grammar program now returns the expert vector its plain twin returns"
+    for p in ("chunk2g", "chunk8g", "step_p16_n1g", "step_p16_n4g", "step_p64_n1g", "step_p64_n4g")
+}
+
+
+def _build(name: str) -> LLMEngine:
+    preset, kw = ENGINES[name]
+    cfg = getattr(TransformerConfig, preset)()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if kw.get("quantize"):
+        params = quantize_params(params, cfg.dtype)
+    return LLMEngine(cfg, params, **kw)
+
+
+def programs(eng: LLMEngine) -> dict:
+    """name -> (program, arguments): every jitted program of the engine with
+    the stand-ins `_warm` hands it."""
+    S, M, V = eng.slots, eng.admit_cap, eng.cfg.vocab_size
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    tail, active, temps = i32(S), jnp.zeros((S,), bool), jnp.zeros((S,), jnp.float32)
+    rng, cache, params = eng._rng, eng.cache, eng.params
+    meta = i32(3, M)
+    paged = eng.kv.paged
+    if paged:
+        scales = eng._kv_scales
+        tables, live = i32(S, eng.kv.table_width), jnp.zeros((S,), bool)
+        pool, pool_live = (cache, scales, tables), (cache, scales, tables, live)
+    else:
+        pool = pool_live = (cache,)
+    out = {
+        "prefill_b16_n1": (eng._prefill_op, (params, i32(1, 18), rng)),
+        "admit_update_n1": (eng._admit_update, (tail, active, temps, i32(1), meta)),
+    }
+    if eng._hit_first_op is not None:
+        out["hit_first_n1"] = (
+            eng._hit_first_op, (jnp.zeros((1, V), jnp.float32), jnp.zeros((1,), jnp.float32), rng)
+        )
+    scratch = eng.kv.init_cache(1)
+    if paged:
+        out["insert_many_n1"] = (eng._insert_many, (cache, scales, scratch, meta[:2], tables))
+        out["kv_seed"] = (eng._seed_op, (cache, scales, i32(M), i32(M), i32(M), i32(M)))
+        L, B = cache.k.shape[0], eng.kv.block
+        (hk, dk), (hv, dv) = eng.kv.row_shapes
+        hs = jnp.zeros((2, L, 2, B, hk), jnp.float32) if eng.kv.int8 else jnp.zeros((0,), jnp.float32)
+        out["kv_restore2"] = (eng._programs.restore_op(2), (
+            cache, scales, jnp.zeros((L, 2, B, hk, dk), cache.k.dtype),
+            jnp.zeros((L, 2, B, hv, dv), cache.v.dtype), hs, i32(2),
+        ))
+    else:
+        out["insert_many_n1"] = (eng._insert_many, (cache, scratch, meta))
+    gids, gstate, gtab = i32(S), i32(S), jnp.full((2, 8, V), -1, jnp.int32)
+    families = [("", eng._chunk_ops, eng._step_ops, eng._verify_op)]
+    if eng.constrained:
+        families.append(("g", *eng._ops(True)))
+    for g, chunk_ops, step_ops, verify_op in families:
+        for shape, op in sorted(step_ops.items()):
+            for nb in (1, M):
+                smeta = jnp.full((4 if g else 2, nb), S, jnp.int32).at[1].set(0)
+                state = (tail, active, temps) + ((gstate,) if g else ())
+                state = (*pool_live, *state) if paged else (cache, *state)
+                out[f"step_p{shape}_n{nb}{g}"] = (op, (
+                    params, *state, i32(nb, shape + 3), smeta,
+                    *((gids, rng, gtab) if g else (rng,)),
+                ))
+        if verify_op is not None:
+            out[f"step_v{g}"] = (verify_op, (
+                params, *pool, tail, temps, *((gstate,) if g else ()),
+                i32(S, eng.spec_draft + 2), *((gids, rng, gtab) if g else (rng,)),
+            ))
+        for K, op in sorted(chunk_ops.items()):
+            out[f"chunk{K}{g}"] = (op, (
+                params, tail, *pool_live, active, temps,
+                *((gstate, gids, rng, gtab) if g else (rng,)),
+            ))
+    return out
+
+
+def _sha(op, args) -> str:
+    return hashlib.sha256(op.lower(*args).as_text().encode()).hexdigest()[:16]
+
+
+with open(_PINS) as _f:
+    PINNED = json.load(_f)
+
+_engines: dict = {}
+
+
+def _engine_programs(name: str) -> dict:
+    # one engine a name for the whole file: nothing is compiled or run
+    if name not in _engines:
+        _engines[name] = programs(_build(name))
+    return _engines[name]
+
+
+@pytest.mark.parametrize("key", sorted(PINNED))
+def test_a_program_lowers_to_the_text_it_lowered_to_at_pr30(key):
+    engine, name = key.split(".", 1)
+    op, args = _engine_programs(engine)[name]
+    got = _sha(op, args)
+    if key in CHANGED:
+        assert got != PINNED[key], f"{key} is listed as changed ({CHANGED[key]}) and is not"
+    else:
+        assert got == PINNED[key], key
+
+
+def test_every_program_of_the_six_engines_is_pinned():
+    have = {f"{e}.{p}" for e in ENGINES for p in _engine_programs(e)}
+    assert have == set(PINNED)
+    assert set(CHANGED) <= have
+
+
+if __name__ == "__main__":  # python tests/test_engine_programs.py: writes the pins (PR 30's tree)
+    pins = {f"{e}.{p}": _sha(*oa) for e in ENGINES for p, oa in programs(_build(e)).items()}
+    with open(_PINS, "w") as f:
+        json.dump(dict(sorted(pins.items())), f, indent=1)
+        f.write("\n")
+    print(len(pins), "programs pinned")

@@ -6,6 +6,7 @@ What each is for: docs/advanced-guide/profiling.md, "The step timeline"."""
 
 import glob
 import os
+import threading
 import time
 
 import jax
@@ -208,6 +209,19 @@ def test_a_captured_profile_holds_the_spans_on_the_named_threads(params, tmp_pat
     engine = LLMEngine(CFG, params, slots=4, max_seq_len=128, warmup=False,
                        kv_label="timeline-trace", kv_paged=True, step_token_budget=32,
                        prefill_buckets=(16,))
+    # A capture holds the spans that BEGIN inside it. An idle scheduler waits
+    # for a request inside sched.admit (50 ms a pass), so a capture that
+    # starts meanwhile lacks that span, and a request that arrives before the
+    # pass ends is admitted where the capture cannot see it (`admitted` then
+    # sums to 0 or 1; one run in five here before this wait). The requests go
+    # in once a pass has begun after the capture did.
+    passed, admit = threading.Event(), engine._admit
+
+    def _admit():
+        passed.set()
+        return admit()
+
+    engine._admit = _admit
     try:
         serve(engine, [list(range(1, 20))], 8)  # compiled before the capture
         opts = jax.profiler.ProfileOptions()
@@ -215,6 +229,8 @@ def test_a_captured_profile_holds_the_spans_on_the_named_threads(params, tmp_pat
         opts.host_tracer_level = 1
         jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
         try:
+            passed.clear()
+            assert passed.wait(10)
             serve(engine, [list(range(1, 40)), list(range(3, 30))], 24)
         finally:
             jax.profiler.stop_trace()

@@ -241,6 +241,33 @@ class TestEngineConstrained:
         finally:
             eng.close()
 
+    def test_a_routed_models_grammar_programs_count_their_experts(self, grammar):
+        """tiny_moe, a plain request, then one constrained and one plain together: the
+        grammar programs return the expert vector their plain twins return
+        (the parent's constrained paged copies had not been given moe_out),
+        so stats()["moe"] counts the layer calls of EVERY dispatched program."""
+        cfg = TransformerConfig.tiny_moe(vocab_size=128)
+        eng = LLMEngine(cfg, init_params(jax.random.PRNGKey(0), cfg), slots=2,
+                        max_seq_len=160, warmup=False)
+        try:
+            assert eng.kv.paged
+            assert len(eng.generate([7, 8, 9], max_new_tokens=8)) == 8  # no grammar resident: plain programs
+            held = eng.submit(GenRequest([1, 2, 3], max_new_tokens=100, grammar=grammar))
+            free = eng.submit(GenRequest([4, 5, 6], max_new_tokens=24))
+            _validate(json.loads(_text(held.tokens(timeout=120))), SCHEMA)
+            assert len(free.tokens(timeout=120)) == 24
+            time.sleep(0.2)  # tail chunks of finished requests close their records
+            st = eng.stats()
+            records = [dict(zip(st["step_log"]["fields"], r)) for r in st["step_log"]["records"]]
+            with_grammar = [r for r in records if r["program"].endswith("g")]
+            assert with_grammar and len(with_grammar) < len(records)
+            assert all(r["moe_pairs"] > 0 and r["moe_touched"] > 0 for r in records)
+            assert st["moe"]["layer_calls"] == cfg.n_layers * sum(
+                r["k"] + (r["kind"] == "step") for r in records
+            )
+        finally:
+            eng.close()
+
     def test_sampled_outputs_all_valid(self, params, grammar):
         eng = _engine(params)
         try:
