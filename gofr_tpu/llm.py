@@ -47,8 +47,10 @@ Design (all shapes static; a bounded set of compiled executables):
   resident but not decoding. Each device step packs up to
   TPU_LLM_STEP_TOKEN_BUDGET (default 256) tokens of pending prefill
   chunks COALESCED with the active slots' decode chunk into one jitted
-  unified-step program, so no request ever waits behind more than one
-  bounded step (Sarathi-style chunked prefill + piggybacked decode; the
+  unified-step program (the rows go out alone, llm.step_p{n}_d0, when no
+  slot decodes and no row finishes: nobody would read the chunk), so no
+  request ever waits behind more than one bounded step (Sarathi-style
+  chunked prefill + piggybacked decode; the
   monolithic path held the chip for admit_cap x bucket tokens per wave
   and starved decode — BENCH_r05's 1.46 SLO p99/p50 was that
   head-of-line wait). A prompt whose PREFIX is already in the prefix
@@ -254,6 +256,12 @@ def _register_phase_metrics(metrics) -> None:
                 "app_llm_kv_handoffs_total",
                 "llm disaggregated KV handoffs "
                 "(outcome=ok|miss|fallback)",
+            )
+        if not metrics.has("app_llm_steps_without_decode_total"):
+            metrics.new_counter(
+                "app_llm_steps_without_decode_total",
+                "llm unified steps dispatched without their decode chunk "
+                "(no lane decoding, no prompt row finishing)",
             )
         if not metrics.has("app_llm_step_tokens"):
             metrics.new_histogram(
@@ -1429,6 +1437,7 @@ class LLMEngine:
         self._stat_wave_reqs = 0  # requests admitted via waves
         self._stat_steps = 0  # unified steps dispatched (chunked scheduler)
         self._stat_step_tokens = 0  # tokens packed into unified steps
+        self._stat_steps_d0 = 0  # steps that went out without their decode chunk
         # speculative-decoding telemetry (gofr_tpu.spec)
         self.spec_steps = 0  # verify dispatches
         self.spec_proposed = 0  # draft tokens proposed
@@ -1898,6 +1907,7 @@ class LLMEngine:
                 "scheduler": "chunked" if self.chunked else "wave",
                 "steps": self._stat_steps,
                 "step_tokens": self._stat_step_tokens,
+                "steps_without_decode": self._stat_steps_d0,
                 "step_token_budget": self.step_token_budget,
                 "chunk_shapes": list(self.chunk_shapes),
                 "prefilling": len(self._prefilling),
@@ -2975,6 +2985,7 @@ class LLMEngine:
                 self._rep(jnp.zeros((nb,), jnp.int32)), meta,
             )
 
+        rows_ops = self._programs.rows(grammar=False)  # the steps without their decode chunk
         # every power-of-two admission width (wave sizing in _admit)
         nbs: list[int] = []
         nb = 1
@@ -2985,8 +2996,8 @@ class LLMEngine:
 
         def warm_cache_ops():
             """insert + admit_update at every admission width, the
-            unified-step programs at every (chunk shape, width,
-            piggyback) combination, then the decode chunk — CHAINED
+            unified-step programs at every (chunk shape, width) pair, with
+            and without their decode chunk, then the decode chunk — CHAINED
             through the real slot cache by donation, exactly like live
             serving, so warm's peak memory never holds a second full-size
             cache and no two ops donate the same buffer. (The chain also
@@ -3034,12 +3045,13 @@ class LLMEngine:
                 else:
                     env["cache"] = self._insert_many(env["cache"], scratch, meta)
                 warm_admit_update(nb)
-            for shape, op in sorted(self._step_ops.items()):
-                for nb in nbs:
-                    run(
-                        "step", op, pack=jnp.zeros((nb, shape + 3), jnp.int32),
-                        meta=jnp.full((2, nb), self.slots, jnp.int32).at[1].set(0),
-                    )
+            for kind, ops in (("step", self._step_ops), ("rows", rows_ops)):
+                for shape, op in sorted(ops.items()):
+                    for nb in nbs:
+                        run(
+                            kind, op, pack=jnp.zeros((nb, shape + 3), jnp.int32),
+                            meta=jnp.full((2, nb), self.slots, jnp.int32).at[1].set(0),
+                        )
             if self._verify_op is not None:
                 # speculative verify program: one full-batch executable,
                 # chained through the donated cache/tail like the rest.
@@ -3054,7 +3066,7 @@ class LLMEngine:
                 self._kv_scales = env["scales"]
             return env["tail"], env["cache"]
 
-        n_step_tasks = len(self._step_ops) * len(nbs)
+        n_step_tasks = (len(self._step_ops) + len(rows_ops)) * len(nbs)
         if self.chunked:
             # chunked mode: the monolithic prefill family exists (bench
             # probes and the A/B lever call it) but is compiled lazily —
@@ -5063,12 +5075,15 @@ class LLMEngine:
         decode tokens are charged against step_token_budget first and
         prefill coalescing fills what remains, floored at one chunk — the
         budget bounds the step, it is never a stall gate. Decode rides
-        EVERY step unconditionally: it is exactly the work whose
-        starvation the budget exists to prevent, its per-step cost is one
-        bounded chunk, and rows whose prompt completes this step decode
-        immediately in the same program (an all-inactive decode part is
-        masked work that only occurs during cold prefill ramp). Returns
-        False when every queued prefill row turned out stale
+        every step beside which a lane decodes, whatever the budget: it is
+        exactly the work whose starvation the budget exists to prevent, its
+        per-step cost is one bounded chunk, and rows whose prompt completes
+        this step decode immediately in the same program. A step in which
+        NO lane decodes and NO row finishes goes out without the chunk
+        (llm.step_p{n}_d0, k = 0): every result of it would be masked, and
+        it streams every weight K times — the steady state of long prompts
+        on few lanes, and of a request that arrives at an idle engine.
+        Returns False when every queued prefill row turned out stale
         (reassigned/cancelled). `span` is the scheduler's open
         sched.dispatch span."""
         jnp = self._jnp
@@ -5175,13 +5190,17 @@ class LLMEngine:
                 self._grammar_live()
                 or any(m >= 0 for m in meta[2, : len(rows)])
             )
-            _chunk_ops, step_ops, _verify_op = self._ops(use_g)
-            op = step_ops[shape]
+            # the decode chunk follows the rows unless nobody would read it
+            fused = bool(active_n or finishes)
+            if fused:
+                kind, op = "step", self._ops(use_g)[1][shape]
+            else:
+                kind, op = "rows", self._programs.rows(use_g)[shape]
             inputs = {}
-            if use_g:
+            if use_g and fused:
                 inputs["gids"] = self._jnp.asarray(self._gids_np())
             t0 = time.perf_counter()
-            if self.kv.paged:
+            if self.kv.paged and fused:
                 steps_cov = self._inflight_steps()
                 live = np.zeros((self.slots,), bool)
                 for i, r in enumerate(self._slot_req):
@@ -5207,14 +5226,18 @@ class LLMEngine:
                 inputs["meta"] = jnp.asarray(meta if use_g else meta[:2])
                 if self.kv.paged:
                     inputs["tables"] = self._tables_device()
-                    inputs["live"] = jnp.asarray(live)
+                    if fused:
+                        inputs["live"] = jnp.asarray(live)
             with engine_span("dispatch.call", self._hb_dispatch, kind="step"):
-                out = self._run("step", use_g, op, **inputs)
+                out = self._run(kind, use_g, op, **inputs)
             t_dispatched = time.perf_counter()
-            first_dev, logits_dev, toks_dev = out["first"], out["kept"], out["toks"]
-            if finishes:
+            # (the rows alone return no tokens: their `first` tells the
+            # collector that the program ended)
+            first_dev, logits_dev, toks_dev = out["first"], out["kept"], out.get("toks")
+            if finishes or not fused:
                 self._start_fetch(first_dev)
-            self._start_fetch(toks_dev)
+            if fused:
+                self._start_fetch(toks_dev)
             # retain finished prompts for prefix reuse: contiguous rows
             # sliced from the slot cache AFTER the append (device-ordered
             # before any later mutation) / paged blocks shared in place
@@ -5246,12 +5269,13 @@ class LLMEngine:
                 r if (r is not None and r.prefill_done) else None
                 for r in self._slot_req
             ]
-            decode_n = active_n + len(finishes)
-            step_tokens = prefill_tokens + K * decode_n
+            decode_n = active_n + len(finishes)  # 0 exactly when not fused
+            k = K if fused else 0
+            step_tokens = prefill_tokens + k * decode_n
             info = {
                 **self._step_open(
                     span, "step", op, t0, t_dispatched,
-                    k=K, lanes=decode_n, rows=tuple(spans), moe=out.get("moe"),
+                    k=k, lanes=decode_n, rows=tuple(spans), moe=out.get("moe"),
                 ),
                 "shape": shape, "nb": nb,
                 "prefill_tokens": prefill_tokens, "active": active_n,
@@ -5260,10 +5284,16 @@ class LLMEngine:
                 "row_reqs": [r for r, _n in rows],
             }
             self._inflight.append(
-                ("step", first_dev, finishes, toks_dev, snapshot, K, info)
+                ("step", first_dev, finishes, toks_dev, snapshot, k, info)
             )
             self._stat_steps += 1
             self._stat_step_tokens += step_tokens
+            if not fused:
+                self._stat_steps_d0 += 1
+                if self.metrics is not None:
+                    self.metrics.increment_counter(
+                        "app_llm_steps_without_decode_total", model=self.label
+                    )
             if decode_n:
                 self._stat_chunks += 1
                 self._stat_chunk_steps += K
@@ -5544,7 +5574,9 @@ class LLMEngine:
         spans; both beat for the step watchdog."""
         kind, info = entry[0], entry[-1]
         if kind == "step":
-            arrays = (entry[1] if entry[2] else None, entry[3])
+            # first tokens where a row finished; a step without its decode
+            # chunk (k = 0) has no tokens, and `first` says that it ended
+            arrays = (entry[1] if entry[2] or not entry[5] else None, entry[3])
         elif kind == "verify":
             arrays = entry[1:3]
         else:

@@ -14,9 +14,13 @@ Three program kinds carry decode, and each has ONE body:
   (models.transformer.prefill_append), write the rows back, activate rows
   whose prompt just completed (their first token sampled from the chunk's
   last-token logits and merged into the on-device tail, no host round trip),
-  then, in the SAME program, the decode chunk. Decode is ALWAYS fused: rows
-  that finish this step decode immediately, and an all-inactive decode part
-  costs one bounded masked chunk during cold prefill ramp only.
+  then, in the SAME program, the decode chunk: rows that finish this step
+  decode immediately. The body has one static fact, whether that chunk
+  follows the rows. The scheduler dispatches the **rows** alone
+  (`llm.step_p{n}_d0`: the same body, stopped before the chunk, with no
+  `live` mask to take and no tokens to return) for a step in which no lane
+  decodes and no row finishes its prompt: every result of the chunk would
+  have been masked, and it streams every weight K times.
 - **verify**: speculative decoding's fused verify (gofr_tpu.spec). Score all
   W = draft+1 positions of every selected slot in one write-then-attend pass
   (models.transformer.verify_chunk), sample each position, accept the longest
@@ -48,6 +52,7 @@ body each and live here as they were.
 
 from __future__ import annotations
 
+import functools
 import inspect
 from typing import Any
 
@@ -153,7 +158,7 @@ class _GrammarSampler(_Sampler):
     unconstrained programs."""
 
     suffix = "g"
-    names = {"chunk": "_chunk_c", "step": "_step_c", "verify": "_verify_c"}
+    names = {"chunk": "_chunk_c", "step": "_step_c", "rows": "_step_rows_c", "verify": "_verify_c"}
     state, ids, table = ("gstate",), ("gids",), ("gtab",)
 
     def __init__(self, vocab_size: int, numeric_check: bool):
@@ -263,7 +268,7 @@ class _Slab:
     operands = results = ("cache",)  # what the cache is among a program's operands | results
     live: tuple = ()
     moe = False
-    names = {"chunk": "_chunk_op", "step": "_step", "verify": "_verify"}
+    names = {"chunk": "_chunk_op", "step": "_step", "rows": "_step_rows", "verify": "_verify"}
     # the slab's chunk has always called the tail it decodes from `tokens`:
     # a jitted function's parameter names are the compiled program's
     renamed = {("chunk", "tail"): "tokens"}
@@ -272,7 +277,7 @@ class _Slab:
         self.p = p
 
     def donated(self, kind: str) -> tuple:
-        return ("tail",) if kind == "step" else ()
+        return ("tail",) if kind in ("step", "rows") else ()
 
     def append(self, a, tokens, slot_idx, cursors, n_new, aids_row):
         p, cache = self.p, a["cache"]
@@ -337,7 +342,7 @@ class _Pool:
     operands = ("cache", "scales", "tables")
     results = ("cache", "scales")
     live = ("live",)
-    names = {"chunk": "_chunk", "step": "_step", "verify": "_verify_paged"}
+    names = {"chunk": "_chunk", "step": "_step", "rows": "_step_rows", "verify": "_verify_paged"}
     renamed: dict = {}
 
     def __init__(self, p: "Programs", kernel: bool):
@@ -487,9 +492,10 @@ class Programs:
         self.sample = self._samplers[False]
         self._signatures = {
             (kind, g): self._signature(kind, g)
-            for kind in ("chunk", "step", "verify") for g in (False, True)
+            for kind in ("chunk", "step", "rows", "verify") for g in (False, True)
         }
         self._families: dict = {}
+        self._rows_ops: dict = {}
         self._restore_ops: dict[int, Any] = {}
         self.prefill_op = self._jit("llm.prefill", self._prefill_op)
         self.admit_update = self._jit(
@@ -538,6 +544,18 @@ class Programs:
             )
         return fam
 
+    def rows(self, grammar: bool) -> dict:
+        """The step programs without their decode chunk (`llm.step_p{n}_d0`)
+        of one sampler, by chunk shape: what a step dispatches when no lane
+        decodes and no row finishes. Built at first use, as `family` is."""
+        ops = self._rows_ops.get(grammar)
+        if ops is None:
+            s = self._samplers[grammar]
+            ops = self._rows_ops[grammar] = {
+                shape: self._program("rows", s, shape) for shape in self.chunk_shapes
+            }
+        return ops
+
     def _signature(self, kind: str, grammar: bool) -> tuple[tuple, tuple]:
         """A program's positional operands and its results, by name."""
         lay, s = self.layout, self._samplers[grammar]
@@ -545,10 +563,16 @@ class Programs:
             ins = ("params", "tail", *lay.operands, *lay.live, "active", "temps",
                    *s.state, *s.ids, "rng", *s.table)
             outs = ("toks", "tail", *lay.results, *s.state, "rng")
-        elif kind == "step":
-            ins = ("params", *lay.operands, *lay.live, "tail", "active", "temps",
-                   *s.state, "pack", "meta", *s.ids, "rng", *s.table)
-            outs = ("first", "kept", "toks", "tail", *lay.results, "active", "temps",
+        elif kind in ("step", "rows"):
+            # the rows alone run no decode chunk: nothing reads `live` or the
+            # lanes' grammar ids, and no tokens come back (`first` is what
+            # the collector fetches to know the program ended)
+            live, ids, toks = (
+                (lay.live, s.ids, ("toks",)) if kind == "step" else ((), (), ())
+            )
+            ins = ("params", *lay.operands, *live, "tail", "active", "temps",
+                   *s.state, "pack", "meta", *ids, "rng", *s.table)
+            outs = ("first", "kept", *toks, "tail", *lay.results, "active", "temps",
                     *s.state, "rng")
         else:
             ins = ("params", *lay.operands, "tail", "temps", *s.state, "pack",
@@ -571,13 +595,14 @@ class Programs:
 
     _DONATED = {
         "chunk": ("cache",), "step": ("cache", "active", "temps"),
-        "verify": ("cache", "tail"),
+        "rows": ("cache", "active", "temps"), "verify": ("cache", "tail"),
     }
 
     def _program(self, kind: str, sampler: _Sampler, n: int):
         ins, outs = self._signatures[kind, sampler is not self.sample]
         body = {
             "chunk": self._chunk_body, "step": self._step_body,
+            "rows": functools.partial(self._step_body, decode=False),
             "verify": self._verify_body,
         }[kind]
 
@@ -603,7 +628,7 @@ class Programs:
         K = self.decode_chunk
         name = {
             "chunk": f"llm.decode_chunk{n}", "step": f"llm.step_p{n}_d{K}",
-            "verify": f"llm.step_v{n}",
+            "rows": f"llm.step_p{n}_d0", "verify": f"llm.step_v{n}",
         }[kind] + sampler.suffix
         donated = self._DONATED[kind] + self.layout.donated(kind) + sampler.state
         return self._jit(
@@ -616,10 +641,11 @@ class Programs:
         a["toks"], st = self.layout.decode(a, K, sample_fn, state)
         a.update(zip(sampler.state, st))
 
-    def _step_body(self, a, sampler, shape):
+    def _step_body(self, a, sampler, shape, decode: bool = True):
         """pack [nb, shape+3] int32: tokens | cursor | n_new | temp-bits.
         meta [2, nb] int32 (4 with a grammar): slot (= `slots` for inert
-        padding lanes) | finish flag. One packed h2d per step."""
+        padding lanes) | finish flag. One packed h2d per step. `decode`:
+        the decode chunk follows the rows (not in `llm.step_p{n}_d0`)."""
         params, pack, meta = a["params"], a["pack"], a["meta"]
         tokens = pack[:, :shape]
         cursors = pack[:, shape]
@@ -656,7 +682,8 @@ class Programs:
         a["temps"] = a["temps"].at[fin_slot].set(req_temps, mode="drop")
         seed(a, fin_slot)
         a["kept"] = logits if self.keep_logits else None
-        self._chunk_body(a, sampler, self.decode_chunk)
+        if decode:
+            self._chunk_body(a, sampler, self.decode_chunk)
 
     def _verify_body(self, a, sampler, W):
         """pack [S, Kd+2] int32: draft tokens | n_draft | selected.

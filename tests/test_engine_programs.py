@@ -12,6 +12,12 @@ prefix index; a windowed ring; latent + routed, paged), three cover what
 those leave out (an int8 pool, a slab with a prefix cache and LoRA slots, a
 routed GQA model). The pins retire with PR 29's (ROADMAP D12) at the first PR
 that means to change a program.
+
+PR 32 added a program and changed none: a step without its decode chunk
+(`llm.step_p{n}_d0`, `Programs.rows`). Those of the same six engines are
+pinned in `tests/data/engine_programs_pr32.json` (written on PR 32's tree by
+this file's `__main__`, which leaves PR 30's file alone) and are cases of the
+same test.
 """
 
 from __future__ import annotations
@@ -28,7 +34,9 @@ from gofr_tpu.llm import LLMEngine
 from gofr_tpu.models.quant import quantize_params
 from gofr_tpu.models.transformer import TransformerConfig, init_params
 
-_PINS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "engine_programs_pr30.json")
+_DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+_PINS = os.path.join(_DATA, "engine_programs_pr30.json")
+_PINS_D0 = os.path.join(_DATA, "engine_programs_pr32.json")  # the step programs without their decode chunk
 
 _KW = dict(
     slots=4, max_seq_len=128, prefill_buckets=(16, 64), decode_chunk=8,
@@ -108,10 +116,14 @@ def programs(eng: LLMEngine) -> dict:
             for nb in (1, M):
                 smeta = jnp.full((4 if g else 2, nb), S, jnp.int32).at[1].set(0)
                 state = (tail, active, temps) + ((gstate,) if g else ())
-                state = (*pool_live, *state) if paged else (cache, *state)
                 out[f"step_p{shape}_n{nb}{g}"] = (op, (
-                    params, *state, i32(nb, shape + 3), smeta,
+                    params, *pool_live, *state, i32(nb, shape + 3), smeta,
                     *((gids, rng, gtab) if g else (rng,)),
+                ))
+                # the rows alone: no `live` mask, no lanes' grammar ids
+                out[f"step_p{shape}_n{nb}{g}_d0"] = (eng._programs.rows(bool(g))[shape], (
+                    params, *pool, *state, i32(nb, shape + 3), smeta,
+                    *((rng, gtab) if g else (rng,)),
                 ))
         if verify_op is not None:
             out[f"step_v{g}"] = (verify_op, (
@@ -130,8 +142,10 @@ def _sha(op, args) -> str:
     return hashlib.sha256(op.lower(*args).as_text().encode()).hexdigest()[:16]
 
 
-with open(_PINS) as _f:
-    PINNED = json.load(_f)
+PINNED: dict = {}
+for _path in (_PINS, _PINS_D0):
+    with open(_path) as _f:
+        PINNED.update(json.load(_f))
 
 _engines: dict = {}
 
@@ -160,9 +174,12 @@ def test_every_program_of_the_six_engines_is_pinned():
     assert set(CHANGED) <= have
 
 
-if __name__ == "__main__":  # python tests/test_engine_programs.py: writes the pins (PR 30's tree)
-    pins = {f"{e}.{p}": _sha(*oa) for e in ENGINES for p, oa in programs(_build(e)).items()}
-    with open(_PINS, "w") as f:
+if __name__ == "__main__":  # PYTHONPATH=. python tests/test_engine_programs.py: writes the `_d0` pins (PR 32's tree)
+    pins = {
+        f"{e}.{p}": _sha(*oa) for e in ENGINES for p, oa in programs(_build(e)).items()
+        if p.endswith("_d0")
+    }
+    with open(_PINS_D0, "w") as f:
         json.dump(dict(sorted(pins.items())), f, indent=1)
         f.write("\n")
     print(len(pins), "programs pinned")
