@@ -307,13 +307,36 @@ def paged_default():
     TPU_LLM_KV_PAGED=0 (the contiguous escape hatch / A-B lever).
     "auto" resolves per model in CacheManager: paged for
     global-attention models (same worst-case bytes as the dense slab,
-    plus sharing); the ROLLING ring for sliding-window models where it
-    engages — the paged pool does not yet reclaim blocks behind the
-    attention window, so auto-pagination there would trade the ring's
-    O(window) slot bound for O(max_seq_len). Explicit kv_paged=True
-    opts a windowed model in anyway (sessions/radix over window
-    masks)."""
+    plus sharing) and for a MIXED stack (window and full layers: its
+    window layers live in a pool of their own that gives blocks back
+    behind the window, paged.WindowTables, so a slot holds O(window) of
+    them; the ring cannot hold the full layers at all); the ROLLING ring
+    for a model whose EVERY layer has the one window, where it engages —
+    one pool for all layers reclaims nothing (its block ids are shared by
+    the whole stack and a radix prefix may pin them), so auto-pagination
+    there would trade the ring's O(window) slot bound for
+    O(max_seq_len). Explicit kv_paged=True opts such a model in anyway
+    (sessions/radix over window masks)."""
     return "auto" if os.environ.get("TPU_LLM_KV_PAGED", "1") != "0" else False
+
+
+def mixed_kv_refusal(*, int8: bool, retain_bytes: int, session_bytes: int) -> str | None:
+    """What a mixed stack's two pools are refused, as a sentence, or None:
+    every path that was written for ONE pool of blocks says so here."""
+    if int8:
+        return (
+            "an int8 KV pool is not written for a mixed stack (window and full "
+            "layers): its scales are one array over one pool's blocks"
+        )
+    if retain_bytes > 0 or session_bytes > 0:
+        return (
+            "prefix sharing and sessions (prefix_cache_mb, session_mb) are not "
+            "written for a mixed stack: the radix tree, the spill to the host "
+            "and the hand-off between engines name blocks of ONE pool, and a "
+            "window layer's blocks behind the window are gone; serve with "
+            "prefix_cache_mb=0 and no session tier"
+        )
+    return None
 
 
 def row_shapes(cfg) -> tuple[tuple, tuple]:
@@ -410,6 +433,13 @@ class CacheManager:
         self.cfg = cfg
         self.slots = slots
         self.max_seq_len = max_seq_len
+        # window and full layers in one stack: two kinds of paged state
+        self.mixed = bool(getattr(cfg, "mixed", False))
+        if self.mixed and not paged:
+            raise ValueError(
+                "a mixed stack (window and full layers) serves from the paged "
+                "pools only: no contiguous layout holds both kinds"
+            )
         w = cfg.sliding_window if window is None else window
         if w and w != cfg.sliding_window:
             raise ValueError(
@@ -478,7 +508,15 @@ class CacheManager:
             self.row_bytes = self.row_values * kv_itemsize + (
                 2 * self.row_heads * 4 if self.int8 else 0
             )
-            self.block_bytes = cfg.n_layers * self.block * self.row_bytes
+            # the layers this pool's blocks hold: all of them, or a mixed
+            # stack's FULL layers (the window layers' pool: window_tables)
+            self.pool_layers = cfg.n_layers
+            if self.mixed:
+                from ..models.transformer import layer_kinds
+
+                self.kind_layers = layer_kinds(cfg)
+                self.pool_layers = len(self.kind_layers[0])
+            self.block_bytes = self.pool_layers * self.block * self.row_bytes
             retain_bytes = int(prefix_cache_mb * 1024 * 1024)
             if host_cache_mb is None:
                 host_cache_mb = float(
@@ -500,7 +538,29 @@ class CacheManager:
 
             self.pool = BlockPool(pool_blocks, self.block, self.block_bytes)
             self._slot_tables = [SlotTable(self.table_width) for _ in range(slots)]
-            self._tables_np = np.zeros((slots, self.table_width), np.int32)
+            # the device tables' columns: a mixed stack's two kinds side by
+            # side, [full | window] (paged.split_tables)
+            self.table_cols = self.table_width * (2 if self.mixed else 1)
+            self.window_tables = None
+            if self.mixed:
+                why = mixed_kv_refusal(
+                    int8=self.int8, retain_bytes=retain_bytes, session_bytes=session_bytes
+                )
+                if why:
+                    raise ValueError(why)
+                from .paged import WindowTables
+
+                # a step appends a prompt chunk and, where the row finishes
+                # its prompt, the decode chunk behind it
+                self.window_tables = WindowTables(
+                    slots, self.table_width, self.block, cfg.window,
+                    self.append_slack + int(decode_chunk), 2 * int(decode_chunk),
+                    len(self.kind_layers[1]) * self.block * self.row_bytes,
+                )
+                # a prompt chunk's view of a window layer: a ring of the
+                # window and the widest chunk (gather_ring)
+                self.window_ring = cfg.window + self.append_slack
+            self._tables_np = np.zeros((slots, self.table_cols), np.int32)
             self.tables_dirty = True
             # sharing is on whenever there is a retention budget OR the
             # session tier wants the radix as its index
@@ -607,11 +667,17 @@ class CacheManager:
         return ring_pack(cache, self.capacity) if self.rolling else cache
 
     # -- paged layout: pool geometry --------------------------------------
-    def pool_shapes(self) -> tuple[tuple, tuple]:
+    def pool_shapes(self) -> tuple:
         """The pool's two arrays as stored, [L, NB, B, heads * dim] each: a
-        row flat, as its decode kernel reads a page (row_shapes)."""
-        lead = (self.cfg.n_layers, self.pool.n_blocks, self.block)
-        return tuple(lead + (h * d,) for h, d in self.row_shapes)
+        row flat, as its decode kernel reads a page (row_shapes). A mixed
+        stack has two pools: each of the two entries is then the pair (full
+        layers' [L_full, NB, ...], window layers' [L_window, NB_window, ...])."""
+        lead = (self.pool_layers, self.pool.n_blocks, self.block)
+        shapes = tuple(lead + (h * d,) for h, d in self.row_shapes)
+        if not self.mixed:
+            return shapes
+        wlead = (len(self.kind_layers[1]), self.window_tables.pool.n_blocks, self.block)
+        return tuple((full, wlead + full[3:]) for full in shapes)
 
     def pool_arrays(self, jnp):
         """Zeroed device pool (KVCache pool-layout) + int8 scales (or
@@ -622,6 +688,12 @@ class CacheManager:
 
         k_shape, v_shape = self.pool_shapes()
         dtype = jnp.int8 if self.int8 else self.cfg.dtype
+        if self.mixed:  # k and v are each (full layers' pool, window layers')
+            return KVCache(
+                k=tuple(jnp.zeros(s, dtype) for s in k_shape),
+                v=tuple(jnp.zeros(s, dtype) for s in v_shape),
+                length=jnp.zeros((self.slots,), jnp.int32),
+            ), None
         cache = KVCache(
             k=jnp.zeros(k_shape, dtype),
             v=jnp.zeros(v_shape, dtype),
@@ -798,25 +870,40 @@ class CacheManager:
         upto = min(int(upto_tokens), self.capacity)
         need = self.blocks_for(upto)
         with self._plock:
-            st = self._slot_tables[slot]
-            if need <= st.hi:
-                return False
-            n = need - st.hi
-            take_r = min(n, st.reserved)
-            fresh: list[int] = []
-            if take_r:
-                fresh += self.pool.alloc(take_r, reserved=True)
-                st.reserved -= take_r
-            extra = n - take_r
-            if extra:
-                if self.pool.available() < extra and self.radix is not None:
-                    self.radix.evict_for(extra - self.pool.available())
-                fresh += self.pool.alloc(extra)
-            st.rows[st.hi : need] = np.asarray(fresh, np.int32)
-            st.hi = need
-            self.tables_dirty = True
-            self._update_gauges()
-            return True
+            grew = self._grow_locked(slot, need)
+            if self.window_tables is not None:
+                # the window layers' blocks follow the same cursor, and
+                # those behind every coming query's window go back
+                moved, freed = self.window_tables.advance(slot, upto)
+                if moved:
+                    self.tables_dirty = grew = True
+                if freed and self.metrics is not None:
+                    self.metrics.increment_counter(
+                        "app_llm_kv_blocks_reclaimed_total", float(freed), model=self.model
+                    )
+            return grew
+
+    def _grow_locked(self, slot: int, need: int) -> bool:
+        """ensure's growth of the slot's table to `need` entries; the lock is held."""
+        st = self._slot_tables[slot]
+        if need <= st.hi:
+            return False
+        n = need - st.hi
+        take_r = min(n, st.reserved)
+        fresh: list[int] = []
+        if take_r:
+            fresh += self.pool.alloc(take_r, reserved=True)
+            st.reserved -= take_r
+        extra = n - take_r
+        if extra:
+            if self.pool.available() < extra and self.radix is not None:
+                self.radix.evict_for(extra - self.pool.available())
+            fresh += self.pool.alloc(extra)
+        st.rows[st.hi : need] = np.asarray(fresh, np.int32)
+        st.hi = need
+        self.tables_dirty = True
+        self._update_gauges()
+        return True
 
     def _release_slot_locked(self, slot: int) -> None:
         st = self._slot_tables[slot]
@@ -828,6 +915,8 @@ class CacheManager:
         st.shared = 0
         st.reserved = 0
         st.owner = None
+        if self.window_tables is not None:
+            self.window_tables.release(slot)
 
     def release_slot(self, slot: int, owner=None) -> None:
         """Drop a slot's block references (retire/preempt/reassign).
@@ -850,7 +939,9 @@ class CacheManager:
             if not self.tables_dirty:
                 return None
             for s, st in enumerate(self._slot_tables):
-                self._tables_np[s] = st.rows
+                self._tables_np[s, : self.table_width] = st.rows
+            if self.window_tables is not None:
+                self._tables_np[:, self.table_width :] = self.window_tables.rows
             self.tables_dirty = False
             return self._tables_np.copy()
 
@@ -1156,6 +1247,22 @@ class CacheManager:
                 "sessions": (
                     self.sessions.stats() if self.sessions is not None else None
                 ),
+                # A mixed stack keeps two kinds of state. The keys above
+                # (pool_blocks, blocks_in_use, block_bytes, slot_bytes) are
+                # then the FULL layers' pool, the one that grows with the
+                # context; each kind's own numbers are here.
+                **({"kinds": {
+                    "full": {
+                        "layers": self.pool_layers,
+                        "pool_blocks": self.pool.n_blocks,
+                        "blocks_in_use": self.pool.blocks_in_use(),
+                        "block_bytes": self.block_bytes,
+                    },
+                    "window": {
+                        "layers": len(self.kind_layers[1]),
+                        **self.window_tables.stats(),
+                    },
+                }} if self.mixed else {}),
             }
 
     def close(self) -> None:

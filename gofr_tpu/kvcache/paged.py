@@ -53,10 +53,14 @@ import numpy as np
 __all__ = [
     "BlockPool",
     "SlotTable",
+    "WindowTables",
     "RadixTree",
     "RadixMatch",
     "gather_slots",
+    "gather_ring",
+    "split_tables",
     "scatter_rows",
+    "scatter_rows_by_kind",
     "copy_blocks",
     "gather_blocks_host",
     "stored_rows",
@@ -80,6 +84,14 @@ class BlockPool:
     tokens each. Pure host bookkeeping: the device arrays live with the
     engine (donated through every jitted program); this class only
     decides WHICH pool rows a sequence may read and write.
+
+    One pool serves the layers of ONE kind. A stack whose layers all attend
+    alike has one; a mixed stack (window and full layers) has two, each over
+    its own device arrays [L_kind, NB, B, W] with its own block ids: the
+    full layers' grows with the context, the window layers' gives a block
+    back as soon as every row of it lies behind the window of every query
+    still to come (CacheManager.ensure), so a slot never holds more of it
+    than WindowTables.bound blocks.
 
     Not internally locked — every caller goes through the CacheManager
     lock (one mutator at a time; the engine's scheduler thread owns all
@@ -213,6 +225,84 @@ class SlotTable:
 
     def private_blocks(self) -> list[int]:
         return [int(b) for b in self.rows[self.shared : self.hi]]
+
+
+class WindowTables:
+    """The window layers' blocks of a mixed stack: a pool of their own and
+    one table a slot, of the SAME logical width as the full layers' (entry j
+    names the block of positions [j*B, (j+1)*B)), of which only the entries
+    [lo, hi) are live. `advance` materializes blocks up to the cursor and
+    gives back every block that no query still to come can read; an entry
+    below `lo` keeps naming its freed (perhaps re-used) block and is never
+    dereferenced: the decode kernel walks only the pages that meet the band
+    [hi - window, hi) (ops.attention._paged_decode_kernel), the prompt
+    chunks gather the last `ring` positions (gather_ring), and both lie
+    above `lo` by construction.
+
+    What a slot can hold at most, `bound` blocks: the window's own keys
+    below a program's first query, the rows one program appends
+    (append_slack) and `margin` rows of safety below the window (the
+    engine's merge slack: two decode chunks), in whole blocks, plus one
+    for where the first block starts. The pool is `slots x bound` blocks,
+    so it cannot run out and takes no reservation."""
+
+    def __init__(self, slots: int, width: int, block: int, window: int,
+                 append_slack: int, margin: int, block_bytes: int):
+        self.block, self.window, self.margin = int(block), int(window), int(margin)
+        self.bound = -(-(self.window - 1 + self.margin + int(append_slack)) // self.block) + 1
+        self.pool = BlockPool(slots * self.bound, block, block_bytes)
+        self.rows = np.zeros((slots, width), np.int32)
+        self.lo = [0] * slots  # first live entry
+        self.hi = [0] * slots  # one past the last
+        self.upto = [0] * slots  # rows materialized at the last advance
+        self.reclaimed = 0  # blocks given back behind the window
+        self.peak = 0  # most blocks any slot has held
+
+    def advance(self, slot: int, upto: int) -> tuple[bool, int]:
+        """Rows [0, upto) of `slot` are about to be readable or written by
+        the program being dispatched, whose first query stands at the
+        previous `upto` or later: -> (the table changed, blocks freed)."""
+        B = self.block
+        keep = max(0, self.upto[slot] - (self.window - 1) - self.margin) // B
+        lo, hi = self.lo[slot], self.hi[slot]
+        freed = 0
+        if keep > lo:
+            gone = [int(b) for b in self.rows[slot, lo:min(keep, hi)]]
+            freed = self.pool.decref(gone)
+            self.reclaimed += freed
+            lo = keep
+        need = -(-max(0, int(upto)) // B)
+        first = max(hi, lo)
+        if need > first:
+            self.rows[slot, first:need] = np.asarray(self.pool.alloc(need - first), np.int32)
+            hi = need
+        changed = (lo, hi) != (self.lo[slot], self.hi[slot])
+        self.lo[slot], self.hi[slot] = lo, max(hi, lo)
+        self.upto[slot] = max(self.upto[slot], int(upto))
+        self.peak = max(self.peak, self.hi[slot] - lo)
+        return changed, freed
+
+    def release(self, slot: int) -> None:
+        lo, hi = self.lo[slot], self.hi[slot]
+        if hi > lo:
+            self.pool.decref([int(b) for b in self.rows[slot, lo:hi]])
+        self.lo[slot] = self.hi[slot] = self.upto[slot] = 0
+
+    def held(self, slot: int) -> int:
+        return self.hi[slot] - self.lo[slot]
+
+    def stats(self) -> dict:
+        return {
+            "window": self.window,
+            "pool_blocks": self.pool.n_blocks,
+            "blocks_in_use": self.pool.blocks_in_use(),
+            # what the same slots would hold had nothing been given back
+            "blocks_unreclaimed": int(sum(self.hi)),
+            "blocks_reclaimed": self.reclaimed,
+            "bound_blocks_per_slot": self.bound,
+            "peak_blocks_per_slot": self.peak,
+            "block_bytes": self.pool.block_bytes,
+        }
 
 
 # ---------------------------------------------------------------------------
@@ -579,6 +669,39 @@ def gather_slots(pool_k, pool_v, tables, lengths, *, rows, scales=None, dtype=No
     )
 
 
+def split_tables(tables):
+    """A mixed stack's device tables [S, 2 * MB], the two kinds' side by
+    side -> (the full layers' [S, MB], the window layers' [S, MB])."""
+    MB = tables.shape[1] // 2
+    return tables[:, :MB], tables[:, MB:]
+
+
+def gather_ring(pool_k, pool_v, tables, lengths, ring: int, *, rows):
+    """The LAST `ring` positions below each slot's `lengths`, gathered
+    through the block tables as a rolling ring: row j of the view holds the
+    last position congruent to j mod ring (ops.attention.ring_positions),
+    which is the layout prefill_append's ring masks read. What a window
+    layer's prompt chunk needs of its pool and no more: [L, S, ring, h, d]
+    where gather_slots would materialize the table's whole width. A row
+    that was never written (a position below 0) reads block 0 and is
+    masked by position, as gather_slots' stale entries are."""
+    import jax.numpy as jnp
+
+    from ..models.transformer import KVCache
+    from ..ops.attention import ring_positions
+
+    pos = jnp.maximum(ring_positions(lengths, ring), 0)  # [S, ring]
+    L, NB, B, _ = pool_k.shape
+    blk = jnp.take_along_axis(tables, jnp.clip(pos // B, 0, tables.shape[1] - 1), axis=1)
+    flat = jnp.clip(blk, 0, NB - 1) * B + pos % B  # [S, ring] rows of one layer's pool
+    flat = flat[None] + jnp.arange(L, dtype=jnp.int32)[:, None, None] * (NB * B)
+
+    def take(pool, row):
+        return viewed_rows(jnp.take(_flat(pool), flat, axis=0), row)
+
+    return KVCache(k=take(pool_k, rows[0]), v=take(pool_v, rows[1]), length=lengths)
+
+
 def quantize_rows(rows, *, axis=-1):
     """Symmetric per-row/per-head int8: scale = max|x| / 127 over the
     head_dim axis. Returns (int8 rows, f32 scales without that axis)."""
@@ -633,6 +756,24 @@ def scatter_rows(pool_k, pool_v, tables, rows_k, rows_v, positions, valid, *, sc
     qv, sv = quantize_rows(rows_v)
     k, v = put(pool_k, stored_rows(qk)), put(pool_v, stored_rows(qv))
     return k, v, jnp.stack([put(scales[0], sk), put(scales[1], sv)])
+
+
+def scatter_rows_by_kind(pool_k, pool_v, kind_tables, kind_layers, rows_k, rows_v, positions, valid):
+    """scatter_rows for a mixed stack: `pool_k` / `pool_v` are the kinds'
+    pools (full, window), `kind_tables` their tables, `kind_layers` which
+    layers of the whole stack each holds (models.transformer.layer_kinds);
+    `rows_k/v` [L, S, W, h, d] carry EVERY layer's rows, and each kind's
+    layers' go through that kind's table. Returns the (k pools, v pools)."""
+    import jax.numpy as jnp
+
+    done = [
+        scatter_rows(
+            pool_k[i], pool_v[i], kind_tables[i],
+            rows_k[jnp.asarray(layers)], rows_v[jnp.asarray(layers)], positions, valid,
+        )
+        for i, layers in enumerate(kind_layers)
+    ]
+    return tuple(d[0] for d in done), tuple(d[1] for d in done)
 
 
 def copy_blocks(pool_k, pool_v, srcs, dsts, *, scales=None):

@@ -153,6 +153,9 @@ STEP_FIELDS = (
     # routed experts (0 for a dense model): token-expert pairs the program
     # computed and experts that were given a row, summed over its layer calls
     "moe_pairs", "moe_touched",
+    # every pair the program's routers chose: more than moe_pairs where this
+    # program holds a share of the experts (cfg.moe_held_experts)
+    "moe_pairs_routed",
 )
 # the ring holds two benchmark windows of programs (50 s each) down to 25 ms a program
 STEP_LOG_LEN = 4096
@@ -196,6 +199,35 @@ def latent_refusal(
             "LoRA adapters on the latent projections are not supported: serve "
             "with lora_slots=0"
         )
+    return None
+
+
+def mixed_refusal(*, mesh, chunked: bool, speculative: bool, lora_slots) -> str | None:
+    """What a mixed stack (cfg.mixed: window and full layers, two kinds of
+    paged state) is refused at engine build, as a sentence, or None. The
+    cache's own refusals (the contiguous layout, an int8 pool, prefix
+    sharing, sessions and with them spill and hand-off) are
+    kvcache.mixed_kv_refusal's. Preemption follows both kinds: it releases
+    the slot's blocks of each and re-queues the request."""
+    if mesh is not None:
+        return (
+            "a mixed stack (window and full layers) serves on one chip: the "
+            "sharding specs name one pool of KV blocks"
+        )
+    if not chunked:
+        return (
+            "a mixed stack needs the token-budget step scheduler: the wave "
+            "scheduler (step_token_budget=0) prefills through ONE contiguous "
+            "cache and inserts it into one pool"
+        )
+    if speculative:
+        return (
+            "speculative decoding (verify_chunk) is not written for a mixed "
+            "stack: a rolled-back draft's rows would sit in blocks the window "
+            "layers already gave back; serve with speculative=False"
+        )
+    if lora_slots:
+        return "LoRA slots are not written for a mixed stack: serve with lora_slots=0"
     return None
 
 
@@ -262,6 +294,18 @@ def _register_phase_metrics(metrics) -> None:
                 "app_llm_steps_without_decode_total",
                 "llm unified steps dispatched without their decode chunk "
                 "(no lane decoding, no prompt row finishing)",
+            )
+        if not metrics.has("app_llm_kv_blocks_reclaimed_total"):
+            metrics.new_counter(
+                "app_llm_kv_blocks_reclaimed_total",
+                "llm KV blocks a mixed stack's window layers gave back behind "
+                "the attention window",
+            )
+        if not metrics.has("app_llm_moe_pairs_total"):
+            metrics.new_counter(
+                "app_llm_moe_pairs_total",
+                "llm (token, expert) pairs of the routed experts "
+                "(kind=routed: chosen by the router | computed: by experts held here)",
             )
         if not metrics.has("app_llm_step_tokens"):
             metrics.new_histogram(
@@ -838,6 +882,13 @@ class LLMEngine:
             if why:
                 raise ValueError(why)
             constrained, kv_paged = False, True
+        elif getattr(cfg, "mixed", False):
+            why = mixed_refusal(
+                mesh=mesh, chunked=self.chunked, speculative=self.speculative,
+                lora_slots=lora_slots,
+            )
+            if why:
+                raise ValueError(why)
         elif len(getattr(cfg, "group_sizes", ())) > 1 and (mesh is not None or lora_slots):
             raise ValueError(
                 "a model of two layer groups (leading dense layers, then routed "
@@ -1308,7 +1359,7 @@ class LLMEngine:
             chunk_shapes=(self.chunk_shapes if self.chunked else ()),
             spec_draft=self.spec_draft, mesh=self.mesh,
             tp_gather=self._tp_gather,
-            kernel=self.attention_paths["decode"] in (
+            kernel=self.attention_paths["decode"].split(" | ")[0] in (
                 "pallas_paged", "pallas_mla_paged",
             ),
             numeric_check=self.numeric_check, label=self.label,
@@ -1340,7 +1391,7 @@ class LLMEngine:
             if self._kv_scales is None:
                 self._kv_scales = jnp.zeros((0,), jnp.float32)
             self._tables_dev = jnp.zeros(
-                (slots, self.kv.table_width), jnp.int32
+                (slots, self.kv.table_cols), jnp.int32
             )
             if device is not None:
                 self.cache = jax.device_put(self.cache, device)
@@ -1464,7 +1515,12 @@ class LLMEngine:
         # what the routed experts did, summed over the paged programs' records
         # (stats()["moe"]): [pairs, experts touched, rows per expert...]
         _E = int(getattr(cfg, "n_experts", 0) or 0)
-        self._moe_totals = np.zeros((2 + _E,), np.int64)
+        # (a program that holds a share of the experts counts its own, and
+        # every pair its routers chose as one entry more: moe_stats_width)
+        self._moe_held = int(getattr(cfg, "held_experts", _E) or 0)
+        share = bool(getattr(cfg, "moe_held_experts", 0))
+        self._moe_routed_at = -1 if share else 0  # where the vector holds the routed pairs
+        self._moe_totals = np.zeros((2 + self._moe_held + share,), np.int64)
         self._moe_layer_calls = 0
         self._moe_layers = cfg.group_sizes[-1] if _E else 0  # the routed group is the last
         self.moe_path = self._moe_path() if _E else None
@@ -1566,6 +1622,31 @@ class LLMEngine:
             decode = f"xla_gather ({why})" if why else "pallas_paged"
         else:
             decode = "xla_ring" if self.kv.ring else "xla_dense"
+        if self.kv.mixed:
+            # Two kinds of layer, each named: the full layers' path first (as
+            # a stack of one kind names its own), then the window layers'.
+            # Decode is ONE kernel for both (the window is its band, under
+            # the name paged_decode_window); a prompt chunk meets the full
+            # layers' gathered view (flash where the shapes allow) and the
+            # window layers' last rows as a ring, by XLA.
+            w = self.cfg.window
+            paths = {
+                "decode": f"{decode} | window: {decode.split(' ')[0]} band {w}",
+                "prefill": {
+                    c: flash_or(chunk_prefill_why_not_flash(c, self.kv.capacity, hd))
+                    + f" | window: xla ring of {self.kv.window_ring}"
+                    for c in self.chunk_shapes
+                },
+            }
+            if not why:
+                pages = paged_decode_pages(
+                    self.kv.block, self.cfg.n_kv_heads, hd, self.cfg.dtype, self.kv.table_width
+                )
+                paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+                paths["pool_operand"] = " | window: ".join(
+                    paged_pool_operand(shape, hd) for shape in self.kv.pool_shapes()[0]
+                )
+            return paths
         if not self.chunked:
             prefill = {
                 b: flash_or(flash_why_not(b, b, hd, 128, 128))
@@ -1937,7 +2018,17 @@ class LLMEngine:
                     "pairs": int(self._moe_totals[0]),
                     "touched": int(self._moe_totals[1]),
                     "layer_calls": int(self._moe_layer_calls),
-                    "tokens_per_expert": [int(x) for x in self._moe_totals[2:]],
+                    "tokens_per_expert": [
+                        int(x) for x in self._moe_totals[2 : 2 + self._moe_held]
+                    ],
+                    # the experts this program holds of the model's, and every
+                    # pair its routers chose (= pairs when it holds them all)
+                    "held": {
+                        "first": int(getattr(self.cfg, "moe_first_expert", 0) or 0),
+                        "count": self._moe_held,
+                        "of": int(getattr(self.cfg, "n_experts", 0) or 0),
+                    },
+                    "pairs_routed": int(self._moe_totals[self._moe_routed_at]),
                 },
                 "load_tokens": self.load_tokens(),
                 "rejected": self.rejected,
@@ -3016,7 +3107,7 @@ class LLMEngine:
                 # stays zeros.
                 env["scales"] = self._kv_scales
                 env["tables"] = jnp.zeros(
-                    (self.slots, self.kv.table_width), jnp.int32
+                    (self.slots, self.kv.table_cols), jnp.int32
                 )
                 env["live"] = jnp.zeros((self.slots,), bool)
 
@@ -3029,6 +3120,11 @@ class LLMEngine:
 
             M = self.admit_cap
             for nb in nbs:
+                if self.kv.mixed:
+                    # (insert and seed are the wave scheduler's and the
+                    # radix tree's: written for one pool, refused here)
+                    warm_admit_update(nb)
+                    continue
                 scratch = self.kv.init_cache(nb)
                 if self.kv.paged:
                     oob_b = self.kv.pool.n_blocks
@@ -4592,7 +4688,7 @@ class LLMEngine:
         if moe is not None:
             self._start_fetch(moe)
         return {
-            "moe_dev": moe, "moe_pairs": 0, "moe_touched": 0,
+            "moe_dev": moe, "moe_pairs": 0, "moe_touched": 0, "moe_pairs_routed": 0,
             "seq": self._step_seq, "kind": kind, "program": program, "k": k,
             "depth": self._decode_depth(), "lanes": lanes, "decode_ctx": (),
             "rows": rows, "t_dispatch": t_dispatch, "t_dispatched": t_dispatched,
@@ -4613,6 +4709,12 @@ class LLMEngine:
             # the program's tokens are here already, so this small vector is too
             vec = np.asarray(moe_dev).astype(np.int64)
             info["moe_pairs"], info["moe_touched"] = int(vec[0]), int(vec[1])
+            info["moe_pairs_routed"] = int(vec[self._moe_routed_at])
+            if self.metrics is not None:
+                for kind, n in (("routed", info["moe_pairs_routed"]), ("computed", info["moe_pairs"])):
+                    self.metrics.increment_counter(
+                        "app_llm_moe_pairs_total", float(n), model=self.label, kind=kind
+                    )
             self._moe_totals += vec
             self._moe_layer_calls += self._moe_layers * (
                 info["k"] + (1 if info["kind"] == "step" else 0)
@@ -4625,8 +4727,15 @@ class LLMEngine:
         by the collector, where every earlier program's tokens are counted;
         at dispatch `emitted` lags by the programs in flight."""
         c = len(r.prompt_tokens) + r.emitted
-        w = self._costs.sliding_window
+        w = self._costs.sliding_window  # (0 where any layer reads everything)
         return min(c, w) if w else c
+
+    def _ctx_read(self, r: GenRequest) -> float:
+        """The positions a decoding lane's token attends, a layer on average
+        (profiling.mfu.read_ctx): what the MFU and roofline observations sum.
+        _ctx_of's number where every layer is alike; in a mixed stack the
+        window layers' capped share and the full layers' whole context."""
+        return self._mfu_mod.read_ctx(self._costs, len(r.prompt_tokens) + r.emitted)
 
     # -- observability ----------------------------------------------------
     def _observe_mfu(
@@ -5696,8 +5805,9 @@ class LLMEngine:
         # dispatch->fetch cost per decode step, attributed once per chunk
         # (wave = active slots at dispatch, bucketed to a power of two so
         # the label set stays bounded at log2(slots) values)
-        ctxs = [self._ctx_of(r) for r in snapshot if r is not None]
-        active_n, ctx_sum = len(ctxs), sum(ctxs)
+        ctxs = [self._ctx_of(r) for r in snapshot if r is not None]  # the record's
+        active_n = len(ctxs)
+        ctx_sum = sum(self._ctx_read(r) for r in snapshot if r is not None)
         self._observe_tput(k * active_n, now - t_dispatch)
         step_s = (now - t_dispatch) / k
         self._phases["decode_step"].observe(step_s)
@@ -5803,11 +5913,7 @@ class LLMEngine:
                     "app_tpu_stats", now - info["t_fetch"], model="llm", op="decode_chunk",
                 )
         if info["prefill_tokens"]:
-            ctx_read = sum(
-                min(pos, self._costs.sliding_window) if self._costs.sliding_window
-                else pos
-                for pos, _n in spans
-            )
+            ctx_read = sum(self._mfu_mod.read_ctx(self._costs, pos) for pos, _n in spans)
             self._observe_mfu(
                 "prefill",
                 tokens=info["prefill_tokens"],
@@ -5821,7 +5927,7 @@ class LLMEngine:
             )
         if decoded:
             active_n = sum(r is not None for r in snapshot)
-            ctx_sum = sum(self._ctx_of(r) for r in snapshot if r is not None)
+            ctx_sum = sum(self._ctx_read(r) for r in snapshot if r is not None)
             # per-token cadence requests actually experience: a fused
             # step's wall includes its prefill-append compute (a short
             # request may complete entirely inside its own step, so
@@ -5930,7 +6036,6 @@ class LLMEngine:
         ys = ys_t.T
         now, t_dispatch = info["t_fetched"], info["t_dispatch"]
         dt = now - t_dispatch
-        w = self._costs.sliding_window
         emitted_total = 0
         accepted_total = 0
         spans: list[tuple[int, int]] = []
@@ -5945,7 +6050,7 @@ class LLMEngine:
                 accepted_c += int(acc[slot])
             cur = info["cursors"].get(slot, 0)
             spans.append((cur, n))
-            ctx_sum += min(cur, w) if w else cur
+            ctx_sum += self._mfu_mod.read_ctx(self._costs, cur)
         self.spec_accepted += accepted_total
         self.spec_accepted_c += accepted_c
         self._observe_tput(emitted_total, dt)

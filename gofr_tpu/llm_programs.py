@@ -349,7 +349,10 @@ class _Pool:
         self.p = p
         self.int8 = p.kv.int8
         self.kernel = kernel
-        self.paged_fn = kernel or bool(getattr(p.cfg, "latent", False))
+        # (window and full layers in one stack: two pools, which only
+        # decode_chunk_paged reads)
+        self.mixed = bool(getattr(p.cfg, "mixed", False))
+        self.paged_fn = kernel or bool(getattr(p.cfg, "latent", False)) or self.mixed
         self.moe = int(getattr(p.cfg, "n_experts", 0) or 0) > 0
 
     def donated(self, kind: str) -> tuple:
@@ -390,20 +393,54 @@ class _Pool:
             self.rows_at(dense.k, pos), self.rows_at(dense.v, pos), pos, valid,
         )
 
+    def _appended(self, tokens, cursors, n_new):
+        """A prompt chunk's positions [nb, c] and which of them are written."""
+        c = tokens.shape[1]
+        pos = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+        valid = (
+            jnp.arange(c, dtype=jnp.int32)[None, :] < n_new[:, None]
+        ) & (pos < self.p.kv.capacity)
+        return pos, valid
+
+    def _append_mixed(self, a, tokens, tsub, slot_idx, cursors, n_new, aids_row):
+        """A mixed stack's append: the full layers' view through their
+        table, the window layers' last `window_ring` rows as a ring through
+        theirs; the chunk's rows of every layer come back and go through
+        each kind's table (models.transformer._append_forward_mixed)."""
+        from .kvcache.paged import gather_ring, gather_slots, scatter_rows_by_kind, split_tables
+        from .models.transformer import layer_kinds
+
+        p, cache = self.p, a["cache"]
+        kind_tables = split_tables(tsub)
+        rows = p.kv.row_shapes
+        full = gather_slots(cache.k[0], cache.v[0], kind_tables[0], cursors, rows=rows)
+        ring = gather_ring(
+            cache.k[1], cache.v[1], kind_tables[1], cursors, p.kv.window_ring, rows=rows
+        )
+        logits, new = prefill_append(
+            a["params"], p.cfg, tokens,
+            cache._replace(k=(full.k, ring.k), v=(full.v, ring.v), length=cursors),
+            cursors, n_new, aids=aids_row, moe_out=a["moe_out"],
+        )
+        pos, valid = self._appended(tokens, cursors, n_new)
+        k2, v2 = scatter_rows_by_kind(
+            cache.k, cache.v, kind_tables, layer_kinds(p.cfg), new.k, new.v, pos, valid
+        )
+        length = cache.length.at[slot_idx].set(cursors + n_new, mode="drop")
+        a["cache"] = cache._replace(k=k2, v=v2, length=length)
+        return logits
+
     def append(self, a, tokens, slot_idx, cursors, n_new, aids_row):
         p = self.p
         tsub = jnp.take(a["tables"], jnp.clip(slot_idx, 0, p.slots - 1), axis=0)
+        if self.mixed:
+            return self._append_mixed(a, tokens, tsub, slot_idx, cursors, n_new, aids_row)
         sub = self.gather_view(a["cache"], a["scales"], tsub, cursors)
         logits, sub2 = prefill_append(
             a["params"], p.cfg, tokens, sub, cursors, n_new, ring=0,
             aids=aids_row, mesh=p.mesh, moe_out=a["moe_out"],
         )
-        c = tokens.shape[1]
-        pos_a = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-        valid_a = (
-            jnp.arange(c, dtype=jnp.int32)[None, :] < n_new[:, None]
-        ) & (pos_a < p.kv.capacity)
-        self._write_back(a, tsub, sub2, pos_a, valid_a)
+        self._write_back(a, tsub, sub2, *self._appended(tokens, cursors, n_new))
         length = a["cache"].length.at[slot_idx].set(cursors + n_new, mode="drop")
         a["cache"] = a["cache"]._replace(length=length)
         return logits

@@ -152,6 +152,15 @@ def routed_ffn(cfg, h: jnp.ndarray, lp: dict, mm, *, use_kernel=None, interpret=
     """The ONE routed FFN of serving: dropless top-k experts (+ shared ones).
 
     h [T, d] -> (y [T, d], counts [E] int32: rows each expert was given).
+    A program that holds a SHARE of the experts (cfg.moe_held_experts of the
+    n_experts, from cfg.moe_first_expert: one chip of an expert-parallel
+    deployment) routes over all of them all the same (`route`: the scores,
+    the choice and the normalised weights are the whole model's), leaves the
+    pairs whose expert is elsewhere out BEFORE the sort, runs the grouped
+    matmuls over its own pairs and its own stacks [held, in, out] alone, and
+    sums what it computed: its part of the layer's result, the shared
+    experts whole (every chip computes those alike). counts is then [held].
+    Nothing stands in for the absent chips or their exchange.
     Route (`route`), sort the (token, expert) pairs by expert into a layout
     where each expert's rows start on a tile boundary, three grouped matmuls
     over the expert stacks as they are stored (ops.grouped: int8 stays int8
@@ -168,18 +177,27 @@ def routed_ffn(cfg, h: jnp.ndarray, lp: dict, mm, *, use_kernel=None, interpret=
     with jax.named_scope("layer/moe_route"):
         experts, weights = route(cfg, h, lp)
         pairs = T * k
+        share = bool(cfg.moe_held_experts) and cfg.moe_held_experts < cfg.n_experts
+        if share:
+            # an expert held elsewhere becomes E (one past this program's
+            # own): it sorts behind every held expert and is given no row
+            E = cfg.moe_held_experts
+            own = experts - cfg.moe_first_expert
+            experts = jnp.where((own >= 0) & (own < E), own, E)
         tm = _row_tile(pairs, E, interpret or jax.default_backend() == "tpu")
         flat_e = experts.reshape(pairs)  # pair p = token p // k, choice p % k
-        counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+        counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)  # (expert E: dropped)
         padded = -(-counts // tm) * tm
         ends_pad = jnp.cumsum(padded)
         starts, starts_pad = jnp.cumsum(counts) - counts, ends_pad - padded
         order = jnp.argsort(flat_e, stable=True)
         sorted_e = flat_e[order]
         dest_sorted = starts_pad[sorted_e] + jnp.arange(pairs, dtype=jnp.int32) - starts[sorted_e]
-        dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)  # pair -> row
         n_tiles = -(-pairs // tm) + min(E, pairs)  # every non-empty expert wastes under a tile
         rows = n_tiles * tm
+        if share:  # a pair computed elsewhere has no row here: one past the last
+            dest_sorted = jnp.where(sorted_e < E, dest_sorted, rows)
+        dest = jnp.zeros((pairs,), jnp.int32).at[order].set(dest_sorted)  # pair -> row
         src = jnp.full((rows,), T, jnp.int32).at[dest].set(
             jnp.arange(pairs, dtype=jnp.int32) // k
         )  # row -> token; T (out of range) = no token
@@ -198,7 +216,11 @@ def routed_ffn(cfg, h: jnp.ndarray, lp: dict, mm, *, use_kernel=None, interpret=
         w_gate, w_up, w_down = (LayerOf.of(lp[n]) for n in ("w_gate", "w_up", "w_down"))
         a = act_fn(gmm(x, w_gate)) * gmm(x, w_up)
         out = gmm(a, w_down)  # [rows, d]
-        picked = jnp.take(out, dest.reshape(T, k), axis=0).astype(jnp.float32)  # [T, k, d]
+        if share:  # rows of pairs computed elsewhere read as zeros
+            picked = jnp.take(out, dest.reshape(T, k), axis=0, mode="fill", fill_value=0)
+        else:
+            picked = jnp.take(out, dest.reshape(T, k), axis=0)
+        picked = picked.astype(jnp.float32)  # [T, k, d]
         y = jnp.sum(picked * weights[..., None], axis=1)
     if "ws_gate" in lp:
         with jax.named_scope("layer/moe_shared"):

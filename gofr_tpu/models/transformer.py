@@ -84,11 +84,82 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Layers of different kinds in one stack. `layer_windows` gives every
+    # layer the keys it attends (0 = all of them); empty means every layer
+    # alike, `sliding_window` (the special case every preset but one is).
+    # A stack that holds both kinds is MIXED (`mixed`): it serves from the
+    # paged pool, where each kind keeps what it can ever read
+    # (kvcache.CacheManager). `qk_norm`: an RMSNorm over each head's values
+    # of q and of k (leaves `q_norm`, `k_norm` [head_dim]) before any
+    # rotation. `rope_on_window_only`: windowed layers rotate q and k, full
+    # layers carry no positional encoding at all.
+    layer_windows: tuple = ()
+    qk_norm: bool = False
+    rope_on_window_only: bool = False
+    # One chip's share of the routed experts: this program HOLDS
+    # `moe_held_experts` of the n_experts (0 = all of them), the first of
+    # which is expert `moe_first_expert`. The router still scores and chooses
+    # over all n_experts; pairs routed elsewhere are left out of the grouped
+    # matmuls and of the sum (models.moe.routed_ffn), and nothing stands in
+    # for the chips that hold the rest.
+    moe_first_expert: int = 0
+    moe_held_experts: int = 0
     dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.layer_windows:
+            if len(self.layer_windows) != self.n_layers:
+                raise ValueError(
+                    f"layer_windows names {len(self.layer_windows)} layers, the "
+                    f"stack has {self.n_layers}"
+                )
+            if len({w for w in self.layer_windows if w}) > 1:
+                raise ValueError(
+                    "layer_windows holds more than one window size: a stack "
+                    "mixes ONE window with full attention"
+                )
+            if not self.mixed and self.layer_windows[0] != self.sliding_window:
+                raise ValueError(
+                    "every layer has the same window: say it as sliding_window"
+                )
+            if self.mixed and (self.latent or self.sliding_window):
+                raise ValueError(
+                    "a mixed stack is GQA and names its windows in layer_windows "
+                    "alone (sliding_window 0)"
+                )
+        if self.moe_held_experts and not (
+            0 <= self.moe_first_expert
+            and self.moe_first_expert + self.moe_held_experts <= self.n_experts
+        ):
+            raise ValueError(
+                f"experts [{self.moe_first_expert}, {self.moe_first_expert + self.moe_held_experts}) "
+                f"are not among the model's {self.n_experts}"
+            )
 
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def windows(self) -> tuple:
+        """The keys each layer attends (0 = all), layer by layer: the ONE
+        place the pattern is read."""
+        return tuple(self.layer_windows) or (self.sliding_window,) * self.n_layers
+
+    @property
+    def mixed(self) -> bool:
+        """Window layers and full layers in one stack."""
+        return len(set(self.windows)) > 1
+
+    @property
+    def window(self) -> int:
+        """The one window size of the stack's windowed layers (0: none)."""
+        return max(self.windows)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this program holds (all of them unless told)."""
+        return self.moe_held_experts or self.n_experts
 
     @property
     def group_sizes(self) -> tuple:
@@ -197,6 +268,24 @@ class TransformerConfig:
         )
 
     @staticmethod
+    def tiny_mixed_moe(vocab_size: int = 512) -> "TransformerConfig":
+        """CI-sized mixed stack: three window layers (8 keys, RoPE) then a
+        full one (no positional encoding), q/k norm; one dense layer, then
+        two periods of 16 sigmoid-routed experts (top-4, normalised, scaled,
+        correction bias) with a shared one, of which this program holds
+        the first 4."""
+        return TransformerConfig(
+            vocab_size=vocab_size, d_model=64, n_layers=9, n_heads=4,
+            n_kv_heads=2, head_dim=16, d_ff=128, rope_theta=1_000_000.0,
+            norm_eps=1e-5, act="silu", scale_embed=False, dtype=jnp.float32,
+            layer_windows=(8, 8, 8, 0, 8, 8, 8, 0, 8), qk_norm=True,
+            rope_on_window_only=True,
+            n_experts=16, moe_top_k=4, moe_score="sigmoid", moe_norm_topk=True,
+            moe_scale=2.5, n_shared_experts=1, moe_d_ff=32, n_dense_layers=1,
+            moe_first_expert=0, moe_held_experts=4,
+        )
+
+    @staticmethod
     def tiny_moe(vocab_size: int = 512) -> "TransformerConfig":
         """CI-sized sparse config: 4 experts, top-2 routing — expert count
         divisible by TP=2/4 for the 8-virtual-device CPU mesh tests."""
@@ -263,8 +352,12 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             "wo": w(keys[3], (L, hq * dv, d), hq * dv),
         }
     else:
+        qk = (
+            {"q_norm": jnp.zeros((L, hd), cfg.dtype), "k_norm": jnp.zeros((L, hd), cfg.dtype)}
+            if cfg.qk_norm else {}
+        )
         attn = {
-            **bias,
+            **bias, **qk,
             "attn_norm": jnp.zeros((L, d), cfg.dtype),
             "wq": w(keys[1], (L, d, hq * hd), d),
             "wkv": w(keys[2], (L, d, 2 * hkv * hd), d),
@@ -286,12 +379,13 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     if cfg.n_experts > 0:
         # Sparse MLP: experts batched on a leading E axis (the EP shard
         # axis — parallel.sharding.param_specs) plus a replicated router.
-        E, fe = cfg.n_experts, cfg.moe_d_ff or ff
+        # (the router scores all E; the stacks hold this program's share)
+        E, Eh, fe = cfg.n_experts, cfg.held_experts, cfg.moe_d_ff or ff
         mlp = {
             "w_router": w(jax.random.fold_in(keys[3], 1), (L, d, E), d),
-            "w_gate": w(keys[4], (L, E, d, fe), d),
-            "w_up": w(jax.random.fold_in(keys[4], 1), (L, E, d, fe), d),
-            "w_down": w(keys[5], (L, E, fe, d), fe),
+            "w_gate": w(keys[4], (L, Eh, d, fe), d),
+            "w_up": w(jax.random.fold_in(keys[4], 1), (L, Eh, d, fe), d),
+            "w_down": w(keys[5], (L, Eh, fe, d), fe),
         }
         if cfg.moe_score == "sigmoid":  # sigmoid scores are chosen with a correction bias
             mlp["router_bias"] = 0.01 * jax.random.normal(
@@ -323,6 +417,50 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
 
 
 LAYER_KEY = "_layer"  # in a layer's params under the indexed scan: its index in the whole stack
+
+
+def layer_kinds(cfg) -> tuple[tuple, tuple]:
+    """A stack's layers by kind, read from cfg.windows: (the full layers,
+    the windowed layers) as indices into the whole stack. A mixed stack's
+    cache keeps one pool a kind, each [L_kind, ...]: a layer's place in its
+    kind's pool is its place in its tuple."""
+    ws = cfg.windows
+    return (
+        tuple(i for i, w in enumerate(ws) if not w),
+        tuple(i for i, w in enumerate(ws) if w),
+    )
+
+
+def _kind_of(cfg, layer):
+    """(is windowed, index among the layers of its kind) of the layer whose
+    index in the whole stack is the traced scalar `layer`."""
+    import numpy as np
+
+    at = np.zeros((cfg.n_layers,), np.int32)
+    for kind in layer_kinds(cfg):
+        at[list(kind)] = np.arange(len(kind), dtype=np.int32)
+    windowed = np.asarray([w > 0 for w in cfg.windows])
+    return jnp.asarray(windowed)[layer], jnp.asarray(at)[layer]
+
+
+def _by_kind(cfg, layer, window_fn, full_fn, *operands):
+    """A mixed stack's one branch on a layer's kind: `window_fn` under the
+    scope layer/attn_window, `full_fn` under layer/attn_full (a compiled
+    program's op_names say which kind an operation serves). Both take
+    (index among the layers of its kind, *operands)."""
+    windowed, at = _kind_of(cfg, layer)
+
+    def scoped(name, fn):
+        def branch(at, *ops):
+            with jax.named_scope(name):
+                return fn(at, *ops)
+
+        return branch
+
+    return jax.lax.cond(
+        windowed, scoped("layer/attn_window", window_fn),
+        scoped("layer/attn_full", full_fn), at, *operands,
+    )
 
 
 def _expert_stacks(group: dict) -> dict:
@@ -485,20 +623,30 @@ def _mlp_block(cfg, h, lp, mm, aids=None):
 
         b, s, d = h.shape
         y, counts = routed_ffn(cfg, h.reshape(b * s, d), lp, mm)
-        return y.reshape(b, s, d).astype(h.dtype), moe_layer_stats(counts)
+        routed = b * s * cfg.moe_top_k if cfg.moe_held_experts else None
+        return y.reshape(b, s, d).astype(h.dtype), moe_layer_stats(counts, routed)
     g = _lora_mm(mm, h, lp, "w_gate", aids)
     u = _lora_mm(mm, h, lp, "w_up", aids)
     y = _lora_mm(mm, _act_fn(cfg)(g) * u, lp, "w_down", aids)
     # a dense layer of a routed model (a leading group): nothing routed
-    return y, (jnp.zeros((2 + cfg.n_experts,), jnp.int32) if cfg.n_experts > 0 else None)
+    return y, (jnp.zeros((moe_stats_width(cfg),), jnp.int32) if cfg.n_experts > 0 else None)
 
 
-def moe_layer_stats(counts: jnp.ndarray) -> jnp.ndarray:
+def moe_stats_width(cfg) -> int:
+    """Entries of moe_layer_stats for this config."""
+    return 2 + cfg.held_experts + (1 if cfg.moe_held_experts else 0)
+
+
+def moe_layer_stats(counts: jnp.ndarray, routed: int | None = None) -> jnp.ndarray:
     """One routed layer call as [pairs, experts touched, rows per expert...]
     int32: summed over a program's layer calls it is what the engine's step
-    record and stats()["moe"] report."""
+    record and stats()["moe"] report. A program that holds a SHARE of the
+    experts (cfg.moe_held_experts) counts the pairs it computed, its own
+    experts, and appends `routed`: every pair the call's router chose, its
+    own or not."""
     return jnp.concatenate(
         [jnp.sum(counts)[None], jnp.sum(counts > 0)[None].astype(jnp.int32), counts]
+        + ([] if routed is None else [jnp.full((1,), routed, jnp.int32)])
     )
 
 
@@ -591,8 +739,16 @@ def _attn_block(cfg, x, lp, positions, mm, aids, attend):
             kv = kv + lp["bkv"].astype(kv.dtype)
         kv = kv.reshape(b, s, hkv, 2, hd)
         k_new, v_new = kv[:, :, :, 0], kv[:, :, :, 1]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k_new = apply_rope(k_new, positions, cfg.rope_theta)
+        if cfg.qk_norm:  # over each head's values, before any rotation
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k_new = rms_norm(k_new, lp["k_norm"], cfg.norm_eps)
+        if cfg.rope_on_window_only and cfg.mixed:
+            # a full layer carries no positional encoding: position 0 is the
+            # identity rotation (cos 1, sin 0), exactly
+            positions = jnp.where(_kind_of(cfg, lp[LAYER_KEY])[0], positions, 0)
+        if not cfg.rope_on_window_only or cfg.window:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k_new = apply_rope(k_new, positions, cfg.rope_theta)
     # Gemma queries are scaled by 1/sqrt(head_dim) (applied inside attention).
     attn, carry = attend(q, k_new, v_new)
     with jax.named_scope("layer/attn"):
@@ -638,6 +794,21 @@ def _layer_body(
             attn = latent_chunk_prefill_attention(
                 q, k, v, zero, scale=_latent_scale(cfg)
             )
+            return attn, (k, v)
+        if cfg.mixed:
+            if decode or prefill_attn is not None:
+                raise ValueError(
+                    "a mixed stack (window and full layers) serves through the "
+                    "paged engine (decode_chunk_paged / prefill_append); this "
+                    "forward pass is its whole-sequence form only"
+                )
+
+            def whole(window):
+                return lambda _at, q, k, v: multi_head_attention(
+                    q, k, v, causal=True, logit_cap=cfg.attn_logit_cap, window=window
+                )
+
+            attn = _by_kind(cfg, lp[LAYER_KEY], whole(cfg.window), whole(0), q, k, v)
             return attn, (k, v)
         if decode:
             # Write this step's k/v at each sequence's cursor, then attend over
@@ -715,7 +886,7 @@ def transformer_forward(
             )
             return x, (nk, nv)
 
-        x, (ks, vs) = _layer_scan(params["layers"], layer, x, ())
+        x, (ks, vs) = _layer_scan(params["layers"], layer, x, (), index=cfg.mixed)
         if cache is not None:
             max_len = cache.k.shape[2]
             s = tokens.shape[1]
@@ -899,6 +1070,11 @@ def decode_chunk(
             "latent attention has no contiguous decode chunk: it serves from "
             "the paged pool (decode_chunk_paged)"
         )
+    if cfg.mixed:
+        raise ValueError(
+            "a mixed stack (window and full layers) has no contiguous decode "
+            "chunk: it serves from the paged pools (decode_chunk_paged)"
+        )
     L, b = cfg.n_layers, tokens.shape[0]
     max_len = cache.k.shape[2]
     K = n_steps
@@ -1040,13 +1216,18 @@ def decode_chunk_paged(
     Returns (tokens [n_steps, b], last [b], pool', scales', rng).
     """
     from ..kvcache import row_shapes
-    from ..kvcache.paged import scatter_rows
+    from ..kvcache.paged import scatter_rows, scatter_rows_by_kind, split_tables
     from ..ops import mla_paged_chunk_decode_attention, paged_chunk_decode_attention
 
     L, b = cfg.n_layers, tokens.shape[0]
     K = n_steps
     aids = params.get("aids")  # per-slot adapter ids (see decode_chunk)
     quant = scales is not None and scales.size > 0
+    if cfg.mixed:
+        # pool.k / pool.v are (full layers' pool, window layers' pool), the
+        # tables the two kinds' side by side (kvcache.CacheManager)
+        kind_tables = split_tables(tables)
+        tables = kind_tables[0]
     k_row, v_row = row_shapes(cfg)
     kb0 = jnp.zeros((L, b, K) + k_row, cfg.dtype)
     vb0 = jnp.zeros((L, b, K) + v_row, cfg.dtype)
@@ -1068,6 +1249,23 @@ def decode_chunk_paged(
                 kb_n, vb_n = _chunk_buffer_write(kb_l, vb_l, k_new, v_new, k_i)
                 # the whole pool and the layer's index: the kernel copies its
                 # pages from there, no layer's pool is sliced out
+                if cfg.mixed:
+                    # two pools and two tables, the layer's kind chooses: a
+                    # window layer reads the band [hi - window, hi) of its
+                    # own (bounded) pool, under a kernel name of its own
+                    def kind(i, window, name):
+                        return lambda at, q, kb_n, vb_n: paged_chunk_decode_attention(
+                            q, pool.k[i], pool.v[i], kind_tables[i], kb_n, vb_n,
+                            pool.length, k_i, layer=at,
+                            logit_cap=cfg.attn_logit_cap, window=window,
+                            use_kernel=use_kernel, interpret=interpret, name=name,
+                        )
+
+                    attn = _by_kind(
+                        cfg, lp[LAYER_KEY], kind(1, cfg.window, "paged_decode_window"),
+                        kind(0, 0, "paged_decode"), q, kb_n, vb_n,
+                    )
+                    return attn, (kb_n, vb_n)
                 with jax.named_scope("layer/attn"):
                     if cfg.latent:
                         attn = mla_paged_chunk_decode_attention(
@@ -1114,10 +1312,16 @@ def decode_chunk_paged(
     cap = tables.shape[1] * block
     pos = pool.length[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]
     valid = active[:, None] & (pos < cap)
-    k2, v2, sc2 = scatter_rows(
-        pool.k, pool.v, tables, kb, vb, pos, valid,
-        scales=(scales if quant else None),
-    )
+    if cfg.mixed:  # each kind's layers' rows through that kind's table
+        k2, v2 = scatter_rows_by_kind(
+            pool.k, pool.v, kind_tables, layer_kinds(cfg), kb, vb, pos, valid
+        )
+        sc2 = None
+    else:
+        k2, v2, sc2 = scatter_rows(
+            pool.k, pool.v, tables, kb, vb, pos, valid,
+            scales=(scales if quant else None),
+        )
     new_len = jnp.where(active, jnp.minimum(pool.length + K, cap), pool.length)
     out = (
         toks, last, KVCache(k=k2, v=v2, length=new_len),
@@ -1151,9 +1355,14 @@ def _append_forward(
     from ..ops import latent_chunk_prefill_attention
 
     b, c = tokens.shape
-    capacity = cache.k.shape[2]
     positions = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     i = jnp.arange(c, dtype=jnp.int32)[None, :]
+    if cfg.mixed:
+        return _append_forward_mixed(
+            params, cfg, tokens, cache, cursors, positions, i < n_new[:, None],
+            aids=aids, moe_out=moe_out,
+        )
+    capacity = cache.k.shape[2]
     idx = positions if ring <= 0 else jnp.mod(positions, ring)
     # out-of-bounds scatter indices are dropped (jax .at[] default), which
     # both masks the padding lanes and makes an overfull dense cache
@@ -1189,6 +1398,58 @@ def _append_forward(
         return x, rows + stats
 
     x, ys = _layer_scan(params["layers"], layer, x, (cache.k, cache.v))
+    _note_moe(moe_out, ys[2:])
+    return x, ys[:2]
+
+
+def _append_forward_mixed(params, cfg, tokens, cache, cursors, positions, live, *, aids, moe_out):
+    """_append_forward for a mixed stack. `cache.k` / `cache.v` are the two
+    kinds' gathered views, (full [L_full, b, capacity, hkv, hd], window
+    [L_window, b, ring, hkv, hd]): the full layers' rows at their absolute
+    positions, the window layers' as a ROLLING ring of `ring` rows (row =
+    position mod ring, kvcache.paged.gather_ring: the last `ring` positions
+    below the cursor are all a window layer can read). A layer indexes its
+    kind's view, writes the chunk's rows into that copy and attends
+    (chunk_prefill_attention: dense with no window, or the ring's positional
+    masks with the window). What comes back beside the hidden states is NOT
+    the views but the chunk's own rows, [L, b, c, hkv, hd] for every layer
+    of the stack: the caller writes them through each kind's block table."""
+    rings = (0, cache.k[1].shape[2])  # full: position-indexed; window: a ring
+    idx = tuple(
+        jnp.where(live, jnp.mod(positions, ring) if ring else positions, view.shape[2])
+        for ring, view in zip(rings, cache.k)
+    )
+    mm = qmm_a8
+    write = jax.vmap(lambda cb, ub, ib: cb.at[ib].set(ub))
+
+    def kind(j, window):
+        def attend(at, q, k_new, v_new):
+            kc = jax.lax.dynamic_index_in_dim(cache.k[j], at, 0, keepdims=False)
+            vc = jax.lax.dynamic_index_in_dim(cache.v[j], at, 0, keepdims=False)
+            with jax.named_scope("layer/kv_write"):
+                kc = write(kc, k_new.astype(kc.dtype), idx[j])
+                vc = write(vc, v_new.astype(vc.dtype), idx[j])
+            return chunk_prefill_attention(
+                q, kc, vc, cursors, logit_cap=cfg.attn_logit_cap,
+                window=window, ring=rings[j],
+            )
+
+        return attend
+
+    x = _embed_tokens(params, cfg, tokens)
+
+    def layer(x, lp, _rest):
+        def attend(q, k_new, v_new):
+            attn = _by_kind(
+                cfg, lp[LAYER_KEY], kind(1, cfg.window), kind(0, 0), q, k_new, v_new
+            )
+            return attn, (k_new.astype(cache.k[0].dtype), v_new.astype(cache.v[0].dtype))
+
+        x, rows = _attn_block(cfg, x, lp, positions, mm, aids, attend)
+        x, stats = _mlp_residual(cfg, x, lp, mm, aids)
+        return x, rows + stats
+
+    x, ys = _layer_scan(params["layers"], layer, x, (), index=True)
     _note_moe(moe_out, ys[2:])
     return x, ys[:2]
 
@@ -1229,6 +1490,9 @@ def prefill_append(
     Returns (last-valid-token logits [b, vocab] f32, updated cache with
     length = cursors + n_new). Rows with n_new == 0 return garbage logits
     (callers only read logits for rows whose prompt just completed).
+    For a mixed stack `cache.k` / `cache.v` are the two kinds' views and
+    what comes back in their place is the chunk's own rows, every layer's
+    (_append_forward_mixed): the caller writes them through each kind's table.
     """
     b, c = tokens.shape
     x, (ks, vs) = _append_forward(

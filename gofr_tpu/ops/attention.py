@@ -900,6 +900,7 @@ def _paged_decode_partials(
     v_scales=None,
     interpret: bool = False,
     mesh=None,  # TP mesh: the kernel runs per head shard (_head_axes)
+    name: str = "paged_decode",  # what a device trace calls this call's kernel
 ):
     """Pallas paged-attention decode over the valid band [lo, hi):
     returns (o [b, hq, d] f32 normalized, m [b, hq] f32, l [b, hq] f32)
@@ -908,7 +909,7 @@ def _paged_decode_partials(
     quantized = k_scales is not None
     layer = jnp.asarray(layer, jnp.int32)
     call = functools.partial(
-        _paged_decode_call, scale=scale, logit_cap=logit_cap, interpret=interpret
+        _paged_decode_call, scale=scale, logit_cap=logit_cap, interpret=interpret, name=name
     )
     if mesh is None or mesh.size == 1:
         return call(q, k_pool, v_pool, tables, lo, hi, layer, k_scales, v_scales)
@@ -933,10 +934,10 @@ def _paged_decode_partials(
 
 # jitted so that every program that attends at the same shapes (an engine's
 # two dozen step and chunk programs) shares ONE trace of the kernel's body
-@functools.partial(jax.jit, static_argnames=("scale", "logit_cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "logit_cap", "interpret", "name"))
 def _paged_decode_call(
     q, k_pool, v_pool, tables, lo, hi, layer, k_scales, v_scales,
-    *, scale: float, logit_cap: float, interpret: bool,
+    *, scale: float, logit_cap: float, interpret: bool, name: str = "paged_decode",
 ):
     """_paged_decode_partials on one device (or one head shard)."""
     b, hq, d = q.shape
@@ -994,7 +995,9 @@ def _paged_decode_call(
     )
     o, m, l = pl.pallas_call(
         kernel,
-        name="paged_decode",  # what a device trace calls the kernel
+        # what a device trace calls the kernel: a mixed stack's window layers
+        # call it as paged_decode_window, so the two kinds are told apart
+        name=name,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, group, d), jnp.float32),
@@ -1048,6 +1051,7 @@ def paged_chunk_decode_attention(
     use_kernel: bool | None = None,
     interpret: bool = False,
     mesh=None,  # TP mesh the engine serves over (kernel path only)
+    name: str = "paged_decode",  # the kernel's name in a device trace
 ) -> jnp.ndarray:
     """chunk_decode_attention reading the MAIN region through a block
     table: pool rows hold logical positions [0, lengths) via the table,
@@ -1084,6 +1088,7 @@ def paged_chunk_decode_attention(
         q[:, 0], k_pool, v_pool, tables, lo, hi, layer,
         scale=scale, logit_cap=logit_cap,
         k_scales=k_scales, v_scales=v_scales, interpret=interpret, mesh=mesh,
+        name=name,
     )
     # buffer region (dense, [b, chunk]) — same mask set as
     # chunk_decode_attention's buffer half

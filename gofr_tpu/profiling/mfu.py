@@ -120,7 +120,36 @@ class ModelCosts:
     attn_flops_per_token_per_ctx: int
     kv_bytes_per_ctx_token: int  # bytes of K+V a step reads per attended position
     params_bytes: int  # resident weight bytes (int8 when quantized)
-    sliding_window: int  # 0 = global attention
+    # the one window EVERY layer has, 0 where any layer reads all its context
+    # (what a step record's decode context is capped by)
+    sliding_window: int
+    # the keys each layer attends, 0 = all (TransformerConfig.windows): what
+    # read_ctx and attended_below cap attention work by, layer by layer
+    layer_windows: tuple = ()
+
+
+def read_ctx(costs: ModelCosts, ctx: int) -> float:
+    """Positions a token at context `ctx` attends, a layer on average: what
+    `attn_flops_per_token_per_ctx` and `kv_bytes_per_ctx_token` (both summed
+    over ALL layers) are multiplied by. min(ctx, window) where every layer
+    has the one window; in a mixed stack the window layers' capped share and
+    the full layers' whole context."""
+    ws = costs.layer_windows or (costs.sliding_window,)
+    return sum(min(ctx, w) if w else ctx for w in ws) / len(ws)
+
+
+def attended_below(costs: ModelCosts, p: int) -> float:
+    """Sum over positions 0..p-1 of the keys a token there attends (itself
+    included), a layer on average, exact at the window's edge."""
+
+    def one(w: int) -> float:
+        if not w or p <= w:
+            return p * (p + 1) / 2  # the full causal triangle
+        # the first w tokens attend causally, every later one exactly w
+        return w * (w + 1) / 2 + (p - w) * w
+
+    ws = costs.layer_windows or (costs.sliding_window,)
+    return sum(one(w) for w in ws) / len(ws)
 
 
 def model_costs(cfg, *, quantized: bool = False) -> ModelCosts:
@@ -158,11 +187,14 @@ def model_costs(cfg, *, quantized: bool = False) -> ModelCosts:
         shared = int(getattr(cfg, "n_shared_experts", 0) or 0)
         n_dense = cfg.n_dense_layers if len(cfg.group_sizes) > 1 else 0
         n_moe = cfg.n_layers - n_dense
-        active = n_dense * dense_mlp + n_moe * (
-            3 * d * fe * (cfg.moe_top_k + shared) + d * n_experts
+        # (a program that holds a share of the experts multiplies that share
+        # of a token's top-k choices on average, and keeps only its own)
+        held = int(getattr(cfg, "held_experts", n_experts) or n_experts)
+        active = n_dense * dense_mlp + n_moe * int(
+            3 * d * fe * (cfg.moe_top_k * held / n_experts + shared) + d * n_experts
         )
         resident = n_dense * dense_mlp + n_moe * (
-            3 * d * fe * (n_experts + shared) + d * n_experts
+            3 * d * fe * (held + shared) + d * n_experts
         )
     else:
         active = resident = dense_mlp * cfg.n_layers
@@ -179,6 +211,9 @@ def model_costs(cfg, *, quantized: bool = False) -> ModelCosts:
         kv_bytes_per_ctx_token=cfg.n_layers * kv_values * kv_itemsize,
         params_bytes=(attn_params * cfg.n_layers + resident + embed_params) * itemsize,
         sliding_window=int(getattr(cfg, "sliding_window", 0) or 0),
+        layer_windows=tuple(
+            getattr(cfg, "windows", ()) if getattr(cfg, "mixed", False) else ()
+        ),
     )
 
 
@@ -209,14 +244,8 @@ def prefill_flops(costs: ModelCosts, seq_lens: list[int]) -> float:
     unembed matmul runs once per sequence (last position only) and
     causal attention attends ~s/2 positions per token (window-capped)."""
     total = 0.0
-    w = costs.sliding_window
     for s in seq_lens:
-        if not w or s <= w:
-            attended = s * (s + 1) / 2  # full causal triangle
-        else:
-            # exact window cap: the first w tokens attend causally, every
-            # later token attends exactly w positions
-            attended = w * (w + 1) / 2 + (s - w) * w
+        attended = attended_below(costs, s)
         total += (
             2 * s * costs.layer_params
             + 2 * costs.embed_params
@@ -234,18 +263,10 @@ def chunk_prefill_flops(costs: ModelCosts, spans: list[tuple[int, int]]) -> floa
     (the step op computes last-token logits every chunk, which is the
     chunked path's extra cost over one-shot prefill)."""
     total = 0.0
-    w = costs.sliding_window
-
-    def attended_below(p: int) -> float:
-        # sum over positions 0..p-1 of min(pos + 1, window or inf)
-        if not w or p <= w:
-            return p * (p + 1) / 2
-        return w * (w + 1) / 2 + (p - w) * w
-
     for cursor, n in spans:
         if n <= 0:
             continue
-        attended = attended_below(cursor + n) - attended_below(cursor)
+        attended = attended_below(costs, cursor + n) - attended_below(costs, cursor)
         total += (
             2 * n * costs.layer_params
             + 2 * costs.embed_params
@@ -272,17 +293,10 @@ def spec_verify_flops(costs: ModelCosts, spans: list[tuple[int, int]]) -> float:
     sampled from its own unembed) with position-exact attention per
     accepted position, the chunk_prefill_flops span convention."""
     total = 0.0
-    w = costs.sliding_window
-
-    def attended_below(p: int) -> float:
-        if not w or p <= w:
-            return p * (p + 1) / 2
-        return w * (w + 1) / 2 + (p - w) * w
-
     for cursor, n in spans:
         if n <= 0:
             continue
-        attended = attended_below(cursor + n) - attended_below(cursor)
+        attended = attended_below(costs, cursor + n) - attended_below(costs, cursor)
         total += (
             n * costs.matmul_flops_per_token
             + costs.attn_flops_per_token_per_ctx * attended

@@ -18,6 +18,12 @@ PR 32 added a program and changed none: a step without its decode chunk
 pinned in `tests/data/engine_programs_pr32.json` (written on PR 32's tree by
 this file's `__main__`, which leaves PR 30's file alone) and are cases of the
 same test.
+
+PR 33 changed none of them either (one scalar window and "every expert held"
+are the special cases of the per-layer pattern and of the expert share) and
+added an engine: `mixed`, the tiny twin of a stack of window and full layers
+over two pools that holds a share of its experts. Its programs are pinned in
+`tests/data/engine_programs_pr33.json`, written on PR 33's tree.
 """
 
 from __future__ import annotations
@@ -37,6 +43,7 @@ from gofr_tpu.models.transformer import TransformerConfig, init_params
 _DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 _PINS = os.path.join(_DATA, "engine_programs_pr30.json")
 _PINS_D0 = os.path.join(_DATA, "engine_programs_pr32.json")  # the step programs without their decode chunk
+_PINS_MIXED = os.path.join(_DATA, "engine_programs_pr33.json")  # the mixed engine's, all of them
 
 _KW = dict(
     slots=4, max_seq_len=128, prefill_buckets=(16, 64), decode_chunk=8,
@@ -50,6 +57,7 @@ ENGINES = {
     "kv8": ("tiny_qwen2", dict(_KW, kv_paged=True, kv_int8=True, session_mb=1)),
     "slab": ("tiny_llama", dict(_KW, kv_paged=False, prefix_cache_mb=1, lora_slots=2)),
     "moe": ("tiny_moe", dict(_KW, kv_paged=True)),
+    "mixed": ("tiny_mixed_moe", dict(_KW, quantize=True, speculative=False)),
 }
 # The ONLY programs whose text may differ from the parent's, and why: the
 # parent's constrained paged copies were not given PR 29's `moe_out`, so a
@@ -82,7 +90,7 @@ def programs(eng: LLMEngine) -> dict:
     paged = eng.kv.paged
     if paged:
         scales = eng._kv_scales
-        tables, live = i32(S, eng.kv.table_width), jnp.zeros((S,), bool)
+        tables, live = i32(S, eng.kv.table_cols), jnp.zeros((S,), bool)
         pool, pool_live = (cache, scales, tables), (cache, scales, tables, live)
     else:
         pool = pool_live = (cache,)
@@ -94,8 +102,10 @@ def programs(eng: LLMEngine) -> dict:
         out["hit_first_n1"] = (
             eng._hit_first_op, (jnp.zeros((1, V), jnp.float32), jnp.zeros((1,), jnp.float32), rng)
         )
-    scratch = eng.kv.init_cache(1)
-    if paged:
+    scratch = None if eng.kv.mixed else eng.kv.init_cache(1)
+    if eng.kv.mixed:
+        pass  # insert, seed and restore are written for one pool and never run for two
+    elif paged:
         out["insert_many_n1"] = (eng._insert_many, (cache, scales, scratch, meta[:2], tables))
         out["kv_seed"] = (eng._seed_op, (cache, scales, i32(M), i32(M), i32(M), i32(M)))
         L, B = cache.k.shape[0], eng.kv.block
@@ -143,7 +153,7 @@ def _sha(op, args) -> str:
 
 
 PINNED: dict = {}
-for _path in (_PINS, _PINS_D0):
+for _path in (_PINS, _PINS_D0, _PINS_MIXED):
     with open(_path) as _f:
         PINNED.update(json.load(_f))
 
@@ -174,12 +184,9 @@ def test_every_program_of_the_six_engines_is_pinned():
     assert set(CHANGED) <= have
 
 
-if __name__ == "__main__":  # PYTHONPATH=. python tests/test_engine_programs.py: writes the `_d0` pins (PR 32's tree)
-    pins = {
-        f"{e}.{p}": _sha(*oa) for e in ENGINES for p, oa in programs(_build(e)).items()
-        if p.endswith("_d0")
-    }
-    with open(_PINS_D0, "w") as f:
+if __name__ == "__main__":  # PYTHONPATH=. python tests/test_engine_programs.py: writes the mixed engine's pins (PR 33's tree)
+    pins = {f"mixed.{p}": _sha(*oa) for p, oa in programs(_build("mixed")).items()}
+    with open(_PINS_MIXED, "w") as f:
         json.dump(dict(sorted(pins.items())), f, indent=1)
         f.write("\n")
     print(len(pins), "programs pinned")

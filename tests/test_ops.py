@@ -276,6 +276,47 @@ def test_paged_decode_compiles_for_the_v5e(
     assert sorted(pool_sized) == [("parameter", (layers, n_blocks, block, hkv * d))] * 2
 
 
+@pytest.mark.parametrize("name,layers,n_blocks,window", [
+    ("paged_decode", 3, 8208, 0),  # the full layers' pool: grows with the context
+    ("paged_decode_window", 10, 240, 128),  # the window layers': 15 blocks a slot
+])
+def test_a_mixed_stacks_two_decode_calls_compile_for_the_v5e(v5e_chip, name, layers, n_blocks, window):
+    """k-exaone-236b-a23b.mixed-closed's two kinds of decode call (16 lanes,
+    64 / 8 heads of 128, 16-token blocks, a table of 520 slots): ONE kernel
+    through Mosaic for a v5e under the name each kind gives it, which is what
+    tells them apart in a device trace; each kind's pool, as stored, is the
+    call's own operand."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from gofr_tpu.ops.attention import paged_chunk_decode_attention
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    lanes, hq, hkv, d, block, n_tbl = 16, 64, 8, 128, 16, 520
+    kp = S((layers, n_blocks, block, hkv * d), jnp.bfloat16)
+    buf = S((lanes, 8, hkv, d), jnp.bfloat16)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(
+            lambda q, kp, vp, t, kb, vb, n, s, ly: paged_chunk_decode_attention(
+                q, kp, vp, t, kb, vb, n, s, layer=ly, window=window, use_kernel=True, name=name,
+            )
+        ).lower(
+            S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
+            buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
+    assert f"%{name}." in call or f"%{name} " in call
+    stack = f"bf16[{layers},{n_blocks},{block},{hkv * d}]"
+    assert call.split("operand_layout_constraints", 1)[1].count(stack) == 2
+
+
 def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     """The latent kernel at glm-4.7-flash.think-closed's own shapes (13 layers
     of 5,104 blocks, rows of 512 | 128, 20 heads, 16 lanes, a table of 200):
