@@ -9,9 +9,10 @@ Three program kinds carry decode, and each has ONE body:
 
 - **chunk**: advance every active slot K decode steps (models.transformer
   decode_chunk / decode_chunk_paged), sampling on the device.
-- **step**: the token-budget scheduler's unified step. Gather the prefilling
-  slots' KV rows, append one prompt chunk per packed row
-  (models.transformer.prefill_append), write the rows back, activate rows
+- **step**: the token-budget scheduler's unified step. Append one prompt
+  chunk per packed row to its slot's KV (models.transformer.prefill_append:
+  over the pool's gathered view, whose new rows go back through the tables,
+  or straight into the contiguous stack, in place), activate rows
   whose prompt just completed (their first token sampled from the chunk's
   last-token logits and merged into the on-device tail, no host round trip),
   then, in the SAME program, the decode chunk: rows that finish this step
@@ -33,8 +34,8 @@ What differs between the engine's configurations is supplied by two things
 chosen when the engine is built, at trace time, never inside a traced branch:
 
 - a **layout** (`_Slab`: the contiguous slab or rolling ring | `_Pool`: the
-  paged block pool): how a step gathers the rows it appends to and writes
-  them back, how the decode chunk runs, which operands the cache is
+  paged block pool): how a step reads the rows it appends to and where it
+  writes the new ones, how the decode chunk runs, which operands the cache is
   (`cache` | `cache, scales, tables` and a host `live` mask) and which of
   them are donated;
 - a **sampler** (`_Sampler`: plain | `_GrammarSampler`: masked by a resident
@@ -262,8 +263,11 @@ class _Slab:
     """The contiguous layouts (kv_paged=False): a dense
     [n_layers, S, rows, hkv, hd] slab for global attention, or the
     window-bounded ROLLING ring of a sliding-window model (kv.ring > 0).
-    A step gathers and scatters whole slot rows; the decode chunk merges
-    into the slab itself."""
+    Every program that adds rows writes THOSE rows into the engine's own
+    donated stack, where it lies, by one scatter at (layer, slot, row), and
+    reads the resident rows from it as stored: a step's append and verify
+    through models.transformer._append_forward's `slots` form, the ring's
+    decode chunk at its end-of-chunk merge (PERF.md §3)."""
 
     operands = results = ("cache",)  # what the cache is among a program's operands | results
     live: tuple = ()
@@ -280,22 +284,13 @@ class _Slab:
         return ("tail",) if kind in ("step", "rows") else ()
 
     def append(self, a, tokens, slot_idx, cursors, n_new, aids_row):
-        p, cache = self.p, a["cache"]
-        # gather the target slots' resident rows (padding lanes clip to a
-        # real slot but never write back)
-        sub = cache._replace(
-            k=jnp.take(cache.k, slot_idx, axis=1, mode="clip"),
-            v=jnp.take(cache.v, slot_idx, axis=1, mode="clip"),
-            length=cursors,
-        )
-        logits, sub = prefill_append(
-            a["params"], p.cfg, tokens, sub, cursors, n_new,
-            ring=p.kv.ring, aids=aids_row, mesh=p.mesh,
-        )
-        a["cache"] = cache._replace(
-            k=cache.k.at[:, slot_idx].set(sub.k, mode="drop"),
-            v=cache.v.at[:, slot_idx].set(sub.v, mode="drop"),
-            length=cache.length.at[slot_idx].set(cursors + n_new, mode="drop"),
+        # the engine's own donated stack and the packed rows' slots: the
+        # chunk's rows are written into it where it lies (padding lanes
+        # read a real slot, clipped, and write nothing)
+        p = self.p
+        logits, a["cache"] = prefill_append(
+            a["params"], p.cfg, tokens, a["cache"], cursors, n_new,
+            ring=p.kv.ring, aids=aids_row, mesh=p.mesh, slots=slot_idx,
         )
         return logits
 
@@ -313,6 +308,7 @@ class _Slab:
         logits, a["cache"] = verify_chunk(
             a["params"], p.cfg, toks, cache, cache.length, n_in,
             ring=p.kv.ring, aids=a["params"].get("aids"), mesh=p.mesh,
+            slots=jnp.arange(p.slots, dtype=jnp.int32),
         )
         return logits
 

@@ -1001,6 +1001,21 @@ def _chunk_buffer_write(kb_l, vb_l, k_new, v_new, k_i):
     return kb_l, vb_l
 
 
+def _land_rows(stack, rows, slots, idx):
+    """Write `rows` [L, n, c, hkv, hd] into a contiguous stack
+    [L, S, C, hkv, hd] WHERE IT LIES: row (l, i, j) lands at
+    (l, slots[i], idx[i, j]) by ONE scatter over the stored shape, so a
+    donated stack is updated in place and no whole slot, layer or stack is
+    copied, transposed or restacked on the way (PERF.md §3, the contiguous
+    layouts). An entry whose slot or index is out of range is dropped: a
+    padding lane carries slot = S, a position past n_new the index C. Only
+    unsharded axes are indexed (a TP mesh shards hkv)."""
+    layers = jnp.arange(stack.shape[0], dtype=jnp.int32)[:, None, None]
+    return stack.at[layers, slots[None, :, None], idx[None]].set(
+        rows.astype(stack.dtype), mode="drop"
+    )
+
+
 def _note_moe(moe_out: list | None, stats) -> None:
     """Hand a routed model's summed moe_layer_stats to the caller's list
     (a trace-time side channel beside the program's own results: the
@@ -1036,11 +1051,18 @@ def decode_chunk(
     of a small [L, b, n_steps, hkv, hd] ring buffer (one aligned
     dynamic_update_slice), and attention spans cache+buffer with a joint
     softmax (ops.chunk_decode_attention). The buffer is merged into
-    per-slot cursor positions ONCE at chunk end. Rationale (measured on
-    v5e): per-step vmap'd scatters at per-sequence cursors plus restacking
-    the full cache through scan outputs cost ~3.5 ms/step across 18 layers
-    — 6x the attention math itself; this layout amortizes the scatter to
-    once per chunk and removes the restack entirely.
+    per-slot cursor positions ONCE at chunk end, so the scan never writes
+    the cache at per-sequence cursors and never restacks it through its
+    outputs (the first Gemma runs on a v5e measured that at six times the
+    attention math). What the merge is now: on the rolling ring ONE scatter
+    over the stored `[L, S, C, hkv, hd]` shape (_land_rows), which XLA
+    runs in place on the donated stack; before PR 34 it was a vmap over the
+    slot axis, which compiled to a transpose of the whole stack in and out
+    around a write that was not in place: 13 of the 19 ms that passes over
+    whole rings took of a 130 ms decode half at mistral-7b's sizes (PERF.md
+    §5, §6 PR 34; the rest lays the ring out as the scan's dots read it, and
+    is still there). The dense slab keeps its per-slot
+    dynamic_update_slice, which the pool's off-TPU decode shares.
 
     ALL slots run every step (no per-step freeze): inactive slots sample
     garbage the host discards, and only active slots' lengths advance at
@@ -1138,14 +1160,13 @@ def decode_chunk(
         # the scatter is order-independent. Garbage rows written for
         # inactive slots are harmless: a free slot is rewritten wholesale
         # at admission, and lengths (hence masks) never advance for them.
+        # One scatter at (layer, slot, row) over the stack as it is stored.
         idx = jnp.mod(
             cache.length[:, None] + jnp.arange(K, dtype=jnp.int32), ring
         )  # [b, K]
-        merge = jax.vmap(
-            lambda c, u, ix: c.at[:, ix].set(u), in_axes=(1, 1, 0), out_axes=1
-        )
-        new_k = merge(cache.k, kb, idx)
-        new_v = merge(cache.v, vb, idx)
+        slots = jnp.arange(b, dtype=jnp.int32)
+        new_k = _land_rows(cache.k, kb, slots, idx)
+        new_v = _land_rows(cache.v, vb, slots, idx)
         # lengths stay ABSOLUTE (positions/RoPE/window math need them);
         # the engine's submit() cap bounds them by max_seq_len + a chunk
         new_len = jnp.where(active, cache.length + K, cache.length)
@@ -1342,11 +1363,24 @@ def _append_forward(
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
     mesh=None,  # TP mesh: the flash kernel runs per head shard (ops.attention)
     moe_out: list | None = None,  # a routed model appends its moe stats (_note_moe)
+    slots: jnp.ndarray | None = None,  # [b] int32: `cache` is the whole stack, lane i appends to slot slots[i]
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """Shared write-then-attend chunk append (prefill_append and
     verify_chunk): write the chunk's K/V rows at the per-sequence cursor,
     attend over all resident keys + the chunk's causal triangle, return
     the final hidden states [b, c, d] plus the updated (k, v) stacks.
+
+    Two callers, told apart by ``slots``. The paged pool (slots=None) hands
+    a view it GATHERED, [L, b, capacity, hkv, hd], one lane a row; the
+    layers write into it and it comes back whole, for the caller to scatter
+    through its tables. The contiguous layouts (llm_programs._Slab) hand the
+    engine's own stack [L, S, capacity, hkv, hd] and the lanes' slots: each
+    layer gathers its lanes' rows out of ITS layer (the copy that
+    write-then-attend needs anyway, a layer at a time), the scan yields only
+    the chunk's rows [L, b, c, hkv, hd], and ONE scatter lands them in the
+    stack where it lies (_land_rows): no whole slot is taken out, restacked
+    by the scan or written back. A lane whose slot is out of range (padding)
+    reads a real slot, clipped, and writes nothing.
 
     ``aids`` is EXPLICIT here (unlike the decode chunks, which read
     params["aids"] directly): the unified step ops prefill a PACKED
@@ -1373,13 +1407,19 @@ def _append_forward(
     x = _embed_tokens(params, cfg, tokens)
 
     def layer(x, lp, rest):
-        kc, vc = rest  # [b, capacity, hkv, hd]
+        kc, vc = rest  # [b, capacity, hkv, hd]; with `slots` the layer's [S, capacity, hkv, hd]
 
         def attend(q, k_new, v_new):
             with jax.named_scope("layer/kv_write"):
+                kv, vv = kc, vc
+                if slots is not None:
+                    kv = jnp.take(kc, slots, axis=0, mode="clip")
+                    vv = jnp.take(vc, slots, axis=0, mode="clip")
                 write = jax.vmap(lambda cb, ub, ib: cb.at[ib].set(ub))
-                kc2 = write(kc, k_new.astype(kc.dtype), idx)
-                vc2 = write(vc, v_new.astype(vc.dtype), idx)
+                k_new = k_new.astype(kc.dtype)
+                kc2 = write(kv, k_new, idx)
+                v_new = v_new.astype(vc.dtype)
+                vc2 = write(vv, v_new, idx)
             with jax.named_scope("layer/attn"):
                 if cfg.latent:
                     attn = latent_chunk_prefill_attention(
@@ -1391,7 +1431,7 @@ def _append_forward(
                         logit_cap=cfg.attn_logit_cap, window=cfg.sliding_window,
                         ring=ring, mesh=mesh,
                     )
-            return attn, (kc2, vc2)
+            return attn, ((kc2, vc2) if slots is None else (k_new, v_new))
 
         x, rows = _attn_block(cfg, x, lp, positions, mm, aids, attend)
         x, stats = _mlp_residual(cfg, x, lp, mm, aids)
@@ -1399,7 +1439,18 @@ def _append_forward(
 
     x, ys = _layer_scan(params["layers"], layer, x, (cache.k, cache.v))
     _note_moe(moe_out, ys[2:])
-    return x, ys[:2]
+    if slots is None:
+        return x, ys[:2]
+    return x, (_land_rows(cache.k, ys[0], slots, idx), _land_rows(cache.v, ys[1], slots, idx))
+
+
+def _appended_length(cache: KVCache, cursors, n_new, slots):
+    """The lengths after an append: the lanes' own, or with `slots` the whole
+    stack's with the lanes' slots advanced (a padding lane's slot is out of
+    range and dropped)."""
+    if slots is None:
+        return cursors + n_new
+    return cache.length.at[slots].set(cursors + n_new, mode="drop")
 
 
 def _append_forward_mixed(params, cfg, tokens, cache, cursors, positions, live, *, aids, moe_out):
@@ -1467,6 +1518,7 @@ def prefill_append(
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
     mesh=None,  # TP mesh (see _append_forward)
     moe_out: list | None = None,  # a routed model appends its moe stats (_note_moe)
+    slots: jnp.ndarray | None = None,  # [b] int32: `cache` is the whole contiguous stack (see _append_forward)
 ) -> tuple[jnp.ndarray, KVCache]:
     """Append one prefill chunk into an existing per-slot KV cache.
 
@@ -1484,8 +1536,12 @@ def prefill_append(
 
     Unlike decode_chunk there is no per-step ring buffer: the whole chunk
     is one forward pass (c token rows, MXU-bound like prefill), so the
-    scatter amortizes over c tokens and the cache restack through the
-    layer scan costs what the gather already paid.
+    scatter amortizes over c tokens. The paged pool's gathered view comes
+    back restacked by the layer scan, which costs what its gather already
+    paid; the contiguous layouts pass ``slots`` and the engine's whole
+    stack, and get it back with the chunk's rows written where it lies
+    (_append_forward: nothing of a ring's size is gathered, restacked or
+    written back) and the lanes' slots' lengths advanced.
 
     Returns (last-valid-token logits [b, vocab] f32, updated cache with
     length = cursors + n_new). Rows with n_new == 0 return garbage logits
@@ -1497,13 +1553,13 @@ def prefill_append(
     b, c = tokens.shape
     x, (ks, vs) = _append_forward(
         params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids,
-        mesh=mesh, moe_out=moe_out,
+        mesh=mesh, moe_out=moe_out, slots=slots,
     )
     last = jnp.clip(n_new - 1, 0, c - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None].astype(jnp.int32), axis=1)
     with jax.named_scope("unembed_sample"):  # the caller samples from these
         logits = _unembed_last(params, cfg, x_last)  # [b, vocab] f32
-    new_cache = KVCache(k=ks, v=vs, length=cursors + n_new)
+    new_cache = KVCache(k=ks, v=vs, length=_appended_length(cache, cursors, n_new, slots))
     return logits, new_cache
 
 
@@ -1518,6 +1574,7 @@ def verify_chunk(
     ring: int = 0,  # >0: cache is a rolling ring of this capacity
     aids: jnp.ndarray | None = None,  # [b] int32 per-row adapter ids (LoRA)
     mesh=None,  # TP mesh (see _append_forward)
+    slots: jnp.ndarray | None = None,  # [b] int32: `cache` is the whole contiguous stack (see _append_forward)
 ) -> tuple[jnp.ndarray, KVCache]:
     """Score every position of a speculative-decoding draft in ONE
     forward pass (gofr_tpu.spec; docs/advanced-guide/speculative-decoding.md).
@@ -1543,11 +1600,11 @@ def verify_chunk(
     """
     x, (ks, vs) = _append_forward(
         params, cfg, tokens, cache, cursors, n_new, ring=ring, aids=aids,
-        mesh=mesh,
+        mesh=mesh, slots=slots,
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _unembed(params, cfg, x)  # [b, c, vocab] f32
-    new_cache = KVCache(k=ks, v=vs, length=cursors + n_new)
+    new_cache = KVCache(k=ks, v=vs, length=_appended_length(cache, cursors, n_new, slots))
     return logits, new_cache
 
 

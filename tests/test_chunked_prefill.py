@@ -116,6 +116,102 @@ class TestPrefillAppendOp:
             assert int(jnp.argmax(logits[0])) == int(jnp.argmax(logits_ref[0]))
 
 
+def _gathered_form(params, cfg, tokens, cache, slots, cursors, n_new, ring):
+    """The contiguous layouts' append as every step ran it before PR 34: take
+    the rows' whole slots out of the stack, append to that copy, write the
+    whole slots back (padding lanes, slot = S, clip to a real slot and drop
+    their write). What the `slots` form of prefill_append must equal, bit
+    for bit."""
+    sub = cache._replace(
+        k=jnp.take(cache.k, slots, axis=1, mode="clip"),
+        v=jnp.take(cache.v, slots, axis=1, mode="clip"), length=cursors,
+    )
+    logits, sub = prefill_append(params, cfg, tokens, sub, cursors, n_new, ring=ring)
+    return logits, cache._replace(
+        k=cache.k.at[:, slots].set(sub.k, mode="drop"),
+        v=cache.v.at[:, slots].set(sub.v, mode="drop"),
+        length=cache.length.at[slots].set(cursors + n_new, mode="drop"),
+    )
+
+
+# (slots, cursors, n_new) a step, over a stack of 4 slots and rows of 16:
+# slot 0 takes 51 tokens in all, twice round a ring of 24
+_STEPS = [
+    ([2, 0], [0, 0], [16, 16]),  # a packed subset, in another order than the slots'
+    ([0, 2, 4], [16, 16, 0], [16, 5, 0]),  # n_new < c beside a full row, and a padding lane (slot 4 of 4)
+    ([3, 4, 0, 4], [0, 0, 32, 0], [16, 0, 16, 0]),  # padding lanes between real ones; slot 0 rolls the ring
+    ([0], [48], [3]),
+]
+
+
+class TestAppendInPlace:
+    """prefill_append's `slots` form (llm_programs._Slab: the engine's whole
+    stack and the packed rows' slots, the chunk's rows written where the
+    stack lies) against the gather / append / write-back it replaced."""
+
+    @pytest.mark.parametrize("layout", ["ring", "slab"])
+    def test_equals_the_gathered_form_step_by_step(self, layout, params, params_w):
+        cfg, prm, cap, ring = (
+            (CFGW, params_w, 24, 24) if layout == "ring" else (CFG, params, 64, 0)
+        )
+        rng = np.random.default_rng(34)
+        cache = init_cache(cfg, 4, cap)
+        # rows of a previous occupant everywhere: what an append must not disturb
+        cache = cache._replace(
+            k=jnp.asarray(rng.standard_normal(cache.k.shape), cache.k.dtype),
+            v=jnp.asarray(rng.standard_normal(cache.v.shape), cache.v.dtype),
+        )
+        theirs = mine = cache
+        prompt0: list[int] = []
+        for slots, cursors, n_new in _STEPS:
+            toks = rng.integers(1, cfg.vocab_size, (len(slots), 16)).astype(np.int32)
+            if 0 in slots:
+                i = slots.index(0)
+                prompt0 += toks[i, : n_new[i]].tolist()
+            args = [jnp.asarray(x, jnp.int32) for x in (slots, cursors, n_new)]
+            want, theirs = _gathered_form(prm, cfg, jnp.asarray(toks), theirs, *args, ring)
+            got, mine = prefill_append(
+                prm, cfg, jnp.asarray(toks), mine, args[1], args[2], ring=ring, slots=args[0]
+            )
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            for a, b in zip(mine, theirs):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert mine.length.tolist() == [51, 0, 21, 16]
+        # ...and slot 0's last logits are the monolithic prefill's of its 51 tokens
+        toks = np.zeros((1, 64), np.int32)
+        toks[0, :51] = prompt0
+        ref, _ = prefill(prm, cfg, jnp.asarray(toks), jnp.asarray([51], jnp.int32), 64)
+        assert int(jnp.argmax(got[0])) == int(jnp.argmax(ref[0]))
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref[0]), atol=2e-4)
+
+    def test_the_one_scatter_is_the_merges_it_replaced(self):
+        """_land_rows against the two writes of the parent: the ring merge
+        vmapped over the slot axis (decode_chunk) and a row's masked write
+        into its own slot (the append), with lengths that wrap, a padding
+        lane and positions past n_new."""
+        from gofr_tpu.models.transformer import _land_rows
+
+        rng = np.random.default_rng(7)
+        L, S, C, K = 3, 4, 24, 8
+        stack = jnp.asarray(rng.standard_normal((L, S, C, 2, 16)), jnp.float32)
+        rows = jnp.asarray(rng.standard_normal((L, S, K, 2, 16)), jnp.float32)
+        idx = jnp.mod(jnp.asarray([0, 20, 47, 23])[:, None] + jnp.arange(K), C)
+        merge = jax.vmap(lambda c, u, ix: c.at[:, ix].set(u), in_axes=(1, 1, 0), out_axes=1)
+        np.testing.assert_array_equal(
+            np.asarray(_land_rows(stack, rows, jnp.arange(S), idx)), np.asarray(merge(stack, rows, idx))
+        )
+        # the append: rows of slots 2, (padding), 0; indices past n_new are C and dropped
+        slots = jnp.asarray([2, S, 0])
+        idx3 = jnp.asarray([[22, 23, 0, 1, C, C, C, C], [C] * K, list(range(8, 16))])
+        want = stack
+        for i, s_ in enumerate([2, None, 0]):
+            if s_ is not None:
+                want = want.at[:, s_, idx3[i]].set(rows[:, i], mode="drop")
+        np.testing.assert_array_equal(
+            np.asarray(_land_rows(stack, rows[:, :3], slots, idx3)), np.asarray(want)
+        )
+
+
 class TestChunkPrefillAttention:
     def test_matches_reference_with_offsets(self):
         rng = np.random.default_rng(0)

@@ -24,6 +24,17 @@ are the special cases of the per-layer pattern and of the expert share) and
 added an engine: `mixed`, the tiny twin of a stack of window and full layers
 over two pools that holds a share of its experts. Its programs are pinned in
 `tests/data/engine_programs_pr33.json`, written on PR 33's tree.
+
+PR 34 is the first that MEANS to change pinned programs, those of the
+contiguous layouts (`llm_programs._Slab`): a program that adds rows writes
+them into the donated stack where it lies. The 22 `mistral.*` programs that
+append, verify or merge (every step, the verify, the ring's decode chunks)
+and the 18 `slab.*` ones that append or verify are listed in `CHANGED` with
+the reason, must differ from their old pins, and are held to their new text
+in `tests/data/engine_programs_pr34.json` (this file's `__main__`, on PR 34's
+tree). The dense slab's decode chunks (`slab.chunk*`: the `ring == 0` merge,
+which the pool's off-TPU decode shares), every wave-path program and every
+program of the five paged engines pass byte for byte.
 """
 
 from __future__ import annotations
@@ -44,6 +55,7 @@ _DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 _PINS = os.path.join(_DATA, "engine_programs_pr30.json")
 _PINS_D0 = os.path.join(_DATA, "engine_programs_pr32.json")  # the step programs without their decode chunk
 _PINS_MIXED = os.path.join(_DATA, "engine_programs_pr33.json")  # the mixed engine's, all of them
+_PINS_IN_PLACE = os.path.join(_DATA, "engine_programs_pr34.json")  # the contiguous layouts' programs PR 34 changed
 
 _KW = dict(
     slots=4, max_seq_len=128, prefill_buckets=(16, 64), decode_chunk=8,
@@ -68,6 +80,29 @@ CHANGED = {
     f"moe.{p}": "a routed model's grammar program now returns the expert vector its plain twin returns"
     for p in ("chunk2g", "chunk8g", "step_p16_n1g", "step_p16_n4g", "step_p64_n1g", "step_p64_n4g")
 }
+# PR 34, the contiguous layouts: every step (fused or `_d0`, plain or
+# grammar) and the verify of the ring and of the slab, and the ring's decode
+# chunks. Re-pinned in _PINS_IN_PLACE.
+_STEPS = [
+    f"step_p{shape}_n{nb}{g}{d0}"
+    for shape in (16, 64) for nb in (1, 4) for g in ("", "g") for d0 in ("", "_d0")
+]
+IN_PLACE = {
+    **{
+        f"{eng}.{p}": "the append scatters the chunk's rows into the donated stack: no take of whole "
+        "slots, no scan output of ring size, no whole-slot write-back"
+        for eng in ("mistral", "slab") for p in _STEPS
+    },
+    **{
+        f"{eng}.{p}": "verify_chunk follows the append: the stack and every slot, the drafts' rows scattered in place"
+        for eng in ("mistral", "slab") for p in ("step_v", "step_vg")
+    },
+    **{
+        f"mistral.{p}": "the ring's end-of-chunk merge is one scatter over the stored shape, not a vmap over the slot axis"
+        for p in ("chunk2", "chunk2g", "chunk8", "chunk8g")
+    },
+}
+CHANGED.update(IN_PLACE)
 
 
 def _build(name: str) -> LLMEngine:
@@ -178,15 +213,29 @@ def test_a_program_lowers_to_the_text_it_lowered_to_at_pr30(key):
         assert got == PINNED[key], key
 
 
+with open(_PINS_IN_PLACE) as _f:
+    REPINNED = json.load(_f)
+
+
+@pytest.mark.parametrize("key", sorted(IN_PLACE))
+def test_a_program_pr34_changed_lowers_to_its_new_text(key):
+    engine, name = key.split(".", 1)
+    assert _sha(*_engine_programs(engine)[name]) == REPINNED[key], key
+
+
 def test_every_program_of_the_six_engines_is_pinned():
     have = {f"{e}.{p}" for e in ENGINES for p in _engine_programs(e)}
     assert have == set(PINNED)
     assert set(CHANGED) <= have
+    assert set(REPINNED) == set(IN_PLACE)
 
 
-if __name__ == "__main__":  # PYTHONPATH=. python tests/test_engine_programs.py: writes the mixed engine's pins (PR 33's tree)
-    pins = {f"mixed.{p}": _sha(*oa) for p, oa in programs(_build("mixed")).items()}
-    with open(_PINS_MIXED, "w") as f:
+if __name__ == "__main__":  # PYTHONPATH=. python tests/test_engine_programs.py: writes the re-pins of PR 34 (on its tree)
+    pins = {}
+    for key in IN_PLACE:
+        engine, name = key.split(".", 1)
+        pins[key] = _sha(*_engine_programs(engine)[name])
+    with open(_PINS_IN_PLACE, "w") as f:
         json.dump(dict(sorted(pins.items())), f, indent=1)
         f.write("\n")
     print(len(pins), "programs pinned")

@@ -2,6 +2,7 @@
 XLA reference — the test-oracle pattern the reference repo uses for its SQL
 mocks (SURVEY.md §4: seams tested against a stand-in implementation)."""
 
+import contextlib
 import math
 
 import jax
@@ -210,6 +211,22 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _persistent_cache_off():
+    """A compile for a described chip can be written to the persistent
+    compile cache but not read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
 # lanes, hq, hkv, d, block, table slots, pool blocks, pool dtype, window
 @pytest.mark.parametrize(
     "lanes,hq,hkv,d,block,n_tbl,n_blocks,pool,window",
@@ -231,8 +248,6 @@ def test_paged_decode_compiles_for_the_v5e(
     paged_decode and its first operand is the 2-D s32 block table. Its pool
     operands are the program's own parameters, the stack as stored: the
     compiled program holds no array of a layer's pool's size but them."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
     def S(shape, dtype):
@@ -242,12 +257,7 @@ def test_paged_decode_compiles_for_the_v5e(
     kp = S((layers, n_blocks, block, hkv * d), pool)
     sc = S((layers, n_blocks, block, hkv), jnp.float32) if pool == jnp.int8 else None
     buf = S((lanes, 8, hkv, d), jnp.bfloat16)
-    # a compile for a described chip can be written to the persistent
-    # cache but not read back without one: keep it out
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _persistent_cache_off():
         text = jax.jit(
             lambda q, kp, vp, t, kb, vb, n, s, ly, ks, vs: paged_chunk_decode_attention(
                 q, kp, vp, t, kb, vb, n, s, layer=ly, window=window,
@@ -257,9 +267,6 @@ def test_paged_decode_compiles_for_the_v5e(
             S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
             buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32), sc, sc,
         ).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
     call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
     assert "%paged_decode" in call
     assert f"operand_layout_constraints={{s32[{lanes},{n_tbl}]" in call
@@ -286,8 +293,6 @@ def test_a_mixed_stacks_two_decode_calls_compile_for_the_v5e(v5e_chip, name, lay
     through Mosaic for a v5e under the name each kind gives it, which is what
     tells them apart in a device trace; each kind's pool, as stored, is the
     call's own operand."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from gofr_tpu.ops.attention import paged_chunk_decode_attention
 
     def S(shape, dtype):
@@ -296,10 +301,7 @@ def test_a_mixed_stacks_two_decode_calls_compile_for_the_v5e(v5e_chip, name, lay
     lanes, hq, hkv, d, block, n_tbl = 16, 64, 8, 128, 16, 520
     kp = S((layers, n_blocks, block, hkv * d), jnp.bfloat16)
     buf = S((lanes, 8, hkv, d), jnp.bfloat16)
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _persistent_cache_off():
         text = jax.jit(
             lambda q, kp, vp, t, kb, vb, n, s, ly: paged_chunk_decode_attention(
                 q, kp, vp, t, kb, vb, n, s, layer=ly, window=window, use_kernel=True, name=name,
@@ -308,9 +310,6 @@ def test_a_mixed_stacks_two_decode_calls_compile_for_the_v5e(v5e_chip, name, lay
             S((lanes, 1, hq, d), jnp.bfloat16), kp, kp, S((lanes, n_tbl), jnp.int32),
             buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
         ).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
     call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
     assert f"%{name}." in call or f"%{name} " in call
     stack = f"bf16[{layers},{n_blocks},{block},{hkv * d}]"
@@ -322,18 +321,13 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     of 5,104 blocks, rows of 512 | 128, 20 heads, 16 lanes, a table of 200):
     through Mosaic for a v5e, its pool operands the program's parameters as
     stored, and nothing else in the program as large as a layer of them."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     from gofr_tpu.ops.attention import mla_paged_chunk_decode_attention
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
     layers, n_blocks, block, C, R, lanes, hq, n_tbl = 13, 5104, 16, 512, 128, 16, 20, 200
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _persistent_cache_off():
         text = jax.jit(
             lambda q, cp, rp, t, cb, rb, n, s, ly: mla_paged_chunk_decode_attention(
                 q, cp, rp, t, cb, rb, n, s, scale=0.1, layer=ly, use_kernel=True,
@@ -344,9 +338,6 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
             S((lanes, n_tbl), jnp.int32), S((lanes, 8, 1, C), jnp.bfloat16), S((lanes, 8, 1, R), jnp.bfloat16),
             S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
         ).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
     call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
     assert "%mla_paged_decode" in call
     pool_sized = [
@@ -357,17 +348,90 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     ]
 
 
-def _hlo_results(text: str):
+def _hlo_results(text: str, dtype: str = "[a-z]+[0-9]*"):
     """(operation, result shape) of every instruction in a compiled
-    program's text, a tuple's members each."""
+    program's text, a tuple's members each (those of `dtype`, where given)."""
     import re
 
     inst = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (?P<type>.*?) (?P<op>[a-z][a-z\-]*)\(")
     for ln in text.splitlines():
         m = inst.match(ln)
         if m:
-            for dims in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]*)\]", m["type"]):
+            for dims in re.findall(rf"\b{dtype}\[([0-9,]*)\]", m["type"]):
                 yield m["op"], tuple(int(x) for x in dims.split(",") if x)
+
+
+# what hands an array on without making one: the entry's parameters, the
+# loops that carry the stack and the views of it
+_PASSED_ON = ("parameter", "tuple", "get-tuple-element", "while", "bitcast")
+
+
+@pytest.mark.parametrize("program,rows,copies,writers,spare_gb", [
+    ("rows", 4, 0, 1, 0.5),  # llm.step_p64_d0: the append alone
+    ("chunk", 0, 2, 1, 0.9),  # llm.decode_chunk8: the end-of-chunk merge
+    ("step", 4, 2, 2, 0.9),  # llm.step_p64_d8: both
+    ("step", 2, 2, 2, 0.9),
+])
+def test_the_rings_programs_write_the_stack_where_it_lies(v5e_chip, program, rows, copies, writers, spare_gb):
+    """mistral-7b.long-closed's three programs (32 layers, 4 slots, a ring of
+    4,160 rows of 8 x 128, prompt rows of 64, abstract int8 weights) compiled
+    for a v5e: a program that adds rows to the rolling ring writes THOSE rows
+    into the donated `[L, S, C, hkv, hd]` stack in place. Of the
+    instructions that yield a bf16 array of a whole stack's size, beside
+    what only hands one on, there are the in-place scatters (a fusion and
+    the scatter inside it, one pair an array a writer) and, where a decode
+    chunk runs, the TWO copies that lay K and V out for the scan's per-head
+    dots (PERF.md §5, ROADMAP S2: the physical layout's, not a writer's).
+    No take of whole slots, no scan output of ring size, no transposed
+    `[S, L, ...]` operand, no chained whole-stack dynamic-update-slice; and
+    the temporaries beside those copies stay under `spare_gb`, which a
+    scatter that is not in place (1.09 GB an array) cannot. The parent of
+    PR 34 fails every case (7.2 GB of temporaries in the first)."""
+    from gofr_tpu.kvcache import CacheManager
+    from gofr_tpu.llm_programs import Programs
+    from gofr_tpu.models.quant import quantize_params
+    from gofr_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig.mistral_7b()
+    S, K, c = 4, 8, 64
+    kv = CacheManager(cfg, S, 8192, K, append_widths=(K, c), paged=False)
+    assert (cfg.n_layers, kv.ring, cfg.n_kv_heads, cfg.head_dim) == (32, 4160, 8, 128)
+    progs = Programs(
+        cfg, kv, slots=S, decode_chunk=K, chunk_shapes=(c,), spec_draft=0, mesh=None,
+        tp_gather=None, kernel=False, numeric_check=False, label="ring-v5e", metrics=None,
+    )
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = abstract(jax.eval_shape(
+        lambda k: quantize_params(init_params(k, cfg), cfg.dtype), jax.random.PRNGKey(0)
+    ))
+    cache = abstract(jax.eval_shape(lambda: kv.init_cache(S)))
+    rng = abstract(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    state = (spec((S,)), spec((S,), jnp.bool_), spec((S,), jnp.float32))  # tail, active, temps
+    chunks, steps, _verify = progs.family(False)
+    op, args = {
+        "chunk": (chunks[K], (params, state[0], cache, *state[1:], rng)),
+        "step": (steps[c], (params, cache, *state, spec((rows, c + 3)), spec((2, rows)), rng)),
+        "rows": (progs.rows(False)[c], (params, cache, *state, spec((rows, c + 3)), spec((2, rows)), rng)),
+    }[program]
+    with _persistent_cache_off():
+        compiled = op.lower(*args).compile()
+    stack = math.prod(cache.k.shape)
+    made = sorted(
+        (o, shape) for o, shape in _hlo_results(compiled.as_text(), "bf16")
+        if math.prod(shape) >= stack and o not in _PASSED_ON
+    )
+    flat = (stack // (cfg.n_kv_heads * cfg.head_dim), cfg.n_kv_heads, cfg.head_dim)  # XLA merges (L, S, C) itself
+    assert made == sorted(
+        [("copy", cache.k.shape)] * copies + [("fusion", flat), ("scatter", flat)] * 2 * writers
+    ), made
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries - copies * 2 * stack < spare_gb * 1e9, temporaries
 
 
 @pytest.mark.parametrize("hkv", [4, 1, 2])  # kv sharded / MQA / replicated

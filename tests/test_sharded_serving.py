@@ -185,6 +185,37 @@ class TestTPTokenEquality:
         finally:
             eng.close()
 
+    @pytest.mark.parametrize("layout", ["ring", "slab"])
+    def test_contiguous_stack_written_in_place(self, params, layout):
+        """The contiguous stack under TP is sharded on its kv heads, and a
+        step writes its rows into it by a scatter that indexes (layer,
+        slot, row) alone: three requests at once (packed rows, padding
+        lanes, last rows shorter than their chunk), prompts that take the
+        ring of 12 round three times, equal single-chip greedy; the stack
+        keeps the sharding it was committed with."""
+        cfg = TransformerConfig.tiny_mistral() if layout == "ring" else CFG
+        prm = init_params(jax.random.PRNGKey(0), cfg) if layout == "ring" else params
+        rng = np.random.default_rng(34)
+        prompts = [rng.integers(1, cfg.vocab_size, n).tolist() for n in (38, 5, 23)]
+        want = [_reference(prm, cfg, p, 5 + i) for i, p in enumerate(prompts)]
+        eng = _tp_engine(prm, 2, cfg=cfg, kv_paged=False)
+        try:
+            assert eng.kv.rolling == (layout == "ring") and not eng.kv.paged
+            outs: list = [None] * len(prompts)
+
+            def run(i):
+                outs[i] = eng.generate(list(prompts[i]), max_new_tokens=5 + i)
+
+            threads = [threading.Thread(target=run, args=(i,)) for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert outs == want
+            assert eng.cache.k.sharding.is_equivalent_to(eng._kv_sharding, eng.cache.k.ndim)
+        finally:
+            eng.close()
+
     def test_speculative(self, params):
         """Spec-on TP engine == spec-off single chip (greedy): the fused
         verify program runs against the sharded pool through the same

@@ -40,6 +40,9 @@ LAYOUTS = {
     "pool": ("tiny_qwen2", dict(_KW, kv_paged=True), True),
     "latent": ("tiny_latent_moe", dict(_KW), False),
 }
+# one more contiguous layout, for the test of the stack written in place (PR 34)
+# alone: the dense slab beside what else reads and seeds it
+SLAB_PLUS = {"slab-prefix-lora": ("tiny_llama", dict(_KW, kv_paged=False, prefix_cache_mb=1, lora_slots=2), True)}
 PROMPT = np.random.default_rng(32).integers(1, V - 1, 40).tolist()  # rows of 16, 16 and 8
 SHORT = [5, 9, 2, 7]
 
@@ -52,16 +55,29 @@ def grammar():
 _built: dict = {}
 
 
+def _layout(layout):
+    return LAYOUTS.get(layout) or SLAB_PLUS[layout]
+
+
 def _model(layout):
     if layout not in _built:
-        cfg = getattr(TransformerConfig, LAYOUTS[layout][0])(vocab_size=V)
+        cfg = getattr(TransformerConfig, _layout(layout)[0])(vocab_size=V)
         _built[layout] = cfg, init_params(jax.random.PRNGKey(1), cfg)
     return _built[layout]
 
 
 def _engine(layout, **kw) -> LLMEngine:
     cfg, params = _model(layout)
-    return LLMEngine(cfg, params, **{**LAYOUTS[layout][1], **kw})
+    return LLMEngine(cfg, params, **{**_layout(layout)[1], **kw})
+
+
+def _generated(layout, prompt, n) -> list[int]:
+    """The standalone generate()'s greedy tokens for one prompt."""
+    cfg, params = _model(layout)
+    toks = np.zeros((1, 64), np.int32)
+    toks[0, : len(prompt)] = prompt
+    want = generate(params, cfg, jax.numpy.asarray(toks), jax.numpy.asarray([len(prompt)], jax.numpy.int32), n)
+    return [int(t) for t in np.asarray(want)[0]]
 
 
 def _steps(eng, since=0) -> list[dict]:
@@ -123,12 +139,7 @@ def test_greedy_tokens_are_those_of_fused_steps(layout, constrained, grammar):
         assert all(r["k"] == K for r in _steps(eng, seq1))
         assert alone == beside
         if LAYOUTS[layout][2] and not constrained:
-            cfg, params = _model(layout)
-            toks = np.zeros((1, 64), np.int32)
-            toks[0, : len(PROMPT)] = PROMPT
-            want = generate(params, cfg, jax.numpy.asarray(toks),
-                            jax.numpy.asarray([len(PROMPT)], jax.numpy.int32), n)
-            assert alone == [int(t) for t in np.asarray(want)[0]]
+            assert alone == _generated(layout, PROMPT, n)
         _the_rule_holds(_steps(eng))
     finally:
         eng.close()
@@ -169,6 +180,42 @@ def test_the_choice_follows_the_lanes_and_the_finishing_rows(layout):
         )
         expo = metrics.render_prometheus()
         assert f'app_llm_steps_without_decode_total{{model="{eng.label}"}} {len(d0)}' in expo
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("layout", ["ring", "slab", "slab-prefix-lora"])
+def test_a_contiguous_stack_written_in_place_serves_what_generate_does(layout):
+    """Six requests over four slots, two packed rows a step (so a step's rows
+    are a subset of the slots in the scheduler's order, padded to a width,
+    the last row of a prompt shorter than its chunk), prompts of up to 64
+    tokens over a ring of 24 (it rolls more than twice), lanes that finish
+    early and ride the following merges inactive: every request's greedy
+    tokens are the standalone generate()'s, and again on a second pass
+    (where a prefix cache is on, its hits seed the slots)."""
+    eng = _engine(layout, slots=4, step_token_budget=2 * CHUNK)
+    try:
+        if layout == "ring":
+            assert eng.kv.rolling and eng.kv.ring == 24
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(1, V - 1, n).tolist() for n in (50, 3, 33, 64, 17, 40)]
+        want = [_generated(layout, p, 4 + 3 * i) for i, p in enumerate(prompts)]
+        for _pass in range(2):
+            outs: list = [None] * len(prompts)
+
+            def run(i):
+                outs[i] = eng.generate(prompts[i], max_new_tokens=4 + 3 * i)
+
+            threads = [threading.Thread(target=run, args=(i,)) for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert outs == want
+        _settle(eng)
+        assert any(len(r["rows"]) == 2 for r in _steps(eng))  # rows were packed
+        if "prefix" in layout:
+            assert eng.stats()["kvcache"]["prefix"]["hits"] >= 1
     finally:
         eng.close()
 
