@@ -1640,7 +1640,7 @@ class LLMEngine:
         if getattr(self.cfg, "latent", False):
             # one latent row for every head: its own paged-decode kernel, and
             # absorbed XLA attention over the gathered rows for prompt chunks
-            from .ops.attention import mla_kernel_why_not
+            from .ops.attention import mla_decode_schedule, mla_kernel_why_not
 
             (_, C), (_, R) = self.kv.row_shapes
             why = mla_kernel_why_not(C, R, self.kv.block)
@@ -1653,10 +1653,10 @@ class LLMEngine:
                 },
             }
             if not why:
-                pages = paged_decode_pages(
-                    self.kv.block, 1, C, self.cfg.dtype, self.kv.table_width
-                )
-                paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+                # the kernel's own schedule: group, depth, one walk over lanes
+                paths["decode_tile"] = mla_decode_schedule(
+                    self.kv.block, C, R, self.cfg.dtype, self.kv.table_width
+                ).report()
                 paths["pool_operand"] = paged_pool_operand(self.kv.pool_shapes()[0], C)
             return paths
         if self.kv.paged:

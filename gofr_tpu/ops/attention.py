@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -1196,90 +1197,205 @@ def mla_kernel_why_not(latent: int, rope: int, block: int, *, interpret: bool = 
     return ""
 
 
+class MlaSchedule(NamedTuple):
+    """How the latent decode kernel walks the pool (mla_decode_schedule)."""
+
+    pages: int  # table slots a page group
+    tokens: int  # rows a group: pages * block
+    in_flight: int  # groups whose copies run ahead of the one being read
+
+    def report(self) -> dict:
+        """stats()["attention"]["decode_tile"] on the latent path."""
+        return {**self._asdict(), "walk": "across lanes"}
+
+
+# The latent kernel's schedule is its own (paged_decode_pages sizes the GQA
+# kernel's tile and shares nothing with it). The latent row is the smallest
+# the pool has (glm: 1,280 B stored), so a step of 128 rows moves little and
+# pays mostly for itself: on a v5e a step's cost was its own work and the
+# starting of its copies, not their latency (two to eight groups in flight
+# read alike). So a group is wide, _MLA_GROUP_TOKENS rows, and as many groups are
+# in flight as _MLA_STREAM_BYTES of page buffers hold beside the one being
+# read, never fewer than two.
+_MLA_GROUP_TOKENS = 512
+_MLA_STREAM_BYTES = 1 << 20
+
+
+def mla_decode_schedule(block: int, latent: int, rope: int, dtype, table_slots: int) -> MlaSchedule:
+    """The latent kernel's page group and depth, from the pool's shapes
+    alone: 16-token pages of 512 | 128 bf16 columns (glm) give 32 pages,
+    512 tokens, 640 KB a group and 2 groups in flight. A group never spans
+    more slots than the block table has."""
+    pages = max(1, min(_MLA_GROUP_TOKENS // block, table_slots))
+    group_bytes = pages * block * (latent + rope) * jnp.dtype(dtype).itemsize
+    return MlaSchedule(pages, pages * block, max(2, _MLA_STREAM_BYTES // group_bytes - 1))
+
+
 def _mla_paged_decode_kernel(
     # scalar prefetch: block tables + per-sequence valid bounds + the layer
     tbl_ref, lo_ref, hi_ref, layer_ref,
     # the lane's queries (pre-scaled, the pool's dtype), the two pools left in
-    # HBM whole ([L, NB, B, C | R]), outputs, page buffers, DMA semaphores
-    qc_ref, qr_ref, c_hbm, r_hbm, o_ref, m_ref, l_ref, c_buf, r_buf, sems,
+    # HBM whole ([L, NB, B, C | R]), outputs, page buffers, DMA semaphores,
+    # the walk's cursor (SMEM, kept across the lanes' programs)
+    qc_ref, qr_ref, c_hbm, r_hbm, o_ref, m_ref, l_ref, c_buf, r_buf, sems, walk,
     *,
     pages: int,
+    in_flight: int,
 ):
-    """_paged_decode_kernel for latent rows: one program a lane, pages
-    copied by the kernel (double buffered), only the lane's band. Every
-    query head meets the same rows, so a step is two matmuls of all heads:
-    scores [hq, tile] from the c_kv and rope parts, then p @ c_kv."""
+    """_paged_decode_kernel for latent rows: one program a lane, only the
+    lane's band, and ONE stream of page copies over every lane's groups in
+    lane order. The programs run in turn and share the buffers, so the
+    copies of the next groups, the next lanes' included, are in flight
+    while a group is read: `in_flight` groups ahead, `in_flight + 1`
+    buffers. `walk` holds the next group to copy (lane, group; lane ==
+    lanes once none is left) and the counts of groups started and read,
+    whose remainders by the buffer count name a group's buffer. A lane with
+    an empty band is passed over and its table never read. Every query head
+    meets the same rows, so a step is two matmuls of all heads: scores
+    [hq, tile] from the c_kv and rope parts, then p @ c_kv."""
     n_pool, block = c_hbm.shape[1], c_hbm.shape[2]
-    n_tbl = tbl_ref.shape[1]
+    lanes, n_tbl = tbl_ref.shape
+    n_buf = in_flight + 1
     hq, C = qc_ref.shape[1:]
     R = qr_ref.shape[2]
     tile = pages * block
     bi = pl.program_id(0)
     layer = layer_ref[0]
-    lo = jnp.maximum(lo_ref[bi], 0)
-    hi = jnp.minimum(hi_ref[bi], n_tbl * block)
-    first = lo // tile
-    n = jnp.where(hi > lo, pl.cdiv(hi, tile) - first, 0)
 
-    def page_copies(g, slot, go):
+    def band(lane):
+        """A lane's band [lo, hi), held to what the table can name, and its
+        walk: page groups [first, first + n) cover the band."""
+        lo = jnp.maximum(lo_ref[lane], 0)
+        hi = jnp.minimum(hi_ref[lane], n_tbl * block)
+        first = lo // tile
+        return lo, hi, first, jnp.where(hi > lo, pl.cdiv(hi, tile) - first, 0)
+
+    def page_copies(lane, g, slot, go):
+        """start (go=True) or wait for the copies of a lane's group g's LIVE
+        pages into buffer `slot`: a page outside the band is never looked
+        up, so a stale table entry is never dereferenced."""
+        lo, hi, _, _ = band(lane)
         j0 = jnp.maximum(g * pages, lo // block)
         j1 = jnp.minimum((g + 1) * pages, pl.cdiv(hi, block))
 
-        def one(j, carry):
-            page = jnp.clip(tbl_ref[bi, j], 0, n_pool - 1) if go else 0
+        def one(j, k):
+            """table slot j into the group's page k"""
+            # a wait only needs the copy's shape, not its source
+            page = jnp.clip(tbl_ref[lane, j], 0, n_pool - 1) if go else 0
             for part, (src, dst) in enumerate(((c_hbm, c_buf), (r_hbm, r_buf))):
-                cp = pltpu.make_async_copy(
-                    src.at[layer, page], dst.at[slot, j - g * pages], sems.at[part, slot]
-                )
+                cp = pltpu.make_async_copy(src.at[layer, page], dst.at[slot, k], sems.at[part, slot])
                 cp.start() if go else cp.wait()
-            return carry
 
-        jax.lax.fori_loop(j0, j1, one, 0)
+        # a group inside the band has every page live: its copies unrolled
+        @pl.when(j1 - j0 == pages)
+        def _whole():
+            for k in range(pages):
+                one(g * pages + k, k)
 
-    @pl.when(n > 0)
-    def _first():
-        page_copies(first, 0, True)
+        @pl.when(j1 - j0 < pages)
+        def _edge():
+            def edge_page(j, carry):
+                one(j, j - g * pages)
+                return carry
 
+            jax.lax.fori_loop(j0, j1, edge_page, 0)
+
+    def aim(lane):
+        """Point the cursor at the first group of the first lane from `lane`
+        on whose band is not empty."""
+        lane = jax.lax.while_loop(
+            lambda x: (x < lanes) & (band(jnp.minimum(x, lanes - 1))[3] == 0), lambda x: x + 1, lane
+        )
+        walk[0] = lane
+        walk[1] = band(jnp.minimum(lane, lanes - 1))[2]
+
+    def start_next():
+        """Start the copies of the walk's next group, if one is left."""
+        lane, g, started = walk[0], walk[1], walk[2]
+
+        @pl.when(lane < lanes)
+        def _():
+            page_copies(lane, g, started % n_buf, True)
+            walk[2] = started + 1
+            _, _, first, n = band(lane)
+
+            @pl.when(g + 1 < first + n)
+            def _same_lane():
+                walk[1] = g + 1
+
+            @pl.when(g + 1 >= first + n)
+            def _next_lane():
+                aim(lane + 1)
+
+    @pl.when(bi == 0)
+    def _prologue():
+        walk[2] = 0
+        walk[3] = 0
+        aim(0)
+        for _ in range(in_flight):
+            start_next()
+
+    lo, hi, first, n = band(bi)
+    done = walk[3]  # groups read by the lanes before this one
     qc, qr = qc_ref[0], qr_ref[0]  # [hq, C], [hq, R]
     nt = (((1,), (1,)), ((), ()))
+    # a group at the band's edge is read in parts of up to 128 rows (one
+    # MXU pass), only those that meet the band
+    part = math.gcd(pages, max(1, 128 // block))
 
-    def body(i, carry):
+    def update(carry, c, r, base, masked):
+        """One online-softmax update of every head from rows c | r at the
+        logical positions [base, base + rows). At the band's edge (masked)
+        the rows outside it are masked: they may be pages never copied, and whatever
+        the buffer holds there (NaN included) must not reach 0 * c."""
         m, l, acc = carry
-        g = first + i
-        slot = i % 2
-
-        @pl.when(i + 1 < n)
-        def _next():
-            page_copies(g + 1, 1 - slot, True)
-
-        page_copies(g, slot, False)
-        base = g * tile
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (hq, tile), 1)
-        in_band = (pos >= lo) & (pos < hi)
-        row = base + jax.lax.broadcasted_iota(jnp.int32, (tile, C), 0)
-        c = c_buf[slot].reshape(tile, C)
-        r = r_buf[slot].reshape(tile, R)
         s = jax.lax.dot_general(qc, c, nt, preferred_element_type=jnp.float32)
         s = s + jax.lax.dot_general(qr, r, nt, preferred_element_type=jnp.float32)
-        s = jnp.where(in_band, s, NEG_INF)
+        if masked:
+            pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where((pos >= lo) & (pos < hi), s, NEG_INF)
+            row = base + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+            c = jnp.where((row >= lo) & (row < hi), c, jnp.zeros_like(c))
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        # rows outside the band may be pages never copied: whatever the
-        # buffer holds there (NaN included) must not reach 0 * v
-        v = jnp.where((row >= lo) & (row < hi), c, jnp.zeros_like(c))
         acc_new = acc * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         return m_new, l_new, acc_new
+
+    def body(i, carry):
+        g = first + i
+        slot = (done + i) % n_buf
+        start_next()
+        page_copies(bi, g, slot, False)
+        base = g * tile
+
+        def whole(carry):
+            rows = (c_buf[slot].reshape(tile, C), r_buf[slot].reshape(tile, R))
+            return update(carry, *rows, base, False)
+
+        def edge(carry):
+            def read_part(k, carry):
+                pages_k = pl.ds(k * part, part)
+                rows = (c_buf[slot, pages_k].reshape(part * block, C),
+                        r_buf[slot, pages_k].reshape(part * block, R))
+                return update(carry, *rows, base + k * part * block, True)
+
+            k0 = jnp.maximum(lo - base, 0) // (part * block)
+            k1 = pl.cdiv(jnp.minimum(hi - base, tile), part * block)
+            return jax.lax.fori_loop(k0, k1, read_part, carry)
+
+        return jax.lax.cond((base >= lo) & (base + tile <= hi), whole, edge, carry)
 
     m, l, acc = jax.lax.fori_loop(0, n, body, (
         jnp.full((hq, 1), NEG_INF, jnp.float32),
         jnp.zeros((hq, 1), jnp.float32),
         jnp.zeros((hq, C), jnp.float32),
     ))
+    walk[3] = done + n
     o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
     m_ref[0] = jnp.broadcast_to(m, m_ref.shape[1:])
     l_ref[0] = jnp.broadcast_to(l, l_ref.shape[1:])
@@ -1295,9 +1411,9 @@ def _mla_paged_decode_call(q, c_pool, r_pool, tables, lo, hi, layer, *, scale: f
     R = r_pool.shape[-1]
     paged_pool_operand(c_pool.shape, q.shape[-1] - R)
     B, C = c_pool.shape[2:]
-    MB = tables.shape[1]
     hp = -(-hq // 16) * 16
-    pages = paged_decode_pages(B, 1, C, c_pool.dtype, MB)
+    sched = mla_decode_schedule(B, C, R, c_pool.dtype, tables.shape[1])
+    n_buf = sched.in_flight + 1
     qs = jnp.pad((q.astype(jnp.float32) * scale).astype(c_pool.dtype),
                  ((0, 0), (0, hp - hq), (0, 0)))
 
@@ -1315,13 +1431,15 @@ def _mla_paged_decode_call(q, c_pool, r_pool, tables, lo, hi, layer, *, scale: f
             pl.BlockSpec((1, hp, 128), lane),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, pages, B, C), c_pool.dtype),
-            pltpu.VMEM((2, pages, B, R), r_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((n_buf, sched.pages, B, C), c_pool.dtype),
+            pltpu.VMEM((n_buf, sched.pages, B, R), r_pool.dtype),
+            # one DMA semaphore per (c_kv | rope, buffer)
+            pltpu.SemaphoreType.DMA((2, n_buf)),
+            pltpu.SMEM((4,), jnp.int32),  # the walk's cursor
         ],
     )
     o, m, l = pl.pallas_call(
-        functools.partial(_mla_paged_decode_kernel, pages=pages),
+        functools.partial(_mla_paged_decode_kernel, pages=sched.pages, in_flight=sched.in_flight),
         name="mla_paged_decode",  # what a device trace calls the kernel
         grid_spec=grid_spec,
         out_shape=[
@@ -1329,6 +1447,9 @@ def _mla_paged_decode_call(q, c_pool, r_pool, tables, lo, hi, layer, *, scale: f
             jax.ShapeDtypeStruct((b, hp, 128), jnp.float32),
             jax.ShapeDtypeStruct((b, hp, 128), jnp.float32),
         ],
+        # the lanes' programs run in turn: the stream of copies and its
+        # cursor pass from one to the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
         tables.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),

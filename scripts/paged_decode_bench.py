@@ -1,21 +1,29 @@
 #!/usr/bin/env python
-"""Microbenchmark of the paged-decode kernel alone, on the chip.
+"""Microbenchmark of the paged-decode kernels alone, on the chip.
 
-    chiprun -- python scripts/paged_decode_bench.py
-    chiprun -- env PYTHONPATH=.scratch/parent python scripts/paged_decode_bench.py
+    python scripts/paged_decode_bench.py [gqa] [latent]        # on a TPU host
+    PYTHONPATH=<another checkout> python scripts/paged_decode_bench.py latent
 
-Times one layer-call of ``ops.attention`` at the shapes of
-``qwen2-7b.reason-closed`` (hq 28, hkv 4, d 128, 16-token blocks, a table of
-120, a bf16 pool of 2,394 blocks) with every lane's context at 16, 700 and
-1,792 tokens and at a spread like the cell's, for 16 and 48 lanes: the
-kernel's partials alone, the whole paged_chunk_decode_attention around it,
-and the dense-gather path (use_kernel=False). 28 calls (a model's layers)
-run inside one jit, each through a block table of its own (so that the
-gather cannot be hoisted out of the loop), and the host's dispatch is
-outside the number. Each row also gives max |kernel - gather| on the same
-inputs. ``PYTHONPATH`` picks the checkout whose kernel is timed (this one's
-by default). One JSON line per row on stdout, and all of them in
-chiprun_out/paged_decode_bench.<here | the other checkout's name>.jsonl.
+Times layer-calls of ``ops.attention`` over a pool stored as the engine
+stores it, the whole stack ``[L, NB, B, W]`` read at a layer index:
+
+- ``gqa``: ``qwen2-7b.reason-closed``'s shapes (hq 28, hkv 4, d 128, 16-token
+  blocks, a table of 120, bf16 pools of 2,394 blocks and 28 layers), 16 and
+  48 lanes; the kernel's partials, the whole paged_chunk_decode_attention
+  around it and the dense-gather path (use_kernel=False);
+- ``latent``: ``glm-4.7-flash.think-closed``'s (20 heads over rows of
+  512 | 128, 16-token blocks, a table of 200, pools of 5,104 blocks and 13
+  layers), 16 lanes; the latent kernel's partials and
+  mla_paged_chunk_decode_attention around it.
+
+Tables are in the pool's own allocation order: the lanes take blocks from
+one free list as they grow, in turn. Every layer of the stack is called in
+one jit, four times over, so the host's dispatch is a small share of the
+number; a row gives the least and the median of 7 timed runs per call, the
+share of the HBM roofline the least reaches over the bytes the bands' rows
+count, and max |kernel - gather| on the same inputs. ``PYTHONPATH`` picks
+the checkout whose kernels are timed (this one's by default); each row
+names it. One JSON line per row on stdout.
 """
 
 from __future__ import annotations
@@ -35,101 +43,200 @@ import numpy as np  # noqa: E402
 
 from gofr_tpu.ops import attention as A  # noqa: E402
 
-HQ, HKV, D, BLOCK, N_TBL, N_POOL, STEPS, LAYERS = 28, 4, 128, 16, 120, 2394, 8, 28
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(A.__file__))))
-REST = ("k_pool", "v_pool", "k_buf", "v_buf", "lengths", "step")  # after (q, tables)
+BLOCK, STEPS, REPS = 16, 8, 4
+# qwen2-7b: heads, kv heads, head_dim, table width, pool blocks, layers
+HQ, HKV, D, GQA_TBL, GQA_POOL, GQA_LAYERS = 28, 4, 128, 120, 2394, 28
+# glm-4.7-flash: heads, latent, rope stored / counted, table width, pool blocks, layers
+MHQ, C, R, ROPE, MLA_TBL, MLA_POOL, MLA_LAYERS = 20, 512, 128, 64, 200, 5104, 13
+MLA_SCALE = (192 + ROPE) ** -0.5
 
 
-def inputs(lanes: int, contexts: np.ndarray, seed: int = 0):
-    ks = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+def allocation_order(contexts: np.ndarray, n_tbl: int, n_pool: int) -> np.ndarray:
+    """[lanes, n_tbl] tables as one free list hands blocks out to lanes that
+    grow in turn (a lane takes its next block as it crosses a block's end),
+    each lane started 7 blocks after the one before; the slots past a
+    lane's context name the pool's last block, which no lane holds."""
+    lanes = len(contexts)
+    need = -(-np.asarray(contexts) // BLOCK)
+    order = sorted((j + 7 * i, i, j) for i in range(lanes) for j in range(need[i]))
+    assert len(order) < n_pool
+    tables = np.full((lanes, n_tbl), n_pool - 1, np.int32)
+    for block, (_, i, j) in enumerate(order):
+        tables[i, j] = block
+    return tables
 
-    def rand(shape):
-        return jax.random.normal(next(ks), shape, jnp.bfloat16)
 
-    return dict(
-        qs=rand((LAYERS, lanes, 1, HQ, D)),
-        k_pool=rand((N_POOL, BLOCK, HKV, D)), v_pool=rand((N_POOL, BLOCK, HKV, D)),
-        tables=jax.random.randint(next(ks), (LAYERS, lanes, N_TBL), 0, N_POOL, jnp.int32),
-        k_buf=rand((lanes, STEPS, HKV, D)), v_buf=rand((lanes, STEPS, HKV, D)),
-        lengths=jnp.asarray(contexts, jnp.int32), step=jnp.asarray(3, jnp.int32),
-    )
+def per_call_us(fn, args, layers: int) -> tuple[float, float]:
+    """(least, median) microseconds per layer-call: every layer of the stack
+    REPS times inside one jit, 7 timed runs after one that compiles. `fn`
+    takes (layer, q, *rest) and `args` is (qs [layers * REPS, ...], *rest)."""
 
+    def calls(qs, *rest):
+        idx = jnp.arange(layers * REPS, dtype=jnp.int32) % layers
+        return jax.lax.map(lambda lq: fn(lq[0], lq[1], *rest), (idx, qs))
 
-def per_call_us(fn, x) -> tuple[float, float]:
-    """(least, median) microseconds per layer-call: 28 calls inside one jit,
-    7 timed runs after one that compiles."""
-
-    def layers(qs, tables, *rest):
-        return jax.lax.map(lambda qt: fn(*qt, *rest), (qs, tables))
-
-    run = jax.jit(layers)
-    args = (x["qs"], x["tables"], *(x[k] for k in REST))
+    run = jax.jit(calls)
     jax.block_until_ready(run(*args))
     times = []
     for _ in range(7):
         t0 = time.perf_counter()
         jax.block_until_ready(run(*args))
-        times.append((time.perf_counter() - t0) / LAYERS * 1e6)
+        times.append((time.perf_counter() - t0) / (layers * REPS) * 1e6)
     return min(times), statistics.median(times)
 
 
-def attend(use_kernel):
-    def fn(q, tables, k_pool, v_pool, k_buf, v_buf, lengths, step):
+def max_abs_diff(attend, args, layers: int) -> float:
+    """|kernel - gather| at the last layer with the first query."""
+    qs, *rest = args
+    one = (jnp.int32(layers - 1), qs[0], *rest)
+    got = jax.jit(attend(True))(*one)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(attend(False))(*one)
+    return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
+
+
+def _rand(key, shape):
+    return jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+
+
+# -- GQA (qwen2) ------------------------------------------------------------------
+
+
+def gqa_inputs(lanes: int):
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    return dict(
+        qs=_rand(ks[0], (GQA_LAYERS * REPS, lanes, 1, HQ, D)),
+        k_pool=_rand(ks[1], (GQA_LAYERS, GQA_POOL, BLOCK, HKV * D)),
+        v_pool=_rand(ks[2], (GQA_LAYERS, GQA_POOL, BLOCK, HKV * D)),
+        k_buf=_rand(ks[3], (lanes, STEPS, HKV, D)), v_buf=_rand(ks[4], (lanes, STEPS, HKV, D)),
+    )
+
+
+def gqa_args(x, ctx):
+    tables = jnp.asarray(allocation_order(ctx, GQA_TBL, GQA_POOL))
+    return (x["qs"], x["k_pool"], x["v_pool"], tables, x["k_buf"], x["v_buf"],
+            jnp.asarray(ctx, jnp.int32), jnp.asarray(3, jnp.int32))
+
+
+def gqa_attend(use_kernel):
+    def fn(layer, q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step):
         return A.paged_chunk_decode_attention(
-            q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step, use_kernel=use_kernel
+            q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step, layer=layer, use_kernel=use_kernel
         )
     return fn
 
 
-def partials(q, tables, k_pool, v_pool, k_buf, v_buf, lengths, step):
+def gqa_partials(layer, q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step):
     return A._paged_decode_partials(
-        q[:, 0], k_pool, v_pool, tables, jnp.zeros_like(lengths), lengths, scale=D ** -0.5
+        q[:, 0], k_pool, v_pool, tables, jnp.zeros_like(lengths), lengths, layer, scale=D ** -0.5
     )
 
 
-def main() -> int:
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        print(f"no TPU here ({dev.platform}): a time from this device is not a result", file=sys.stderr)
-        return 2
-    with open(os.path.join(REPO, "benchmarks", "peaks.json")) as f:
-        hbm_bytes_per_s = json.load(f)[dev.device_kind]["hbm_bytes_per_s"]  # no default
-    rows = []
-    os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
-    rng = np.random.default_rng(0)
+def gqa_rows(rng, peak: float):
     for lanes in (16, 48):
+        x = gqa_inputs(lanes)
         cases = {
             "16": np.full(lanes, 16), "700": np.full(lanes, 700), "1792": np.full(lanes, 1792 - STEPS),
             "spread 300-1100": rng.integers(300, 1100, lanes),
         }
         for name, ctx in cases.items():
-            x = inputs(lanes, ctx)
-            kern_min, kern_med = per_call_us(partials, x)
-            att_min, att_med = per_call_us(attend(True), x)
-            row = {
-                "checkout": CHECKOUT,
-                "device": dev.device_kind, "lanes": lanes, "contexts": name,
-                "kernel_us_min": round(kern_min, 1), "kernel_us_median": round(kern_med, 1),
-                "attend_us_min": round(att_min, 1), "attend_us_median": round(att_med, 1),
-            }
-            kv_bytes = int(ctx.sum()) * HKV * D * 2 * 2
-            row["kv_mb"] = round(kv_bytes / 1e6, 2)
-            row["kernel_roofline_pct"] = round(100 * kv_bytes / hbm_bytes_per_s / (kern_min * 1e-6), 2)
+            args = gqa_args(x, ctx)
+            row = timed("gqa", lanes, name, int(ctx.sum()) * HKV * D * 2 * 2, peak, GQA_LAYERS,
+                        gqa_partials, gqa_attend, args)
             if name in ("700", "spread 300-1100"):
-                g_min, g_med = per_call_us(attend(False), x)
-                row["gather_us_min"], row["gather_us_median"] = round(g_min, 1), round(g_med, 1)
-            one = (x["qs"][0], x["tables"][0], *(x[k] for k in REST))
-            got = jax.jit(attend(True))(*one)
-            with jax.default_matmul_precision("highest"):
-                want = jax.jit(attend(False))(*one)
-            row["max_abs_diff"] = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
-            rows.append(row)
+                g_min, g_med = per_call_us(gqa_attend(False), args, GQA_LAYERS)
+                row["gather_us_min"], row["gather_us_median"] = round(g_min, 2), round(g_med, 2)
+            yield row
+
+
+# -- latent (glm) -------------------------------------------------------------------
+
+
+def latent_inputs(lanes: int = 16):
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    return dict(
+        qs=_rand(ks[0], (MLA_LAYERS * REPS, lanes, 1, MHQ, C + R)).at[..., C + ROPE:].set(0),
+        c_pool=_rand(ks[1], (MLA_LAYERS, MLA_POOL, BLOCK, C)),
+        r_pool=_rand(ks[2], (MLA_LAYERS, MLA_POOL, BLOCK, R)).at[..., ROPE:].set(0),
+        c_buf=_rand(ks[3], (lanes, STEPS, 1, C)),
+        r_buf=_rand(ks[4], (lanes, STEPS, 1, R)).at[..., ROPE:].set(0),
+    )
+
+
+def latent_cases(rng, lanes: int = 16) -> dict:
+    return {
+        "16": np.full(lanes, 16), "1160": np.full(lanes, 1160), "3064": np.full(lanes, 3072 - STEPS),
+        "spread 128-3064": rng.integers(128, 3072 - STEPS, lanes),
+    }
+
+
+def latent_args(x, ctx):
+    tables = jnp.asarray(allocation_order(ctx, MLA_TBL, MLA_POOL))
+    return (x["qs"], x["c_pool"], x["r_pool"], tables, x["c_buf"], x["r_buf"],
+            jnp.asarray(ctx, jnp.int32), jnp.asarray(3, jnp.int32))
+
+
+def latent_attend(use_kernel):
+    def fn(layer, q, c_pool, r_pool, tables, c_buf, r_buf, lengths, step):
+        return A.mla_paged_chunk_decode_attention(
+            q, c_pool, r_pool, tables, c_buf, r_buf, lengths, step,
+            scale=MLA_SCALE, layer=layer, use_kernel=use_kernel,
+        )
+    return fn
+
+
+def latent_partials(layer, q, c_pool, r_pool, tables, c_buf, r_buf, lengths, step):
+    return A._mla_paged_decode_call(
+        q[:, 0], c_pool, r_pool, tables, jnp.zeros_like(lengths), lengths, layer,
+        scale=MLA_SCALE, interpret=False,
+    )
+
+
+def latent_bytes(ctx) -> int:
+    """What latent_decode_roofline_pct counts: 512 + 64 bf16 a row."""
+    return int(np.sum(ctx)) * (C + ROPE) * 2
+
+
+def latent_rows(rng, peak: float):
+    x = latent_inputs()
+    for name, ctx in latent_cases(rng).items():
+        yield timed("latent", 16, name, latent_bytes(ctx), peak, MLA_LAYERS,
+                    latent_partials, latent_attend, latent_args(x, ctx))
+
+
+def timed(kind, lanes, contexts, kv_bytes, peak, layers, partials, attend, args) -> dict:
+    kern_min, kern_med = per_call_us(partials, args, layers)
+    att_min, att_med = per_call_us(attend(True), args, layers)
+    return {
+        "checkout": CHECKOUT, "device": jax.devices()[0].device_kind, "kind": kind, "lanes": lanes,
+        "contexts": contexts, "kernel_us_min": round(kern_min, 2), "kernel_us_median": round(kern_med, 2),
+        "attend_us_min": round(att_min, 2), "attend_us_median": round(att_med, 2),
+        "kv_mb": round(kv_bytes / 1e6, 3),
+        "kernel_roofline_pct": round(100 * kv_bytes / peak / (kern_min * 1e-6), 2),
+        "max_abs_diff": max_abs_diff(attend, args, layers),
+    }
+
+
+def hbm_peak() -> float:
+    """The chip's HBM bytes/s from the benchmark's peaks table; no default."""
+    dev = jax.devices()[0]
+    with open(os.path.join(REPO, "benchmarks", "peaks.json")) as f:
+        return json.load(f)[dev.device_kind]["hbm_bytes_per_s"]
+
+
+def main(argv) -> int:
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"no TPU here ({dev.platform}): a time from this device is not a result", file=sys.stderr)
+        return 2
+    peak = hbm_peak()
+    rng = np.random.default_rng(0)
+    for kind in argv or ["gqa", "latent"]:
+        for row in {"gqa": gqa_rows, "latent": latent_rows}[kind](rng, peak):
             print(json.dumps(row), flush=True)
-    tag = "here" if CHECKOUT == REPO else os.path.basename(CHECKOUT)
-    with open(os.path.join(REPO, "chiprun_out", f"paged_decode_bench.{tag}.jsonl"), "w") as f:
-        f.writelines(json.dumps(r) + "\n" for r in rows)
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

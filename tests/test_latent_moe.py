@@ -222,22 +222,165 @@ def test_the_engine_serves_it_and_shares_a_prefix(twin):
 # -- the latent decode kernel ----------------------------------------------------------
 
 
-def test_the_latent_kernel_in_interpret_mode_equals_the_dense_gather():
-    b, hq, C, R, MB = 3, 5, 128, 128, 12
-    keys = jax.random.split(jax.random.PRNGKey(1), 5)
-    c_pool = jax.random.normal(keys[0], (b * MB, BLOCK, C), jnp.float32)
-    r_pool = jnp.pad(jax.random.normal(keys[1], (b * MB, BLOCK, 8), jnp.float32), ((0, 0),) * 2 + ((0, R - 8),))
+# Each lane's band [lo, hi) and the table's slots. The test's f32 rows of
+# 128 | 128 make a page group 128 tokens (8 pages) and keep 7 groups in
+# flight (mla_decode_schedule); the kernel walks every lane's groups as ONE
+# stream of copies, so what a band looks like next to its neighbours matters.
+LATENT_BANDS = {
+    "an empty band, a part page, the last page group": ([0, 0, 0], [0, 37, 190], 12),
+    "an empty lane between live ones": ([0] * 6, [150, 0, 70, 0, 0, 129], 12),
+    "every lane empty": ([0] * 3, [0] * 3, 12),
+    "one lane alone": ([0], [100], 12),
+    "bands ending on a group boundary and of one token": ([0] * 4, [128, 1, 256, 16], 16),
+    "lanes ten times apart": ([0] * 4, [30, 300, 29, 310], 20),
+    "more groups than buffers in flight": ([0] * 6, [380, 250, 0, 384, 130, 200], 24),
+    "lo > 0": ([5, 130, 0, 255, 200], [100, 300, 40, 384, 201], 24),
+    "a table narrower than a group": ([0, 0, 3], [90, 17, 96], 6),
+}
+
+
+def _latent_inputs(b, MB, hq=5, C=128, R=128, seed=1):
+    """q [b, 1, hq, C + R], the pools as stored: a stack of two layers
+    [2, NB, B, C | R] (read at the second), a rope key of 8 columns padded
+    to R, tables over the pool in random order, and the chunk's rows."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    NB = b * MB
+    c_pool = jax.random.normal(keys[0], (NB, BLOCK, C), jnp.float32)
+    r_pool = jnp.pad(jax.random.normal(keys[1], (NB, BLOCK, 8), jnp.float32), ((0, 0),) * 2 + ((0, R - 8),))
+    c_pool, r_pool = (jnp.stack([jnp.zeros_like(a), a]) for a in (c_pool, r_pool))
     q = jax.random.normal(keys[2], (b, 1, hq, C + R), jnp.float32)
-    tables = jnp.asarray(np.random.default_rng(0).permutation(b * MB).reshape(b, MB).astype(np.int32))
-    lengths = jnp.asarray([0, 37, 190], jnp.int32)  # an empty band, a part page, the last page group
+    tables = np.random.default_rng(seed).permutation(NB).reshape(b, MB).astype(np.int32)
     c_buf = jax.random.normal(keys[3], (b, 4, 1, C), jnp.float32)
     r_buf = jnp.pad(jax.random.normal(keys[4], (b, 4, 1, 8), jnp.float32), ((0, 0),) * 3 + ((0, R - 8),))
-    # the pools come whole: here a stack of two layers, read at the second
-    c_pool, r_pool = (jnp.stack([jnp.zeros_like(a), a]) for a in (c_pool, r_pool))
-    args = (q, c_pool, r_pool, tables, c_buf, r_buf, lengths, jnp.int32(2))
-    kernel = A.mla_paged_chunk_decode_attention(*args, scale=0.1, layer=1, use_kernel=True, interpret=True)
-    gather = A.mla_paged_chunk_decode_attention(*args, scale=0.1, layer=1, use_kernel=False)
-    assert kernel.shape == (b, 1, hq, C) and float(jnp.abs(kernel - gather).max()) < 1e-5
+    return q, c_pool, r_pool, tables, c_buf, r_buf
+
+
+def _band_partials(q, c_pool, r_pool, tables, lo, hi, layer, scale):
+    """The kernel's partials over [lo, hi) from the gathered rows, by XLA:
+    (o normalized, m, l); an empty band gives l = 0 and no o or m."""
+    C = c_pool.shape[-1]
+    rc, rr = (a[:, :, 0] for a in A.paged_gather(c_pool, r_pool, tables, layer, ((1, C), (1, r_pool.shape[-1]))))
+    qs = q * scale
+    s = jnp.einsum("bhc,bnc->bhn", qs[..., :C], rc) + jnp.einsum("bhr,bnr->bhn", qs[..., C:], rr)
+    pos = jnp.arange(rc.shape[1])
+    live = (pos >= jnp.asarray(lo)[:, None]) & (pos < jnp.asarray(hi)[:, None])
+    s = jnp.where(live[:, None], s, -jnp.inf)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(live[:, None], jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.sum(p, axis=-1)
+    return jnp.einsum("bhn,bnc->bhc", p, rc) / jnp.where(l == 0, 1.0, l)[..., None], m, l
+
+
+def _in_time(fn, seconds=300):
+    """fn() on a thread of its own: the DMA simulator blocks for ever on a
+    wait for a copy that was never started, which fails here instead."""
+    import threading
+
+    out = []
+
+    def run():
+        try:
+            out.append(jax.block_until_ready(fn()))
+        except Exception as e:  # noqa: BLE001 — raised again on the test's thread
+            out.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert out, f"the kernel's copy stream did not finish in {seconds} s: a wait without its copy"
+    if isinstance(out[0], Exception):
+        raise out[0]
+    return out[0]
+
+
+def _simulated_partials(q, c_pool, r_pool, tables, lo, hi, layer, scale):
+    """The kernel's partials in the TPU interpreter that simulates its DMAs
+    and semaphores (a copy lands when it is waited for; a read outside a
+    buffer raises; VMEM starts as NaN)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return _in_time(lambda: A._mla_paged_decode_call(
+        q, c_pool, r_pool, jnp.asarray(tables), jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+        layer, scale=scale, interpret=pltpu.InterpretParams(),
+    ))
+
+
+def _assert_partials_equal(got, want, hi, lo):
+    empty = np.asarray(hi) <= np.asarray(lo)
+    o, m, l = (np.asarray(x) for x in got)
+    wo, wm, wl = (np.asarray(x) for x in want)
+    assert (l[empty] == 0).all() and (o[empty] == 0).all()
+    live = ~empty
+    np.testing.assert_allclose(o[live], wo[live], atol=1e-5)
+    np.testing.assert_allclose(m[live], wm[live], rtol=1e-6, atol=1e-5)
+    np.testing.assert_allclose(l[live], wl[live], rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", list(LATENT_BANDS))
+def test_the_latent_kernel_in_interpret_mode_equals_the_dense_gather(case):
+    """The kernel against the gathered rows, band by band: the main region's
+    partials over [lo, hi) with its copies simulated, and where every band
+    starts at 0 the whole chunk-decode (main region and the chunk's own rows)
+    against the gather path's one softmax."""
+    lo, hi, MB = LATENT_BANDS[case]
+    b = len(hi)
+    q, c_pool, r_pool, tables, c_buf, r_buf = _latent_inputs(b, MB)
+    sched = A.mla_decode_schedule(BLOCK, 128, 128, jnp.float32, MB)
+    groups = sum(-(-h // sched.tokens) - l_ // sched.tokens for l_, h in zip(lo, hi) if h > l_)
+    if case == "more groups than buffers in flight":
+        assert groups > sched.in_flight + 1 >= 3
+    got = _simulated_partials(q[:, 0], c_pool, r_pool, tables, lo, hi, 1, 0.1)
+    _assert_partials_equal(got, _band_partials(q[:, 0], c_pool, r_pool, tables, lo, hi, 1, 0.1), hi, lo)
+    if not any(lo):
+        args = (q, c_pool, r_pool, jnp.asarray(tables), c_buf, r_buf, jnp.asarray(hi, jnp.int32), jnp.int32(2))
+        kernel = A.mla_paged_chunk_decode_attention(*args, scale=0.1, layer=1, use_kernel=True, interpret=True)
+        gather = A.mla_paged_chunk_decode_attention(*args, scale=0.1, layer=1, use_kernel=False)
+        assert kernel.shape == (b, 1, 5, 128) and float(jnp.abs(kernel - gather).max()) < 1e-5
+
+
+def test_the_latent_kernel_never_reads_a_dead_page():
+    """NaN in every pool block outside the lanes' bands and in the whole of
+    the other layer, and every table entry outside a band (before lo and
+    past hi) an id outside the pool: the partials are finite and equal
+    those over the clean pool and table."""
+    lo, hi, MB = LATENT_BANDS["lo > 0"]
+    q, c_pool, r_pool, tables, _, _ = _latent_inputs(len(hi), MB, seed=4)
+    slot = np.arange(MB)[None, :]
+    live = (slot * BLOCK < np.asarray(hi)[:, None]) & ((slot + 1) * BLOCK > np.asarray(lo)[:, None])
+    NB = c_pool.shape[1]
+    dead = np.setdiff1d(np.arange(NB), tables[live])
+    poisoned = np.where(live, tables, np.random.default_rng(0).choice([-7, NB, NB + 1000], tables.shape))
+
+    def poison(a):
+        return jnp.full_like(a, jnp.nan).at[1].set(a[1].at[dead].set(jnp.nan))
+
+    got = _simulated_partials(q[:, 0], poison(c_pool), poison(r_pool), poisoned, lo, hi, 1, 0.1)
+    assert all(np.isfinite(np.asarray(x)).all() for x in got)
+    _assert_partials_equal(got, _band_partials(q[:, 0], c_pool, r_pool, tables, lo, hi, 1, 0.1), hi, lo)
+
+
+def test_the_engine_reports_the_latent_schedule(monkeypatch):
+    """stats()["attention"]["decode_tile"] on the latent path is the schedule
+    the kernel runs, from the engine's own pool (16-token blocks of f32 rows
+    of 128 | 128): asked as a TPU would answer, since the CPU takes no
+    kernel; nothing is traced."""
+    import dataclasses
+
+    cfg = dataclasses.replace(TransformerConfig.tiny_latent_moe(), kv_lora_rank=128)
+    eng = LLMEngine(cfg, init_params(jax.random.PRNGKey(0), cfg), slots=1, max_seq_len=240, warmup=False)
+    try:
+        assert "decode_tile" not in eng.stats()["attention"]
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        paths = eng._attention_paths()
+        assert paths["decode"] == "pallas_mla_paged"
+        want = A.mla_decode_schedule(eng.kv.block, 128, 128, jnp.float32, eng.kv.table_width)
+        assert paths["decode_tile"] == want.report()
+        assert want.in_flight >= 2 and paths["decode_tile"]["walk"] == "across lanes"
+    finally:
+        eng.close()
+
+
+def test_the_latent_kernel_says_why_not():
     assert A.mla_kernel_why_not(512, 128, 16, interpret=True) == ""
     assert "128-lane" in A.mla_kernel_why_not(32, 128, 16, interpret=True)
     assert "not tpu" in A.mla_kernel_why_not(512, 128, 16)

@@ -357,7 +357,7 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     of 5,104 blocks, rows of 512 | 128, 20 heads, 16 lanes, a table of 200):
     through Mosaic for a v5e, its pool operands the program's parameters as
     stored, and nothing else in the program as large as a layer of them."""
-    from gofr_tpu.ops.attention import mla_paged_chunk_decode_attention
+    from gofr_tpu.ops.attention import mla_decode_schedule, mla_paged_chunk_decode_attention
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -382,6 +382,26 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     assert sorted(pool_sized) == [
         ("parameter", (layers, n_blocks, block, R)), ("parameter", (layers, n_blocks, block, C)),
     ]
+    # the schedule the kernel runs here is the one the engine reports
+    # (stats()["attention"]["decode_tile"]): one walk over every lane's
+    # 512-token groups, two of them in flight
+    tile = mla_decode_schedule(block, C, R, jnp.bfloat16, n_tbl).report()
+    assert tile == {"pages": 32, "tokens": 512, "in_flight": 2, "walk": "across lanes"}
+
+
+# block, kv heads, head_dim, table slots: the GQA kernel's cells keep their tile
+@pytest.mark.parametrize("block,hkv,d,n_tbl", [
+    (16, 4, 128, 120),  # qwen2-7b.reason-closed
+    (16, 8, 128, 520),  # k-exaone-236b-a23b.mixed-closed, both kinds of layer
+    (16, 30, 128, 129),  # olmo-hybrid-7b.chat-closed's full layers
+])
+def test_the_gqa_kernels_tile_is_its_own(block, hkv, d, n_tbl):
+    """paged_decode_pages, which sizes the GQA kernel for every cell but
+    glm's, gives the 8 pages of 128 tokens it always gave: the latent
+    kernel's schedule is a function of its own."""
+    from gofr_tpu.ops.attention import paged_decode_pages
+
+    assert paged_decode_pages(block, hkv, d, jnp.bfloat16, n_tbl) == 8
 
 
 def _hlo_results(text: str, dtype: str = "[a-z]+[0-9]*"):
