@@ -230,19 +230,25 @@ class FlightRecorder:
         *,
         queue_wait_ms=None,
         ttft_ms=None,
+        ttft_split_ms=None,
         per_token_ms=None,
         total_ms=None,
         chip=None,
     ) -> dict | None:
         """Stamp the terminal outcome. Every terminal path lands here —
         the regular finish observer AND the die-drain paths — so a
-        record is never left dangling non-final for a finished request."""
+        record is never left dangling non-final for a finished request.
+        `ttft_split_ms` is TTFT in the request timeline's three pieces
+        (the wait for the first prompt row's dispatch, the stretch to the
+        last row's, and from it to the first token), or None where the
+        request dispatched no row."""
         if not self.enabled:
             return None
         with self._lock:
             rec = self._ring.get(req.id)
         if rec is None:
             return None
+        wait, rows, tail = ttft_split_ms or (None, None, None)
         rec.update({
             "final": True,
             "finish_reason": req.finish_reason,
@@ -256,6 +262,9 @@ class FlightRecorder:
             "phase_ms": {
                 "queue_wait": queue_wait_ms,
                 "ttft": ttft_ms,
+                "first_row_wait": wait,
+                "prompt_rows": rows,
+                "first_token_tail": tail,
                 "per_token": per_token_ms,
                 "total": total_ms,
             },

@@ -120,7 +120,9 @@ the HBM stream that bounds decode.
 
 from __future__ import annotations
 
+import bisect
 import itertools
+import operator
 import queue
 import threading
 import time
@@ -160,6 +162,33 @@ STEP_FIELDS = (
 # the ring holds two benchmark windows of programs (50 s each) down to 25 ms a program
 STEP_LOG_LEN = 4096
 _DECODE_KINDS = ("chunk", "step", "verify")
+_SEQ, _T_DISPATCH = operator.itemgetter(0), STEP_FIELDS.index("t_dispatch")
+_JUMP = 16  # more programs than a first token is served ahead of (_jump_safe)
+# One record per request, appended when its first token is handed to the
+# consumer (docs/advanced-guide/profiling.md, "The request timeline"). The
+# stamps are the request's own, taken where the work happens, on the step
+# records' clock:
+#   t_submit       `submitted_at`: submit(), or the preemption that re-queued it
+#   t_admit        `admitted_at`: _observe_admission, when a slot is assigned
+#                  (a wave's requests when its program is dispatched)
+#   seq_first_row  the `seq` of the first program that carried a row of its
+#                  prompt, and seq_last_row of the one whose row finished it
+#                  (_rows_out: _dispatch_step's fused and rows-only steps, the
+#                  wave's prefill in _slot_in)
+#   rows           prompt rows dispatched for it; row_steps the programs that
+#                  carried at least one of them
+#   t_first_token  `first_token_at`: _emit_to, as the collector hands it over
+# `prompt` and `cached` count tokens: the prompt, and what no row carried (a
+# prefix hit, transferred blocks). A request with no row has None seqs. The
+# dispatch and fetch times of its rows are the step records', joined by seq.
+# Preemption or failover before the first token starts the stamps again;
+# after it they stay those of the admission that produced it.
+REQUEST_FIELDS = (
+    "id", "prompt", "cached", "t_submit", "t_admit", "seq_first_row", "seq_last_row",
+    "rows", "row_steps", "t_first_token",
+)
+# two benchmark windows of the busiest cell's first tokens
+REQUEST_LOG_LEN = 1024
 
 def latent_refusal(
     *, mesh, chunked: bool, kv_paged, speculative: bool, constrained, lora_slots,
@@ -632,7 +661,6 @@ class GenRequest:
         self._kv_resv = 0  # admission block promise not yet bound to a slot
         self._kv_plan = None  # pinned seed plan not yet attached to a slot
         self._session_published = False  # end-of-turn radix publish done
-        self._prefill_t0: float | None = None  # first chunk dispatch time
         self._load_acct = 0  # outstanding token estimate (router weighting)
         # -- grammar-constrained decoding (engine-maintained) --
         # _g_id: this engine's resident-grammar table slot (set at
@@ -666,6 +694,13 @@ class GenRequest:
         self.prefix_hit = False
         self.admitted_at: float | None = None
         self.first_token_at: float | None = None
+        # the request timeline's rows (REQUEST_FIELDS); _first_cursor is
+        # where the first row began, so the prompt below it was cached
+        self.seq_first_row: int | None = None
+        self.seq_last_row: int | None = None
+        self.rows = 0
+        self.row_steps = 0
+        self._first_cursor: int | None = None
         self.span = None  # detached llm.request span (engine has a tracer)
         self._observed = False  # terminal observability emitted (idempotence)
         # journey accounting: hop counts every re-admission after the
@@ -684,6 +719,14 @@ class GenRequest:
         # the ledger classes their re-prefill as `replay`, not `useful`.
         self._chip: dict[str, float] = {}
         self._replay_pos = 0
+
+    def _requeued(self) -> None:
+        """Preemption or failover re-queues the request: before its first
+        token the timeline's rows start again with the admission that will
+        produce it; after it they stay that admission's."""
+        if self.first_token_at is None:
+            self.seq_first_row = self.seq_last_row = self._first_cursor = None
+            self.rows = self.row_steps = 0
 
     # -- consumption ------------------------------------------------------
     def _raise_terminal(self) -> None:
@@ -1554,6 +1597,7 @@ class LLMEngine:
         self._step_seq = 0
         self._stat_admitted = 0  # slots assigned: sched.admit's count per pass
         self._step_log: deque = deque(maxlen=STEP_LOG_LEN)
+        self._request_log: deque = deque(maxlen=REQUEST_LOG_LEN)  # REQUEST_FIELDS
         # what the routed experts did, summed over the paged programs' records
         # (stats()["moe"]): [pairs, experts touched, rows per expert...]
         _E = int(getattr(cfg, "n_experts", 0) or 0)
@@ -2128,6 +2172,9 @@ class LLMEngine:
                 # program, oldest first; a copy of the ring's references,
                 # no work per record
                 "step_log": {"fields": STEP_FIELDS, "records": tuple(self._step_log)},
+                # the request timeline: one record per request at its first
+                # token, oldest first, its rows' programs named by seq
+                "request_log": {"fields": REQUEST_FIELDS, "records": tuple(self._request_log)},
                 # utilization: analytic-FLOPs MFU + tokens/s/chip windows
                 # and the roofline verdict (profiling.mfu)
                 "mfu": self._mfu_summary(),
@@ -2245,6 +2292,7 @@ class LLMEngine:
             waiting = [req_row(r) for r in self._waiting[:32]]
             phases = {k: w.summary() for k, w in self._phases.items()}
             steps = list(self._step_log)[-32:]
+            requests = list(self._request_log)[-32:]
         return {
             "label": self.label,
             "version": self.version,
@@ -2290,6 +2338,9 @@ class LLMEngine:
             # the step timeline's newest records (STEP_FIELDS; the whole
             # ring is stats()["step_log"])
             "steps": [dict(zip(STEP_FIELDS, rec)) for rec in steps],
+            # the request timeline's newest records (REQUEST_FIELDS; the
+            # whole ring is stats()["request_log"])
+            "requests": [dict(zip(REQUEST_FIELDS, rec)) for rec in requests],
             "waiting_total": waiting_total,
             "waiting": waiting,
             "admitting": self._admitting,
@@ -3595,7 +3646,7 @@ class LLMEngine:
         r.prefill_pos = 0
         r.prefill_done = False
         r._rows_hi = 0
-        r._prefill_t0 = None
+        r._requeued()
         r._spec_pending = []
         r._spec_inflight = 0
         r.phase = "queued"
@@ -3851,11 +3902,6 @@ class LLMEngine:
             with engine_span("dispatch.call", self._hb_dispatch, kind="prefill"):
                 first_dev, new_cache, logits_dev, self._rng = self._prefill_op(
                     self.params, jnp.asarray(pack), self._rng,
-                )
-            if self.metrics is not None:
-                self.metrics.record_histogram(
-                    "app_tpu_stats", time.perf_counter() - t0,
-                    model="llm", op=f"prefill_dispatch_{bucket}",
                 )
             if self.kv.prefix is not None:
                 # retain each fresh row + its last-token logits for future
@@ -4664,11 +4710,12 @@ class LLMEngine:
         insert-many, scatter first tokens into the on-device chain tail,
         and queue the entry for the collector. wave_nb records prefill wave
         width telemetry (hit waves dispatched no prefill, so they don't);
-        wave_t0/bucket feed the prefill phase span recorded at fetch."""
+        wave_t0/bucket feed the prefill phase span recorded at fetch. The
+        wave's requests are admitted when its program was dispatched."""
         jnp = self._jnp
         now = time.perf_counter()
         for r in reqs:
-            self._observe_admission(r, now)
+            self._observe_admission(r, now if wave_t0 is None else wave_t0)
         with self._work_cv:
             meta = np.zeros((3, self.admit_cap), np.int32)
             taken: list[tuple[int, GenRequest]] = []
@@ -4715,6 +4762,8 @@ class LLMEngine:
                 ),
                 "nb": wave_nb or 0, "bucket": bucket,
             }
+            if bucket is not None:  # each prompt whole, one row
+                self._rows_out(((r, 0) for r in reqs), info["seq"])
             self._inflight.append(("prefill", first_dev, taken, info))
             self._admitting -= len(reqs)
             if wave_nb is not None:
@@ -4791,6 +4840,53 @@ class LLMEngine:
                 info["k"] + (1 if info["kind"] == "step" else 0)
             )
         self._step_log.append(tuple(info[f] for f in STEP_FIELDS))
+
+    # -- the request timeline -----------------------------------------------
+    @staticmethod
+    def _rows_out(rows, seq: int) -> None:
+        """The program `seq` carries these prompt rows, (request, cursor)
+        each: stamp their requests (REQUEST_FIELDS). A continuation past its
+        first token keeps the stamps of the admission that produced it.
+        Call with the lock held."""
+        for r, pos in rows:
+            if r.first_token_at is not None:
+                continue
+            if r.seq_first_row is None:
+                r.seq_first_row, r._first_cursor = seq, pos
+            r.rows += 1
+            if r.seq_last_row != seq:
+                r.seq_last_row = seq
+                r.row_steps += 1
+
+    def _dispatched_at(self, seq: int | None) -> float | None:
+        """`t_dispatch` of program `seq`, joined by seq: its step record, or
+        its in-flight entry's info while the collector still holds it; None
+        for no seq or one the ring has dropped. Call with the lock held."""
+        if seq is None:
+            return None
+        log = self._step_log
+        # the ring is in seq order but for first tokens the collector served
+        # ahead of the programs in flight (_jump_safe): look about the place
+        i = bisect.bisect_left(log, seq, key=_SEQ)
+        for j in range(max(0, i - _JUMP), min(len(log), i + _JUMP)):
+            if log[j][0] == seq:
+                return log[j][_T_DISPATCH]
+        entries = [self._processing, *self._inflight] if self._processing else self._inflight
+        return next((e[-1]["t_dispatch"] for e in entries if e[-1]["seq"] == seq), None)
+
+    def _ttft_split(self, r: GenRequest) -> tuple[float, float, float] | None:
+        """(first_row_wait, prompt_rows, first_token_tail) seconds: submit ->
+        the first row's dispatch -> the last row's -> the first token, which
+        sum to the engine's TTFT. None for a request without rows, one whose
+        rows' records are gone, or one re-queued after its first token (its
+        submit is then a later admission's). Call with the lock held."""
+        t_sub, t_first = r.submitted_at, r.first_token_at
+        if t_sub is None or t_first is None or t_sub > t_first:
+            return None
+        a, b = self._dispatched_at(r.seq_first_row), self._dispatched_at(r.seq_last_row)
+        if a is None or b is None:
+            return None
+        return a - t_sub, b - a, t_first - b
 
     def _ctx_of(self, r: GenRequest) -> int:
         """A decoding lane's context (prompt + emitted so far), capped at
@@ -4957,10 +5053,13 @@ class LLMEngine:
         # so the ring never holds a dangling non-final record for a
         # finished request
         chip = dict(r._chip) if r._chip else {}
+        # TTFT's three pieces from the request timeline (None without rows)
+        split = self._ttft_split(r) if self.flightrec.enabled else None
         self.flightrec.finalize(
             r,
             queue_wait_ms=None if queue_wait is None else queue_wait * 1e3,
             ttft_ms=None if ttft is None else ttft * 1e3,
+            ttft_split_ms=None if split is None else tuple(v * 1e3 for v in split),
             per_token_ms=None if tpot is None else tpot * 1e3,
             total_ms=None if total is None else total * 1e3,
             chip={c: round(v * 1e3, 3) for c, v in chip.items()} or None,
@@ -5117,9 +5216,12 @@ class LLMEngine:
                             ),
                             **self._role_labels,
                         )
-                        self.metrics.record_histogram(
-                            "app_tpu_queue_wait", ttft, model="llm", op="ttft",
-                        )
+                plen = len(r.prompt_tokens)
+                self._request_log.append((
+                    r.id, plen, plen if r._first_cursor is None else r._first_cursor,
+                    r.submitted_at, r.admitted_at, r.seq_first_row, r.seq_last_row,
+                    r.rows, r.row_steps, now,
+                ))
             r.out.put(toks)
             r.emitted += len(toks)
             r.history.extend(toks)  # failover continuation seed
@@ -5315,7 +5417,6 @@ class LLMEngine:
             self._prefilling = keep
             if not rows:
                 return False
-            now = time.perf_counter()
             nb = self._wave_width(len(rows))
             pack = np.zeros((nb, shape + 3), np.int32)
             # meta rows 2/3 (grammar id, start DFA state) ride only the
@@ -5343,8 +5444,6 @@ class LLMEngine:
                     # the lane unconstrained rather than wrong-state)
                     meta[2, j] = r._g_id
                     meta[3, j] = r._g_state
-                if r._prefill_t0 is None:
-                    r._prefill_t0 = now
                 r.prefill_pos = pos + n
                 # rows actually written: the append scatter drops indices
                 # at i >= n, so padding past the valid count never lands —
@@ -5463,6 +5562,7 @@ class LLMEngine:
                 # ledger attributes each prefill span to its owner at the fetch
                 "row_reqs": [r for r, _n in rows],
             }
+            self._rows_out(zip(info["row_reqs"], (s[0] for s in spans)), info["seq"])
             self._inflight.append(
                 ("step", first_dev, finishes, toks_dev, snapshot, k, info)
             )
@@ -5849,6 +5949,8 @@ class LLMEngine:
                                 "llm.wave": info["nb"] or len(taken),
                                 "llm.bucket": info["bucket"] or 0,
                                 "llm.prefix_hit": r.prefix_hit,
+                                "llm.prompt_rows": r.rows,
+                                "llm.prompt_steps": r.row_steps,
                             },
                         )
                     emitted += self._emit_to(r, slot, [int(first[j])], now)
@@ -6059,12 +6161,20 @@ class LLMEngine:
             dctx = []
             for j, slot, r in finishes:
                 if r.span is not None and r.finish_reason is None:
+                    # from its first row's dispatch (a continuation past its
+                    # first token: this admission's last row)
+                    first_row = (
+                        None if r.first_token_at is not None
+                        else self._dispatched_at(r.seq_first_row)
+                    )
                     self._phase_span(
-                        r, "llm.prefill", r._prefill_t0 or t_dispatch, now,
+                        r, "llm.prefill", first_row or t_dispatch, now,
                         attrs={
                             "llm.wave": info["nb"],
                             "llm.bucket": info["shape"],
                             "llm.prefix_hit": r.prefix_hit,
+                            "llm.prompt_rows": r.rows,
+                            "llm.prompt_steps": r.row_steps,
                         },
                     )
                 emitted += self._emit_to(r, slot, [int(first[j])], now)
@@ -7611,7 +7721,7 @@ class ReplicatedLLMEngine:
                 r.prefill_done = False
                 r.slot = None
                 r._rows_hi = 0
-                r._prefill_t0 = None
+                r._requeued()
                 r._load_acct = 0
                 tried: set[int] = set()
                 # Mid-stream version pin (docs/advanced-guide/rollouts.md):
