@@ -372,7 +372,7 @@ def server_phase(args, preset: str, on_chip: bool) -> None:
         check(paths["decode"].startswith(want_decode), f"decode traced {paths['decode']!r}")
         for shape, path in paths["prefill"].items():
             check(path.startswith(want_prefill), f"prefill chunk {shape} traced {path!r}")
-        tile = paths.get("decode_tile")  # what the paged kernel holds per step
+        tile = paths.get("decode_tile")  # the paged kernel's page group and depth
         say(f"attention traced: decode {paths['decode']}{f' {tile}' if tile else ''}; "
             f"prefill {paths['prefill']}")
 

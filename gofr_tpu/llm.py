@@ -1667,12 +1667,13 @@ class LLMEngine:
         the ops themselves consult at trace time (ops.attention). A shape
         that cannot take its Pallas kernel carries the reason, so a
         fallback to XLA attention is never silent (stats()["attention"]).
-        Beside a paged-decode kernel, the tile it derived from the pool's
-        shape (pages and tokens of every local kv head per step) and what
-        its pool operand is (ops.attention.paged_pool_operand: the stack
-        as this engine stores it, or the call is refused)."""
+        Beside a paged-decode kernel, the schedule it derives from the
+        pool's shape (pages and tokens of every local kv head a group, the
+        groups in flight, one walk over every lane) and what its pool
+        operand is (ops.attention.paged_pool_operand: the stack as this
+        engine stores it, or the call is refused)."""
         from .ops.attention import (
-            chunk_prefill_why_not_flash, flash_why_not, paged_decode_pages,
+            chunk_prefill_why_not_flash, flash_why_not, paged_decode_schedule,
             paged_kernel_why_not, paged_pool_operand,
         )
 
@@ -1703,9 +1704,17 @@ class LLMEngine:
                 ).report()
                 paths["pool_operand"] = paged_pool_operand(self.kv.pool_shapes()[0], C)
             return paths
+        tile = None
         if self.kv.paged:
             why = paged_kernel_why_not(hd, self.kv.block)
             decode = f"xla_gather ({why})" if why else "pallas_paged"
+            if not why:
+                # the kernel's own schedule, for every kind of paged stack
+                tile = paged_decode_schedule(
+                    self.kv.block, self.cfg.n_kv_heads, hd,
+                    "int8" if self.kv.int8 else self.cfg.dtype,
+                    self.kv.table_width, hq=self.cfg.n_heads, mesh=self.mesh,
+                ).report()
         else:
             decode = "xla_ring" if self.kv.ring else "xla_dense"
         if self.kv.linear:
@@ -1730,11 +1739,8 @@ class LLMEngine:
                     for c in self.chunk_shapes
                 },
             }
-            if not why:
-                pages = paged_decode_pages(
-                    self.kv.block, self.cfg.n_kv_heads, hd, self.cfg.dtype, self.kv.table_width
-                )
-                paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+            if tile:
+                paths["decode_tile"] = tile
                 paths["pool_operand"] = paged_pool_operand(self.kv.pool_shapes()[0], hd)
             return paths
         if self.kv.mixed:
@@ -1753,11 +1759,8 @@ class LLMEngine:
                     for c in self.chunk_shapes
                 },
             }
-            if not why:
-                pages = paged_decode_pages(
-                    self.kv.block, self.cfg.n_kv_heads, hd, self.cfg.dtype, self.kv.table_width
-                )
-                paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+            if tile:
+                paths["decode_tile"] = tile
                 paths["pool_operand"] = " | window: ".join(
                     paged_pool_operand(shape, hd) for shape in self.kv.pool_shapes()[0]
                 )
@@ -1775,13 +1778,8 @@ class LLMEngine:
                 for c in self.chunk_shapes
             }
         paths = {"decode": decode, "prefill": prefill}
-        if decode == "pallas_paged":
-            pages = paged_decode_pages(
-                self.kv.block, self.cfg.n_kv_heads, hd,
-                "int8" if self.kv.int8 else self.cfg.dtype,
-                self.kv.table_width, hq=self.cfg.n_heads, mesh=self.mesh,
-            )
-            paths["decode_tile"] = {"pages": pages, "tokens": pages * self.kv.block}
+        if tile:
+            paths["decode_tile"] = tile
             paths["pool_operand"] = paged_pool_operand(self.kv.pool_shapes()[0], hd)
         return paths
 

@@ -644,23 +644,25 @@ def chunk_prefill_attention(
 # selected at trace time exactly like the flash kernel:
 #
 # - Pallas TPU kernel (_paged_decode_partials): grid (batch,): one program
-#   per lane, every kv head at once. The block table and the per-sequence
-#   valid band [lo, hi) ride as SCALAR PREFETCH operands; the pool stays in
-#   HBM and the program copies pages itself (make_async_copy, double
-#   buffered): a GROUP of `pages` table slots at a time, each page the
-#   [B, hkv * d] slab at [layer, page] of the pool as it is STORED, the whole
-#   stack [L, NB, B, hkv * d] (the layer rides as a fourth scalar prefetch:
-#   no layer's pool is sliced out or laid out again before a call), so
-#   a step holds pages * B tokens of every head (paged_decode_pages sizes
-#   it from the pool's shape and dtype; qwen2: 8 pages, 128 tokens, 128 KB
-#   of K and of V). The walk covers only the groups that meet the band,
-#   from lo // (pages * B) to where hi ends, and inside an edge group only
-#   the pages that meet it: a table entry outside the band is never read,
-#   a lane with an empty band costs one empty program. The pool is never
-#   gathered into a dense copy, which is the whole point (decode is
-#   HBM-bound; a gather would double the dominant stream). Returns
-#   online-softmax PARTIALS (normalized output + running max + denom) so
-#   the caller can merge the chunk ring buffer region with one rescale.
+#   per lane, every kv head at once. The block tables, the per-sequence
+#   valid band [lo, hi) and the layer ride as SCALAR PREFETCH operands; the
+#   pool stays in HBM as it is STORED, the whole stack [L, NB, B, hkv * d]
+#   (no layer's pool is sliced out or laid out again before a call), and
+#   the kernel copies pages itself (make_async_copy), each the [B, hkv * d]
+#   slab at [layer, page], a GROUP of `pages` table slots at a time. The
+#   lanes' programs run in turn and walk ONE stream of copies over every
+#   lane's groups (_PageStream): several groups in flight, the next lanes'
+#   first groups among them, so no lane waits on a copy of its own. A group
+#   is sized by bytes from the pool's shape (paged_decode_schedule; qwen2:
+#   32 pages, 512 tokens, 1 MB of K and V). The walk covers only the groups
+#   that meet the band; a group inside it is read whole without a mask, a
+#   group at its edge in parts of 256 rows, only those that meet it: a
+#   table entry outside the band is never read, a lane with an empty band
+#   is passed over. The pool is never gathered into a dense copy, which is
+#   the whole point (decode is HBM-bound; a gather would double the
+#   dominant stream). Returns online-softmax PARTIALS (normalized output +
+#   running max + denom) so the caller can merge the chunk ring buffer
+#   region with one rescale.
 # - Dense-gather reference (paged_gather): jnp.take the layer's table rows
 #   into the contiguous layout and reuse the proven attention above — the
 #   off-TPU path and the test oracle. Bit-exact with the
@@ -672,7 +674,8 @@ def chunk_prefill_attention(
 # the HBM stream the decode loop is bound by moves at half width. The
 # kernel applies a row's scale to its score column and its probability
 # (the scale factors out of the dot over d); the scales reach it gathered
-# through the table by XLA, 1/32 of the rows' bytes.
+# through the table by XLA, 1/32 of the rows' bytes, a row of them for each
+# part of a page group.
 
 
 def paged_pool_operand(pool_shape, cols: int) -> str:
@@ -733,149 +736,302 @@ def paged_gather(k_pool, v_pool, tables, layer, rows, *, k_scales=None, v_scales
     return take(k_pool, rows[0], k_scales), take(v_pool, rows[1], v_scales)
 
 
-# What one step of the paged-decode kernel holds is derived from the pool's
-# own shape: as many tokens of every local kv head as _PAGED_TILE_BYTES of K
-# hold at the pool's dtype (as much again of V, both twice over for the
-# double buffer), but no fewer than the 128 lanes a score row spans and no
-# more than 512 (a step's f32 rows and scores are values the compiler holds).
-_PAGED_TILE_BYTES = 128 * 1024
-_PAGED_TILE_TOKENS = (128, 512)
+class DecodeSchedule(NamedTuple):
+    """How a paged-decode kernel walks the pool (paged_decode_schedule,
+    mla_decode_schedule)."""
+
+    pages: int  # table slots a page group
+    tokens: int  # rows a group: pages * block
+    in_flight: int  # groups whose copies run ahead of the one being read
+
+    def report(self) -> dict:
+        """stats()["attention"]["decode_tile"]"""
+        return {**self._asdict(), "walk": "across lanes"}
 
 
-def paged_decode_pages(
+# The GQA kernel's group is sized by bytes: as many tokens of every local kv
+# head as _PAGED_GROUP_BYTES of K and V hold at the pool's dtype, but no
+# fewer than the 128 lanes a score row spans and no more than 512 (a step's
+# f32 rows and scores are values the compiler holds). On a v5e a step costs
+# its own work and the starting of its copies more than their latency, so
+# a wide group pays a step's fixed cost over more rows. As many groups are
+# in flight as _PAGED_STREAM_BYTES of page buffers hold beside the one
+# being read, never fewer than two nor more than eight.
+_PAGED_GROUP_BYTES = 1 << 20
+_PAGED_GROUP_TOKENS = (128, 512)
+_PAGED_STREAM_BYTES = 4 << 20
+_PAGED_IN_FLIGHT = (2, 8)
+# A group at the band's edge is read in parts of up to 256 rows: a part
+# costs every head's online-softmax step, and on a v5e at qwen2's shapes
+# parts of 256 rows read a call in 64.3 us, of 128 in 68.1-68.5, the whole
+# group masked in 65.6 (16 lanes of 700 tokens).
+_PAGED_PART_ROWS = 256
+
+
+def paged_decode_schedule(
     block: int, hkv: int, d: int, dtype, table_slots: int, *, hq: int = 0, mesh=None
-) -> int:
-    """Pages of one lane the paged-decode kernel holds per step (qwen2's
-    16-token bf16 pages of 4 heads x 128: 8 pages, 128 tokens), never more
-    than the block table has slots. Under a TP mesh the kernel runs per
-    head shard: name the mesh (and hq) to count the LOCAL kv heads."""
+) -> DecodeSchedule:
+    """The GQA kernel's page group and depth, from the pool's shapes alone:
+    16-token bf16 pages of 4 kv heads x 128 (qwen2) give 32 pages, 512
+    tokens, 1 MB of K and V a group and 3 groups in flight; 8 heads
+    (k-exaone) 256 tokens; 30 (olmo) 128. A group never spans more slots
+    than the block table has. Under a TP mesh the kernel runs per head
+    shard: name the mesh (and hq) to count the LOCAL kv heads."""
     if mesh is not None and mesh.size > 1:
         ka = _head_axes(mesh, hq, hkv)[1]
         hkv //= mesh.shape[ka] if ka else 1
-    least, most = _PAGED_TILE_TOKENS
-    row_bytes = hkv * d * jnp.dtype(dtype).itemsize
-    tokens = min(max(_PAGED_TILE_BYTES // row_bytes, least), most)
-    return max(1, min(tokens // block, table_slots))
+    least, most = _PAGED_GROUP_TOKENS
+    token_bytes = 2 * hkv * d * jnp.dtype(dtype).itemsize  # K and V
+    tokens = min(max(_PAGED_GROUP_BYTES // token_bytes, least), most)
+    pages = max(1, min(tokens // block, table_slots))
+    group_bytes = pages * block * token_bytes
+    least, most = _PAGED_IN_FLIGHT
+    in_flight = min(max(_PAGED_STREAM_BYTES // group_bytes - 1, least), most)
+    return DecodeSchedule(pages, pages * block, in_flight)
+
+
+def _edge_part(pages: int, block: int, rows: int) -> int:
+    """Pages of a part: a group at the band's edge is read in parts of up to
+    `rows` rows, only those that meet the band."""
+    return math.gcd(pages, max(1, rows // block))
+
+
+class _PageStream:
+    """ONE stream of page copies over every lane's page groups, in lane
+    order, for a paged-decode kernel whose lanes' programs run in turn
+    (dimension_semantics "arbitrary") and share its page buffers, their DMA
+    semaphores and `walk`, a cursor in SMEM: the copies of the next groups,
+    the next lanes' first groups included, are in flight while a group is
+    read, `in_flight` groups ahead in `in_flight + 1` buffers. `walk` holds
+    the next group to copy (lane, group; lane == lanes once none is left)
+    and the counts of groups started and read, whose remainders by the
+    buffer count name a group's buffer. `pairs` are the (pool in HBM
+    [L, NB, B, W], buffers [in_flight + 1, pages, B, W]) a page is copied
+    between, one semaphore row each. A lane with an empty band is passed
+    over and its table never read; a group at its band's edge is read in
+    parts of up to `part_rows` rows."""
+
+    def __init__(
+        self, tbl_ref, lo_ref, hi_ref, layer_ref, pairs, sems, walk, *, pages: int, in_flight: int, part_rows: int
+    ):
+        self.tbl_ref, self.lo_ref, self.hi_ref, self.layer = tbl_ref, lo_ref, hi_ref, layer_ref[0]
+        self.pairs, self.sems, self.walk = pairs, sems, walk
+        self.n_pool, self.block = pairs[0][0].shape[1:3]
+        self.lanes, self.n_tbl = tbl_ref.shape
+        self.pages, self.in_flight, self.n_buf = pages, in_flight, in_flight + 1
+        self.tile = pages * self.block
+        self.part = _edge_part(pages, self.block, part_rows)
+        self.bi = pl.program_id(0)  # this program's lane
+        self.own = self.band(self.bi)
+
+    def band(self, lane):
+        """A lane's band [lo, hi), held to what the table can name, and its
+        walk: page groups [first, first + n) cover the band."""
+        lo = jnp.maximum(self.lo_ref[lane], 0)
+        hi = jnp.minimum(self.hi_ref[lane], self.n_tbl * self.block)
+        first = lo // self.tile
+        return lo, hi, first, jnp.where(hi > lo, pl.cdiv(hi, self.tile) - first, 0)
+
+    def _copies(self, lane, g, slot, go):
+        """start (go=True) or wait for the copies of a lane's group g's LIVE
+        pages into buffer `slot`: a page outside the band is never looked
+        up, so a stale table entry is never dereferenced."""
+        pages, block = self.pages, self.block
+        lo, hi, _, _ = self.band(lane)
+        j0 = jnp.maximum(g * pages, lo // block)
+        j1 = jnp.minimum((g + 1) * pages, pl.cdiv(hi, block))
+
+        def one(j, k):
+            """table slot j into the group's page k"""
+            # a wait only needs the copy's shape, not its source
+            page = jnp.clip(self.tbl_ref[lane, j], 0, self.n_pool - 1) if go else 0
+            for part, (src, dst) in enumerate(self.pairs):
+                cp = pltpu.make_async_copy(src.at[self.layer, page], dst.at[slot, k], self.sems.at[part, slot])
+                cp.start() if go else cp.wait()
+
+        # a group inside the band has every page live: its copies unrolled
+        @pl.when(j1 - j0 == pages)
+        def _whole():
+            for k in range(pages):
+                one(g * pages + k, k)
+
+        @pl.when(j1 - j0 < pages)
+        def _edge():
+            def edge_page(j, carry):
+                one(j, j - g * pages)
+                return carry
+
+            jax.lax.fori_loop(j0, j1, edge_page, 0)
+
+    def _aim(self, lane):
+        """Point the cursor at the first group of the first lane from `lane`
+        on whose band is not empty."""
+        lanes, walk = self.lanes, self.walk
+        lane = jax.lax.while_loop(
+            lambda x: (x < lanes) & (self.band(jnp.minimum(x, lanes - 1))[3] == 0), lambda x: x + 1, lane
+        )
+        walk[0] = lane
+        walk[1] = self.band(jnp.minimum(lane, lanes - 1))[2]
+
+    def _start_next(self):
+        """Start the copies of the walk's next group, if one is left."""
+        walk = self.walk
+        lane, g, started = walk[0], walk[1], walk[2]
+
+        @pl.when(lane < self.lanes)
+        def _():
+            self._copies(lane, g, started % self.n_buf, True)
+            walk[2] = started + 1
+            _, _, first, n = self.band(lane)
+
+            @pl.when(g + 1 < first + n)
+            def _same_lane():
+                walk[1] = g + 1
+
+            @pl.when(g + 1 >= first + n)
+            def _next_lane():
+                self._aim(lane + 1)
+
+    def read_lane(self, read, carry):
+        """Walk this program's lane: every group of its band, in order, the
+        next group's copies started before this one's are waited for, then
+        `read(carry, slot, g, k) -> carry`: group g's rows in buffer `slot`,
+        all of them without a mask (k None: the group lies inside the band)
+        or part k of `part` pages, to be masked against the band."""
+        walk, block, tile, part, bi = self.walk, self.block, self.tile, self.part, self.bi
+
+        @pl.when(bi == 0)
+        def _prologue():
+            walk[2] = 0
+            walk[3] = 0
+            self._aim(0)
+
+            def start(i, carry):
+                self._start_next()
+                return carry
+
+            jax.lax.fori_loop(0, self.in_flight, start, 0)
+
+        lo, hi, first, n = self.own
+        done = walk[3]  # groups read by the lanes before this one
+
+        def body(i, carry):
+            g = first + i
+            slot = (done + i) % self.n_buf
+            self._start_next()
+            self._copies(bi, g, slot, False)
+            base = g * tile
+
+            def edge(carry):
+                k0 = jnp.maximum(lo - base, 0) // (part * block)
+                k1 = pl.cdiv(jnp.minimum(hi - base, tile), part * block)
+                return jax.lax.fori_loop(k0, k1, lambda k, c: read(c, slot, g, k), carry)
+
+            whole = (base >= lo) & (base + tile <= hi)
+            return jax.lax.cond(whole, lambda c: read(c, slot, g, None), edge, carry)
+
+        carry = jax.lax.fori_loop(0, n, body, carry)
+        walk[3] = done + n
+        return carry
+
+    def rows(self, buf, slot, k):
+        """Group rows of `buf` in `slot` as [rows, W]: all (k None) or part k."""
+        if k is None:
+            return buf[slot].reshape(self.tile, buf.shape[-1])
+        return buf[slot, pl.ds(k * self.part, self.part)].reshape(self.part * self.block, buf.shape[-1])
+
+    def live(self, g, k, shape, axis):
+        """Which of part k of group g's rows lie in this lane's band, as a
+        mask of `shape` whose `axis` runs over the rows."""
+        lo, hi, _, _ = self.own
+        pos = g * self.tile + k * self.part * self.block + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        return (pos >= lo) & (pos < hi)
 
 
 def _paged_decode_kernel(
     # scalar prefetch: block tables + per-sequence valid bounds + the layer
     tbl_ref, lo_ref, hi_ref, layer_ref,
     # q, the pools left in HBM whole ([L, NB, B, hkv * d]: k, v), [the lane's
-    # k and v scales, one row per page group], outputs, page buffers, DMA
-    # semaphores
+    # k and v scales, one row per part of a page group], outputs, page
+    # buffers, DMA semaphores, the walk's cursor (SMEM, kept across the
+    # lanes' programs)
     *refs,
     pages: int,
+    in_flight: int,
     scale: float,
     logit_cap: float,
     quantized: bool,
 ):
     if quantized:
-        q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
+        q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, m_ref, l_ref, k_buf, v_buf, sems, walk = refs
     else:
-        q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, k_buf, v_buf, sems = refs
+        q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref, k_buf, v_buf, sems, walk = refs
         ks_ref = vs_ref = None
-    n_pool, block = k_hbm.shape[1], k_hbm.shape[2]
-    n_tbl = tbl_ref.shape[1]
+    stream = _PageStream(
+        tbl_ref, lo_ref, hi_ref, layer_ref, ((k_hbm, k_buf), (v_hbm, v_buf)), sems, walk,
+        pages=pages, in_flight=in_flight, part_rows=_PAGED_PART_ROWS,
+    )
     hkv, group, d = q_ref.shape[1:]
-    tile = pages * block  # tokens a step
-    bi = pl.program_id(0)
-    layer = layer_ref[0]
-    # the band, held to what the table can name: a wild bound walks no further
-    lo = jnp.maximum(lo_ref[bi], 0)
-    hi = jnp.minimum(hi_ref[bi], n_tbl * block)
-    # the lane's walk: page groups [first, first + n) cover the band [lo, hi)
-    first = lo // tile
-    n = jnp.where(hi > lo, pl.cdiv(hi, tile) - first, 0)
-
-    def page_copies(g, slot, go):
-        """start (go=True) or wait for the copies of group g's LIVE pages
-        into buffer `slot`: a page outside the band is never looked up, so
-        a stale table entry is never dereferenced."""
-        j0 = jnp.maximum(g * pages, lo // block)
-        j1 = jnp.minimum((g + 1) * pages, pl.cdiv(hi, block))
-
-        def one(j, carry):
-            # a wait only needs the copy's shape, not its source
-            page = jnp.clip(tbl_ref[bi, j], 0, n_pool - 1) if go else 0
-            for kv, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
-                cp = pltpu.make_async_copy(
-                    src.at[layer, page], dst.at[slot, j - g * pages], sems.at[kv, slot]
-                )
-                cp.start() if go else cp.wait()
-            return carry
-
-        jax.lax.fori_loop(j0, j1, one, 0)
-
-    @pl.when(n > 0)
-    def _first():
-        page_copies(first, 0, True)
-
-    def rows_of(buf, slot):
-        """the step's pages as [tile, hkv * d] f32 rows: a head's are the
-        lane-aligned columns [head * d, (head + 1) * d)"""
-        return buf[slot].astype(jnp.float32).reshape(tile, hkv * d)
-
+    parts = pages // stream.part  # a group's rows of scales
     heads = range(hkv)
     qs = [q_ref[0, h].astype(jnp.float32) * scale for h in heads]  # [group, d]
 
-    # One online-softmax update of every head from one group of pages. The
+    def scales(ref, h, g, k):
+        """[1, rows] scales of head h for the rows read: part k of group g,
+        or all the group's parts side by side"""
+        if k is not None:
+            return ref[0, h, pl.ds(g * parts + k, 1), :]
+        rows = [ref[0, h, pl.ds(g * parts + j, 1), :] for j in range(parts)]
+        return rows[0] if parts == 1 else jnp.concatenate(rows, axis=1)
+
+    # One online-softmax update of every head from the rows read. The
     # running max / denominator / accumulator are loop-carried VALUES and
     # each phase is written for all heads before the next, so nothing
     # orders one head's matmuls behind another's: on the v5e the same
     # arithmetic through per-head VMEM accumulators took half as long again.
-    def body(i, carry):
+    def read(carry, slot, g, k):
         m, l, acc = carry
-        g = first + i
-        slot = i % 2
-
-        @pl.when(i + 1 < n)
-        def _next():
-            page_copies(g + 1, 1 - slot, True)
-
-        page_copies(g, slot, False)
-        base = g * tile  # logical position of the group's first row
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (group, tile), 1)
-        in_band = (pos >= lo) & (pos < hi)
-        row = base + jax.lax.broadcasted_iota(jnp.int32, (tile, hkv * d), 0)
-        row_in_band = (row >= lo) & (row < hi)
-        k = rows_of(k_buf, slot)
+        # the rows as [rows, hkv * d] f32: a head's are the lane-aligned
+        # columns [head * d, (head + 1) * d)
+        kr = stream.rows(k_buf, slot, k).astype(jnp.float32)
+        vr = stream.rows(v_buf, slot, k).astype(jnp.float32)
         s = [
             jax.lax.dot_general(
-                qs[h], k[:, h * d:(h + 1) * d], (((1,), (1,)), ((), ())),
+                qs[h], kr[:, h * d:(h + 1) * d], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             for h in heads
-        ]  # [group, tile] each
+        ]  # [group, rows] each
         if quantized:
             # int8 rows: one f32 scale per (row, head) factors out of the
             # dot over d, onto the score's column
-            s = [s[h] * ks_ref[0, h, pl.ds(g, 1), :] for h in heads]
+            s = [s[h] * scales(ks_ref, h, g, k) for h in heads]
         if logit_cap > 0.0:
             s = [logit_cap * jnp.tanh(x / logit_cap) for x in s]
-        s = [jnp.where(in_band, x, NEG_INF) for x in s]
+        if k is not None:
+            # rows outside the band may be pages never copied: whatever the
+            # buffer holds there (NaN included) must not reach 0 * v
+            in_band = stream.live(g, k, s[0].shape, 1)
+            s = [jnp.where(in_band, x, NEG_INF) for x in s]
+            vr = jnp.where(stream.live(g, k, vr.shape, 0), vr, 0.0)
         m_new = [jnp.maximum(m[h], jnp.max(s[h], axis=-1, keepdims=True)) for h in heads]
         p = [jnp.exp(s[h] - m_new[h]) for h in heads]
         alpha = [jnp.exp(m[h] - m_new[h]) for h in heads]
         l_new = [alpha[h] * l[h] + jnp.sum(p[h], axis=-1, keepdims=True) for h in heads]
         if quantized:
-            p = [
-                jnp.where(in_band, p[h] * vs_ref[0, h, pl.ds(g, 1), :], 0.0)
-                for h in heads
-            ]
-        # rows outside the band may be pages never copied: whatever the
-        # buffer holds there (NaN included) must not reach 0 * v
-        v = jnp.where(row_in_band, rows_of(v_buf, slot), 0.0)
+            p = [p[h] * scales(vs_ref, h, g, k) for h in heads]
+            if k is not None:
+                p = [jnp.where(in_band, x, 0.0) for x in p]
         acc_new = [
             acc[h] * alpha[h] + jax.lax.dot_general(
-                p[h], v[:, h * d:(h + 1) * d], (((1,), (0,)), ((), ())),
+                p[h], vr[:, h * d:(h + 1) * d], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             for h in heads
         ]
         return tuple(m_new), tuple(l_new), tuple(acc_new)
 
-    m, l, acc = jax.lax.fori_loop(0, n, body, (
+    m, l, acc = stream.read_lane(read, (
         tuple(jnp.full((group, 1), NEG_INF, jnp.float32) for _ in heads),
         tuple(jnp.zeros((group, 1), jnp.float32) for _ in heads),
         tuple(jnp.zeros((group, d), jnp.float32) for _ in heads),
@@ -948,7 +1104,8 @@ def _paged_decode_call(
     MB = tables.shape[1]
     quantized = k_scales is not None
     group = hq // hkv
-    pages = paged_decode_pages(B, hkv, d, k_pool.dtype, MB)
+    sched = paged_decode_schedule(B, hkv, d, k_pool.dtype, MB)
+    pages, n_buf = sched.pages, sched.in_flight + 1
 
     def lane(bi, tbl, lo_, hi_, layer_):
         return (bi, 0, 0, 0)
@@ -963,21 +1120,23 @@ def _paged_decode_call(
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     in_specs = [pl.BlockSpec((1, hkv, group, d), lane), hbm, hbm]
     operands = [q.reshape(b, hkv, group, d), k_pool, v_pool]
-    page_buf = pltpu.VMEM((2, pages, B, W), k_pool.dtype)
+    page_buf = pltpu.VMEM((n_buf, pages, B, W), k_pool.dtype)
     if quantized:
         # A page's [B, hkv] scales are too narrow a slab to copy from HBM
         # (Mosaic wants whole 128-lane rows), so XLA gathers the lane's
         # scales through the table, 1/32 of the int8 rows' bytes, and the
-        # kernel holds them as one [tile] row per (head, page group).
-        n_groups = pl.cdiv(MB, pages)
+        # kernel holds them as one row per (head, part of a page group): an
+        # edge part reads its row, a whole group its parts' side by side.
+        part = _edge_part(pages, B, _PAGED_PART_ROWS)
+        n_rows = pl.cdiv(MB, pages) * pages // part
 
-        def group_rows(sc):  # [L, NB, B, hkv] -> [b, hkv, n_groups, pages * B]
+        def part_rows(sc):  # [L, NB, B, hkv] -> [b, hkv, n_rows, part * B]
             sc = _take_scales(sc, layer, tables)  # [b, MB, B, hkv]
-            sc = jnp.pad(sc, ((0, 0), (0, n_groups * pages - MB), (0, 0), (0, 0)))
-            return sc.reshape(b, n_groups, pages * B, hkv).transpose(0, 3, 1, 2)
+            sc = jnp.pad(sc, ((0, 0), (0, n_rows * part - MB), (0, 0), (0, 0)))
+            return sc.reshape(b, n_rows, part * B, hkv).transpose(0, 3, 1, 2)
 
-        in_specs += [pl.BlockSpec((1, hkv, n_groups, pages * B), lane)] * 2
-        operands += [group_rows(k_scales), group_rows(v_scales)]
+        in_specs += [pl.BlockSpec((1, hkv, n_rows, part * B), lane)] * 2
+        operands += [part_rows(k_scales), part_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b,),
@@ -987,12 +1146,15 @@ def _paged_decode_call(
             pl.BlockSpec((1, hkv, group, 128), lane),
             pl.BlockSpec((1, hkv, group, 128), lane),
         ],
-        # one DMA semaphore per (k | v, buffer)
-        scratch_shapes=[page_buf, page_buf, pltpu.SemaphoreType.DMA((2, 2))],
+        scratch_shapes=[
+            page_buf, page_buf,
+            pltpu.SemaphoreType.DMA((2, n_buf)),  # one per (k | v, buffer)
+            pltpu.SMEM((4,), jnp.int32),  # the walk's cursor
+        ],
     )
     kernel = functools.partial(
-        _paged_decode_kernel,
-        pages=pages, scale=scale, logit_cap=logit_cap, quantized=quantized,
+        _paged_decode_kernel, pages=pages, in_flight=sched.in_flight,
+        scale=scale, logit_cap=logit_cap, quantized=quantized,
     )
     o, m, l = pl.pallas_call(
         kernel,
@@ -1005,6 +1167,9 @@ def _paged_decode_call(
             jax.ShapeDtypeStruct((b, hkv, group, 128), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, group, 128), jnp.float32),
         ],
+        # the lanes' programs run in turn: the stream of copies and its
+        # cursor pass from one to the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
         tables.astype(jnp.int32), lo.astype(jnp.int32), hi.astype(jnp.int32),
@@ -1197,38 +1362,26 @@ def mla_kernel_why_not(latent: int, rope: int, block: int, *, interpret: bool = 
     return ""
 
 
-class MlaSchedule(NamedTuple):
-    """How the latent decode kernel walks the pool (mla_decode_schedule)."""
-
-    pages: int  # table slots a page group
-    tokens: int  # rows a group: pages * block
-    in_flight: int  # groups whose copies run ahead of the one being read
-
-    def report(self) -> dict:
-        """stats()["attention"]["decode_tile"] on the latent path."""
-        return {**self._asdict(), "walk": "across lanes"}
-
-
-# The latent kernel's schedule is its own (paged_decode_pages sizes the GQA
-# kernel's tile and shares nothing with it). The latent row is the smallest
-# the pool has (glm: 1,280 B stored), so a step of 128 rows moves little and
-# pays mostly for itself: on a v5e a step's cost was its own work and the
-# starting of its copies, not their latency (two to eight groups in flight
-# read alike). So a group is wide, _MLA_GROUP_TOKENS rows, and as many groups are
-# in flight as _MLA_STREAM_BYTES of page buffers hold beside the one being
+# The latent kernel's schedule is its own (paged_decode_schedule sizes the
+# GQA kernel's group by bytes). The latent row is the smallest the pool has
+# (glm: 1,280 B stored), so a step of 128 rows moves little and pays mostly
+# for itself: on a v5e a step's cost was its own work and the starting of
+# its copies, not their latency (two to eight groups in flight read alike).
+# So a group is wide, _MLA_GROUP_TOKENS rows, and as many groups are in
+# flight as _MLA_STREAM_BYTES of page buffers hold beside the one being
 # read, never fewer than two.
 _MLA_GROUP_TOKENS = 512
 _MLA_STREAM_BYTES = 1 << 20
 
 
-def mla_decode_schedule(block: int, latent: int, rope: int, dtype, table_slots: int) -> MlaSchedule:
+def mla_decode_schedule(block: int, latent: int, rope: int, dtype, table_slots: int) -> DecodeSchedule:
     """The latent kernel's page group and depth, from the pool's shapes
     alone: 16-token pages of 512 | 128 bf16 columns (glm) give 32 pages,
     512 tokens, 640 KB a group and 2 groups in flight. A group never spans
     more slots than the block table has."""
     pages = max(1, min(_MLA_GROUP_TOKENS // block, table_slots))
     group_bytes = pages * block * (latent + rope) * jnp.dtype(dtype).itemsize
-    return MlaSchedule(pages, pages * block, max(2, _MLA_STREAM_BYTES // group_bytes - 1))
+    return DecodeSchedule(pages, pages * block, max(2, _MLA_STREAM_BYTES // group_bytes - 1))
 
 
 def _mla_paged_decode_kernel(
@@ -1243,119 +1396,29 @@ def _mla_paged_decode_kernel(
     in_flight: int,
 ):
     """_paged_decode_kernel for latent rows: one program a lane, only the
-    lane's band, and ONE stream of page copies over every lane's groups in
-    lane order. The programs run in turn and share the buffers, so the
-    copies of the next groups, the next lanes' included, are in flight
-    while a group is read: `in_flight` groups ahead, `in_flight + 1`
-    buffers. `walk` holds the next group to copy (lane, group; lane ==
-    lanes once none is left) and the counts of groups started and read,
-    whose remainders by the buffer count name a group's buffer. A lane with
-    an empty band is passed over and its table never read. Every query head
-    meets the same rows, so a step is two matmuls of all heads: scores
-    [hq, tile] from the c_kv and rope parts, then p @ c_kv."""
-    n_pool, block = c_hbm.shape[1], c_hbm.shape[2]
-    lanes, n_tbl = tbl_ref.shape
-    n_buf = in_flight + 1
+    lane's band, over the same stream of page copies (_PageStream). Every
+    query head meets the same rows, so a step is two matmuls of all heads:
+    scores [hq, rows] from the c_kv and rope parts, then p @ c_kv."""
+    stream = _PageStream(
+        tbl_ref, lo_ref, hi_ref, layer_ref, ((c_hbm, c_buf), (r_hbm, r_buf)), sems, walk,
+        pages=pages, in_flight=in_flight, part_rows=128,  # one MXU pass
+    )
     hq, C = qc_ref.shape[1:]
-    R = qr_ref.shape[2]
-    tile = pages * block
-    bi = pl.program_id(0)
-    layer = layer_ref[0]
-
-    def band(lane):
-        """A lane's band [lo, hi), held to what the table can name, and its
-        walk: page groups [first, first + n) cover the band."""
-        lo = jnp.maximum(lo_ref[lane], 0)
-        hi = jnp.minimum(hi_ref[lane], n_tbl * block)
-        first = lo // tile
-        return lo, hi, first, jnp.where(hi > lo, pl.cdiv(hi, tile) - first, 0)
-
-    def page_copies(lane, g, slot, go):
-        """start (go=True) or wait for the copies of a lane's group g's LIVE
-        pages into buffer `slot`: a page outside the band is never looked
-        up, so a stale table entry is never dereferenced."""
-        lo, hi, _, _ = band(lane)
-        j0 = jnp.maximum(g * pages, lo // block)
-        j1 = jnp.minimum((g + 1) * pages, pl.cdiv(hi, block))
-
-        def one(j, k):
-            """table slot j into the group's page k"""
-            # a wait only needs the copy's shape, not its source
-            page = jnp.clip(tbl_ref[lane, j], 0, n_pool - 1) if go else 0
-            for part, (src, dst) in enumerate(((c_hbm, c_buf), (r_hbm, r_buf))):
-                cp = pltpu.make_async_copy(src.at[layer, page], dst.at[slot, k], sems.at[part, slot])
-                cp.start() if go else cp.wait()
-
-        # a group inside the band has every page live: its copies unrolled
-        @pl.when(j1 - j0 == pages)
-        def _whole():
-            for k in range(pages):
-                one(g * pages + k, k)
-
-        @pl.when(j1 - j0 < pages)
-        def _edge():
-            def edge_page(j, carry):
-                one(j, j - g * pages)
-                return carry
-
-            jax.lax.fori_loop(j0, j1, edge_page, 0)
-
-    def aim(lane):
-        """Point the cursor at the first group of the first lane from `lane`
-        on whose band is not empty."""
-        lane = jax.lax.while_loop(
-            lambda x: (x < lanes) & (band(jnp.minimum(x, lanes - 1))[3] == 0), lambda x: x + 1, lane
-        )
-        walk[0] = lane
-        walk[1] = band(jnp.minimum(lane, lanes - 1))[2]
-
-    def start_next():
-        """Start the copies of the walk's next group, if one is left."""
-        lane, g, started = walk[0], walk[1], walk[2]
-
-        @pl.when(lane < lanes)
-        def _():
-            page_copies(lane, g, started % n_buf, True)
-            walk[2] = started + 1
-            _, _, first, n = band(lane)
-
-            @pl.when(g + 1 < first + n)
-            def _same_lane():
-                walk[1] = g + 1
-
-            @pl.when(g + 1 >= first + n)
-            def _next_lane():
-                aim(lane + 1)
-
-    @pl.when(bi == 0)
-    def _prologue():
-        walk[2] = 0
-        walk[3] = 0
-        aim(0)
-        for _ in range(in_flight):
-            start_next()
-
-    lo, hi, first, n = band(bi)
-    done = walk[3]  # groups read by the lanes before this one
     qc, qr = qc_ref[0], qr_ref[0]  # [hq, C], [hq, R]
     nt = (((1,), (1,)), ((), ()))
-    # a group at the band's edge is read in parts of up to 128 rows (one
-    # MXU pass), only those that meet the band
-    part = math.gcd(pages, max(1, 128 // block))
 
-    def update(carry, c, r, base, masked):
-        """One online-softmax update of every head from rows c | r at the
-        logical positions [base, base + rows). At the band's edge (masked)
-        the rows outside it are masked: they may be pages never copied, and whatever
-        the buffer holds there (NaN included) must not reach 0 * c."""
+    def read(carry, slot, g, k):
+        """One online-softmax update of every head from the rows read. At
+        the band's edge (k a part) the rows outside it are masked: they may
+        be pages never copied, and whatever the buffer holds there (NaN
+        included) must not reach 0 * c."""
         m, l, acc = carry
+        c, r = stream.rows(c_buf, slot, k), stream.rows(r_buf, slot, k)
         s = jax.lax.dot_general(qc, c, nt, preferred_element_type=jnp.float32)
         s = s + jax.lax.dot_general(qr, r, nt, preferred_element_type=jnp.float32)
-        if masked:
-            pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where((pos >= lo) & (pos < hi), s, NEG_INF)
-            row = base + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
-            c = jnp.where((row >= lo) & (row < hi), c, jnp.zeros_like(c))
+        if k is not None:
+            s = jnp.where(stream.live(g, k, s.shape, 1), s, NEG_INF)
+            c = jnp.where(stream.live(g, k, c.shape, 0), c, jnp.zeros_like(c))
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -1366,36 +1429,11 @@ def _mla_paged_decode_kernel(
         )
         return m_new, l_new, acc_new
 
-    def body(i, carry):
-        g = first + i
-        slot = (done + i) % n_buf
-        start_next()
-        page_copies(bi, g, slot, False)
-        base = g * tile
-
-        def whole(carry):
-            rows = (c_buf[slot].reshape(tile, C), r_buf[slot].reshape(tile, R))
-            return update(carry, *rows, base, False)
-
-        def edge(carry):
-            def read_part(k, carry):
-                pages_k = pl.ds(k * part, part)
-                rows = (c_buf[slot, pages_k].reshape(part * block, C),
-                        r_buf[slot, pages_k].reshape(part * block, R))
-                return update(carry, *rows, base + k * part * block, True)
-
-            k0 = jnp.maximum(lo - base, 0) // (part * block)
-            k1 = pl.cdiv(jnp.minimum(hi - base, tile), part * block)
-            return jax.lax.fori_loop(k0, k1, read_part, carry)
-
-        return jax.lax.cond((base >= lo) & (base + tile <= hi), whole, edge, carry)
-
-    m, l, acc = jax.lax.fori_loop(0, n, body, (
+    m, l, acc = stream.read_lane(read, (
         jnp.full((hq, 1), NEG_INF, jnp.float32),
         jnp.zeros((hq, 1), jnp.float32),
         jnp.zeros((hq, C), jnp.float32),
     ))
-    walk[3] = done + n
     o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
     m_ref[0] = jnp.broadcast_to(m, m_ref.shape[1:])
     l_ref[0] = jnp.broadcast_to(l, l_ref.shape[1:])

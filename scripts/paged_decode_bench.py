@@ -7,10 +7,16 @@
 Times layer-calls of ``ops.attention`` over a pool stored as the engine
 stores it, the whole stack ``[L, NB, B, W]`` read at a layer index:
 
-- ``gqa``: ``qwen2-7b.reason-closed``'s shapes (hq 28, hkv 4, d 128, 16-token
-  blocks, a table of 120, bf16 pools of 2,394 blocks and 28 layers), 16 and
-  48 lanes; the kernel's partials, the whole paged_chunk_decode_attention
-  around it and the dense-gather path (use_kernel=False);
+- ``gqa``: the GQA kernel at the shapes of the cells that run it, 16 lanes
+  each: ``qwen2-7b.reason-closed`` (hq 28, hkv 4, a table of 120, 2,394
+  blocks, 28 layers), ``k-exaone-236b-a23b.mixed-closed``'s full layers
+  (64 / 8 heads, a table of 520, 8,208 blocks, 3 layers) and window layers
+  (the same heads over a band of 128 keys, 240 blocks, 10 layers), and
+  ``olmo-hybrid-7b.chat-closed``'s full layers (30 / 30 heads, a table of
+  129, 2,080 blocks, 8 layers); head_dim 128, 16-token blocks, bf16 pools.
+  The kernel's partials, the same without its dots, the whole
+  paged_chunk_decode_attention around it and, at the cell's mean context,
+  the dense-gather path (use_kernel=False);
 - ``latent``: ``glm-4.7-flash.think-closed``'s (20 heads over rows of
   512 | 128, 16-token blocks, a table of 200, pools of 5,104 blocks and 13
   layers), 16 lanes; the latent kernel's partials and
@@ -21,13 +27,17 @@ one free list as they grow, in turn. Every layer of the stack is called in
 one jit, four times over, so the host's dispatch is a small share of the
 number; a row gives the least and the median of 7 timed runs per call, the
 share of the HBM roofline the least reaches over the bytes the bands' rows
-count, and max |kernel - gather| on the same inputs. ``PYTHONPATH`` picks
+count, and max |kernel - gather| on the same inputs. "Without its dots"
+(``nodots_us_*``) traces the kernel with ``jax.lax.dot_general`` replaced
+by a stand-in of the same shape (``without_dots``): what is left is the
+copies, the waits and the per-step work around the matmuls. ``PYTHONPATH`` picks
 the checkout whose kernels are timed (this one's by default); each row
 names it. One JSON line per row on stdout.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import statistics
@@ -44,22 +54,30 @@ import numpy as np  # noqa: E402
 from gofr_tpu.ops import attention as A  # noqa: E402
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(A.__file__))))
-BLOCK, STEPS, REPS = 16, 8, 4
-# qwen2-7b: heads, kv heads, head_dim, table width, pool blocks, layers
-HQ, HKV, D, GQA_TBL, GQA_POOL, GQA_LAYERS = 28, 4, 128, 120, 2394, 28
+BLOCK, STEPS, REPS, D = 16, 8, 4, 128
+# the GQA kernel's cells: heads, kv heads, table width, pool blocks, layers,
+# window (the band a lane reads), 16 lanes' contexts (the cell's mean first)
+GQA_SHAPES = {
+    "qwen2": (28, 4, 120, 2394, 28, 0, {"700": 700, "16": 16, "1792": 1792 - STEPS, "spread 300-1100": (300, 1100)}),
+    "k-exaone full": (64, 8, 520, 8208, 3, 0, {"1280": 1280, "16": 16, "spread 64-4072": (64, 4072)}),
+    "k-exaone window": (64, 8, 520, 240, 10, 128, {"1280": 1280, "16": 16, "spread 64-4072": (64, 4072)}),
+    "olmo": (30, 30, 129, 2080, 8, 0, {"800": 800, "16": 16, "spread 64-2040": (64, 2040)}),
+}
 # glm-4.7-flash: heads, latent, rope stored / counted, table width, pool blocks, layers
 MHQ, C, R, ROPE, MLA_TBL, MLA_POOL, MLA_LAYERS = 20, 512, 128, 64, 200, 5104, 13
 MLA_SCALE = (192 + ROPE) ** -0.5
 
 
-def allocation_order(contexts: np.ndarray, n_tbl: int, n_pool: int) -> np.ndarray:
+def allocation_order(contexts: np.ndarray, n_tbl: int, n_pool: int, starts=None) -> np.ndarray:
     """[lanes, n_tbl] tables as one free list hands blocks out to lanes that
     grow in turn (a lane takes its next block as it crosses a block's end),
-    each lane started 7 blocks after the one before; the slots past a
-    lane's context name the pool's last block, which no lane holds."""
+    each lane started 7 blocks after the one before; the slots outside a
+    lane's band [starts, contexts) name the pool's last block, which no
+    lane holds (a window's reclaimed blocks)."""
     lanes = len(contexts)
     need = -(-np.asarray(contexts) // BLOCK)
-    order = sorted((j + 7 * i, i, j) for i in range(lanes) for j in range(need[i]))
+    first = np.zeros(lanes, int) if starts is None else np.asarray(starts) // BLOCK
+    order = sorted((j + 7 * i, i, j) for i in range(lanes) for j in range(first[i], need[i]))
     assert len(order) < n_pool
     tables = np.full((lanes, n_tbl), n_pool - 1, np.int32)
     for block, (_, i, j) in enumerate(order):
@@ -96,58 +114,91 @@ def max_abs_diff(attend, args, layers: int) -> float:
     return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))))
 
 
+@contextlib.contextmanager
+def without_dots():
+    """jax.lax.dot_general, as a kernel's body looks it up while it is
+    traced, replaced by a stand-in of the dot's shape: the first column of
+    the (2-D, rows-first) left operand broadcast along the lanes. What only
+    fed a dot's right operand (the page rows, widened and masked) goes with
+    it; the copies, the waits and the softmax's own work stay. The jit caches
+    are cleared on both sides, so no program traced one way is run the other."""
+    real = jax.lax.dot_general
+
+    def stand_in(a, b, dimension_numbers, **_):
+        (_, cb), _ = dimension_numbers
+        cols = [n for i, n in enumerate(b.shape) if i not in cb]
+        return jnp.broadcast_to(a[:, :1].astype(jnp.float32), (a.shape[0], *cols))
+
+    jax.clear_caches()
+    jax.lax.dot_general = stand_in
+    try:
+        yield
+    finally:
+        jax.lax.dot_general = real
+        jax.clear_caches()
+
+
 def _rand(key, shape):
     return jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
 
 
-# -- GQA (qwen2) ------------------------------------------------------------------
+# -- GQA (qwen2, k-exaone, olmo) ----------------------------------------------------
 
 
-def gqa_inputs(lanes: int):
+def gqa_inputs(hq, hkv, n_pool, layers, lanes=16):
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     return dict(
-        qs=_rand(ks[0], (GQA_LAYERS * REPS, lanes, 1, HQ, D)),
-        k_pool=_rand(ks[1], (GQA_LAYERS, GQA_POOL, BLOCK, HKV * D)),
-        v_pool=_rand(ks[2], (GQA_LAYERS, GQA_POOL, BLOCK, HKV * D)),
-        k_buf=_rand(ks[3], (lanes, STEPS, HKV, D)), v_buf=_rand(ks[4], (lanes, STEPS, HKV, D)),
+        qs=_rand(ks[0], (layers * REPS, lanes, 1, hq, D)),
+        k_pool=_rand(ks[1], (layers, n_pool, BLOCK, hkv * D)),
+        v_pool=_rand(ks[2], (layers, n_pool, BLOCK, hkv * D)),
+        k_buf=_rand(ks[3], (lanes, STEPS, hkv, D)), v_buf=_rand(ks[4], (lanes, STEPS, hkv, D)),
     )
 
 
-def gqa_args(x, ctx):
-    tables = jnp.asarray(allocation_order(ctx, GQA_TBL, GQA_POOL))
+def band_start(ctx, window: int):
+    """The band's first position, as paged_chunk_decode_attention sets it at
+    the step the calls take (3)."""
+    return np.maximum(np.asarray(ctx) + 3 - window + 1, 0) if window else np.zeros_like(ctx)
+
+
+def gqa_args(x, ctx, n_tbl, n_pool, window):
+    tables = jnp.asarray(allocation_order(ctx, n_tbl, n_pool, band_start(ctx, window)))
     return (x["qs"], x["k_pool"], x["v_pool"], tables, x["k_buf"], x["v_buf"],
             jnp.asarray(ctx, jnp.int32), jnp.asarray(3, jnp.int32))
 
 
-def gqa_attend(use_kernel):
+def gqa_attend(window):
+    def attend(use_kernel):
+        def fn(layer, q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step):
+            return A.paged_chunk_decode_attention(
+                q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step,
+                layer=layer, window=window, use_kernel=use_kernel,
+            )
+        return fn
+    return attend
+
+
+def gqa_partials(window):
     def fn(layer, q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step):
-        return A.paged_chunk_decode_attention(
-            q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step, layer=layer, use_kernel=use_kernel
-        )
+        lo = jnp.maximum(lengths + step - window + 1, 0) if window else jnp.zeros_like(lengths)
+        return A._paged_decode_partials(q[:, 0], k_pool, v_pool, tables, lo, lengths, layer, scale=D ** -0.5)
     return fn
 
 
-def gqa_partials(layer, q, k_pool, v_pool, tables, k_buf, v_buf, lengths, step):
-    return A._paged_decode_partials(
-        q[:, 0], k_pool, v_pool, tables, jnp.zeros_like(lengths), lengths, layer, scale=D ** -0.5
-    )
-
-
 def gqa_rows(rng, peak: float):
-    for lanes in (16, 48):
-        x = gqa_inputs(lanes)
-        cases = {
-            "16": np.full(lanes, 16), "700": np.full(lanes, 700), "1792": np.full(lanes, 1792 - STEPS),
-            "spread 300-1100": rng.integers(300, 1100, lanes),
-        }
-        for name, ctx in cases.items():
-            args = gqa_args(x, ctx)
-            row = timed("gqa", lanes, name, int(ctx.sum()) * HKV * D * 2 * 2, peak, GQA_LAYERS,
-                        gqa_partials, gqa_attend, args)
-            if name in ("700", "spread 300-1100"):
-                g_min, g_med = per_call_us(gqa_attend(False), args, GQA_LAYERS)
+    for shape, (hq, hkv, n_tbl, n_pool, layers, window, contexts) in GQA_SHAPES.items():
+        x = gqa_inputs(hq, hkv, n_pool, layers)
+        for i, (name, ctx) in enumerate(contexts.items()):
+            ctx = rng.integers(*ctx, 16) if isinstance(ctx, tuple) else np.full(16, ctx)
+            args = gqa_args(x, ctx, n_tbl, n_pool, window)
+            band = ctx - band_start(ctx, window)
+            row = timed(f"gqa {shape}", 16, name, int(band.sum()) * hkv * D * 2 * 2, peak, layers,
+                        gqa_partials(window), gqa_attend(window), args)
+            if i == 0:  # the cell's mean context
+                g_min, g_med = per_call_us(gqa_attend(window)(False), args, layers)
                 row["gather_us_min"], row["gather_us_median"] = round(g_min, 2), round(g_med, 2)
             yield row
+        del x
 
 
 # -- latent (glm) -------------------------------------------------------------------
@@ -207,10 +258,13 @@ def latent_rows(rng, peak: float):
 
 def timed(kind, lanes, contexts, kv_bytes, peak, layers, partials, attend, args) -> dict:
     kern_min, kern_med = per_call_us(partials, args, layers)
+    with without_dots():
+        bare_min, bare_med = per_call_us(partials, args, layers)
     att_min, att_med = per_call_us(attend(True), args, layers)
     return {
         "checkout": CHECKOUT, "device": jax.devices()[0].device_kind, "kind": kind, "lanes": lanes,
         "contexts": contexts, "kernel_us_min": round(kern_min, 2), "kernel_us_median": round(kern_med, 2),
+        "nodots_us_min": round(bare_min, 2), "nodots_us_median": round(bare_med, 2),
         "attend_us_min": round(att_min, 2), "attend_us_median": round(att_med, 2),
         "kv_mb": round(kv_bytes / 1e6, 3),
         "kernel_roofline_pct": round(100 * kv_bytes / peak / (kern_min * 1e-6), 2),

@@ -389,19 +389,110 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     assert tile == {"pages": 32, "tokens": 512, "in_flight": 2, "walk": "across lanes"}
 
 
-# block, kv heads, head_dim, table slots: the GQA kernel's cells keep their tile
-@pytest.mark.parametrize("block,hkv,d,n_tbl", [
-    (16, 4, 128, 120),  # qwen2-7b.reason-closed
-    (16, 8, 128, 520),  # k-exaone-236b-a23b.mixed-closed, both kinds of layer
-    (16, 30, 128, 129),  # olmo-hybrid-7b.chat-closed's full layers
-])
-def test_the_gqa_kernels_tile_is_its_own(block, hkv, d, n_tbl):
-    """paged_decode_pages, which sizes the GQA kernel for every cell but
-    glm's, gives the 8 pages of 128 tokens it always gave: the latent
-    kernel's schedule is a function of its own."""
-    from gofr_tpu.ops.attention import paged_decode_pages
+# block, kv heads, head_dim, table slots: the GQA kernel's cells, and the
+# shapes at its limits, each sized by bytes (bf16 and int8 pools)
+GQA_SCHEDULES = {
+    (16, 4, 128, 120): ((32, 512, 3), (32, 512, 7)),  # qwen2-7b.reason-closed: 1 MB a group
+    (16, 8, 128, 520): ((16, 256, 3), (32, 512, 3)),  # k-exaone-236b-a23b, both kinds of layer
+    (16, 30, 128, 129): ((8, 128, 2), (8, 128, 3)),  # olmo-hybrid-7b's full layers: 1.9 MB
+    (16, 1, 128, 120): ((32, 512, 8), (32, 512, 8)),  # MQA: 512 tokens, 8 in flight at most
+    (16, 4, 128, 5): ((5, 80, 8), (5, 80, 8)),  # no group past the table
+}
 
-    assert paged_decode_pages(block, hkv, d, jnp.bfloat16, n_tbl) == 8
+
+@pytest.mark.parametrize("block,hkv,d,n_tbl", list(GQA_SCHEDULES))
+def test_the_gqa_kernels_tile_is_its_own(block, hkv, d, n_tbl):
+    """paged_decode_schedule, which sizes the GQA kernel for every cell but
+    glm's: about 1 MB of K and V a group (128 to 512 tokens) and 4 MB of
+    buffers beside the one read (2 to 8 groups in flight), at the pool's
+    dtype; under a TP mesh the local heads (test_paged_kv). The latent
+    kernel's schedule is a function of its own."""
+    from gofr_tpu.ops.attention import paged_decode_schedule
+
+    got = tuple(paged_decode_schedule(block, hkv, d, dt, n_tbl) for dt in (jnp.bfloat16, jnp.int8))
+    assert got == GQA_SCHEDULES[block, hkv, d, n_tbl]
+    assert got[0].report()["walk"] == "across lanes"
+
+
+def _gqa_engine(path):
+    """A CPU engine of each kind of stack the GQA kernel serves, its heads
+    of 128 so that a TPU would take the kernel: plain (qwen2's GQA 4 / 2),
+    mixed (window and full layers) and linear (gated delta-rule layers
+    beside full ones)."""
+    import dataclasses
+
+    from gofr_tpu.llm import LLMEngine
+    from gofr_tpu.models import TransformerConfig, init_params
+
+    base = {
+        "plain": TransformerConfig.tiny_qwen2, "mixed": TransformerConfig.tiny_mixed_moe,
+        "linear": TransformerConfig.tiny_olmo_hybrid,
+    }[path]()
+    cfg = dataclasses.replace(base, head_dim=128, dtype=jnp.bfloat16)
+    kw = dict(kv_paged=True, kv_block=16) if path == "plain" else {}
+    return LLMEngine(cfg, init_params(jax.random.PRNGKey(0), cfg), slots=2, max_seq_len=240, warmup=False, **kw)
+
+
+def _page_buffers(jaxpr):
+    """(page buffers' shapes, dimension semantics) of the paged-decode
+    kernels a traced program calls."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            vmem = [tuple(v.aval.shape) for v in eqn.params["jaxpr"].invars if "vmem" in str(v.aval)]
+            yield vmem, eqn.params["compiler_params"]["mosaic_tpu"].dimension_semantics
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _page_buffers(sub)
+
+
+@pytest.mark.parametrize("path", ["plain", "mixed", "linear"])
+def test_the_gqa_kernel_runs_the_schedule_its_engine_reports(v5e_chip, monkeypatch, path):
+    """The GQA kernel at an engine's own pool, table and heads, through
+    Mosaic for a v5e: each pool (a mixed stack's two kinds, each under its
+    kernel's name) is the call's own operand and nothing else in the
+    program is as large, and the page buffers the kernel walks with are
+    the schedule stats()["attention"]["decode_tile"] reports (asked as a
+    TPU would answer: the CPU takes no kernel)."""
+    from gofr_tpu.ops.attention import paged_chunk_decode_attention
+
+    eng = _gqa_engine(path)
+    try:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        paths = eng._attention_paths()
+        shapes, cfg, n_tbl = eng.kv.pool_shapes()[0], eng.cfg, eng.kv.table_width
+    finally:
+        eng.close()
+    assert paths["decode"].startswith("pallas_paged")
+    tile = paths["decode_tile"]
+    assert tile["walk"] == "across lanes"
+    kinds = [("paged_decode", shapes, 0)] if path != "mixed" else [
+        ("paged_decode", shapes[0], 0), ("paged_decode_window", shapes[1], cfg.window),
+    ]
+    lanes, hq, hkv, d = 2, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    for name, shape, window in kinds:
+        buf = S((lanes, 8, hkv, d), jnp.bfloat16)
+        args = (
+            S((lanes, 1, hq, d), jnp.bfloat16), S(shape, jnp.bfloat16), S(shape, jnp.bfloat16),
+            S((lanes, n_tbl), jnp.int32), buf, buf, S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+        )
+        fn = jax.jit(lambda q, kp, vp, t, kb, vb, n, s, ly: paged_chunk_decode_attention(
+            q, kp, vp, t, kb, vb, n, s, layer=ly, window=window, use_kernel=True, name=name,
+        ))
+        [(buffers, semantics)] = list(_page_buffers(fn.trace(*args).jaxpr))
+        k_buf, v_buf = buffers
+        assert k_buf == v_buf and k_buf[2:] == shape[2:] and semantics == ("arbitrary",)
+        assert {"pages": k_buf[1], "tokens": k_buf[1] * shape[2], "in_flight": k_buf[0] - 1} == {
+            k: tile[k] for k in ("pages", "tokens", "in_flight")
+        }
+        with _persistent_cache_off():
+            text = fn.lower(*args).compile().as_text()
+        call = next(ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln)
+        assert f"%{name}" in call
+        pool_sized = [(op, sh) for op, sh in _hlo_results(text) if math.prod(sh) >= math.prod(shape[1:])]
+        assert sorted(pool_sized) == [("parameter", shape)] * 2
 
 
 def _hlo_results(text: str, dtype: str = "[a-z]+[0-9]*"):

@@ -553,11 +553,12 @@ class TestPagedAttentionKernel:
     interpret mode runs the real kernel logic on CPU."""
 
     # (hq, hkv, table slots, contexts). At 8-token blocks of head_dim 16 a
-    # step of the kernel holds 16 pages, 128 tokens, or the whole table.
+    # page group of the kernel holds 64 pages, 512 tokens, so here the whole
+    # table: the lanes' groups follow one another in the one stream of
+    # copies (GQA_BANDS below walks several groups a lane).
     SHAPES = {
         "one-group": (4, 2, 6, [13, 0, 37]),
-        # none, one row, mid-page, a page-group boundary, the whole table;
-        # 20 slots are one group of 16 pages and a rest of 4
+        # none, one row, mid-page, a part boundary, the whole table
         "edges": (4, 2, 20, [0, 1, 13, 128, 160]),
         "group7": (28, 4, 20, [5, 129, 77]),
         "mqa": (4, 1, 20, [160, 0, 64]),
@@ -720,12 +721,13 @@ class TestPagedAttentionKernel:
             np.asarray(kern), np.asarray(ref), atol=2e-6
         )
 
-    # (block, table slots): at 4 or 8 bf16 heads of 128 a step holds 128
-    # tokens, so 8, 2 and 1 pages. 112 is the width max_seq_len 1,792 gave
-    # a 16-token table before a slot held the engine's merge slack, 113
-    # what a request can fill since, 120 the width it is built with; the
-    # widths at 64 and 128 are the same rows. 113 and 29 leave the last
-    # page group partial.
+    # (block, table slots): a page group holds 512 tokens of 4 bf16 heads of
+    # 128 or of int8 heads, 256 of 8 bf16 heads (paged_decode_schedule), so
+    # 32 / 16, 8 / 4 and 4 / 2 pages. 112 is the width max_seq_len 1,792
+    # gave a 16-token table before a slot held the engine's merge slack,
+    # 113 what a request can fill since, 120 the width it is built with;
+    # the widths at 64 and 128 are the same rows. 113, 29 and 15 leave the
+    # last page group partial.
     @pytest.mark.parametrize("window", [0, 300])
     @pytest.mark.parametrize("pool", ["bf16", "int8"])
     @pytest.mark.parametrize("hkv", [4, 8])
@@ -738,7 +740,7 @@ class TestPagedAttentionKernel:
         block sizes, head counts and table widths engines serve with:
         contexts of none, one row, mid-page, mid-table across page groups,
         one short of the table and the whole table."""
-        from gofr_tpu.ops.attention import paged_chunk_decode_attention, paged_decode_pages
+        from gofr_tpu.ops.attention import paged_chunk_decode_attention, paged_decode_schedule
 
         rows = n_tbl * block
         lengths = [0, 1, block + block // 2 + 1, rows // 2 + 3, rows - 1, rows]
@@ -753,8 +755,8 @@ class TestPagedAttentionKernel:
             pk, pv = pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16)
         pk, pv = stored_rows(pk), stored_rows(pv)
         layer = n_tbl % self.LAYERS
-        assert paged_decode_pages(block, hkv, 128, pk.dtype, n_tbl) * block == (
-            256 if pool == "int8" and hkv == 4 else 128
+        assert paged_decode_schedule(block, hkv, 128, pk.dtype, n_tbl).tokens == (
+            256 if pool == "bf16" and hkv == 8 else 512
         )
         step = jnp.asarray(3, jnp.int32)
         # the reference reads the bf16 pool's own values widened to f32 (it
@@ -771,33 +773,41 @@ class TestPagedAttentionKernel:
         np.testing.assert_allclose(np.asarray(kern), np.asarray(ref), atol=5e-6)
 
     def test_tile_follows_the_pool_shape(self):
-        """paged_decode_pages: a step's pages come from block size, local
-        kv heads, head_dim and the pool's dtype alone."""
-        from gofr_tpu.ops.attention import paged_decode_pages
+        """paged_decode_schedule: a group's pages and the groups in flight
+        come from block size, local kv heads, head_dim and the pool's dtype
+        alone: about 1 MB of K and V a group (128 to 512 tokens), 4 MB of
+        buffers beside the one read (2 to 8 groups in flight)."""
+        from gofr_tpu.ops.attention import paged_decode_schedule as sched
 
         bf16, i8 = jnp.bfloat16, jnp.int8
-        assert paged_decode_pages(16, 4, 128, bf16, 112) == 8  # qwen2: 128 tokens
-        assert paged_decode_pages(16, 4, 128, i8, 112) == 16  # half the bytes a row
-        assert paged_decode_pages(64, 4, 128, bf16, 28) == 2
-        assert paged_decode_pages(128, 4, 128, bf16, 14) == 1
-        assert paged_decode_pages(16, 1, 128, bf16, 112) == 32  # MQA: 512 tokens at most
-        assert paged_decode_pages(16, 8, 256, bf16, 64) == 8  # never under 128 tokens
-        assert paged_decode_pages(16, 4, 128, bf16, 5) == 5  # nor over the table
+        assert sched(16, 4, 128, bf16, 112) == (32, 512, 3)  # qwen2: 1 MB a group
+        assert sched(16, 4, 128, i8, 112) == (32, 512, 7)  # half the bytes, 512 tokens at most
+        assert sched(64, 4, 128, bf16, 28) == (8, 512, 3)
+        assert sched(128, 4, 128, bf16, 14) == (4, 512, 3)
+        assert sched(16, 1, 128, bf16, 112) == (32, 512, 8)  # MQA: 8 in flight at most
+        assert sched(16, 8, 128, bf16, 520) == (16, 256, 3)  # k-exaone
+        assert sched(16, 30, 128, bf16, 129) == (8, 128, 2)  # olmo: 128 tokens, 2 in flight
+        assert sched(16, 8, 256, bf16, 64) == (8, 128, 3)  # never under 128 tokens
+        assert sched(16, 4, 128, bf16, 5) == (5, 80, 8)  # nor over the table
         # under a TP mesh the kernel runs per head shard: the LOCAL kv heads
         from gofr_tpu.parallel import make_mesh
 
         tp4 = make_mesh({"data": 1, "model": 4}, devices=jax.devices()[:4])
-        assert paged_decode_pages(16, 4, 128, bf16, 120, hq=28, mesh=tp4) == 32  # 1 head a shard
-        assert paged_decode_pages(16, 8, 128, bf16, 120, hq=32, mesh=tp4) == 16  # 2 heads a shard
-        assert paged_decode_pages(16, 2, 128, bf16, 120, hq=32, mesh=tp4) == 16  # replicated: both
+        assert sched(16, 4, 128, bf16, 120, hq=28, mesh=tp4) == (32, 512, 8)  # 1 head a shard
+        assert sched(16, 8, 128, bf16, 120, hq=32, mesh=tp4) == (32, 512, 7)  # 2 heads a shard
+        assert sched(16, 2, 128, bf16, 120, hq=32, mesh=tp4) == (32, 512, 7)  # replicated: both
 
-    @pytest.mark.parametrize("int8,tile", [(False, {"pages": 8, "tokens": 128}), (True, {"pages": 16, "tokens": 256})])
+    @pytest.mark.parametrize("int8,tile", [
+        (False, {"pages": 16, "tokens": 256, "in_flight": 7, "walk": "across lanes"}),
+        (True, {"pages": 16, "tokens": 256, "in_flight": 8, "walk": "across lanes"}),
+    ])
     def test_engine_reports_the_tile(self, monkeypatch, int8, tile):
-        """stats()["attention"] names the kernel's tile beside `decode:
+        """stats()["attention"] names the kernel's schedule beside `decode:
         pallas_paged` (the string the benchmark compares), from the
         engine's own pool: here 16-token blocks of 2 f32 / int8 heads of
-        128 (the int8 tile is held to the table's 16 slots). The CPU has no such kernel, so the
-        REPORT is asked as a TPU would answer; nothing is traced."""
+        128 (a group held to the table's 16 slots). The CPU has no such
+        kernel, so the REPORT is asked as a TPU would answer; nothing is
+        traced."""
         cfg = TransformerConfig(
             vocab_size=64, d_model=256, n_layers=1, n_heads=2, n_kv_heads=2,
             head_dim=128, d_ff=64, dtype=jnp.float32,
@@ -953,3 +963,166 @@ def test_the_pool_reaches_its_decode_kernel_as_it_is_stored(preset):
         assert pools == [(k_shape, "pool.k"), (v_shape, "pool.v")], operands
     layer_pool = min(int(np.prod(s[1:])) for s in (k_shape, v_shape))
     assert 0 < found["largest"][0] < layer_pool, found["largest"]
+
+
+# -- the GQA kernel's walk over several page groups a lane ------------------------------
+
+
+# Each lane's band [lo, hi) and the table's slots. The test's f32 rows of 2
+# kv heads of 128 make a page group 512 tokens (64 pages of 8), read at the
+# band's edge in parts of 256 rows, and keep 3 groups in flight
+# (paged_decode_schedule); the kernel walks every lane's groups as ONE
+# stream of copies, so what a band looks like next to its neighbours
+# matters.
+GQA_BANDS = {
+    "the first and last lanes empty, an empty lane between live ones": ([0] * 6, [0, 600, 0, 280, 1200, 0], 160),
+    "every lane empty": ([0] * 3, [0] * 3, 160),
+    "one lane alone": ([0], [400], 160),
+    "bands that start mid-group and end mid-page (a window)": ([5, 520, 0, 800], [400, 1200, 160, 1268], 160),
+    "a lane inside one whole group, an edge group at both ends": ([512, 300, 0], [1024, 1200, 512], 160),
+    "a table that leaves the last group partial": ([0, 0, 12], [1184, 68, 1160], 148),
+    "more groups than buffers in flight": ([0] * 5, [1280, 1000, 0, 1240, 520], 160),
+}
+GQA_WALK = dict(hq=4, hkv=2, d=128, block=8)
+
+
+def _gqa_walk_inputs(b, MB, pool, seed=0):
+    """q [b, hq, d] and the pools as stored, a stack of two layers
+    [2, NB, 8, hkv * d] read at the second (int8: with its scales),
+    tables over the pool in random order."""
+    hq, hkv, d, Bk = (GQA_WALK[k] for k in ("hq", "hkv", "d", "block"))
+    rng = np.random.default_rng(seed)
+    NB = b * MB
+    q = jnp.asarray(rng.standard_normal((b, hq, d)).astype(np.float32))
+    pk, pv = (jnp.asarray(rng.standard_normal((2, NB, Bk, hkv, d)).astype(np.float32)) for _ in range(2))
+    scales = {}
+    if pool == "int8":
+        (pk, sk), (pv, sv) = quantize_rows(pk), quantize_rows(pv)
+        scales = dict(k_scales=sk, v_scales=sv)
+    tables = rng.permutation(NB).reshape(b, MB).astype(np.int32)
+    return q, stored_rows(pk), stored_rows(pv), tables, scales
+
+
+def _gqa_band_partials(q, pk, pv, tables, lo, hi, layer, scale, scales):
+    """The kernel's partials over [lo, hi) from the gathered rows, by XLA:
+    (o normalized, m, l); an empty band gives l = 0 and no o or m."""
+    from gofr_tpu.ops.attention import paged_gather
+
+    b, hq, d = q.shape
+    hkv = GQA_WALK["hkv"]
+    kc, vc = paged_gather(pk, pv, jnp.asarray(tables), layer, ((hkv, d), (hkv, d)), dtype=jnp.float32, **scales)
+    s = jnp.einsum("bhgd,bnhd->bhgn", (q * scale).reshape(b, hkv, hq // hkv, d), kc, precision="highest")
+    pos = jnp.arange(kc.shape[1])
+    live = ((pos >= jnp.asarray(lo)[:, None]) & (pos < jnp.asarray(hi)[:, None]))[:, None, None]
+    s = jnp.where(live, s, -jnp.inf)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(live, jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.sum(p, axis=-1)
+    o = jnp.einsum("bhgn,bnhd->bhgd", p, vc, precision="highest") / jnp.where(l == 0, 1.0, l)[..., None]
+    return o.reshape(b, hq, d), m.reshape(b, hq), l.reshape(b, hq)
+
+
+def _gqa_simulated_partials(q, pk, pv, tables, lo, hi, layer, scale, scales, seconds=300):
+    """The kernel's partials in the TPU interpreter that simulates its DMAs
+    and semaphores (a copy lands when it is waited for; a read outside a
+    buffer raises; VMEM starts as NaN), on a thread of its own: the
+    simulator blocks for ever on a wait for a copy never started, which
+    fails here instead."""
+    import threading
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from gofr_tpu.ops.attention import _paged_decode_partials
+
+    out = []
+
+    def run():
+        try:
+            out.append(jax.block_until_ready(_paged_decode_partials(
+                q, pk, pv, jnp.asarray(tables), jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+                layer, scale=scale, interpret=pltpu.InterpretParams(), **scales,
+            )))
+        except Exception as e:  # noqa: BLE001 — raised again on the test's thread
+            out.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert out, f"the kernel's copy stream did not finish in {seconds} s: a wait without its copy"
+    if isinstance(out[0], Exception):
+        raise out[0]
+    return out[0]
+
+
+def _assert_gqa_partials_equal(got, want, lo, hi):
+    empty = np.asarray(hi) <= np.asarray(lo)
+    o, m, l = (np.asarray(x) for x in got)
+    wo, wm, wl = (np.asarray(x) for x in want)
+    assert (l[empty] == 0).all() and (o[empty] == 0).all()
+    live = ~empty
+    np.testing.assert_allclose(o[live], wo[live], atol=2e-5)
+    np.testing.assert_allclose(m[live], wm[live], rtol=1e-6, atol=1e-5)
+    np.testing.assert_allclose(l[live], wl[live], rtol=1e-5)
+
+
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+@pytest.mark.parametrize("case", list(GQA_BANDS))
+def test_the_gqa_walk_across_lanes_equals_the_gathered_band(case, pool):
+    """The GQA kernel against the gathered rows, band by band, over several
+    page groups a lane, with its copies simulated: whole groups read
+    without a mask, edge groups in parts, empty lanes passed over."""
+    from gofr_tpu.ops.attention import paged_decode_schedule
+
+    lo, hi, MB = GQA_BANDS[case]
+    q, pk, pv, tables, scales = _gqa_walk_inputs(len(hi), MB, pool)
+    sched = paged_decode_schedule(8, 2, 128, pk.dtype, MB)
+    assert (sched.tokens, sched.in_flight) == (512, 3 if pool == "f32" else 8)
+    groups = sum(-(-h // sched.tokens) - l_ // sched.tokens for l_, h in zip(lo, hi) if h > l_)
+    assert case != "more groups than buffers in flight" or groups > 3 + 1
+    got = _gqa_simulated_partials(q, pk, pv, tables, lo, hi, 1, 0.1, scales)
+    _assert_gqa_partials_equal(got, _gqa_band_partials(q, pk, pv, tables, lo, hi, 1, 0.1, scales), lo, hi)
+
+
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+def test_the_gqa_walk_never_reads_a_dead_page(pool):
+    """NaN in every pool block outside the lanes' bands and in the whole of
+    the other layer (an int8 pool: its scales), and every table entry
+    outside a band (before lo and past hi) an id outside the pool, over
+    bands that start mid-group and end mid-page: the partials are finite
+    and equal those over the clean pool and table."""
+    lo, hi, MB = GQA_BANDS["bands that start mid-group and end mid-page (a window)"]
+    q, pk, pv, tables, scales = _gqa_walk_inputs(len(hi), MB, pool, seed=4)
+    Bk = GQA_WALK["block"]
+    slot = np.arange(MB)[None, :]
+    live = (slot * Bk < np.asarray(hi)[:, None]) & ((slot + 1) * Bk > np.asarray(lo)[:, None])
+    NB = pk.shape[1]
+    dead = np.setdiff1d(np.arange(NB), tables[live])
+    poisoned = np.where(live, tables, np.random.default_rng(0).choice([-7, NB, NB + 1000], tables.shape))
+
+    def poison(a):
+        return jnp.full_like(a, jnp.nan).at[1].set(a[1].at[dead].set(jnp.nan))
+
+    if pool == "int8":
+        dirty = (pk, pv, {k: poison(v) for k, v in scales.items()})
+    else:
+        dirty = (poison(pk), poison(pv), scales)
+    got = _gqa_simulated_partials(q, dirty[0], dirty[1], poisoned, lo, hi, 1, 0.1, dirty[2])
+    assert all(np.isfinite(np.asarray(x)).all() for x in got)
+    _assert_gqa_partials_equal(got, _gqa_band_partials(q, pk, pv, tables, lo, hi, 1, 0.1, scales), lo, hi)
+
+
+@pytest.mark.parametrize("pool", ["f32", "int8"])
+def test_the_gqa_walk_over_the_stack_equals_it_over_the_slab(pool):
+    """_paged_decode_partials over the second layer of the stack == over
+    that layer's slab given alone, to the bit, over several page groups a
+    lane: the same pages reach the same arithmetic in the same order."""
+    from gofr_tpu.ops.attention import _paged_decode_partials
+
+    lo, hi, MB = GQA_BANDS["a lane inside one whole group, an edge group at both ends"]
+    q, pk, pv, tables, scales = _gqa_walk_inputs(len(hi), MB, pool, seed=2)
+    slab = {k: v[1:] for k, v in scales.items()}
+    args = (jnp.asarray(tables), jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32))
+    whole = _paged_decode_partials(q, pk, pv, *args, 1, scale=0.1, interpret=True, **scales)
+    alone = _paged_decode_partials(q, pk[1:], pv[1:], *args, 0, scale=0.1, interpret=True, **slab)
+    for w, a in zip(whole, alone):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(a))
