@@ -384,11 +384,21 @@ def row_shapes(cfg) -> tuple[tuple, tuple]:
     the engine owns is the kernel's HBM operand and the scatter's target
     with no layout in between. Who needs heads (a gathered view, rows about
     to be written, a block on its way out of the process) reshapes those
-    rows (paged.viewed_rows / stored_rows), never the pool."""
+    rows (paged.viewed_rows / stored_rows), never the pool.
+
+    A sparse latent layer (cfg.sparse) keeps a THIRD part, its indexer's key
+    of index_head_dim values, as the tail of `v`'s row: [k_rope | k_idx],
+    R + index_head_dim columns. It is written at the same (block, row) as
+    the latent by every write, and every path that moves a latent pool's
+    two arrays (the radix tree's shared blocks and copy-on-write tail
+    copies, spill and restore, hand-off, gather_blocks_host) moves it with
+    them: none needs a case of its own."""
     if getattr(cfg, "latent", False):
         from ..ops import latent_rope_width
 
-        return (1, cfg.kv_lora_rank), (1, latent_rope_width(cfg.qk_rope_head_dim))
+        rope = latent_rope_width(cfg.qk_rope_head_dim)
+        index = cfg.index_head_dim if getattr(cfg, "sparse", False) else 0
+        return (1, cfg.kv_lora_rank), (1, rope + index)
     return (cfg.n_kv_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)
 
 

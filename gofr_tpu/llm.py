@@ -371,6 +371,12 @@ def _register_phase_metrics(metrics) -> None:
                 "llm (token, expert) pairs of the routed experts "
                 "(kind=routed: chosen by the router | computed: by experts held here)",
             )
+        if not metrics.has("app_llm_dsa_rows_total"):
+            metrics.new_counter(
+                "app_llm_dsa_rows_total",
+                "llm latent rows a sparse (indexer-selected) decode attended, over "
+                "its tokens' layers (kind=selected: attended | context: in the context)",
+            )
         if not metrics.has("app_llm_step_tokens"):
             metrics.new_histogram(
                 "app_llm_step_tokens",
@@ -723,7 +729,9 @@ class GenRequest:
     def _requeued(self) -> None:
         """Preemption or failover re-queues the request: before its first
         token the timeline's rows start again with the admission that will
-        produce it; after it they stay that admission's."""
+        produce it; after it they stay that admission's. Whether the
+        prefix cache served its prompt is the next admission's to say."""
+        self.prefix_hit = False
         if self.first_token_at is None:
             self.seq_first_row = self.seq_last_row = self._first_cursor = None
             self.rows = self.row_steps = 0
@@ -1444,8 +1452,8 @@ class LLMEngine:
             chunk_shapes=(self.chunk_shapes if self.chunked else ()),
             spec_draft=self.spec_draft, mesh=self.mesh,
             tp_gather=self._tp_gather,
-            kernel=self.attention_paths["decode"].split(" | ")[0] in (
-                "pallas_paged", "pallas_mla_paged",
+            kernel=self.attention_paths["decode"].split(" | ")[0].split(" (")[0] in (
+                "pallas_paged", "pallas_mla_paged", "pallas_dsa_sparse",
             ),
             numeric_check=self.numeric_check, label=self.label,
             metrics=metrics,
@@ -1609,6 +1617,9 @@ class LLMEngine:
         self._moe_totals = np.zeros((2 + self._moe_held + share,), np.int64)
         self._moe_layer_calls = 0
         self._moe_layers = cfg.group_sizes[-1] if _E else 0  # the routed group is the last
+        # a sparse latent stack's decode: [rows attended, rows in context],
+        # summed over emitted tokens' layers (stats()["attention"]["sparse"])
+        self._dsa_rows = np.zeros((2,), np.int64)
         self.moe_path = self._moe_path() if _E else None
         # Two engine threads: the SCHEDULER owns every device dispatch
         # (admission prefills, inserts, decode chunks); the COLLECTOR owns
@@ -1661,6 +1672,57 @@ class LLMEngine:
             self._registry.note_degraded("moe_grouped_matmul", self.label, why)
         return f"ragged_dot ({why})"
 
+    def _sparse_attention_paths(self) -> dict:
+        """_attention_paths of a sparse latent stack (cfg.sparse): decode
+        scores every row's index key through the table (the dsa_index_decode
+        kernel), takes the exact top index_topk and attends the rows gathered
+        (mla_sparse_decode); a prompt chunk scores and masks over its
+        gathered view in XLA."""
+        from .ops.attention import (
+            dsa_kernel_why_not, latent_rope_width, mla_decode_schedule, paged_pool_operand,
+        )
+
+        cfg, kv = self.cfg, self.kv
+        (_, C), (_, W) = kv.row_shapes
+        R = latent_rope_width(cfg.qk_rope_head_dim)
+        topk = min(cfg.index_topk, kv.capacity + self.decode_chunk)
+        why = dsa_kernel_why_not(C, R, W - R, kv.block, topk)
+        paths = {
+            "decode": (
+                f"xla_gather_sparse ({why})" if why
+                else f"pallas_dsa_sparse (dsa_index_decode, top-{topk}, mla_sparse_decode)"
+            ),
+            "prefill": {c: self._sparse_prefill_path(c) for c in self.chunk_shapes},
+        }
+        if not why:
+            # the index kernel's walk over the rope array's pages
+            paths["decode_tile"] = mla_decode_schedule(kv.block, W, 0, cfg.dtype, kv.table_width).report()
+            paths["pool_operand"] = paged_pool_operand(kv.pool_shapes()[1], W)
+        return paths
+
+    def _sparse_prefill_path(self, chunk: int) -> str:
+        """A prompt chunk's sparse path: its index scores by dsa_index_prefill
+        (or XLA, and why), its chosen rows gathered and attended in XLA."""
+        from .ops.attention import dsa_prefill_why_not
+
+        cfg = self.cfg
+        why = dsa_prefill_why_not(cfg.index_head_dim, chunk, cfg.index_heads)
+        if why:
+            return f"xla_sparse_latent (top-{cfg.index_topk} chosen rows; {why})"
+        return f"dsa_index_prefill + xla_chosen_rows (top-{cfg.index_topk})"
+
+    def _sparse_summary(self) -> dict | None:
+        """stats()["attention"]["sparse"]: the rows the indexer's choice let
+        decode attend, against the rows in the context, summed over every
+        emitted decode token's layers (None off a sparse stack)."""
+        if not getattr(self.cfg, "sparse", False):
+            return None
+        return {
+            "index_topk": int(self.cfg.index_topk),
+            "rows_selected": int(self._dsa_rows[0]),
+            "rows_in_context": int(self._dsa_rows[1]),
+        }
+
     def _attention_paths(self) -> dict:
         """The attention implementation each program family traces —
         decode, and prefill per chunk shape — decided by the predicates
@@ -1682,6 +1744,8 @@ class LLMEngine:
         def flash_or(why: str) -> str:
             return f"xla ({why})" if why else "pallas_flash"
 
+        if getattr(self.cfg, "sparse", False):
+            return self._sparse_attention_paths()
         if getattr(self.cfg, "latent", False):
             # one latent row for every head: its own paged-decode kernel, and
             # absorbed XLA attention over the gathered rows for prompt chunks
@@ -2162,7 +2226,10 @@ class LLMEngine:
                 "kvcache": self.kv.stats(),
                 # which attention implementation decode / each prefill
                 # chunk shape traced (Pallas kernel, or XLA and why)
-                "attention": self.attention_paths,
+                "attention": (
+                    self.attention_paths if not getattr(self.cfg, "sparse", False)
+                    else {**self.attention_paths, "sparse": self._sparse_summary()}
+                ),
                 # recent-window phase latencies (seconds): exact p50/p99
                 # over the last ~512 observations per phase
                 "phases": {k: w.summary() for k, w in self._phases.items()},
@@ -4821,6 +4888,8 @@ class LLMEngine:
         counts the first tokens that came out of prompt rows."""
         info["decode_ctx"] = tuple(decode_ctx)
         info["emitted"] = first + sum(n for _ctx, n in info["decode_ctx"])
+        if getattr(self.cfg, "sparse", False) and info["decode_ctx"]:
+            self._note_dsa_rows(info["decode_ctx"])
         info["t_emitted"] = time.perf_counter()
         moe_dev = info.pop("moe_dev", None)
         if moe_dev is not None:
@@ -4838,6 +4907,21 @@ class LLMEngine:
                 info["k"] + (1 if info["kind"] == "step" else 0)
             )
         self._step_log.append(tuple(info[f] for f in STEP_FIELDS))
+
+    def _note_dsa_rows(self, decode_ctx) -> None:
+        """Add a program's decode tokens to the sparse stack's row counts:
+        the i-th token a lane emitted from context `ctx` attended
+        min(index_topk, ctx + i + 1) of its ctx + i + 1 rows, in every layer."""
+        topk, L = int(self.cfg.index_topk), int(self.cfg.n_layers)
+        sel = ctx_rows = 0
+        for ctx, n in decode_ctx:
+            rows = np.arange(ctx + 1, ctx + n + 1, dtype=np.int64)
+            sel += int(np.minimum(rows, topk).sum())
+            ctx_rows += int(rows.sum())
+        self._dsa_rows += (L * sel, L * ctx_rows)
+        if self.metrics is not None:
+            for kind, n in (("selected", L * sel), ("context", L * ctx_rows)):
+                self.metrics.increment_counter("app_llm_dsa_rows_total", float(n), model=self.label, kind=kind)
 
     # -- the request timeline -----------------------------------------------
     @staticmethod
@@ -5379,6 +5463,12 @@ class LLMEngine:
             shape = 0
             budget_left = 0
             keep: deque[GenRequest] = deque()
+            if any(r.prefix_hit for r in self._prefilling):
+                # a prompt the prefix cache served has only its unshared
+                # rows left: they go ahead of cold prompts' (FIFO within
+                # each), or a hit that arrives behind cold documents waits
+                # for every one of their rows (PERF.md section 6)
+                self._prefilling = deque(sorted(self._prefilling, key=lambda r: not r.prefix_hit))
             while self._prefilling:
                 r = self._prefilling.popleft()
                 if (

@@ -112,6 +112,19 @@ def _deq(w):
     return w
 
 
+def group_limited(choose: jnp.ndarray, groups: int, keep: int) -> jnp.ndarray:
+    """Group-limited choice (DeepSeek-V3's noaux_tc): the experts [T, E] in
+    `groups` equal groups, each scored by the sum of its two best `choose`
+    values; the experts of all but the `keep` best groups leave the choice
+    (their `choose` becomes -inf). A group of one expert scores by it alone."""
+    T, E = choose.shape
+    by_group = choose.reshape(T, groups, E // groups)
+    best = jax.lax.top_k(by_group, min(2, E // groups))[0].sum(axis=-1)  # [T, groups]
+    _, kept = jax.lax.top_k(best, keep)
+    eligible = jnp.zeros((T, groups), jnp.bool_).at[jnp.arange(T)[:, None], kept].set(True)
+    return jnp.where(eligible[:, :, None], by_group, -jnp.inf).reshape(T, E)
+
+
 def route(cfg, h: jnp.ndarray, lp: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The router of one layer over token rows h [T, d], in float32 whatever
     the activations' dtype: -> (experts [T, k] int32, weights [T, k] f32).
@@ -133,6 +146,9 @@ def route(cfg, h: jnp.ndarray, lp: dict) -> tuple[jnp.ndarray, jnp.ndarray]:
     choose = scores
     if "router_bias" in lp:
         choose = scores + lp["router_bias"].astype(jnp.float32)
+    groups = getattr(cfg, "moe_groups", 1)
+    if groups > 1:
+        choose = group_limited(choose, groups, cfg.moe_topk_groups)
     _, experts = jax.lax.top_k(choose, cfg.moe_top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if cfg.moe_norm_topk:

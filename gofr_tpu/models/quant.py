@@ -109,6 +109,8 @@ _QUANT_KEYS = (
     # a linear-attention layer's projections (models.hybrid; its conv, decay
     # and norm leaves stay float)
     "wk", "wv", "wz", "wb", "wa",
+    # a lightning indexer's projections (its LayerNorm stays float)
+    "wq_idx", "wk_idx", "w_idx",
 )
 
 

@@ -104,6 +104,26 @@ class TransformerConfig:
     # for the chips that hold the rest.
     moe_first_expert: int = 0
     moe_held_experts: int = 0
+    # Group-limited choice (DeepSeek-V3's noaux_tc): the routed experts in
+    # `moe_groups` equal groups, each scored by the sum of its two best
+    # choice scores; only the experts of the `moe_topk_groups` best groups
+    # may be chosen (models.moe.group_limited). One group is no limit.
+    moe_groups: int = 1
+    moe_topk_groups: int = 1
+    # Scaled rope: YaRN's (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale, mscale_all_dim), empty = none
+    # (ops.rope). A latent layer's score scale gains yarn_mscale(factor,
+    # mscale_all_dim) squared (_latent_scale).
+    rope_scaling: tuple = ()
+    # A lightning indexer in every latent layer (DeepSeek-V3.2's DSA;
+    # index_heads > 0): `index_heads` heads of `index_head_dim` score every
+    # causal row by sum_j w_j relu(q_j . k) against ONE index key a token,
+    # and each query attends only its top `index_topk` rows. The index key
+    # rides the latent pool's rope array (kvcache.row_shapes); its first
+    # qk_rope_head_dim columns, and the indexer queries', are rotated.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # Linear-attention layers beside full ones (a gated DeltaNet; models.
     # hybrid): `layer_mixers` names every layer's mixer, "linear" or "full"
     # (empty: every layer attends, the case of every other stack), read in
@@ -162,6 +182,31 @@ class TransformerConfig:
                 )
             if not (self.linear_heads and self.linear_key_dim and self.linear_value_dim) or self.linear_conv < 1:
                 raise ValueError("a linear layer needs its heads, key and value widths and conv taps")
+        if self.index_heads:
+            if not self.latent or not self.q_lora_rank:
+                raise ValueError(
+                    "a lightning indexer (index_heads) serves latent attention with a "
+                    "query bottleneck (kv_lora_rank, q_lora_rank)"
+                )
+            if self.index_head_dim <= self.qk_rope_head_dim or self.index_topk < 1:
+                raise ValueError(
+                    f"an indexer needs heads wider than the rope part ({self.index_head_dim} <= "
+                    f"{self.qk_rope_head_dim}) and a top-k of at least 1 ({self.index_topk})"
+                )
+        if self.moe_groups > 1 and not (
+            self.n_experts % self.moe_groups == 0
+            and 1 <= self.moe_topk_groups <= self.moe_groups
+            and self.n_experts // self.moe_groups * self.moe_topk_groups >= self.moe_top_k
+        ):
+            raise ValueError(
+                f"{self.n_experts} experts in {self.moe_groups} groups, {self.moe_topk_groups} of them "
+                f"eligible, cannot give a top-{self.moe_top_k} choice"
+            )
+        if self.rope_scaling and (len(self.rope_scaling) != 6 or self.rope_scaling[0] <= 0):
+            raise ValueError(
+                "rope_scaling is YaRN's (factor, original_max_position_embeddings, beta_fast, "
+                f"beta_slow, mscale, mscale_all_dim); got {self.rope_scaling!r}"
+            )
         if self.moe_held_experts and not (
             0 <= self.moe_first_expert
             and self.moe_first_expert + self.moe_held_experts <= self.n_experts
@@ -174,6 +219,11 @@ class TransformerConfig:
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def sparse(self) -> bool:
+        """Latent layers whose queries attend the rows an indexer picks."""
+        return self.index_heads > 0
 
     @property
     def windows(self) -> tuple:
@@ -308,6 +358,25 @@ class TransformerConfig:
         )
 
     @staticmethod
+    def tiny_dsa_moe(vocab_size: int = 512) -> "TransformerConfig":
+        """CI-sized twin of DeepSeek-V3.2: tiny_latent_moe's block with a
+        lightning indexer (4 heads of 16, top-8: contexts past 8 rows
+        select), 8 sigmoid-routed experts in 2 groups of which the best one
+        is eligible, this program holding the first 4, and YaRN rope."""
+        return TransformerConfig(
+            vocab_size=vocab_size, d_model=64, n_layers=3, n_heads=4,
+            n_kv_heads=1, head_dim=24, d_ff=128, rope_theta=10_000.0,
+            norm_eps=1e-6, act="silu", scale_embed=False, dtype=jnp.float32,
+            n_experts=8, moe_top_k=2, moe_score="sigmoid", moe_norm_topk=True,
+            moe_scale=2.5, n_shared_experts=1, moe_d_ff=32,
+            n_dense_layers=1, kv_lora_rank=32, q_lora_rank=48,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=24,
+            moe_groups=2, moe_topk_groups=1, moe_first_expert=0, moe_held_experts=4,
+            rope_scaling=(40.0, 64, 32.0, 1.0, 1.0, 1.0),
+            index_heads=4, index_head_dim=16, index_topk=8,
+        )
+
+    @staticmethod
     def tiny_mixed_moe(vocab_size: int = 512) -> "TransformerConfig":
         """CI-sized mixed stack: three window layers (8 keys, RoPE) then a
         full one (no positional encoding), q/k norm; one dense layer, then
@@ -418,6 +487,14 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             "wkv_b": w(ka[3], (L, C, hq * (dn + dv)), C),
             "wo": w(keys[3], (L, hq * dv, d), hq * dv),
         }
+        if cfg.sparse:  # the lightning indexer's leaves
+            ih, di = cfg.index_heads, cfg.index_head_dim
+            ki = jax.random.split(jax.random.fold_in(keys[1], 7), 3)
+            attn.update(
+                wq_idx=w(ki[0], (L, ql, ih * di), ql), wk_idx=w(ki[1], (L, d, di), d),
+                k_idx_norm=jnp.ones((L, di), cfg.dtype), k_idx_bias=jnp.zeros((L, di), cfg.dtype),
+                w_idx=w(ki[2], (L, d, ih), d),
+            )
     else:
         qk = (
             {"q_norm": jnp.zeros((L, hd), cfg.dtype), "k_norm": jnp.zeros((L, hd), cfg.dtype)}
@@ -753,8 +830,10 @@ def _attn_block(cfg, x, lp, positions, mm, aids, attend):
     [q_nope W_uk^T | RoPE(q_rope)] per head, zero-padded to the pool's rope
     width, k_new the normalized latent c_kv [b, s, 1, C], v_new the shared
     rotated rope key [b, s, 1, R]; ``attend`` returns o_lat [b, s, hq, C]
-    and W_uv is applied here. LoRA on the latent projections is refused at
-    engine build."""
+    and W_uv is applied here. A sparse latent layer (cfg.sparse) hands
+    ``attend`` its queries as ops.SparseQuery (the latent query and the
+    indexer's) and a v_new of [rope key | index key] (_indexer). LoRA on the
+    latent projections is refused at engine build."""
     b, s, _ = x.shape
     hq = cfg.n_heads
     if cfg.latent:
@@ -768,8 +847,8 @@ def _attn_block(cfg, x, lp, positions, mm, aids, attend):
             q = mm(cq, lp["wq_b"]).reshape(b, s, hq, dn + dr)
             kv = mm(h, lp["wkv_a"])  # [b, s, C + dr]
             c_kv = rms_norm(kv[..., :C], lp["kv_norm"], cfg.norm_eps)[:, :, None, :]
-            k_rope = apply_rope(kv[:, :, None, C:], positions, cfg.rope_theta)
-            q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+            k_rope = apply_rope(kv[:, :, None, C:], positions, cfg.rope_theta, cfg.rope_scaling)
+            q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta, cfg.rope_scaling)
             w_uk, s_uk, w_uv, s_uv = _latent_up(cfg, lp["wkv_b"])
             q_nope = q[..., :dn] if s_uk is None else q[..., :dn] * s_uk.astype(q.dtype)
             q_abs = jnp.einsum(
@@ -779,6 +858,9 @@ def _attn_block(cfg, x, lp, positions, mm, aids, attend):
             widen = ((0, 0), (0, 0), (0, 0), (0, pad))
             q = jnp.concatenate([q_abs, jnp.pad(q_rope, widen)], axis=-1)
             k_rope = jnp.pad(k_rope, widen)
+        if cfg.sparse:
+            q, k_idx = _indexer(cfg, h, cq, q, positions, lp, mm)
+            k_rope = jnp.concatenate([k_rope, k_idx.astype(k_rope.dtype)], axis=-1)
         o_lat, carry = attend(q, c_kv, k_rope)
         with jax.named_scope("layer/attn_latent"):
             o = jnp.einsum(
@@ -825,8 +907,54 @@ def _attn_block(cfg, x, lp, positions, mm, aids, attend):
     return x, carry
 
 
+def _indexer(cfg, h, cq, q, positions, lp, mm):
+    """A sparse latent layer's lightning indexer: (the layer's queries as
+    ops.SparseQuery, the tokens' index keys [b, s, 1, di]). Keys are a
+    LayerNorm (weight and bias) of h's projection, queries the query
+    bottleneck's; both rotate their first qk_rope_head_dim columns. The head
+    weights carry 1 / sqrt(heads * di)."""
+    from ..ops import SparseQuery
+
+    b, s, _ = h.shape
+    ih, di, dr = cfg.index_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+
+    def rotate(x):
+        r = apply_rope(x[..., :dr], positions, cfg.rope_theta, cfg.rope_scaling)
+        return jnp.concatenate([r, x[..., dr:]], axis=-1)
+
+    with jax.named_scope("layer/dsa_index"):
+        q_idx = rotate(mm(cq, lp["wq_idx"]).reshape(b, s, ih, di))
+        k = mm(h, lp["wk_idx"]).astype(jnp.float32)
+        mu = jnp.mean(k, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
+        k = (k - mu) * jax.lax.rsqrt(var + 1e-6) * lp["k_idx_norm"].astype(jnp.float32) + lp[
+            "k_idx_bias"
+        ].astype(jnp.float32)
+        k_idx = rotate(k.astype(q.dtype)[:, :, None, :])
+        w = mm(h, lp["w_idx"]).astype(jnp.float32) * (ih * di) ** -0.5
+    return SparseQuery(q, q_idx, w), k_idx
+
+
 def _latent_scale(cfg) -> float:
-    return 1.0 / (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+    scale = 1.0 / (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+    if cfg.rope_scaling and cfg.rope_scaling[5]:
+        from ..ops import yarn_mscale
+
+        scale *= yarn_mscale(cfg.rope_scaling[0], cfg.rope_scaling[5]) ** 2
+    return scale
+
+
+def _latent_chunk(cfg, q, c_cache, r_cache, cursors):
+    """A latent layer's prompt-chunk attention over the rows its view holds:
+    every causal row, or (cfg.sparse) each query's top index_topk of them."""
+    from ..ops import dsa_chunk_prefill_attention, latent_chunk_prefill_attention, latent_rope_width
+
+    if cfg.sparse:
+        return dsa_chunk_prefill_attention(
+            q, c_cache, r_cache, cursors, scale=_latent_scale(cfg), topk=cfg.index_topk,
+            rope=latent_rope_width(cfg.qk_rope_head_dim),
+        )
+    return latent_chunk_prefill_attention(q, c_cache, r_cache, cursors, scale=_latent_scale(cfg))
 
 
 def _layer_body(
@@ -855,12 +983,8 @@ def _layer_body(
                     "(decode_chunk_paged / prefill_append); this forward pass "
                     "is its whole-sequence form only"
                 )
-            from ..ops import latent_chunk_prefill_attention
-
             zero = jnp.zeros((x.shape[0],), jnp.int32)
-            attn = latent_chunk_prefill_attention(
-                q, k, v, zero, scale=_latent_scale(cfg)
-            )
+            attn = _latent_chunk(cfg, q, k, v, zero)
             return attn, (k, v)
         if cfg.mixed:
             if decode or prefill_attn is not None:
@@ -1310,7 +1434,10 @@ def decode_chunk_paged(
     """
     from ..kvcache import row_shapes
     from ..kvcache.paged import scatter_rows, scatter_rows_by_kind, split_tables
-    from ..ops import mla_paged_chunk_decode_attention, paged_chunk_decode_attention
+    from ..ops import (
+        dsa_paged_chunk_decode_attention, latent_rope_width, mla_paged_chunk_decode_attention,
+        paged_chunk_decode_attention,
+    )
 
     L, b = cfg.n_layers, tokens.shape[0]
     K = n_steps
@@ -1360,7 +1487,14 @@ def decode_chunk_paged(
                     )
                     return attn, (kb_n, vb_n)
                 with jax.named_scope("layer/attn"):
-                    if cfg.latent:
+                    if cfg.sparse:
+                        attn = dsa_paged_chunk_decode_attention(
+                            q, pool.k, pool.v, tables, kb_n, vb_n, pool.length, k_i,
+                            scale=_latent_scale(cfg), layer=lp[LAYER_KEY],
+                            topk=cfg.index_topk, rope=latent_rope_width(cfg.qk_rope_head_dim),
+                            use_kernel=use_kernel, interpret=interpret,
+                        )
+                    elif cfg.latent:
                         attn = mla_paged_chunk_decode_attention(
                             q, pool.k, pool.v, tables, kb_n, vb_n, pool.length, k_i,
                             scale=_latent_scale(cfg), layer=lp[LAYER_KEY],
@@ -1458,8 +1592,6 @@ def _append_forward(
     params["aids"] directly): the unified step ops prefill a PACKED
     subset of engine slots, so the caller gathers the per-slot vector
     down to the rows actually present."""
-    from ..ops import latent_chunk_prefill_attention
-
     b, c = tokens.shape
     positions = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     i = jnp.arange(c, dtype=jnp.int32)[None, :]
@@ -1494,9 +1626,7 @@ def _append_forward(
                 vc2 = write(vv, v_new, idx)
             with jax.named_scope("layer/attn"):
                 if cfg.latent:
-                    attn = latent_chunk_prefill_attention(
-                        q, kc2, vc2, cursors, scale=_latent_scale(cfg)
-                    )
+                    attn = _latent_chunk(cfg, q, kc2, vc2, cursors)
                 else:
                     attn = chunk_prefill_attention(
                         q, kc2, vc2, cursors,

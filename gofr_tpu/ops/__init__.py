@@ -13,6 +13,10 @@ from .attention import (
     chunk_prefill_attention,
     chunk_prefill_why_not_flash,
     decode_attention,
+    dsa_chunk_prefill_attention,
+    dsa_kernel_why_not,
+    dsa_paged_chunk_decode_attention,
+    dsa_prefill_why_not,
     flash_attention,
     latent_attention,
     latent_chunk_prefill_attention,
@@ -26,9 +30,10 @@ from .attention import (
     paged_pool_operand,
     paged_kernel_why_not,
     ring_positions,
+    SparseQuery,
 )
 from .norms import rms_norm
-from .rope import apply_rope, rope_frequencies
+from .rope import apply_rope, rope_frequencies, yarn_mscale
 
 __all__ = [
     "multi_head_attention",
@@ -39,6 +44,11 @@ __all__ = [
     "chunk_prefill_attention",
     "paged_chunk_decode_attention",
     "latent_attention",
+    "SparseQuery",
+    "dsa_chunk_prefill_attention",
+    "dsa_kernel_why_not",
+    "dsa_paged_chunk_decode_attention",
+    "dsa_prefill_why_not",
     "latent_chunk_prefill_attention",
     "latent_rope_width",
     "mla_kernel_why_not",
@@ -51,4 +61,5 @@ __all__ = [
     "rms_norm",
     "apply_rope",
     "rope_frequencies",
+    "yarn_mscale",
 ]

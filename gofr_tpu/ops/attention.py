@@ -1560,6 +1560,385 @@ def mla_paged_chunk_decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# Sparse latent attention (DSA): a lightning indexer picks each query's rows
+# ---------------------------------------------------------------------------
+#
+# DeepSeek-V3.2's layer attends, for each query t, only the `topk` rows of
+# its causal context that the indexer scores highest:
+# I[t, s] = sum_j w[t, j] relu(q_idx[t, j] . k_idx[s]), with ONE index key
+# k_idx a token (its first rope columns rotated). The pool keeps that key as
+# the tail of the rope array's row, [k_rope padded to R | k_idx], so every
+# helper that moves a latent pool's two arrays moves it with the rest
+# (kvcache.row_shapes). Decode scores the lane's index keys through its
+# table (dsa_index_decode: one walk of page copies over every lane, the
+# latent kernel's _PageStream), takes the exact top-k (lax.top_k), gathers
+# the chosen rows and attends them (mla_sparse_decode); a prompt chunk
+# scores its view's index keys (dsa_index_prefill), selects, and gathers
+# each query's chosen rows out of the view it gathered anyway, a block of
+# queries at a time, so that its work is its chosen rows' and no
+# [rows, heads, view] array is ever whole.
+
+
+class SparseQuery(NamedTuple):
+    """What a sparse latent layer's attention takes for its queries."""
+
+    q: jnp.ndarray  # [b, s, hq, C + R]: the absorbed latent query (latent_attention)
+    q_idx: jnp.ndarray  # [b, s, ih, di]: the indexer's queries, rope applied
+    w_idx: jnp.ndarray  # [b, s, ih] f32: the indexer's head weights, scaled
+
+
+def _head_group(heads: int, most: int) -> int:
+    """The largest divisor of `heads` that is at most `most`."""
+    return max(g for g in range(1, min(heads, most) + 1) if heads % g == 0)
+
+
+def dsa_index_scores(q_idx, w_idx, keys, *, head_group: int = 8):
+    """I [b, s, n] f32 = sum_j w[b, s, j] relu(q_idx[b, s, j] . keys[b, n]):
+    dots at the operands' dtype with f32 accumulation, summed over heads a
+    group at a time (no [s, heads, n] array is whole)."""
+    b, s, ih, di = q_idx.shape
+    g = _head_group(ih, head_group)
+    qg = q_idx.reshape(b, s, ih // g, g, di).transpose(2, 0, 1, 3, 4)
+    wg = w_idx.astype(jnp.float32).reshape(b, s, ih // g, g).transpose(2, 0, 1, 3)
+    keys = keys.astype(q_idx.dtype)
+
+    def body(acc, xs):
+        qq, ww = xs
+        d = jnp.einsum("bsgd,bnd->bsgn", qq, keys, preferred_element_type=jnp.float32)
+        return acc + jnp.einsum("bsgn,bsg->bsn", jax.nn.relu(d), ww), None
+
+    acc = jnp.zeros((b, s, keys.shape[1]), jnp.float32)
+    return jax.lax.scan(body, acc, (qg, wg))[0]
+
+
+def dsa_select(scores, topk: int):
+    """The exact top-min(topk, n) of scores [..., n] (masked rows hold
+    NEG_INF): (their indices [..., T] int32, which of them are live rows)."""
+    vals, idx = jax.lax.top_k(scores, min(int(topk), scores.shape[-1]))
+    return idx.astype(jnp.int32), vals > NEG_INF
+
+
+def _chosen_attention(q, rows_c, rows_r, idx, live, *, scale: float):
+    """Absorbed latent attention of each query over ITS chosen rows: q
+    [b, s, hq, C + R], the view's rows [b, n, C] and [b, n, R], idx / live
+    [b, s, T] -> o_lat [b, s, hq, C]. The rows are gathered a query at a
+    time (ops at the rows' dtype, f32 accumulation, latent_attention's
+    convention)."""
+    b, s, hq, _ = q.shape
+    T, C = idx.shape[-1], rows_c.shape[-1]
+    flat = idx.reshape(b, s * T)[..., None]
+    rc = jnp.take_along_axis(rows_c, flat, axis=1).reshape(b, s, T, C)
+    rr = jnp.take_along_axis(rows_r, flat, axis=1).reshape(b, s, T, rows_r.shape[-1])
+    qs = (q.astype(jnp.float32) * scale).astype(rc.dtype)
+    sc = jnp.einsum("bqhc,bqtc->bqht", qs[..., :C], rc, preferred_element_type=jnp.float32) + jnp.einsum(
+        "bqhr,bqtr->bqht", qs[..., C:], rr, preferred_element_type=jnp.float32)
+    sc = jnp.where(live[:, :, None, :], sc, NEG_INF)
+    p = jax.nn.softmax(sc, axis=-1).astype(rc.dtype)
+    return jnp.einsum("bqht,bqtc->bqhc", p, rc, preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+_DSA_PREFILL_QUERIES = 16  # a prompt chunk's queries whose chosen rows are gathered at once
+
+
+def dsa_prefill_why_not(index_dim: int, queries: int, heads: int, *, interpret: bool = False) -> str:
+    """Why a prompt chunk's index scores cannot take dsa_index_prefill, or
+    "": lane-aligned index keys, whole blocks of queries, whole sublane
+    tiles of heads."""
+    if not interpret and jax.default_backend() != "tpu":
+        return f"backend {jax.default_backend()} is not tpu"
+    if index_dim % 128:
+        return f"index key of {index_dim} is not whole 128-lane rows"
+    if queries % _DSA_PREFILL_QUERIES or heads % 8:
+        return f"{queries} queries of {heads} heads are not blocks of {_DSA_PREFILL_QUERIES} x 8"
+    return ""
+
+
+_DSA_PREFILL_KEYS = 512  # index keys a program of dsa_index_prefill scores
+
+
+def _dsa_index_prefill_kernel(q_ref, w_ref, k_ref, o_ref, *, queries: int, heads: int):
+    """One block of a lane's queries against one block of its index keys:
+    every (query, head) row's dots on the MXU, relu, weighted, and each
+    query's heads summed (static row slices, no relayout)."""
+    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    s = jax.nn.relu(s) * w_ref[0]  # [queries * heads, keys] x [queries * heads, 1]
+    for t in range(queries):
+        o_ref[0, t : t + 1, :] = jnp.sum(s[t * heads : (t + 1) * heads], axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dsa_index_prefill_call(q_idx, w_idx, keys, *, interpret: bool = False):
+    """dsa_index_scores by a kernel: q_idx [b, c, ih, di], w_idx [b, c, ih],
+    keys [b, n, di] -> [b, c, n] f32."""
+    b, c, ih, di = q_idx.shape
+    n = keys.shape[1]
+    tq, tk = _DSA_PREFILL_QUERIES, _DSA_PREFILL_KEYS
+    n_pad = -(-n // tk) * tk
+    keys = jnp.pad(keys.astype(q_idx.dtype), ((0, 0), (0, n_pad - n), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_dsa_index_prefill_kernel, queries=tq, heads=ih),
+        name="dsa_index_prefill",  # what a device trace calls the kernel
+        grid=(b, c // tq, n_pad // tk),
+        in_specs=[
+            pl.BlockSpec((1, tq * ih, di), lambda bi, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, tq * ih, 1), lambda bi, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, tk, di), lambda bi, i, j: (bi, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, tq, tk), lambda bi, i, j: (bi, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, c, n_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=interpret,
+    )(q_idx.reshape(b, c * ih, di), w_idx.astype(jnp.float32).reshape(b, c * ih, 1), keys)
+    return out[..., :n]
+
+
+def dsa_chunk_prefill_attention(
+    sq: SparseQuery, c_cache, v_cache, cursors, *, scale: float, topk: int, rope: int,
+    use_kernel: bool | None = None, interpret: bool = False,
+):
+    """latent_chunk_prefill_attention with the indexer's choice: queries at
+    positions [cursors, cursors + c) over every resident row (the chunk's
+    own already written; `v_cache` rows [k_rope | k_idx], [b, n, 1, R + di]),
+    each attending only its causal top-`topk` by index score (a view of
+    no more rows than that: every causal row, plain latent attention). The
+    scores come from dsa_index_prefill where it can serve the shapes; the chosen
+    rows are gathered out of the view the chunk gathered anyway, a block of
+    queries at a time: a query's work is its own rows', not the view's."""
+    c, capacity = sq.q.shape[1], c_cache.shape[1]
+    if topk >= capacity:  # every causal row is chosen: the layer is plain latent attention
+        return latent_chunk_prefill_attention(sq.q, c_cache, v_cache[..., :rope], cursors, scale=scale)
+    if use_kernel is None:
+        use_kernel = not dsa_prefill_why_not(
+            sq.q_idx.shape[-1], c, sq.q_idx.shape[2], interpret=interpret)
+    qpos = cursors[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    causal = jnp.arange(capacity, dtype=jnp.int32)[None, None, :] <= qpos[:, :, None]
+    keys = v_cache[:, :, 0, rope:]
+    with jax.named_scope("layer/dsa_index"):
+        if use_kernel:
+            scores = _dsa_index_prefill_call(sq.q_idx, sq.w_idx, keys, interpret=interpret)
+        else:
+            scores = dsa_index_scores(sq.q_idx, sq.w_idx, keys)
+    with jax.named_scope("layer/dsa_select"):
+        idx, live = dsa_select(jnp.where(causal, scores, NEG_INF), topk)
+    with jax.named_scope("layer/attn_sparse_latent"):
+        rows_c, rows_r = c_cache[:, :, 0], v_cache[:, :, 0, :rope]
+        g = _head_group(c, _DSA_PREFILL_QUERIES)
+        if g == c:
+            return _chosen_attention(sq.q, rows_c, rows_r, idx, live, scale=scale)
+        b, _, hq, _ = sq.q.shape
+
+        def blocks(a):
+            return a.reshape((b, c // g, g) + a.shape[2:]).swapaxes(0, 1)
+
+        out = jax.lax.map(
+            lambda xs: _chosen_attention(xs[0], rows_c, rows_r, xs[1], xs[2], scale=scale),
+            (blocks(sq.q), blocks(idx), blocks(live)),
+        )
+        return out.swapaxes(0, 1).reshape(b, c, hq, rows_c.shape[-1])
+
+
+def dsa_kernel_why_not(latent: int, rope: int, index_dim: int, block: int, rows: int, *, interpret: bool = False) -> str:
+    """Why the sparse decode kernels (dsa_index_decode, mla_sparse_decode)
+    cannot serve these shapes, or "": lane-aligned parts, a page of whole
+    bf16 sublane tiles and a choice of whole 128-row tiles."""
+    why = mla_kernel_why_not(latent, rope, block, interpret=interpret)
+    if why:
+        return why
+    if index_dim % 128:
+        return f"index key of {index_dim} is not whole 128-lane rows"
+    if rows % 128:
+        return f"{rows} chosen rows are not whole 128-row tiles"
+    return ""
+
+
+def _dsa_index_kernel(
+    tbl_ref, lo_ref, hi_ref, layer_ref, q_ref, w_ref, v_hbm, o_ref, buf, sems, walk,
+    *, pages: int, in_flight: int, rope: int,
+):
+    """One program a lane: every page of the lane's band [lo, hi) copied
+    once (_PageStream), its index keys (the rope rows' columns from `rope`)
+    scored by every indexer head on the MXU, relu, weighted and summed over
+    heads; a group's scores are ONE row of the output [groups, tile]. Rows
+    outside the band read NEG_INF."""
+    stream = _PageStream(
+        tbl_ref, lo_ref, hi_ref, layer_ref, ((v_hbm, buf),), sems, walk,
+        pages=pages, in_flight=in_flight, part_rows=128,
+    )
+    q = q_ref[0]  # [ih, di]
+    w = w_ref[0][:, :1]  # [ih, 1] f32
+    lo, hi, _, _ = stream.own
+    o_ref[...] = jnp.full(o_ref.shape, NEG_INF, o_ref.dtype)
+    nt = (((1,), (1,)), ((), ()))
+
+    def read(carry, slot, g, k):
+        # a group at the band's edge is scored whole and masked: rows outside
+        # the band (pages never copied, whatever the buffer holds) are dropped
+        # by the select, never by arithmetic
+        keys = stream.rows(buf, slot, None)[:, rope:]
+        s = jax.lax.dot_general(q, keys, nt, preferred_element_type=jnp.float32)  # [ih, tile]
+        sc = jnp.sum(jax.nn.relu(s) * w, axis=0, keepdims=True)  # [1, tile]
+        if k is not None:
+            pos = g * stream.tile + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where((pos >= lo) & (pos < hi), sc, NEG_INF)
+        o_ref[0, pl.ds(g, 1), :] = sc
+        return carry
+
+    stream.read_lane(read, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("rope", "interpret"))
+def _dsa_index_call(q_idx, w_idx, v_pool, tables, lengths, layer, *, rope: int, interpret: bool):
+    """Index scores [b, MB * B] f32 of every row of layer `layer` of the
+    WHOLE rope pool [L, NB, B, R + di] through the tables; NEG_INF at and
+    past a lane's length."""
+    b, ih, di = q_idx.shape
+    B, W = v_pool.shape[2:]
+    paged_pool_operand(v_pool.shape, W)
+    # the latent kernel's rule over the rope array's pages: 512-token groups
+    sched = mla_decode_schedule(B, W, 0, v_pool.dtype, tables.shape[1])
+    groups = -(-tables.shape[1] * B // sched.tokens)
+    hp = -(-ih // 16) * 16
+    q = jnp.pad(q_idx.astype(v_pool.dtype), ((0, 0), (0, hp - ih), (0, 0)))
+    w = jnp.broadcast_to(
+        jnp.pad(w_idx.astype(jnp.float32), ((0, 0), (0, hp - ih)))[..., None], (b, hp, 128)
+    )
+
+    def lane(bi, tbl, lo_, hi_, layer_):
+        return (bi, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hp, di), lane), pl.BlockSpec((1, hp, 128), lane),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+        ],
+        out_specs=pl.BlockSpec((1, groups, sched.tokens), lane),
+        scratch_shapes=[
+            pltpu.VMEM((sched.in_flight + 1, sched.pages, B, W), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((1, sched.in_flight + 1)),
+            pltpu.SMEM((4,), jnp.int32),  # the walk's cursor
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_dsa_index_kernel, pages=sched.pages, in_flight=sched.in_flight, rope=rope),
+        name="dsa_index_decode",  # what a device trace calls the kernel
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, groups, sched.tokens), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(
+        tables.astype(jnp.int32), jnp.zeros_like(lengths, jnp.int32), lengths.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), q, w, v_pool,
+    )
+    return out.reshape(b, groups * sched.tokens)[:, : tables.shape[1] * B]
+
+
+def _mla_sparse_kernel(qc_ref, qr_ref, c_ref, r_ref, live_ref, o_ref):
+    """One program a lane: every head over the lane's chosen rows at once,
+    scores [hq, rows] from the latent and rope parts, one softmax, p @ c."""
+    nt = (((1,), (1,)), ((), ()))
+    c = c_ref[0]
+    s = jax.lax.dot_general(qc_ref[0], c, nt, preferred_element_type=jnp.float32)
+    s = s + jax.lax.dot_general(qr_ref[0], r_ref[0], nt, preferred_element_type=jnp.float32)
+    s = jnp.where(live_ref[0] > 0, s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jax.lax.dot_general(p.astype(c.dtype), c, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    o_ref[0] = o / jnp.where(l == 0.0, 1.0, l)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _mla_sparse_call(q, rows_c, rows_r, live, *, scale: float, interpret: bool):
+    """o_lat [b, hq, C] f32 of queries [b, hq, C + R] over their lanes'
+    chosen rows [b, T, C | R] (live [b, T] bool)."""
+    b, hq, _ = q.shape
+    T, C = rows_c.shape[1:]
+    R = rows_r.shape[-1]
+    hp = -(-hq // 16) * 16
+    qs = jnp.pad((q.astype(jnp.float32) * scale).astype(rows_c.dtype), ((0, 0), (0, hp - hq), (0, 0)))
+
+    def lane(bi):
+        return (bi, 0, 0)
+
+    o = pl.pallas_call(
+        _mla_sparse_kernel,
+        name="mla_sparse_decode",  # what a device trace calls the kernel
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, hp, C), lane), pl.BlockSpec((1, hp, R), lane),
+            pl.BlockSpec((1, T, C), lane), pl.BlockSpec((1, T, R), lane),
+            pl.BlockSpec((1, 1, T), lane),
+        ],
+        out_specs=pl.BlockSpec((1, hp, C), lane),
+        out_shape=jax.ShapeDtypeStruct((b, hp, C), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(qs[..., :C], qs[..., C:], rows_c, rows_r.astype(rows_c.dtype), live.astype(jnp.float32)[:, None, :])
+    return o[:, :hq]
+
+
+def dsa_paged_chunk_decode_attention(
+    sq: SparseQuery,  # q [b, 1, hq, C + R], q_idx [b, 1, ih, di], w_idx [b, 1, ih]
+    c_pool: jnp.ndarray,  # [L, NB, B, C]: the WHOLE pool, as stored
+    v_pool: jnp.ndarray,  # [L, NB, B, R + di]: rope key | index key
+    tables: jnp.ndarray,  # [b, MB] int32
+    c_buf: jnp.ndarray,  # [b, chunk, 1, C] — this chunk's new rows
+    v_buf: jnp.ndarray,  # [b, chunk, 1, R + di]
+    lengths: jnp.ndarray,  # [b] valid pool prefix (at chunk START)
+    step: jnp.ndarray,  # scalar int32 — current step within the chunk
+    *,
+    scale: float,
+    layer,  # scalar int32: which layer of the pools this is
+    topk: int,
+    rope: int,  # R: the rope part's columns of a v row
+    use_kernel: bool | None = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """mla_paged_chunk_decode_attention with the indexer's choice -> o_lat
+    [b, 1, hq, C]. The rows a query may choose are the pool's [0, lengths)
+    through the table and the chunk buffer's [0, step]: all are scored, the
+    exact top-`topk` of them are gathered (pool rows through the table,
+    buffer rows from the buffer) and attended under one softmax."""
+    b = c_buf.shape[0]
+    _, NB, B, C = c_pool.shape
+    W = v_pool.shape[-1]
+    chunk = c_buf.shape[1]
+    cap = tables.shape[1] * B
+    topk = min(int(topk), cap + chunk)
+    if use_kernel is None:
+        use_kernel = not dsa_kernel_why_not(C, rope, W - rope, B, topk, interpret=interpret)
+    with jax.named_scope("layer/dsa_index"):
+        if use_kernel:
+            main = _dsa_index_call(sq.q_idx[:, 0], sq.w_idx[:, 0], v_pool, tables, lengths, layer, rope=rope, interpret=interpret)
+        else:
+            keys = take_pages(v_pool, layer, tables).reshape(b, cap, W)[..., rope:]
+            main = dsa_index_scores(sq.q_idx, sq.w_idx, keys)[:, 0]
+            main = jnp.where(jnp.arange(cap)[None, :] < lengths[:, None], main, NEG_INF)
+        buf = dsa_index_scores(sq.q_idx, sq.w_idx, v_buf[:, :, 0, rope:])[:, 0]
+        buf = jnp.where(jnp.arange(chunk)[None, :] <= step, buf, NEG_INF)
+    with jax.named_scope("layer/dsa_select"):
+        idx, live = dsa_select(jnp.concatenate([main, buf], axis=-1), topk)  # [b, T]
+        in_pool = idx < cap
+        p = jnp.clip(idx, 0, cap - 1)
+        blk = jnp.clip(jnp.take_along_axis(tables, p // B, axis=1), 0, NB - 1)
+        flat = layer * (NB * B) + blk * B + p % B
+        rc = jnp.take(c_pool.reshape(-1, C), flat, axis=0)
+        rr = jnp.take(v_pool.reshape(-1, W), flat, axis=0)[..., :rope]
+        j = jnp.clip(idx - cap, 0, chunk - 1)[..., None]
+        bc = jnp.take_along_axis(c_buf[:, :, 0], j, axis=1).astype(rc.dtype)
+        br = jnp.take_along_axis(v_buf[:, :, 0, :rope], j, axis=1).astype(rr.dtype)
+        rows_c = jnp.where(in_pool[..., None], rc, bc)
+        rows_r = jnp.where(in_pool[..., None], rr, br)
+    with jax.named_scope("layer/attn_sparse_latent"):
+        if use_kernel:
+            o = _mla_sparse_call(sq.q[:, 0], rows_c, rows_r, live, scale=scale, interpret=interpret)
+            return o[:, None].astype(sq.q.dtype)
+        return latent_attention(sq.q, rows_c[:, :, None], rows_r[:, :, None], live[:, None], scale=scale)
+
+
+# ---------------------------------------------------------------------------
 # Dispatcher
 # ---------------------------------------------------------------------------
 

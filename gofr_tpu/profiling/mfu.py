@@ -171,6 +171,10 @@ def model_costs(cfg, *, quantized: bool = False) -> ModelCosts:
         )
         attn_per_ctx = 2 * cfg.n_heads * (2 * C + dr)
         kv_values = C + dr
+        if getattr(cfg, "sparse", False):  # the lightning indexer's projections and its key
+            ih, di = cfg.index_heads, cfg.index_head_dim
+            attn_params += cfg.q_lora_rank * ih * di + d * (di + ih)
+            kv_values += di
     else:
         attn_params = (
             d * (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_dim  # qkv

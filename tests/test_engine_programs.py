@@ -42,6 +42,14 @@ twin of a stack of gated DeltaNet and full MHA layers over the paged pool and
 a per-slot recurrent state. Its programs are pinned in
 `tests/data/engine_programs_pr36.json`, written on PR 36's tree by
 `python tests/test_engine_programs.py olmo`.
+
+The sparse latent engine changed none of them (no indexer, one routing
+group and no rope scaling lower as before) and added an engine: `dsa`, the
+tiny twin of DeepSeek-V3.2's block (latent attention with a lightning indexer
+that picks each query's rows, YaRN rope, group-limited routing over a held
+share of the experts). Its programs are pinned in
+`tests/data/engine_programs_dsa.json`, written by
+`python tests/test_engine_programs.py dsa`.
 """
 
 from __future__ import annotations
@@ -65,6 +73,7 @@ _PINS_D0 = os.path.join(_DATA, "engine_programs_pr32.json")  # the step programs
 _PINS_MIXED = os.path.join(_DATA, "engine_programs_pr33.json")  # the mixed engine's, all of them
 _PINS_IN_PLACE = os.path.join(_DATA, "engine_programs_pr34.json")  # the contiguous layouts' programs PR 34 changed
 _PINS_LINEAR = os.path.join(_DATA, "engine_programs_pr36.json")  # the linear stack's engine, all of them
+_PINS_SPARSE = os.path.join(_DATA, "engine_programs_dsa.json")  # the sparse latent engine, all of them
 
 _KW = dict(
     slots=4, max_seq_len=128, prefill_buckets=(16, 64), decode_chunk=8,
@@ -80,6 +89,7 @@ ENGINES = {
     "moe": ("tiny_moe", dict(_KW, kv_paged=True)),
     "mixed": ("tiny_mixed_moe", dict(_KW, quantize=True, speculative=False)),
     "olmo": ("tiny_olmo_hybrid", dict(_KW, quantize=True, speculative=False)),
+    "dsa": ("tiny_dsa_moe", dict(_KW, quantize=True, prefix_cache_mb=1, speculative=False)),
 }
 # The ONLY programs whose text may differ from the parent's, and why: the
 # parent's constrained paged copies were not given PR 29's `moe_out`, so a
@@ -201,7 +211,7 @@ def _sha(op, args) -> str:
 
 
 PINNED: dict = {}
-for _path in (_PINS, _PINS_D0, _PINS_MIXED, _PINS_LINEAR):
+for _path in (_PINS, _PINS_D0, _PINS_MIXED, _PINS_LINEAR, _PINS_SPARSE):
     with open(_path) as _f:
         PINNED.update(json.load(_f))
 
@@ -243,9 +253,10 @@ def test_every_program_of_the_six_engines_is_pinned():
     assert set(REPINNED) == set(IN_PLACE)
 
 
-if __name__ == "__main__" and sys.argv[1:] == ["olmo"]:  # writes PR 36's pins of the linear stack's engine
-    pins = {f"olmo.{name}": _sha(op, args) for name, (op, args) in _engine_programs("olmo").items()}
-    with open(_PINS_LINEAR, "w") as f:
+if __name__ == "__main__" and sys.argv[1:] in (["olmo"], ["dsa"]):  # writes the linear or the sparse engine's pins
+    name = sys.argv[1]
+    pins = {f"{name}.{p}": _sha(op, args) for p, (op, args) in _engine_programs(name).items()}
+    with open({"olmo": _PINS_LINEAR, "dsa": _PINS_SPARSE}[name], "w") as f:
         json.dump(dict(sorted(pins.items())), f, indent=1)
         f.write("\n")
     print(len(pins), "programs pinned")

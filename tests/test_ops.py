@@ -389,6 +389,75 @@ def test_latent_paged_decode_compiles_for_the_v5e(v5e_chip):
     assert tile == {"pages": 32, "tokens": 512, "in_flight": 2, "walk": "across lanes"}
 
 
+def test_the_sparse_latent_decode_compiles_for_the_v5e(v5e_chip):
+    """DeepSeek-V3.2's decode attention at deepseek-v3.2.docs-closed's own
+    shapes (8 layers of 17,083 blocks, latent rows of 512 and rope rows of
+    128 | 128 index columns, 128 heads, an indexer of 64 heads of 128, 16
+    lanes, a table of 904, a top-2,048): both kernels through Mosaic for a
+    v5e, the pools the program's parameters as stored, and nothing else in
+    the program as large as a layer of them."""
+    from gofr_tpu.ops.attention import SparseQuery, dsa_paged_chunk_decode_attention, mla_decode_schedule
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    layers, n_blocks, block, C, R, W, lanes, hq, ih, n_tbl = 8, 17083, 16, 512, 128, 256, 16, 128, 64, 904
+    with _persistent_cache_off():
+        text = jax.jit(
+            lambda q, qi, wi, cp, vp, t, cb, vb, n, s, ly: dsa_paged_chunk_decode_attention(
+                SparseQuery(q, qi, wi), cp, vp, t, cb, vb, n, s, scale=0.1, layer=ly, topk=2048, rope=R,
+                use_kernel=True,
+            )
+        ).lower(
+            S((lanes, 1, hq, C + R), jnp.bfloat16), S((lanes, 1, ih, W - R), jnp.bfloat16),
+            S((lanes, 1, ih), jnp.float32),
+            S((layers, n_blocks, block, C), jnp.bfloat16), S((layers, n_blocks, block, W), jnp.bfloat16),
+            S((lanes, n_tbl), jnp.int32), S((lanes, 8, 1, C), jnp.bfloat16), S((lanes, 8, 1, W), jnp.bfloat16),
+            S((lanes,), jnp.int32), S((), jnp.int32), S((), jnp.int32),
+        ).compile().as_text()
+    calls = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert any("%dsa_index_decode" in c for c in calls) and any("%mla_sparse_decode" in c for c in calls)
+    # the pools as stored, and views of them as rows (the chosen rows' gather
+    # reads them through a bitcast and a fusion's parameter): no copy
+    pool_sized = {
+        (op, shape) for op, shape in _hlo_results(text) if math.prod(shape) >= n_blocks * block * R
+    }
+    assert {op for op, _shape in pool_sized} == {"parameter", "bitcast"}
+    assert {("parameter", (layers, n_blocks, block, W)), ("parameter", (layers, n_blocks, block, C))} <= pool_sized
+    assert {shape for _op, shape in pool_sized} <= {
+        (layers, n_blocks, block, W), (layers, n_blocks, block, C), (layers * n_blocks * block, W),
+        (layers * n_blocks * block, C)}
+    # 512-token groups of 32 pages of the rope array (256 KB), three of them in flight
+    tile = mla_decode_schedule(block, W, 0, jnp.bfloat16, n_tbl).report()
+    assert tile == {"pages": 32, "tokens": 512, "in_flight": 3, "walk": "across lanes"}
+
+
+def test_the_sparse_prompt_chunk_compiles_for_the_v5e(v5e_chip):
+    """A prompt step's sparse attention at deepseek-v3.2.docs-closed's shapes
+    (4 rows of 64 queries, a view of 14,464 rows of 512 | 256): the index
+    scores through dsa_index_prefill for a v5e, and the chosen rows' work
+    within a few hundred MB of temporaries (no [rows, heads, view] array)."""
+    from gofr_tpu.ops.attention import SparseQuery, dsa_chunk_prefill_attention
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    b, c, hq, C, R, ih, di, n = 4, 64, 128, 512, 128, 64, 128, 14464
+    with _persistent_cache_off():
+        compiled = jax.jit(
+            lambda q, qi, wi, cc, vc, cur: dsa_chunk_prefill_attention(
+                SparseQuery(q, qi, wi), cc, vc, cur, scale=0.1, topk=2048, rope=R, use_kernel=True,
+            )
+        ).lower(
+            S((b, c, hq, C + R), jnp.bfloat16), S((b, c, ih, di), jnp.bfloat16), S((b, c, ih), jnp.float32),
+            S((b, n, 1, C), jnp.bfloat16), S((b, n, 1, R + di), jnp.bfloat16), S((b,), jnp.int32),
+        ).compile()
+    calls = [ln for ln in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert any("%dsa_index_prefill" in ln for ln in calls)
+    # a [4, 128, 64, 14464] float32 score array whole would be 1.9 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 600e6
+
+
 # block, kv heads, head_dim, table slots: the GQA kernel's cells, and the
 # shapes at its limits, each sized by bytes (bf16 and int8 pools)
 GQA_SCHEDULES = {
