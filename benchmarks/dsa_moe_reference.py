@@ -1,10 +1,12 @@
 """The dsa_moe family's plain reference (families/dsa_moe.py).
 
 The published equations of a DeepSeek-V3.2 layer in their EXPANDED form,
-float32, every matmul at "highest": multi-head latent attention with every
-head's keys and values written out from the latent (no absorbed query, no
-cache), YaRN rope (the inverse frequencies blended between the correction
-dims of beta_fast and beta_slow, the score scale times yarn_mscale squared);
+float32: every matmul of two activations at "highest", every matmul of a
+weight with exact products and float32 sums (`_dot`). Multi-head latent
+attention with every head's keys and values written out from the latent (no
+absorbed query, no cache), YaRN rope (the inverse frequencies blended
+between the correction dims of beta_fast and beta_slow, the score scale
+times yarn_mscale squared);
 the lightning indexer's FULL score matrix I[t, s] = sum_j w[t, j]
 relu(q_idx[t, j] . k_idx[s]) over the causal rows and an exact top-k per
 query (`index_topk`, or every row at a shorter context), outside of which a
@@ -15,8 +17,11 @@ sigmoid-routed experts with a correction bias on the choice, group-limited
 shared expert. Queries are taken in blocks (`Q_BLOCK`), so that a 13k-token
 sequence's [heads, queries, rows] scores fit on the chip. No kernel, no
 cache, no batching: one sequence at a time, a layer at a time, an expert at
-a time. Nothing of the program is imported; weights come from
-`dsa_moe_weights.py` by the run's seed.
+a time. Sampled requests that open with one document pass that stretch once
+(`hidden_states`' `shared`): a causal layer's rows do not depend on later
+rows, so the stretch gives each request the same keys, values and index
+keys, and only its own rows attend them. Nothing of the program is
+imported; weights come from `dsa_moe_weights.py` by the run's seed.
 
 **The share.** Where the configuration holds a share of a layer's experts
 (`n_routed_experts` of `n_routed_experts_routed`, from `first_expert_held`),
@@ -44,6 +49,7 @@ from __future__ import annotations
 import functools
 import math
 import sys
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -51,14 +57,36 @@ import numpy as np
 
 import dsa_moe_weights as W
 import reference as R
-from latent_moe_reference import WINDOW, _swiglu, window_means
+from latent_moe_reference import WINDOW, window_means
 
 F32 = jnp.float32
 Q_BLOCK = 64  # query rows per attention block: [128 heads, 64, 14k rows] f32 is 470 MB
 
 
-def _real(q, model: dict, int4: bool):
-    return R._real(q, W.fan_in_of(q.shape), model, int4)
+def _dot(x, q, model: dict, int4: bool):
+    """x [..., in] float32 times the real weight `q * s` [in, out]
+    (`reference._real`'s, int4's 15 levels with `int4`), in float32. A
+    weight's levels are integers of at most 8 bits, exact in bfloat16, so x
+    goes in as the three bfloat16 pieces its 24 bits split into exactly:
+    every product is exact and the sums are float32's. That is the accuracy
+    of "highest" in half its passes, and a few seconds of the chip's
+    compiler where "highest" took it tens of seconds a matmul."""
+    s = W.scale_of(W.fan_in_of(q.shape), W.dtype_of(model)).astype(F32)
+    levels = q.astype(F32)
+    if int4:
+        step = 127.0 / 7.0
+        levels, s = jnp.clip(jnp.round(levels / step), -7, 7), s * step
+    levels = levels.astype(jnp.bfloat16)
+    out = 0.0
+    for _ in range(3):
+        piece = x.astype(jnp.bfloat16)
+        out = out + jnp.matmul(piece, levels, precision=jax.lax.Precision.DEFAULT, preferred_element_type=F32)
+        x = x - piece.astype(F32)
+    return out * s
+
+
+def _swiglu(h, gate, up, down, model: dict, int4: bool):
+    return _dot(jax.nn.silu(_dot(h, gate, model, int4)) * _dot(h, up, model, int4), down, model, int4)
 
 
 def yarn(model: dict) -> tuple[jnp.ndarray, float, float]:
@@ -103,15 +131,17 @@ def _layer_norm(x, weight, bias, eps=1e-6):
     return (x - mu) * jax.lax.rsqrt(var + eps) * weight.astype(F32) + bias.astype(F32)
 
 
-def sparse_attention(q, k, v, qi, ki, wi, *, scale: float, topk: int):
+def sparse_attention(q, k, v, qi, ki, wi, *, scale: float, topk: int, start: int = 0):
     """Causal attention where query t sees only its top-`topk` rows by index
-    score, one block of queries at a time. q, k [s, h, dqk], v [s, h, dv];
-    the indexer's qi [s, ih, di], ki [s, di], wi [s, ih] -> [s, h * dv]."""
-    s, h, dqk = q.shape
+    score, one block of queries at a time. The queries are rows `start` on:
+    q [s - start, h, dqk], qi [s - start, ih, di], wi [s - start, ih]; the
+    keys every row: k [s, h, dqk], v [s, h, dv], ki [s, di] -> [s - start, h * dv]."""
+    s, h, dqk = k.shape
+    n = q.shape[0]
     dv = v.shape[-1]
     kpos = jnp.arange(s)
-    nblk = -(-s // Q_BLOCK)
-    pad = ((0, nblk * Q_BLOCK - s),)
+    nblk = -(-n // Q_BLOCK)
+    pad = ((0, nblk * Q_BLOCK - n),)
     qb = jnp.pad(q, pad + ((0, 0), (0, 0))).reshape(nblk, Q_BLOCK, h, dqk)
     qib = jnp.pad(qi, pad + ((0, 0), (0, 0))).reshape(nblk, Q_BLOCK, qi.shape[1], qi.shape[2])
     wib = jnp.pad(wi, pad + ((0, 0),)).reshape(nblk, Q_BLOCK, wi.shape[1])
@@ -119,7 +149,7 @@ def sparse_attention(q, k, v, qi, ki, wi, *, scale: float, topk: int):
 
     def block(args):
         qq, qqi, wwi, i = args
-        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
+        qpos = start + i * Q_BLOCK + jnp.arange(Q_BLOCK)
         causal = kpos[None, :] <= qpos[:, None]
         index = jnp.einsum("qjs,qj->qs", jax.nn.relu(jnp.einsum("qjd,sd->qjs", qqi, ki)), wwi)
         _, chosen = jax.lax.top_k(jnp.where(causal, index, -jnp.inf), kk)
@@ -129,7 +159,7 @@ def sparse_attention(q, k, v, qi, ki, wi, *, scale: float, topk: int):
         return jnp.einsum("hqs,shd->qhd", jax.nn.softmax(logits, axis=-1), v)
 
     out = jax.lax.map(block, (qb, qib, wib, jnp.arange(nblk)))
-    return out.reshape(nblk * Q_BLOCK, h * dv)[:s]
+    return out.reshape(nblk * Q_BLOCK, h * dv)[:n]
 
 
 def route(model: dict, leaves: dict, h):
@@ -151,14 +181,16 @@ def routed_part(model: dict, leaves: dict, h, *, int4: bool = False):
 
     def expert(y, e):
         share = jnp.sum(jnp.where(chosen == m["first"] + e, w, 0.0), axis=-1, keepdims=True)
-        out = _swiglu(h, *(_real(leaves[n][e], model, int4) for n in ("w_gate", "w_up", "w_down")))
+        out = _swiglu(h, *(leaves[n][e] for n in ("w_gate", "w_up", "w_down")), model, int4)
         return y + share * out, None
 
     return jax.lax.scan(expert, jnp.zeros_like(h), jnp.arange(m["Eh"]))[0]
 
 
-def layer_forward(model: dict, leaves: dict, x, *, moe: bool, int4: bool = False):
-    """One layer over one sequence x [s, d] (float32)."""
+def layer_forward(model: dict, leaves: dict, x, *, moe: bool, int4: bool = False, start: int = 0):
+    """One layer over one sequence x [s, d] (float32): the rows `start` on
+    [s - start, d], every row before them giving keys, values and index
+    keys only (causal: what a row gives does not depend on later rows)."""
     m = W.dims(model)
     eps = model["rms_norm_eps"]
     s = x.shape[0]
@@ -166,31 +198,33 @@ def layer_forward(model: dict, leaves: dict, x, *, moe: bool, int4: bool = False
     inv, trig, m2 = yarn(model)
     dn, dr = m["dn"], m["dr"]
 
-    def real(name):
-        return _real(leaves[name], model, int4)
+    def mm(x, name):
+        return _dot(x, leaves[name], model, int4)
 
     h = R._rms(x, leaves["attn_norm"], eps)
-    cq = R._rms(h @ real("wq_a"), leaves["q_norm"], eps)
-    q = (cq @ real("wq_b")).reshape(s, m["hq"], dn + dr)
-    kv = h @ real("wkv_a")
+    cq = R._rms(mm(h[start:], "wq_a"), leaves["q_norm"], eps)
+    q = mm(cq, "wq_b").reshape(s - start, m["hq"], dn + dr)
+    kv = mm(h, "wkv_a")
     c_kv = R._rms(kv[:, : m["C"]], leaves["kv_norm"], eps)
     k_rope = _rope(kv[:, None, m["C"]:], pos, inv, trig)  # ONE head, shared by all
-    up = (c_kv @ real("wkv_b")).reshape(s, m["hq"], dn + m["dv"])
+    up = mm(c_kv, "wkv_b").reshape(s, m["hq"], dn + m["dv"])
     k = jnp.concatenate([up[..., :dn], jnp.broadcast_to(k_rope, (s, m["hq"], dr))], axis=-1)
-    q = jnp.concatenate([q[..., :dn], _rope(q[..., dn:], pos, inv, trig)], axis=-1)
+    q = jnp.concatenate([q[..., :dn], _rope(q[..., dn:], pos[start:], inv, trig)], axis=-1)
     # the lightning indexer
-    qi = (cq @ real("wq_idx")).reshape(s, m["ih"], m["di"])
-    qi = jnp.concatenate([_rope(qi[..., :dr], pos, inv, trig), qi[..., dr:]], axis=-1)
-    ki = _layer_norm(h @ real("wk_idx"), leaves["k_idx_norm"], leaves["k_idx_bias"])[:, None]
+    qi = mm(cq, "wq_idx").reshape(s - start, m["ih"], m["di"])
+    qi = jnp.concatenate([_rope(qi[..., :dr], pos[start:], inv, trig), qi[..., dr:]], axis=-1)
+    ki = _layer_norm(mm(h, "wk_idx"), leaves["k_idx_norm"], leaves["k_idx_bias"])[:, None]
     ki = jnp.concatenate([_rope(ki[..., :dr], pos, inv, trig), ki[..., dr:]], axis=-1)[:, 0]
-    wi = (h @ real("w_idx")) / math.sqrt(m["ih"] * m["di"])
-    attn = sparse_attention(q, k, up[..., dn:], qi, ki, wi, scale=m2 / math.sqrt(dn + dr), topk=m["topk"])
-    x = x + attn @ real("wo")
+    wi = mm(h[start:], "w_idx") / math.sqrt(m["ih"] * m["di"])
+    attn = sparse_attention(q, k, up[..., dn:], qi, ki, wi, scale=m2 / math.sqrt(dn + dr), topk=m["topk"],
+                            start=start)
+    x = x[start:] + mm(attn, "wo")
 
     h = R._rms(x, leaves["mlp_norm"], eps)
     if not moe:
-        return x + _swiglu(h, real("w_gate"), real("w_up"), real("w_down"))
-    return x + routed_part(model, leaves, h, int4=int4) + _swiglu(h, real("ws_gate"), real("ws_up"), real("ws_down"))
+        return x + _swiglu(h, leaves["w_gate"], leaves["w_up"], leaves["w_down"], model, int4)
+    shared_ffn = _swiglu(h, leaves["ws_gate"], leaves["ws_up"], leaves["ws_down"], model, int4)
+    return x + routed_part(model, leaves, h, int4=int4) + shared_ffn
 
 
 def _embed_rows(model_t, key, tokens, int4):
@@ -213,22 +247,87 @@ def _thawed(model_t) -> dict:
     return model
 
 
-@functools.partial(jax.jit, static_argnames=("model_t", "moe", "int4"))
-def _one_layer(model_t, lkey, xs, moe, int4):
+@functools.partial(jax.jit, static_argnames=("model_t", "moe"))
+def _leaves(model_t, lkey, moe):
+    return W.layer_leaves(_thawed(model_t), lkey, moe)
+
+
+@functools.partial(jax.jit, static_argnames=("model_t", "moe", "int4", "start"))
+def _one_layer(model_t, leaves, xs, moe, int4, start):
     model = _thawed(model_t)
-    leaves = W.layer_leaves(model, lkey, moe)
-    return jax.lax.map(lambda x: layer_forward(model, leaves, x, moe=moe, int4=int4), xs)
+    return jax.lax.map(lambda x: layer_forward(model, leaves, x, moe=moe, int4=int4, start=start), xs)
 
 
-def hidden_states(model: dict, seed: int, tokens, *, int4: bool = False):
+def compile_ahead(calls: list) -> None:
+    """Compile each (jitted function, arguments or their shapes, static
+    keywords) side by side, in threads; the calls that follow find them
+    built. The chip's compiler takes half a minute over a layer's program
+    (its float32 attention at "highest"): one after another they took
+    minutes."""
+    def one(call):
+        fn, args, statics = call
+        with jax.default_matmul_precision("highest"):
+            fn.trace(*args, **statics).lower().compile()
+
+    with ThreadPoolExecutor(max(1, len(calls))) as pool:
+        list(pool.map(one, calls))
+
+
+def _shape(x):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+
+def hidden_states(model: dict, seed: int, tokens, *, int4: bool = False, shared: int = 0, also=()):
     """Final hidden states (before the last norm) of each row of `tokens`
-    [n, s], layer by layer."""
+    [n, s], layer by layer, at its positions `shared` on: [n, s - shared, d].
+    Rows that open with the same `shared` tokens (a document that requests
+    share) take that stretch's pass once: a causal layer's row depends on
+    no later row, so the stretch gives each of them the same keys, values
+    and index keys, and the rest of each row attends them. `also` are more
+    calls to compile beside the layers' (`compile_ahead`)."""
     key, mt = W.base_key(seed), _frozen(model)
+    toks = np.asarray(tokens, np.int32)
+    n, s = toks.shape
+    lkeys = W.layer_keys(key, model)
+    kinds = [i >= model["first_k_dense_replace"] for i in range(len(lkeys))]
+    heads, which = (np.unique(toks[:, :shared], axis=0, return_inverse=True) if shared else (None, None))
+    d = model["hidden_size"]
+    calls = list(also)
+    for moe in sorted(set(kinds)):
+        lk = lkeys[kinds.index(moe)]
+        leaves = jax.tree.map(_shape, jax.eval_shape(functools.partial(_leaves, mt, moe=moe), lk))
+        calls.append((_leaves, (mt, _shape(lk)), {"moe": moe}))
+        calls.append((_one_layer, (mt, leaves, jax.ShapeDtypeStruct((n, s, d), F32)),
+                      {"moe": moe, "int4": int4, "start": shared}))
+        if shared:
+            calls.append((_one_layer, (mt, leaves, jax.ShapeDtypeStruct((len(heads), shared, d), F32)),
+                          {"moe": moe, "int4": int4, "start": 0}))
+    compile_ahead(calls)
     with jax.default_matmul_precision("highest"):
-        xs = _embed_rows(mt, key, jnp.asarray(tokens, jnp.int32), int4)
-        for i, lkey in enumerate(W.layer_keys(key, model)):
-            xs = _one_layer(mt, lkey, xs, i >= model["first_k_dense_replace"], int4)
+        xs = _embed_rows(mt, key, jnp.asarray(toks[:, shared:]), int4)
+        hs = _embed_rows(mt, key, jnp.asarray(heads), int4) if shared else None
+        for lkey, moe in zip(lkeys, kinds):
+            leaves = _leaves(mt, lkey, moe=moe)
+            if shared:
+                full = jnp.concatenate([hs[jnp.asarray(which.reshape(-1))], xs], axis=1)
+                xs = _one_layer(mt, leaves, full, moe=moe, int4=int4, start=shared)
+                hs = _one_layer(mt, leaves, hs, moe=moe, int4=int4, start=0)
+            else:
+                xs = _one_layer(mt, leaves, xs, moe=moe, int4=int4, start=0)
+            del leaves
     return xs
+
+
+def shared_head(seqs: list, first: int) -> int:
+    """Tokens, in whole blocks of `Q_BLOCK` and up to row `first`, that open
+    two or more of `seqs` alike: the document that sampled requests share."""
+    best = 0
+    for i, a in enumerate(seqs):
+        for b in seqs[i + 1:]:
+            m = min(len(a), len(b))
+            diff = np.flatnonzero(a[:m] != b[:m])
+            best = max(best, int(diff[0]) if diff.size else m)
+    return min(best, first) // Q_BLOCK * Q_BLOCK
 
 
 def forward_logits(model: dict, seed: int, tokens, *, int4: bool = False):
@@ -256,9 +355,15 @@ def gaps(model: dict, seed: int, samples: list, seq_len: int, *, control: bool =
         seq = list(prompt) + list(served[:-1])
         toks[i, : len(seq)] = seq
         where.append((i, len(prompt) - 1, len(served)))
-    hid = hidden_states(model, seed, toks)
-    hid_c = hidden_states(model, seed, toks, int4=True) if control else None
+    shared = shared_head([toks[i, : len(p) + len(sv) - 1] for i, (p, sv) in enumerate(samples)],
+                         min(first for _, first, _ in where))
     key, mt = W.base_key(seed), R._frozen(model)
+    rows_in = jax.ShapeDtypeStruct((R.ROW_BLOCK, model["hidden_size"]), F32)
+    judge = (R._judge, (mt, _shape(key), rows_in, rows_in, jax.ShapeDtypeStruct((R.ROW_BLOCK,), jnp.int32)),
+             {"control": control})
+    hid = hidden_states(model, seed, toks, shared=shared, also=[judge])
+    hid_c = hidden_states(model, seed, toks, int4=True, shared=shared) if control else None
+    where = [(i, first - shared, count) for i, first, count in where]
     res = {"gap": [], "agree": [], "control_gap": [], "token_gap": [], "token_control_gap": [], "per_request": []}
     with jax.default_matmul_precision("highest"):
         for i, first_row, count in where:

@@ -52,6 +52,27 @@ def test_gaps_of_the_references_own_choice_are_zero_and_the_control_fails():
     assert max(res["control_gap"]) == pytest.approx(np.mean(res["token_control_gap"]))
 
 
+def test_a_shared_document_is_passed_once_and_gives_the_whole_pass():
+    """Two requests that open with one 70-token document: the reference passes
+    its first 64 tokens (whole query blocks) once and each request's rest
+    over them, and gets the hidden states of the whole pass, positions
+    (rope, the causal mask, the indexer's top-k) included."""
+    import dsa_moe_reference as REF
+
+    model = CONFIG["rehearsal"]["model"]
+    rng = np.random.default_rng(3)
+    doc = rng.integers(1, model["vocab_size"], 70)
+    seqs = [np.concatenate([doc, rng.integers(1, model["vocab_size"], n)]) for n in (30, 41)]
+    toks = np.zeros((2, 128), np.int32)
+    for i, q in enumerate(seqs):
+        toks[i, : len(q)] = q
+    assert REF.shared_head(seqs, 99) == 64 and REF.shared_head(seqs, 63) == 0
+    whole = REF.hidden_states(model, SEED, toks)
+    part = REF.hidden_states(model, SEED, toks, shared=64)
+    assert part.shape == (2, 64, model["hidden_size"])
+    assert float(jnp.max(jnp.abs(part - whole[:, 64:]))) < 1e-4 * float(jnp.max(jnp.abs(whole)))
+
+
 def test_the_costs_are_the_hand_counts():
     """DeepSeek-V3.2 as cut: MLA 187.1 M a layer, the indexer 14.0 M, an
     expert 44.04 M, 0.5 of a token's 8 pairs here; a cache row 1,536 B, its

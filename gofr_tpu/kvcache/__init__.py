@@ -831,6 +831,14 @@ class CacheManager:
                 self.pool.incref([plan.tail_src])
             return plan
 
+    def shared_len(self, prompt_tokens) -> int:
+        """Tokens of the block-aligned prefix of ``prompt_tokens`` that
+        the radix tree holds now: no pin, no count (an admission probe)."""
+        if self.radix is None:
+            return 0
+        with self._plock:
+            return self.radix.lookup(prompt_tokens).shared
+
     def release_plan(self, plan: SeedPlan | None) -> None:
         """Drop an unconsumed seed plan's pins (blocked/stranded/failed
         admissions)."""

@@ -4577,6 +4577,67 @@ class LLMEngine:
 
         return self._run_on_scheduler(work, timeout)
 
+    def _take_waiting(self, n: int) -> list[GenRequest]:
+        """Up to n waiting requests, in order, for n free slots. On a
+        pool that shares blocks, a request whose prompt opens with blocks
+        that a prompt still in prefill (or taken earlier in this pass)
+        will publish stays queued, where those blocks are at least half
+        of what that prompt has left to prefill: once it is published the
+        request seeds them from the radix tree instead of computing them a
+        second time. Sixteen clients asking one new document cost one
+        document's rows, not sixteen (PERF.md section 6). A pass looks at
+        no more than n + slots of the queue, so a long queue of waiting
+        requests costs it no more than that."""
+        if not (self.kv.paged and self.kv.share):
+            pulled = self._waiting[:n]
+            self._waiting = self._waiting[n:]
+            return pulled
+        B = self.kv.block
+        leaders = [
+            (r.prompt_tokens, len(r.prompt_tokens) - r.prefill_pos)
+            for r in self._prefilling
+            if r.slot is not None and self._slot_req[r.slot] is r
+            and not r.prefill_done and not r.cancelled
+        ]
+        pulled: list[GenRequest] = []
+        kept: list[GenRequest] = []
+        for i, r in enumerate(self._waiting):
+            if len(pulled) == n or i == n + self.slots:
+                kept.extend(self._waiting[i:])
+                break
+            toks = r.prompt_tokens
+            if (
+                not r.cancelled and not r.session_id and r.grammar is None
+                and r._aid == 0 and len(toks) > B and self._waits_for(toks, leaders)
+            ):
+                kept.append(r)
+                continue
+            pulled.append(r)
+            leaders.append((toks, len(toks)))
+        self._waiting = kept
+        return pulled
+
+    def _waits_for(self, toks: list[int], leaders: list[tuple[list[int], int]]) -> bool:
+        """Whether a prompt should wait for one of `leaders` (prompt, rows
+        it has left to prefill) to publish the blocks they share."""
+        B = self.kv.block
+        head = toks[:B]
+        cap = ((len(toks) - 1) // B) * B  # lookup_seed's clamp: one row still runs
+        best = None
+        for lt, left in leaders:
+            if lt[:B] != head:
+                continue
+            common = B
+            limit = min(cap, len(lt) - len(lt) % B)
+            while common + B <= limit and lt[common : common + B] == toks[common : common + B]:
+                common += B
+            if best is None or common > best[0]:
+                best = (common, left)
+        if best is None:
+            return False
+        gain = best[0] - self.kv.shared_len(toks)
+        return gain >= B and best[1] <= 2 * gain
+
     def _admit_chunked(self) -> bool:
         """Chunked-scheduler admission: assign waiting requests to
         (virtually) free slots IMMEDIATELY — no wave-fill hold, because
@@ -4600,8 +4661,9 @@ class LLMEngine:
         if not self._waiting or not free:
             return False
         self._fault("admission_oom")  # chaos seam: nothing pulled yet
-        pulled = self._waiting[: len(free)]
-        self._waiting = self._waiting[len(free):]
+        pulled = self._take_waiting(len(free))
+        if not pulled:
+            return False
         self._admitting += len(pulled)
         hits: list[tuple[GenRequest, Any]] = []
         partials: list[tuple[GenRequest, Any]] = []

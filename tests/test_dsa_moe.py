@@ -523,3 +523,54 @@ def test_a_prefix_hit_is_not_queued_behind_cold_prompts(twin):
         assert all(h["seq_last_row"] < rec[r.id]["seq_last_row"] for r in reqs), (h, [rec[r.id] for r in reqs])
     finally:
         eng.close()
+
+
+def _log(eng):
+    log = eng.stats()["request_log"]
+    return {r[log["fields"].index("id")]: dict(zip(log["fields"], r)) for r in log["records"]}
+
+
+def test_clients_asking_one_new_document_prefill_it_once(twin):
+    """Four requests that open with one 48-token document, sent together to
+    an engine that has never seen it: the first prefills the document and
+    the other three wait for its blocks to be published, then seed them
+    from the radix tree (the docs cell's cold ramp: sixteen clients, two
+    documents). Each gets the tokens it gets served alone."""
+    _model, _family, cfg, params = twin
+    kw = dict(slots=4, max_seq_len=128, prefill_chunk=8, prefill_buckets=(8,), step_token_budget=16,
+              decode_chunk=4, warmup=False)
+    doc = _tokens(1, 48, seed=21)[0].tolist()
+    prompts = [doc + [100 + i, 200 + i, 300 + i] for i in range(4)]
+    alone = LLMEngine(cfg, params, **kw)
+    try:
+        want = [alone.generate(p, max_new_tokens=3) for p in prompts]
+    finally:
+        alone.close()
+    eng = LLMEngine(cfg, params, prefix_cache_mb=1, **kw)
+    try:
+        reqs = [eng.submit(GenRequest(p, max_new_tokens=3)) for p in prompts]
+        assert [r.tokens() for r in reqs] == want
+        rec = _log(eng)
+        assert sorted(rec[r.id]["cached"] for r in reqs) == [0, 48, 48, 48]
+        assert sum(rec[r.id]["rows"] for r in reqs) == 7 + 3  # the document's 6 rows and a tail once, 1 row each after
+    finally:
+        eng.close()
+
+
+def test_a_prompt_sharing_little_with_a_long_one_does_not_wait(twin):
+    """A prompt that shares one block with a 96-token prompt still in
+    prefill goes out beside it: waiting would cost it more rows than the
+    block saves."""
+    _model, _family, cfg, params = twin
+    eng = LLMEngine(cfg, params, slots=2, max_seq_len=128, prefill_chunk=8, prefill_buckets=(8,),
+                    step_token_budget=16, decode_chunk=4, warmup=False, prefix_cache_mb=1)
+    try:
+        long_ = _tokens(1, 96, seed=31)[0].tolist()
+        short = long_[:16] + _tokens(1, 24, seed=32)[0].tolist()
+        a = eng.submit(GenRequest(long_, max_new_tokens=2))
+        b = eng.submit(GenRequest(short, max_new_tokens=2))
+        a.tokens(), b.tokens()
+        rec = _log(eng)
+        assert rec[b.id]["cached"] == 0 and rec[b.id]["seq_first_row"] < rec[a.id]["seq_last_row"]
+    finally:
+        eng.close()
